@@ -396,8 +396,10 @@ class ValidationService:
             # device-error@serve-dispatch:N) fires BEFORE staging so a
             # faulted window sheds whole segments, never half-built state
             chaos.fire("serve-dispatch")
-            sw = pbatch.prepare_window(self.params, self.lview, self.eta0,
-                                       whvs)
+            sw = pbatch.prepare_window(
+                self.params, self.lview, self.eta0, whvs,
+                pbatch.window_lanes(self.max_window),
+            )
             carry = None
             if len(segments) == 1:
                 # solo-tenant window: chain the device nonce scan from

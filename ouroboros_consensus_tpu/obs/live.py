@@ -14,8 +14,8 @@ in-run surface for the batched pipeline:
     the recorder's last event, retired window index, headers retired,
     a rolling headers/s, ladder/bg-compile state from the warmup
     notes, and the age since the last observable progress. The bench
-    parent and `scripts/tpu_watchdog.sh` read it to tell *compiling* /
-    *staging* / *running* / *stalled* / *dead* apart in real time.
+    parent reads it to tell *compiling* / *staging* / *running* /
+    *stalled* / *dead* apart in real time.
   * `StallWatchdog` — a monotonic no-progress budget
     (`OCT_STALL_BUDGET_S`). On trip it dumps ALL thread stacks
     (`sys._current_frames` + a raw `faulthandler` twin) plus a
@@ -200,7 +200,7 @@ def _stall_count(rec) -> int:
 def classify(doc: dict | None, now_unix: float | None = None,
              interval_s: float = BEAT_INTERVAL_S) -> str:
     """Reader-side classification of a heartbeat document — the
-    vocabulary the bench parent banks and tpu_watchdog.sh logs:
+    vocabulary the bench parent banks:
 
         no-heartbeat   no document (never armed, or never beat)
         dead           the file stopped being rewritten (> 5 beats old)

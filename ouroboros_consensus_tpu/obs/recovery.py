@@ -29,7 +29,7 @@ not the end of the replay: the supervisor escalates through an explicit
 ladder, each rung a full re-validation of JUST that window —
 
     retry            the same path again, after jittered backoff
-                     (transient tunnel/device blips)
+                     (transient device blips)
     stage-split      the per-lane/stage-split packed path (OCT_VRF_AGG
                      semantics forced off for the call — the
                      materialize_verdicts anomaly taxonomy path)

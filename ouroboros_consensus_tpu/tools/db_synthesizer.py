@@ -597,6 +597,9 @@ def main(argv=None) -> None:
     a = p.parse_args(argv)
     if a.with_ledgers and not a.cardano:
         p.error("--with-ledgers requires --cardano")
+    from .. import compile_cache
+
+    compile_cache.configure()  # before the first trace
     if a.cardano:
         from ..hardfork import composite as cardano
 

@@ -247,8 +247,9 @@ def main(argv=None):
     plat = os.environ.get("JAX_PLATFORMS")
     if plat:
         jax.config.update("jax_platforms", plat)
-    jax.config.update("jax_compilation_cache_dir", "/tmp/ouroboros-jax-cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from ouroboros_consensus_tpu import compile_cache
+
+    compile_cache.configure()
     fns = {1: config1, 2: config2, 3: config3, 4: config4, 5: config5}
     for c in (int(x) for x in args.configs.split(",")):
         fns[c](args.scale, args.tmp)

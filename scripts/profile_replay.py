@@ -54,8 +54,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/ouroboros-jax-cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from ouroboros_consensus_tpu import compile_cache
+
+compile_cache.configure()
 
 ARGS = [a for a in sys.argv[1:] if not a.startswith("--")]
 HOST_ONLY = "--host" in sys.argv[1:]
@@ -358,7 +359,7 @@ def overlap_ab():
 
     path, params, lview = bench.build_or_load_chain()
     stubs.install_stub_crypto()
-    # the simulated device/tunnel wait per window: a sleep inside
+    # the simulated device wait per window: a sleep inside
     # materialize releases the GIL, so staging/prefetch threads overlap
     # it exactly as they would a real device round trip
     twin_ms = float(os.environ.get("OCT_TWIN_DEVICE_MS", "40"))

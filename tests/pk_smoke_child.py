@@ -21,10 +21,12 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir", "/tmp/ouroboros-jax-cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+
+from ouroboros_consensus_tpu import compile_cache
+
+compile_cache.configure()
 
 import numpy as np
 from jax import numpy as jnp

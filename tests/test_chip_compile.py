@@ -1,0 +1,193 @@
+"""Kept rehearsal: the per-lane stage programs of the chip path compile
+for a v5e at the production window width, WITHOUT a chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (`jax.experimental.topologies`). Interpret-mode
+tests cannot see what it refuses — a slice off the tiling, more fast
+memory than a kernel may use — so the stages `chip_smoke.py` dispatches
+on the chip (ops/pk/kernels.verify_praos_packed_split) are compiled
+here at 8192 lanes, KES depth 7, 128-byte proofs. A compile that passes
+is not a chip run.
+
+Tier-1: `unpack` (with and without an epoch nonce), `reduce`, `finish`.
+Marked slow (25–90 s each): `ed`, `kes`, `vrf_bc`, `vrf`.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may hold the TPU library, and every xdist
+worker imports every test file. Keep these tests in this one file.
+"""
+
+import functools
+from fractions import Fraction
+
+import numpy as np
+import pytest
+
+import jax
+from jax.sharding import SingleDeviceSharding
+
+from ouroboros_consensus_tpu.block.forge import forge_block
+from ouroboros_consensus_tpu.ops.pk import hashes
+from ouroboros_consensus_tpu.ops.pk import kernels as K
+from ouroboros_consensus_tpu.protocol import batch as pbatch
+from ouroboros_consensus_tpu.protocol import praos
+from ouroboros_consensus_tpu.testing import fixtures
+
+LANES = 8192
+KES_DEPTH = 7
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip_seams(monkeypatch):
+    """Steer the program's CPU/TPU seams onto their chip branch for the
+    duration of one test, and keep the persistent cache out of it (a
+    deviceless executable is written to the cache but cannot be read
+    back without a chip)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setenv("OCT_PK_INTERPRET", "0")  # real Mosaic lowering
+    monkeypatch.setattr(hashes, "FORCE_IMPL", "unrolled")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _params():
+    return praos.PraosParams(
+        slots_per_kes_period=3600, max_kes_evolutions=62,
+        security_param=2160, active_slot_coeff=Fraction(1, 2),
+        epoch_length=43200, kes_depth=KES_DEPTH,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _packed(has_nonce: bool):
+    """(layout, unpack-argument arrays padded to LANES) of a real
+    default-forged window: 128-byte proofs, real CBOR bodies."""
+    params = _params()
+    pool = fixtures.make_pool(0, kes_depth=KES_DEPTH)
+    lview = fixtures.make_ledger_view([pool])
+    nonce = b"\x07" * 32 if has_nonce else None
+    hvs, prev = [], b"\xaa" * 32
+    for i in range(8):
+        blk = forge_block(params, pool, slot=1000 + i, block_no=500 + i,
+                          prev_hash=prev, epoch_nonce=nonce)
+        hvs.append(blk.header.to_view())
+        prev = blk.header.hash_
+    layout, parr = pbatch.stage_packed(params, lview, nonce, hvs)
+    assert layout.vrf_proof_len == 128 and layout.has_nonce is has_nonce
+    return layout, pbatch.pad_packed_to(parr, LANES)[:10]
+
+
+def _sds(sharding, shape, dtype=np.int32):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+
+def _compile(fn, args):
+    """Lower for the TPU and compile. `fn` is wrapped in a FRESH
+    function so that no trace another test made of the same stage in
+    interpret mode can be reused."""
+
+    def fresh(*a):
+        return fn(*a)
+
+    lowered = jax.jit(fresh).trace(*args).lower(lowering_platforms=("tpu",))
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes + ma.generated_code_size_in_bytes)
+    assert used < V5E_HBM_BYTES
+    return compiled
+
+
+def _limb(one_chip, has_nonce=True):
+    """The 22 limb-first stage inputs `unpack` hands the crypto stages
+    (shapes only — eval_shape of the cheap unpack program)."""
+    layout, cols = _packed(has_nonce)
+    out = jax.eval_shape(K._mk_packed_unpack(layout), *cols)
+    assert len(out) == 22  # batch-compatible layout: announced U, V
+    return [_sds(one_chip, s.shape, s.dtype) for s in out]
+
+
+@pytest.mark.parametrize("has_nonce", [True, False],
+                         ids=["epoch-nonce", "neutral-nonce"])
+def test_unpack_compiles(one_chip, chip_seams, has_nonce):
+    layout, cols = _packed(has_nonce)
+    args = [_sds(one_chip, c.shape, c.dtype) for c in map(np.asarray, cols)]
+    _compile(K._mk_packed_unpack(layout), args)
+
+
+def test_reduce_compiles(one_chip, chip_seams):
+    s = functools.partial(_sds, one_chip)
+    args = [
+        s((5, LANES)), s((32, LANES)), s((LANES,), np.uint8), s(()),
+        s((32,)), s((), np.bool_), s((32,)), s((), np.bool_),
+    ]
+    _compile(K._mk_reduce(True), args)
+
+
+def test_finish_compiles_with_the_kernel(one_chip, chip_seams):
+    # the lane count fixes finish's argument shapes: no eval_shape
+    # through the three heavy kernels (that costs their whole trace)
+    s = functools.partial(_sds, one_chip)
+    args = [
+        s((1, LANES)), s((80, LANES)), s((32, LANES)),  # ed ok, point, R
+        s((1, LANES)), s((80, LANES)), s((32, LANES)),  # kes ok, point, R
+        s((1, LANES)), s((400, LANES)), s((16, LANES)),  # vrf ok, points, c
+        s((64, LANES)), s((32, LANES)), s((32, LANES)),  # beta, thr lo/hi
+    ]
+    compiled = _compile(K.finish, args)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.slow
+def test_ed_compiles(one_chip, chip_seams):
+    limb = _limb(one_chip)
+    compiled = _compile(K.ed_points, [limb[0], limb[2], limb[3], limb[4]])
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.slow
+def test_kes_compiles(one_chip, chip_seams):
+    limb = _limb(one_chip)
+    compiled = _compile(
+        functools.partial(K.kes_points, depth=KES_DEPTH),
+        [limb[5], limb[6], limb[8], limb[9], limb[10], limb[11], limb[12]],
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.slow
+def test_vrf_bc_compiles(one_chip, chip_seams):
+    compiled = _compile(K.vrf_points_bc, _limb(one_chip)[13:19])
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.slow
+def test_vrf_draft03_compiles(one_chip, chip_seams):
+    s = functools.partial(_sds, one_chip)
+    args = [s((32, LANES)), s((32, LANES)), s((16, LANES)),
+            s((32, LANES)), s((32, LANES))]
+    compiled = _compile(K.vrf_points, args)
+    assert "tpu_custom_call" in compiled.as_text()

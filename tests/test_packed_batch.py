@@ -150,6 +150,36 @@ def test_packed_limb_first_matches_pk_arrays(pools, lview):
         assert (a == b).all(), i
 
 
+def test_packed_unpack_pads_kes_hash_column_with_headroom(pools, lview,
+                                                          monkeypatch):
+    """One `kes` program per replay on the chip: the packed unpack
+    stage hands the kes stage the block count a body up to 64 bytes
+    longer would need, so a chain's first header (no prev-hash: 410
+    bytes where the rest are 443-449) shares the others' program. The
+    spare block is zero and each lane keeps its own count."""
+    from ouroboros_consensus_tpu.ops.pk import kernels as K
+
+    assert K.kes_hash_blocks(410) == K.kes_hash_blocks(449) == 5
+    assert K.kes_hash_blocks(304) == 4  # the pinned packed_unpack graph
+    params = make_params()
+    nonce = b"\x07" * 32
+    hvs = real_chain(params, pools, 8)
+    pre = pbatch.host_prechecks(params, lview, hvs)
+    layout, parr = pbatch.stage_packed(params, lview, nonce, hvs)
+    ref = pbatch.pk_arrays(
+        pbatch.stage(params, lview, nonce, hvs, pre.kes_evolution)
+    )
+    k = ref[11].shape[0]
+    monkeypatch.setattr(K, "kes_hash_blocks", lambda body_len: k + 1)
+    got = [np.asarray(x) for x in
+           jax.jit(K._mk_packed_unpack(layout))(*parr[:10])]
+    assert got[11].shape == (k + 1, *ref[11].shape[1:])
+    assert (got[11][:k] == ref[11]).all() and not got[11][k:].any()
+    assert (got[12] == ref[12]).all() and (got[12] == k).all()
+    for i in (j for j in range(22) if j != 11):
+        assert (got[i] == np.asarray(ref[i])).all(), i
+
+
 def test_packed_h2d_bytes_shrink(pools, lview):
     """The wire contract: the packed columns must ship at most HALF the
     staged bytes per lane of the generic SoA path on a real window."""

@@ -5,6 +5,7 @@ same resulting PraosState, the same valid-prefix length, and the same
 first-error class as folding `praos.update` header by header.
 """
 
+import os
 from dataclasses import replace
 from fractions import Fraction
 
@@ -488,3 +489,65 @@ def test_validate_chain_cross_epoch_pipelining(pools, lview):
     )
     assert res.error is None and res.n_valid == len(hvs)
     assert res.state == st
+
+
+@pytest.mark.parametrize("asked,impl,want", [
+    (None, "pk", False),  # the chip's default: per-lane stage kernels
+    (None, "xla", True),  # the twin's default: the aggregate, as before
+    ("1", "pk", True), ("0", "pk", False),  # an explicit lever decides
+    ("1", "xla", True), ("0", "xla", False),
+])
+def test_agg_default_follows_the_implementation(monkeypatch, asked, impl,
+                                                want):
+    if asked is None:
+        monkeypatch.delenv("OCT_VRF_AGG", raising=False)
+    else:
+        monkeypatch.setenv("OCT_VRF_AGG", asked)
+    with pbatch.recovery_overrides(impl=impl):
+        assert pbatch._agg_enabled() is want
+        # the recovery rungs still pin either path over the default
+        with pbatch.recovery_overrides(agg=not want, impl=impl):
+            assert pbatch._agg_enabled() is (not want)
+
+
+def test_one_lane_shape_per_replay_on_pk_only(monkeypatch):
+    """On `pk` every window pads to the caller's max_batch bucket; on
+    the XLA twin windows keep their own buckets."""
+    monkeypatch.delenv("OCT_DEVICE_IMPL", raising=False)
+    with pbatch.recovery_overrides(impl="pk"):
+        assert pbatch.window_lanes(8192) == 8192
+        assert pbatch.window_lanes(5000) == pbatch.bucket_size(5000) == 6144
+    with pbatch.recovery_overrides(impl="xla"):
+        assert pbatch.window_lanes(8192) is None
+    pools = [fixtures.make_pool(i, kes_depth=3) for i in range(2)]
+    lview = fixtures.make_ledger_view(pools)
+    hvs = make_chain(5, pools, lview=lview)
+    own = pbatch.prepare_window(PARAMS, lview, b"\x07" * 32, hvs)
+    fixed = pbatch.prepare_window(PARAMS, lview, b"\x07" * 32, hvs, 64)
+    assert (own.b, own.lanes) == (5, 8) and (fixed.b, fixed.lanes) == (5, 64)
+
+
+@pytest.mark.parametrize("placed", [True, False], ids=["env-set", "env-unset"])
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path, placed):
+    """With JAX_COMPILATION_CACHE_DIR set the function sets no directory
+    in code; without it, the one fixed in-tree path."""
+    import jax
+
+    from ouroboros_consensus_tpu import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    got = compile_cache.configure()
+    dirs = [v for k, v in updates if k.endswith("_cache_dir")]
+    if placed:
+        assert got == str(tmp_path) and dirs == []
+    else:
+        assert got == compile_cache.DEFAULT_DIR and dirs == [got]
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(repo, ".jax_cache")
+    assert ("jax_persistent_cache_min_compile_time_secs", 1.0) in updates
