@@ -9,6 +9,8 @@ package is the TPU build's equivalent surface, all host-side:
   * `recorder`  — the FlightRecorder batch tracer: per-window spans
                   through validate_chain's pipelined loop, fed into the
                   registry (see `OCT_TRACE` below)
+  * `spans`     — self time of the replay's span tree (a span less
+                  its children on the same thread)
   * `warmup`    — compile/warmup forensics: per-stage first-execute
                   walls, pk-AOT load/reject attribution, the bench
                   cache probe; crash-safe JSON via $OCT_WARMUP_REPORT
@@ -36,8 +38,8 @@ Env levers:
                        (db_analyser.revalidate, profile_replay, bench)
   OCT_WARMUP_REPORT=f  flush warmup forensics to `f` after every note
   OCT_LEDGER=d|0       run-ledger directory override / kill-switch
-  OCT_STAGE_RESOURCES  =0 kills per-stage resource capture; =1 forces
-                       it; unset follows the installed recorder
+  OCT_STAGE_RESOURCES  =1 turns per-stage resource capture on; unset
+                       or =0 it is off, recorder installed or not
   OCT_HEARTBEAT=f      rewrite a live JSON heartbeat to `f` every ~2 s
   OCT_STALL_BUDGET_S=n stall watchdog: no-progress budget before an
                        all-thread stack dump (+ oct_stalls_total)
@@ -73,10 +75,7 @@ def enabled() -> bool:
 
 
 def installed() -> bool:
-    """True while at least one install() is outstanding — the default
-    gate for the per-stage resource capture (obs/resources.py): replays
-    that installed the recorder account device resources, bare unit
-    runs pay nothing."""
+    """True while at least one install() is outstanding."""
     with _LOCK:
         return _INSTALL_DEPTH > 0
 
@@ -100,16 +99,13 @@ def install() -> FlightRecorder:
         if _INSTALL_DEPTH == 0:
             from ..protocol import batch as pbatch
 
+            from ..utils.trace import fanout
+
             prev = pbatch.BATCH_TRACER
             _PREV_TRACER = prev
-            if prev is None:
-                pbatch.set_batch_tracer(rec)
-            else:
-                def chained(ev, _prev=prev, _rec=rec):
-                    _prev(ev)
-                    _rec(ev)
-
-                pbatch.set_batch_tracer(chained)
+            pbatch.set_batch_tracer(
+                rec if prev is None else fanout(prev, rec)
+            )
         _INSTALL_DEPTH += 1
     return rec
 

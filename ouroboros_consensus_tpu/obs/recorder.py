@@ -271,6 +271,12 @@ class FlightRecorder:
         report, t0 = self._warmup_state()
         return perfetto.write(path, self.timed_events(), report, t0)
 
+    def self_times(self) -> dict:
+        """{label: self seconds} over the recorded spans (obs/spans)."""
+        from . import spans
+
+        return spans.self_times(ev for _, ev in self.timed_events())
+
     def latency_summary(self) -> dict:
         """p50/p99 of the dispatch->materialize device latency plus the
         per-phase p50s — the serving-north-star numbers (ROADMAP #3)."""

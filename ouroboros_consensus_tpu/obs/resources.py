@@ -39,10 +39,10 @@ read ~0 — argument/output bytes are still real), host C++ (chunk scan,
 native crypto) is invisible, and XLA:CPU memory stats stand in for HBM
 when pins are regenerated off-TPU (`measured_at` records the backend).
 
-Env lever: OCT_STAGE_RESOURCES=0 kills runtime capture; =1 forces it;
-unset, capture follows the flight recorder (active only while obs is
-installed — so bench/profile replays capture, bare test runs pay
-nothing)."""
+Env lever: OCT_STAGE_RESOURCES=1 turns runtime capture on; unset (or
+=0) it is off — an installed flight recorder does not turn it on:
+capture makes every stage's first execute trace and lower its program a
+second time, and tracing must not change what set-up costs."""
 
 from __future__ import annotations
 
@@ -63,17 +63,10 @@ CEILING_KEYS = ("flops", "bytes_accessed", "peak_hbm_bytes")
 
 
 def enabled() -> bool:
-    """Runtime-capture lever: explicit OCT_STAGE_RESOURCES wins; unset
-    follows the flight recorder so replays that installed obs capture
-    and bare unit tests pay zero re-lowers."""
-    v = os.environ.get(_ENV, "")
-    if v == "0":
-        return False
-    if v:
-        return True
-    from . import installed
-
-    return installed()
+    """Runtime-capture lever: on only where OCT_STAGE_RESOURCES says so
+    (a re-lower per stage is not what installing the recorder asks
+    for)."""
+    return os.environ.get(_ENV, "0") not in ("0", "")
 
 
 # ---------------------------------------------------------------------------

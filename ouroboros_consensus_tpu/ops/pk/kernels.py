@@ -72,7 +72,11 @@ def _full_spec(shape):
     )
 
 
-def _call(kernel, b, in_prefixes, out_prefixes, args, with_base8: bool):
+def _call(kernel, name: str, b, in_prefixes, out_prefixes, args,
+          with_base8: bool):
+    """One pallas_call over lane tiles. `name` is the kernel's name in
+    the lowered program and in a device trace (a kernel that is a
+    functools.partial has none of its own)."""
     tile = min(TILE, b)
     assert b % tile == 0
     const_args = []
@@ -89,6 +93,7 @@ def _call(kernel, b, in_prefixes, out_prefixes, args, with_base8: bool):
             jax.ShapeDtypeStruct((*p, b), jnp.int32) for p in out_prefixes
         ),
         interpret=_interpret(),
+        name=name,
     )(*const_args, *args)
 
 
@@ -111,7 +116,7 @@ def ed_points(pk, s, hblocks, hnblocks):
     nb = hblocks.shape[0]
     b = pk.shape[-1]
     return _call(
-        _ed_kernel, b,
+        _ed_kernel, "ed_points", b,
         [(32,), (32,), (nb, 128), (1,)],
         [(1,), (80,)],
         (pk, s, hblocks, hnblocks),
@@ -135,7 +140,7 @@ def kes_points(vk, period, s, vk_leaf, siblings, hblocks, hnblocks, depth):
     nb = hblocks.shape[0]
     b = vk.shape[-1]
     return _call(
-        functools.partial(_kes_kernel, depth), b,
+        functools.partial(_kes_kernel, depth), "kes_points", b,
         [(32,), (1,), (32,), (32,), (depth, 32), (nb, 128), (1,)],
         [(1,), (80,)],
         (vk, period, s, vk_leaf, siblings, hblocks, hnblocks),
@@ -181,14 +186,14 @@ def vrf_points(pk, gamma, c, s, alpha):
     contract as the former single kernel."""
     b = pk.shape[-1]
     ok, prep = _call(
-        _vrf_prep_kernel, b,
+        _vrf_prep_kernel, "vrf_prep", b,
         [(32,), (32,), (16,), (32,), (32,)],
         [(1,), (240,)],
         (pk, gamma, c, s, alpha),
         with_base8=False,
     )
     (pts,) = _call(
-        _vrf_ladder_kernel, b,
+        _vrf_ladder_kernel, "vrf_ladder", b,
         [(16,), (32,), (240,)],
         [(400,)],
         (c, s, prep),
@@ -226,14 +231,14 @@ def vrf_points_bc(pk, gamma, u, v, s, alpha):
     points [400, B]); the derived c16 feeds the unchanged finish stage."""
     b = pk.shape[-1]
     ok, c16, prep = _call(
-        _vrf_bc_prep_kernel, b,
+        _vrf_bc_prep_kernel, "vrf_bc_prep", b,
         [(32,), (32,), (32,), (32,), (32,), (32,)],
         [(1,), (16,), (240,)],
         (pk, gamma, u, v, s, alpha),
         with_base8=False,
     )
     (pts,) = _call(
-        _vrf_ladder_kernel, b,
+        _vrf_ladder_kernel, "vrf_ladder", b,
         [(16,), (32,), (240,)],
         [(400,)],
         (c16, s, prep),
@@ -273,7 +278,7 @@ def finish(ed_ok, ed_pt, ed_r, kes_ok, kes_pt, kes_r, vrf_ok, vrf_pts,
            c, beta_decl, thr_lo, thr_hi):
     b = c.shape[-1]
     return _call(
-        _finish_kernel, b,
+        _finish_kernel, "finish", b,
         [(1,), (80,), (32,), (1,), (80,), (32,), (1,), (400,), (16,),
          (64,), (32,), (32,)],
         [(5,), (32,), (32,)],
@@ -466,10 +471,10 @@ def _capture_resources(stage, fn, args, b, kes_depth, via) -> None:
     """Per-stage device resource accounting (obs/resources.py): the AOT
     executable's analyses are free; the jit path pays one re-lower
     (a trace, no XLA compile) — and only while capture is enabled
-    (OCT_STAGE_RESOURCES / an installed flight recorder). Callers gate
-    this on the stage's FIRST execute and call it AFTER the warmup
-    note, so a kill mid-capture can never eat the compile-wall
-    forensics (the note is already flushed)."""
+    (OCT_STAGE_RESOURCES=1). Callers gate this on the stage's FIRST
+    execute and call it AFTER the warmup note, so a kill mid-capture
+    can never eat the compile-wall forensics (the note is already
+    flushed)."""
     from ...obs import resources as obs_resources
 
     obs_resources.capture_stage(
@@ -484,6 +489,16 @@ def _jit1(key, fn):
 
 
 def _stage_call(name, fn, b, kes_depth, *args):
+    """`_run_stage` inside the span `dispatch.<stage>` (the window's id
+    and the parent `dispatch` come from the enclosing span)."""
+    from ...protocol import batch as pbatch
+
+    stage = "unpack" if name.startswith("unpack_") else name
+    with pbatch._enclose("dispatch." + stage):
+        return _run_stage(name, fn, b, kes_depth, *args)
+
+
+def _run_stage(name, fn, b, kes_depth, *args):
     """Dispatch one stage: precompiled AOT executable when available
     (OCT_PK_AOT=1 + a matching scripts/aot_cache entry — see ops/pk/aot),
     else the per-stage jit. An AOT call that fails at runtime disables
@@ -557,6 +572,15 @@ def _stage_call(name, fn, b, kes_depth, *args):
     return out
 
 
+def kes_points_at(kes_depth: int):
+    """`kes_points` at one depth, under its own name: JAX names the jit
+    of a bare functools.partial `jit__unknown`, and a device trace is
+    read by module name."""
+    fn = functools.partial(kes_points, depth=kes_depth)
+    fn.__name__ = fn.__qualname__ = "kes_points"
+    return fn
+
+
 def split_stage_fns(kes_depth: int):
     """The per-stage jitted callables, keyed for cache warm-up:
     [(name, fn), ...] in dependency order. Used by verify_praos_split
@@ -567,8 +591,7 @@ def split_stage_fns(kes_depth: int):
         ("relayout", _jit1("relayout", staged_to_limb_first)),
         ("relayout_bc", _jit1("relayout_bc", staged_to_limb_first_bc)),
         ("ed", _jit1("ed", ed_points)),
-        ("kes", _jit1(("kes", kes_depth),
-                      functools.partial(kes_points, depth=kes_depth))),
+        ("kes", _jit1(("kes", kes_depth), kes_points_at(kes_depth))),
         ("vrf", _jit1("vrf", vrf_points)),
         ("vrf_bc", _jit1("vrf_bc", vrf_points_bc)),
         ("finish", _jit1("finish", finish)),

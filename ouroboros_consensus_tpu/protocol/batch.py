@@ -47,6 +47,7 @@ boundaries and threads the tiny PraosState between them.
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
@@ -55,6 +56,7 @@ from fractions import Fraction
 from functools import lru_cache
 from typing import Mapping, NamedTuple, Sequence
 
+import jax
 import numpy as np
 from jax import numpy as jnp
 
@@ -1925,7 +1927,8 @@ def stage_any(
 # phases: stage (host CBOR->SoA), dispatch (device kernel launch),
 # materialize (device wait), epilogue (sequential fold). Settable so
 # the embedding application (bench, node, tests) observes per-phase
-# latency without touching the code path.
+# latency without touching the code path. The span vocabulary (label,
+# thread, parent, fields) is obs/README.md's table.
 BATCH_TRACER = None  # None = off (zero overhead on the hot path)
 
 
@@ -1934,17 +1937,47 @@ def set_batch_tracer(tracer) -> None:
     BATCH_TRACER = tracer
 
 
-def _enclose(label):
+class _Null:
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL = _Null()
+
+# identifiers the spans of one replay / one window share. A replay's is
+# allotted by db_analyser.revalidate (`begin_replay`), a window's when
+# the window is enqueued for staging (`next_window_id`).
+_REPLAY_IDS = itertools.count()
+_WINDOW_IDS = itertools.count()
+_REPLAY: int | None = None  # the replay in progress (None outside one)
+
+
+def begin_replay() -> int:
+    global _REPLAY
+    _REPLAY = next(_REPLAY_IDS)
+    return _REPLAY
+
+
+def end_replay() -> None:
+    global _REPLAY
+    _REPLAY = None
+
+
+def next_window_id() -> int:
+    return next(_WINDOW_IDS)
+
+
+def _enclose(label, window=None, parent=None):
+    """The span `label` of the replay in progress; `parent` names the
+    causing span where none encloses it on the emitting thread."""
+    if BATCH_TRACER is None:
+        return _NULL
     from ..utils.trace import Enclose
 
-    class _Null:
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return False
-
-    return Enclose(BATCH_TRACER, label) if BATCH_TRACER is not None else _Null()
+    return Enclose(BATCH_TRACER, label, _REPLAY, window, parent)
 
 
 class _FailedDispatch:
@@ -1963,6 +1996,24 @@ class _FailedDispatch:
         raise self.exc
 
 
+class _WinMeta(NamedTuple):
+    """A window's telemetry between dispatch and retire (None while no
+    tracer is installed)."""
+
+    index: int
+    outcome: str
+    gate: str | None
+    stage_s: float
+    dispatch_s: float
+    lanes_padded: int
+    t_dispatch: float
+    t_stage_start: float
+    t_stage_end: float
+    t_dispatch_start: float
+    stage_thread: str
+    stage_wait_s: float = 0.0
+
+
 class _Dispatched(NamedTuple):
     """Opaque handle between dispatch_batch and materialize_verdicts."""
 
@@ -1971,9 +2022,7 @@ class _Dispatched(NamedTuple):
     carried: bool  # device nonce-scan outputs extend the chain carry
     scan: bool
     out: tuple  # impl-specific device handles
-    # telemetry: (index, outcome, gate, stage_s, dispatch_s,
-    # lanes_padded, t_dispatch) — None when tracing is off
-    meta: tuple | None = None
+    meta: _WinMeta | None = None
 
 
 def _nbytes(arrays) -> int:
@@ -1987,45 +2036,44 @@ def _emit_transfer(phase: str, **kw) -> None:
         BATCH_TRACER(TransferEvent(phase=phase, **kw))
 
 
-# process-wide window dispatch sequence (the WindowStaged/WindowSpan
-# `index`); only advanced while a tracer is installed
-_WIN_SEQ = 0
-
-
-def _win_meta(outcome: str, gate: str | None, b: int, lanes: int,
-              t0: float, t1: float) -> tuple | None:
+def _win_meta(outcome: str, gate: str | None, sw: "_StagedWindow",
+              t_d0: float) -> _WinMeta | None:
     """Build the per-window telemetry meta and emit the WindowStaged
     event. Returns None (zero residual cost) when no tracer is set."""
-    global _WIN_SEQ
     if BATCH_TRACER is None:
         return None
     from ..utils.trace import WindowStaged
 
-    idx = _WIN_SEQ
-    _WIN_SEQ += 1
     t2 = time.monotonic()
-    BATCH_TRACER(
-        WindowStaged(idx, b, lanes, outcome, gate, t1 - t0, t2 - t1)
-    )
-    return (idx, outcome, gate, t1 - t0, t2 - t1, lanes, t2)
+    stage_s, dispatch_s = sw.t1 - sw.t0, t2 - t_d0
+    BATCH_TRACER(WindowStaged(sw.window, sw.b, sw.lanes, outcome, gate,
+                              stage_s, dispatch_s))
+    return _WinMeta(sw.window, outcome, gate, stage_s, dispatch_s,
+                    sw.lanes, t2, sw.t0, sw.t1, t_d0, sw.thread)
 
 
 def _emit_window_span(meta, lanes: int, n_valid: int, failed: bool,
                       t_m0: float, t_m1: float, t_e0: float,
-                      t_done: float) -> None:
+                      t_done: float, inflight_behind: int,
+                      staged_ahead: int) -> None:
     """Emit the retired-window span (dispatch_batch meta + the
-    materialize/epilogue walls measured in the validate_chain loop)."""
+    materialize/tick/epilogue walls and the pipeline's fill measured in
+    the validate_chain loop)."""
     if BATCH_TRACER is None or meta is None:
         return
     from ..utils.trace import WindowSpan
 
-    idx, outcome, gate, stage_s, dispatch_s, _lanes_padded, t_disp = meta
     BATCH_TRACER(WindowSpan(
-        index=idx, lanes=lanes, outcome=outcome, gate=gate,
-        stage_s=stage_s, dispatch_s=dispatch_s,
+        index=meta.index, lanes=lanes, outcome=meta.outcome,
+        gate=meta.gate, stage_s=meta.stage_s, dispatch_s=meta.dispatch_s,
         materialize_s=t_m1 - t_m0, epilogue_s=t_done - t_e0,
-        t_dispatch=t_disp, t_materialized=t_m1, t_done=t_done,
+        t_dispatch=meta.t_dispatch, t_materialized=t_m1, t_done=t_done,
         n_valid=n_valid, failed=failed,
+        stage_wait_s=meta.stage_wait_s, tick_s=t_e0 - t_m1,
+        inflight_behind=inflight_behind, staged_ahead=staged_ahead,
+        t_stage_start=meta.t_stage_start, t_stage_end=meta.t_stage_end,
+        t_dispatch_start=meta.t_dispatch_start,
+        stage_thread=meta.stage_thread,
     ))
 
 
@@ -2045,6 +2093,8 @@ class _StagedWindow(NamedTuple):
     gate: str | None
     t0: float
     t1: float
+    window: int  # the window's id (`next_window_id`)
+    thread: str  # the thread that staged it
 
 
 def window_lanes(max_batch: int) -> int | None:
@@ -2065,8 +2115,8 @@ def window_lanes(max_batch: int) -> int | None:
     return bucket_size(max_batch) if _impl() == "pk" else None
 
 
-def prepare_window(params, lview, eta0, hvs,
-                   lanes: int | None = None) -> _StagedWindow:
+def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
+                   window: int | None = None) -> _StagedWindow:
     """The HOST half of dispatch_batch: prechecks + packed/generic
     staging + bucket padding (to `lanes` when the caller fixes the lane
     count — `window_lanes` — else to the window's own bucket). Pure with
@@ -2074,7 +2124,9 @@ def prepare_window(params, lview, eta0, hvs,
     ledger view), so a producer thread may run it arbitrarily far ahead
     of dispatch — the round-10 staging thread overlaps this wall with
     device compute and the retire-side epilogue work on the main
-    thread."""
+    thread. `window` is the id the caller allotted when it enqueued the
+    window (staging order is dispatch order); without one it is
+    allotted here."""
     from ..testing import chaos
 
     # the staging seam (chaos: staging-thread-death@window:N) — when the
@@ -2084,8 +2136,11 @@ def prepare_window(params, lview, eta0, hvs,
     chaos.fire("stage")
     b = len(hvs)
     size = bucket_size(b) if lanes is None or lanes < b else lanes
+    if window is None:
+        window = next_window_id()
+    thread = threading.current_thread().name
     t0 = time.monotonic()
-    with _enclose("stage"):
+    with _enclose("stage", window):
         pre = host_prechecks(params, lview, hvs)
         packed = None
         gate = None
@@ -2111,13 +2166,13 @@ def prepare_window(params, lview, eta0, hvs,
             h2d = _nbytes(flatten_batch(padded))
             lanes = padded.beta.shape[0]
             return _StagedWindow(pre, None, padded, b, lanes, h2d, gate,
-                                 t0, time.monotonic())
+                                 t0, time.monotonic(), window, thread)
         layout, parr = packed
         parr = pad_packed_to(parr, size)
         h2d = _nbytes(parr)
         lanes = parr.body.shape[0]
     return _StagedWindow(pre, (layout, parr), None, b, lanes, h2d, gate,
-                         t0, time.monotonic())
+                         t0, time.monotonic(), window, thread)
 
 
 def _agg_label(layout, lanes: int, scan: bool,
@@ -2154,13 +2209,12 @@ def dispatch_prepared(sw: _StagedWindow, carry=None, ladder=None):
     # XlaRuntimeError-class failure at window launch — and
     # compile-stall@window:N, a simulated compile wall)
     chaos.fire("dispatch")
-    pre, b, lanes, h2d, gate, t0, t1 = (
-        sw.pre, sw.b, sw.lanes, sw.h2d, sw.gate, sw.t0, sw.t1
-    )
-    with _enclose("dispatch"):
+    pre, b, lanes, gate = sw.pre, sw.b, sw.lanes, sw.gate
+    t_d0 = time.monotonic()
+    with _enclose("dispatch", sw.window):
         _emit_transfer(
-            "dispatch", lanes=lanes, h2d_bytes=h2d,
-            packed=sw.packed is not None,
+            "dispatch", lanes=lanes, h2d_bytes=sw.h2d,
+            packed=sw.packed is not None, window=sw.window,
         )
         if sw.packed is None:
             padded = sw.padded
@@ -2172,7 +2226,7 @@ def dispatch_prepared(sw: _StagedWindow, carry=None, ladder=None):
                     *(jnp.asarray(x) for x in flatten_batch(padded))
                 )
                 impl = "xla"
-            meta = _win_meta("generic", gate, b, lanes, t0, t1)
+            meta = _win_meta("generic", gate, sw, t_d0)
             disp = _Dispatched(impl, False, False, False, out, meta)
             return pre, disp, b, None
         layout, parr = sw.packed
@@ -2217,7 +2271,7 @@ def dispatch_prepared(sw: _StagedWindow, carry=None, ladder=None):
                 *parr, n_real, *cargs
             )
             carry_out = tuple(out[0][1:5]) if scan_mode else None
-            meta = _win_meta("packed-agg", None, b, lanes, t0, t1)
+            meta = _win_meta("packed-agg", None, sw, t_d0)
             disp = _Dispatched(
                 "agg", True, scan_mode, scan_mode,
                 (layout, parr, n_real, cargs, out), meta,
@@ -2236,7 +2290,7 @@ def dispatch_prepared(sw: _StagedWindow, carry=None, ladder=None):
             )
             impl = "xla"
         carry_out = tuple(out[0][1:5]) if scan_mode else None
-        meta = _win_meta("packed", refused_gate, b, lanes, t0, t1)
+        meta = _win_meta("packed", refused_gate, sw, t_d0)
         disp = _Dispatched(impl, True, scan_mode, scan_mode, out, meta)
         return pre, disp, b, carry_out
 
@@ -2572,6 +2626,7 @@ def materialize_verdicts(tagged, b):
     per-lane stage kernels here — exact reference error taxonomy and
     lane isolation, at the cost of one extra round trip on the rare
     dirty window."""
+    window = tagged.meta.index if tagged.meta is not None else None
     if not tagged.packed:
         out = tagged.out
         d2h = int(sum(x.nbytes for x in out))
@@ -2579,11 +2634,13 @@ def materialize_verdicts(tagged, b):
             v = _pk_materialize(out, b)
         else:
             v = Verdicts(*(np.asarray(x)[:b] for x in out))
-        _emit_transfer("materialize", lanes=b, d2h_bytes=d2h, packed=False)
+        _emit_transfer("materialize", lanes=b, d2h_bytes=d2h, packed=False,
+                       window=window)
         return v
     if tagged.impl == "agg":
         layout, parr, n_real, cargs, out = tagged.out
-        pv = _materialize_packed(out, b, "pk", tagged.scan, tagged.carried)
+        pv = _materialize_packed(out, b, "pk", tagged.scan, tagged.carried,
+                                 window)
         if pv.clean():
             return pv
         if BATCH_TRACER is not None:
@@ -2603,35 +2660,42 @@ def materialize_verdicts(tagged, b):
             )
             impl2 = "xla"
         return _materialize_packed(out2, b, impl2, tagged.scan,
-                                   tagged.carried)
+                                   tagged.carried, window)
     return _materialize_packed(tagged.out, b, tagged.impl, tagged.scan,
-                               tagged.carried)
+                               tagged.carried, window)
 
 
-def _materialize_packed(out, b, impl, scan, carried):
+def _materialize_packed(out, b, impl, scan, carried, window=None):
     red, flags, eta, lv = out
-    if scan:
-        masks_d, ev, evs, cand, cands = red
-        masks = np.asarray(masks_d)
-        nonces_out = (
-            np.ascontiguousarray(np.asarray(ev).astype(np.uint8)),
-            bool(np.asarray(evs)),
-            np.ascontiguousarray(np.asarray(cand).astype(np.uint8)),
-            bool(np.asarray(cands)),
-        )
-        eta_u8 = None
-        d2h = masks.nbytes + 2 * 32 + 2
-    else:
-        masks_d, eta_d = red
-        masks = np.asarray(masks_d)
-        eta_u8 = np.asarray(eta_d)[:b]
-        nonces_out = None
-        d2h = masks.nbytes + eta_u8.nbytes
+    # the wait for the device and the D2H copies as two spans: a device
+    # still busy reads as `wait`, a transfer that holds the next window
+    # back as `copy` (the copies below would block on `red` anyway)
+    with _enclose("materialize.wait", window):
+        jax.block_until_ready(red)
+    with _enclose("materialize.copy", window):
+        if scan:
+            masks_d, ev, evs, cand, cands = red
+            masks = np.asarray(masks_d)
+            nonces_out = (
+                np.ascontiguousarray(np.asarray(ev).astype(np.uint8)),
+                bool(np.asarray(evs)),
+                np.ascontiguousarray(np.asarray(cand).astype(np.uint8)),
+                bool(np.asarray(cands)),
+            )
+            eta_u8 = None
+            d2h = masks.nbytes + 2 * 32 + 2
+        else:
+            masks_d, eta_d = red
+            masks = np.asarray(masks_d)
+            eta_u8 = np.asarray(eta_d)[:b]
+            nonces_out = None
+            d2h = masks.nbytes + eta_u8.nbytes
     pv = PackedVerdicts(
         masks, b, impl, carried, nonces_out, eta_u8,
         (flags, eta, lv),
     )
-    _emit_transfer("materialize", lanes=b, d2h_bytes=d2h, packed=True)
+    _emit_transfer("materialize", lanes=b, d2h_bytes=d2h, packed=True,
+                   window=window)
     return pv
 
 
@@ -2942,23 +3006,27 @@ def validate_chain(
     # keeps staging/dispatching while the worker waits, so host staging
     # hides behind device execution even when the backend only makes
     # progress under a blocking read
-    pool = None
-    if backend == "device":
-        from concurrent.futures import ThreadPoolExecutor
+    with _enclose("validate-chain"):
+        pool = None
+        if backend == "device":
+            from concurrent.futures import ThreadPoolExecutor
 
-        pool = ThreadPoolExecutor(max_workers=1)
-    try:
-        return _validate_chain_loop(
-            params, ledger_view_for_epoch, state, hvs, max_batch, backend,
-            pipeline_depth, mesh, pool,
-        )
-    finally:
-        if pool is not None:
-            # cancel_futures: on an early error return the queued
-            # materialize futures belong to DISCARDED windows — without
-            # it the worker keeps issuing blocking device reads for
-            # results nobody wants and the atexit join stalls exit
-            pool.shutdown(wait=False, cancel_futures=True)
+            pool = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="oct-read"
+            )
+        try:
+            return _validate_chain_loop(
+                params, ledger_view_for_epoch, state, hvs, max_batch,
+                backend, pipeline_depth, mesh, pool,
+            )
+        finally:
+            if pool is not None:
+                # cancel_futures: on an early error return the queued
+                # materialize futures belong to DISCARDED windows —
+                # without it the worker keeps issuing blocking device
+                # reads for results nobody wants and the atexit join
+                # stalls exit
+                pool.shutdown(wait=False, cancel_futures=True)
 
 
 def _epoch_segments_idx(params, hvs) -> list[tuple[int, int, int]]:
@@ -3160,17 +3228,19 @@ def _device_loop(
             # segmentation never changes verdicts or the first error)
             j = _proof_break(hvs, w, j)
             whvs = hvs[w:j]
+            # the window's id: staging order is dispatch order
+            win = next_window_id()
             if stage_pool is not None:
                 item = stage_pool.submit(
                     prepare_window, params, lview_for(s_stage),
-                    eta_known[s_stage], whvs, lanes,
+                    eta_known[s_stage], whvs, lanes, win,
                 )
             else:
                 item = prepare_window(
                     params, lview_for(s_stage), eta_known[s_stage], whvs,
-                    lanes,
+                    lanes, win,
                 )
-            staged.append((s_stage, whvs, w, item))
+            staged.append((s_stage, whvs, w, item, win))
             w = j
             if w >= seg_end:
                 s_stage += 1
@@ -3195,12 +3265,22 @@ def _device_loop(
         # materialize retire while the producer keeps staging
         nonlocal carry, carry_ok
         while staged and len(inflight) < pipeline_depth:
-            s_w, whvs_w, w_start_w, item = staged[0]
+            s_w, whvs_w, w_start_w, item, win = staged[0]
+            stage_wait_s = 0.0
             if stage_pool is not None and hasattr(item, "result"):
-                if not item.done() and inflight:
+                late = not item.done()
+                if late and inflight:
                     break
                 try:
-                    item = item.result()
+                    if late:
+                        # staging-thread lateness: nothing is in flight
+                        # and the head window is not staged yet
+                        t_w0 = time.monotonic()
+                        with _enclose("stage-wait", win):
+                            item = item.result()
+                        stage_wait_s = time.monotonic() - t_w0
+                    else:
+                        item = item.result()
                 except Exception as e:  # noqa: BLE001 — gated below
                     # the staging producer died mid-prepare: the window
                     # recovers at its retire slot (full re-validation)
@@ -3230,8 +3310,11 @@ def _device_loop(
                 carry_ok = False
             else:
                 carry = carry_out
+            meta = out.meta
+            if meta is not None and stage_wait_s:
+                meta = meta._replace(stage_wait_s=stage_wait_s)
             inflight.append(
-                (s_w, whvs_w, w_start_w, pre, out.meta,
+                (s_w, whvs_w, w_start_w, pre, meta,
                  pool.submit(materialize_verdicts, out, b))
             )
 
@@ -3272,18 +3355,24 @@ def _device_loop(
         enqueue_staging()
 
         s_b, whvs, w_start, pre, meta, fut = inflight.popleft()
+        win = meta.index if meta is not None else None
+        # the pipeline's fill as this window's retire wait begins
+        inflight_behind, staged_ahead = len(inflight), len(staged)
         t_m0 = time.monotonic()
         fail: BaseException | None = None
         v = None
         try:
-            with _enclose("materialize"):
+            with _enclose("materialize", win):
                 v = fut.result()
         except Exception as e:  # noqa: BLE001 — gated by _queue_failure
             if not _queue_failure(e):
                 raise
             fail = e
         t_m1 = time.monotonic()
-        ticked = praos.tick(params, lview_for(s_b), _slot_at(whvs, 0), state)
+        with _enclose("tick", win):
+            ticked = praos.tick(
+                params, lview_for(s_b), _slot_at(whvs, 0), state
+            )
         if w_start == segments[s_b][1]:
             # first batch of a segment staged with a LOOKAHEAD nonce:
             # the real rotation must agree (internal invariant)
@@ -3293,7 +3382,7 @@ def _device_loop(
         t_e0 = time.monotonic()
         if fail is None:
             try:
-                with _enclose("epilogue"):
+                with _enclose("epilogue", win):
                     res = _epilogue(params, ticked, whvs, pre, v)
             except Exception as e:  # noqa: BLE001 — gated below
                 if not _queue_failure(e):
@@ -3315,7 +3404,8 @@ def _device_loop(
         total_valid += res.n_valid
         _emit_window_span(
             meta, len(whvs), res.n_valid, res.error is not None,
-            t_m0, t_m1, t_e0, time.monotonic(),
+            t_m0, t_m1, t_e0, time.monotonic(), inflight_behind,
+            staged_ahead,
         )
         if res.error is not None:
             return BatchResult(state, total_valid, res.error)

@@ -64,13 +64,15 @@ class ValidationResult:
 class _PhaseCollector:
     """Batch tracer aggregating per-phase wall time + boundary bytes
     (Enclose brackets and TransferEvents from protocol/batch.py).
-    Materialize events arrive from the reader worker thread; the +=
-    updates are GIL-atomic enough for accounting."""
+    Events arrive from the staging, reader and prefetch threads too; the
+    += updates and the list append are GIL-atomic enough for
+    accounting."""
 
     def __init__(self):
         from collections import defaultdict
 
         self.wall = defaultdict(float)
+        self.spans: list = []  # end edges, for the self times
         self.h2d = 0
         self.d2h = 0
         self.windows = 0
@@ -82,6 +84,7 @@ class _PhaseCollector:
         if isinstance(ev, EncloseEvent):
             if ev.edge == "end":
                 self.wall[ev.label] += ev.duration
+                self.spans.append(ev)
         elif isinstance(ev, TransferEvent):
             if ev.phase == "dispatch":
                 self.h2d += ev.h2d_bytes
@@ -92,7 +95,13 @@ class _PhaseCollector:
                 self.d2h += ev.d2h_bytes
 
     def fill(self, res: "ValidationResult") -> None:
+        from ..obs import spans as obs_spans
+
         res.phases = dict(self.wall)
+        # "<label>.self": the label's wall less what its child spans on
+        # the same thread cover
+        for label, self_s in obs_spans.self_times(self.spans).items():
+            res.phases[label + ".self"] = self_s
         res.h2d_bytes = self.h2d
         res.d2h_bytes = self.d2h
         res.n_windows = self.windows
@@ -308,7 +317,7 @@ def _stream_windows(imm: ImmutableDB, res: "ValidationResult"):
         # extraction is the "stream" span of the flight recorder (one
         # Enclose bracket per CHUNK — per-window granularity, no object
         # tax); pbatch._enclose is a no-op while no tracer is installed
-        with pbatch._enclose("stream"):
+        with pbatch._enclose("stream", parent="replay"):
             data = _read_chunk(
                 os.path.join(imm.path, _chunk_name(n)), chunk_idx
             )
@@ -650,6 +659,7 @@ def revalidate(
         if installed:
             obs.uninstall()
         raise
+    pbatch.begin_replay()  # the id every span of this replay carries
     try:
         return _revalidate_traced(
             db_path, params, lview, backend, validate_all, max_batch,
@@ -657,6 +667,7 @@ def revalidate(
             resume, repair, network_magic,
         )
     finally:
+        pbatch.end_replay()
         if plane is not None:
             plane.disarm()
         if installed:
@@ -668,31 +679,25 @@ def _revalidate_traced(
     max_headers, trace, ledger, genesis_state, collect_phases, resume,
     repair, network_magic,
 ) -> ValidationResult:
-    if collect_phases:
-        coll = _PhaseCollector()
-        prev = pbatch.BATCH_TRACER
+    args = (db_path, params, lview, backend, validate_all, max_batch,
+            max_headers, trace, ledger, genesis_state, resume, repair,
+            network_magic)
+    if not collect_phases:
+        with pbatch._enclose("replay"):
+            return _revalidate_impl(*args)
+    from ..utils.trace import fanout
 
-        def chained(ev, _prev=prev, _coll=coll):
-            if _prev is not None:
-                _prev(ev)
-            _coll(ev)
-
-        pbatch.set_batch_tracer(chained)
-        try:
-            res = _revalidate_impl(
-                db_path, params, lview, backend, validate_all, max_batch,
-                max_headers, trace, ledger, genesis_state, resume,
-                repair, network_magic,
-            )
-        finally:
-            pbatch.set_batch_tracer(prev)
-        coll.fill(res)
-        return res
-    return _revalidate_impl(
-        db_path, params, lview, backend, validate_all, max_batch,
-        max_headers, trace, ledger, genesis_state, resume, repair,
-        network_magic,
-    )
+    coll = _PhaseCollector()
+    prev = pbatch.BATCH_TRACER
+    pbatch.set_batch_tracer(coll if prev is None else fanout(prev, coll))
+    try:
+        # opened once the collector is chained: res.phases holds it
+        with pbatch._enclose("replay"):
+            res = _revalidate_impl(*args)
+    finally:
+        pbatch.set_batch_tracer(prev)
+    coll.fill(res)
+    return res
 
 
 def _revalidate_impl(
@@ -749,7 +754,8 @@ def _revalidate_impl(
             )
             repair = True
         res.opened_dirty = guard.opened_dirty
-        imm = open_immutable(db_path, validate_all=policy, repair=repair)
+        with pbatch._enclose("open"):
+            imm = open_immutable(db_path, validate_all=policy, repair=repair)
         res.open_s = time.monotonic() - t0
         out = _revalidate_body(
             imm, res, t0, db_path, params, lview, backend, max_batch,
@@ -913,7 +919,14 @@ def _revalidate_body(
                 # staging thread then overlaps prechecks+staging within
                 # the segment
                 segs = _prefetch_iter(segs, depth=2)
-            for seg in segs:
+            segs, end = iter(segs), object()
+            while True:
+                # the main thread's wait for the stream (the prefetch
+                # thread's, or the stream itself when inline)
+                with pbatch._enclose("segment-wait"):
+                    seg = next(segs, end)
+                if seg is end:
+                    break
                 ts = time.monotonic()
                 result = pbatch.validate_chain(
                     params, lambda _e: lview, st, seg,

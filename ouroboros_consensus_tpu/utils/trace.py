@@ -13,6 +13,7 @@ embedding application's job, exactly as in the reference (§5.5)."""
 
 from __future__ import annotations
 
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable
@@ -59,32 +60,50 @@ class ListTracer:
         self.events.append(ev)
 
 
-def stderr_tracer(prefix: str = "") -> Tracer:
-    """db-analyser-style locked stderr tracer with monotonic timestamps
-    (DBAnalyser/Run.hs:122-131)."""
-    import sys
-    import threading
-
-    lock = threading.Lock()
-    t0 = time.monotonic()
-
-    def t(ev):
-        with lock:
-            print(f"[{time.monotonic() - t0:10.3f}] {prefix}{ev}", file=sys.stderr)
-
-    return t
-
-
 @dataclass(frozen=True)
 class EncloseEvent:
     """Start/end bracket (Util/Enclose.hs RisingEdge/FallingEdge).
     Frozen like every other event dataclass: the end edge is a NEW
-    event carrying the duration, never a mutated start event."""
+    event carrying the duration, never a mutated start event.
+
+    `replay` and `window` are the identifiers the spans of one replay /
+    one device window share; `parent` is the label of the span that
+    caused this one (the enclosing span on the same thread, else the
+    cause the emitter names); `thread` is the emitting thread's name —
+    a span's self time counts only children on its own thread
+    (obs/spans.self_times)."""
 
     label: str
     edge: str  # "start" | "end"
     t: float
     duration: float | None = None  # set on the end edge
+    replay: int | None = None
+    window: int | None = None
+    parent: str | None = None
+    thread: str = ""
+
+
+# the open Enclose spans of each thread, innermost last: a span opened
+# inside another on the same thread takes it as parent and inherits its
+# identifiers
+_OPEN = threading.local()
+
+_ANNOTATION: Any = None  # jax.profiler.TraceAnnotation, False without JAX
+
+
+def _annotation():
+    """jax.profiler.TraceAnnotation, imported on the first span of a
+    traced run (this module stays importable without JAX); None where
+    JAX is not installed."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _ANNOTATION = TraceAnnotation
+        except ImportError:
+            _ANNOTATION = False
+    return _ANNOTATION or None
 
 
 class Enclose:
@@ -92,21 +111,62 @@ class Enclose:
 
         with Enclose(tracer, "volatile-write"):
             ...
-    """
 
-    def __init__(self, tracer: Tracer, label: str):
+    The same span is written into the profiler's own timeline as the
+    `TraceAnnotation` "oct:<label>" (with its replay / window / thread),
+    on the profiler's clock and on the line of the thread that did the
+    work — so a device trace needs no offset to show what the host was
+    doing. Outside a profiler session the annotation costs one flag
+    test in native code."""
+
+    __slots__ = ("tracer", "label", "replay", "window", "parent", "thread",
+                 "_t0", "_ann")
+
+    def __init__(self, tracer: Tracer, label: str, replay: int | None = None,
+                 window: int | None = None, parent: str | None = None):
         self.tracer = tracer
         self.label = label
+        self.replay = replay
+        self.window = window
+        self.parent = parent
+        self.thread = ""
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        if stack:
+            outer = stack[-1]
+            self.parent = outer.label
+            if self.replay is None:
+                self.replay = outer.replay
+            if self.window is None:
+                self.window = outer.window
+        stack.append(self)
+        self.thread = threading.current_thread().name
+        annotation = _annotation()
+        if annotation is not None:
+            ids = {k: v for k, v in (("replay", self.replay),
+                                     ("window", self.window)) if v is not None}
+            self._ann = annotation("oct:" + self.label, thread=self.thread,
+                                   **ids)
+            self._ann.__enter__()
         self._t0 = time.monotonic()
-        self.tracer(EncloseEvent(self.label, "start", self._t0))
+        self.tracer(EncloseEvent(self.label, "start", self._t0, None,
+                                 self.replay, self.window, self.parent,
+                                 self.thread))
         return self
 
     def __exit__(self, *exc):
         t1 = time.monotonic()
-        self.tracer(EncloseEvent(self.label, "end", t1, t1 - self._t0))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _OPEN.stack.pop()
+        self.tracer(EncloseEvent(self.label, "end", t1, t1 - self._t0,
+                                 self.replay, self.window, self.parent,
+                                 self.thread))
         return False
 
 
@@ -123,6 +183,7 @@ class TransferEvent:
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     packed: bool = False  # packed staging / packed verdict path
+    window: int | None = None  # the window's id (WindowStaged.index)
 
 
 # -- per-window pipeline spans (the obs/ flight-recorder vocabulary) ---------
@@ -137,7 +198,9 @@ class WindowStaged:
     packed wire declined, WHICH qualification gate said no (the PR 5
     columnar/packed gates were silent about why a window fell back)."""
 
-    index: int  # process-wide dispatch sequence number
+    index: int  # the window's id, allotted when it was enqueued for
+    # staging (staging order is dispatch order); the `window` of every
+    # span and TransferEvent of the window
     lanes: int  # true window size (pre bucket pad)
     lanes_padded: int
     outcome: str  # "packed-agg" | "packed" | "generic"
@@ -306,6 +369,17 @@ class WindowSpan:
     t_done: float  # monotonic after the epilogue
     n_valid: int
     failed: bool  # this window carried the chain's first error
+    stage_wait_s: float = 0.0  # main thread blocked on the staging future
+    tick_s: float = 0.0  # praos.tick between materialize and epilogue
+    # read when this window's retire wait began: windows dispatched and
+    # not yet retired behind it (did the device have its next window
+    # queued?), and windows staged and not yet dispatched
+    inflight_behind: int = 0
+    staged_ahead: int = 0
+    t_stage_start: float = 0.0  # monotonic, on `stage_thread`
+    t_stage_end: float = 0.0
+    t_dispatch_start: float = 0.0
+    stage_thread: str = ""
 
 
 # -- the consensus event vocabulary (Tracers' record, condensed) -------------

@@ -248,7 +248,7 @@ def main():
                   limb[12]]
         nv = 6 if bc else 5  # vrf column count
         vrf_in = limb[13:13 + nv]
-        kes_fn = functools.partial(K.kes_points, depth=KES_DEPTH)
+        kes_fn = K.kes_points_at(KES_DEPTH)
         ed_out = jax.eval_shape(K.ed_points, *ed_in)
         kes_out = jax.eval_shape(kes_fn, *kes_in)
         vrf_name = "vrf_bc" if bc else "vrf"

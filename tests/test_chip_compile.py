@@ -161,6 +161,24 @@ def test_finish_compiles_with_the_kernel(one_chip, chip_seams):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_finish_lowers_with_its_kernel_name(one_chip, chip_seams):
+    """What a device trace shows of a Pallas kernel is its `name` in the
+    Mosaic custom call (tests/test_span_tree.py holds the module names).
+    Lowered only, at one tile of lanes: the kernel body is traced
+    whatever the lane count."""
+
+    def fresh(*a):  # no interpret-mode trace of K.finish to reuse
+        return K.finish(*a)
+
+    s = functools.partial(_sds, one_chip)
+    args = [s((p, K.TILE))
+            for p in (1, 80, 32, 1, 80, 32, 1, 400, 16, 64, 32, 32)]
+    text = jax.jit(fresh).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    assert 'kernel_name = "finish"' in text
+
+
 @pytest.mark.slow
 def test_ed_compiles(one_chip, chip_seams):
     limb = _limb(one_chip)
@@ -172,7 +190,7 @@ def test_ed_compiles(one_chip, chip_seams):
 def test_kes_compiles(one_chip, chip_seams):
     limb = _limb(one_chip)
     compiled = _compile(
-        functools.partial(K.kes_points, depth=KES_DEPTH),
+        K.kes_points_at(KES_DEPTH),
         [limb[5], limb[6], limb[8], limb[9], limb[10], limb[11], limb[12]],
     )
     assert "tpu_custom_call" in compiled.as_text()

@@ -86,6 +86,11 @@ def _span(index=0, n_valid=8):
 
 
 def test_live_snapshot_phase_from_last_event():
+    from ouroboros_consensus_tpu.obs.warmup import WARMUP
+
+    # process-wide: a file that ran before this one in the worker and
+    # noted a first execute would read as "warmup", not "idle"
+    WARMUP.reset()
     rec = obs.recorder()
     doc = live.live_snapshot(rec)
     assert doc["phase"] == "idle" and doc["headers"] == 0

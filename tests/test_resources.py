@@ -114,13 +114,14 @@ def test_capture_lever(monkeypatch):
     monkeypatch.setenv("OCT_STAGE_RESOURCES", "1")
     assert R.capture_stage("lever@b4", fn, args, lanes=4)
     assert "lever@b4|4|None" in R.RESOURCES.report()
-    # unset: follows the installed recorder
+    # unset: off, and installing the recorder does not turn it on
+    # (capture re-lowers every stage: tracing must not change set-up)
     monkeypatch.delenv("OCT_STAGE_RESOURCES")
     R.RESOURCES.reset()
     assert not R.enabled()
     obs.install()
     try:
-        assert R.enabled()
+        assert not R.enabled()
     finally:
         obs.uninstall()
     assert not R.enabled()
