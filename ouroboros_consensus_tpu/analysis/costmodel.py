@@ -426,7 +426,10 @@ def predicted_wall(name: str) -> float | None:
 # twin. The per-stage pk jits wrap exactly the *_core programs plus
 # relayout glue; the packed/fused monoliths map to the composed
 # registry graphs. `unpack_<digest>` stage names (layout-keyed) all
-# resolve to packed_unpack.
+# resolve to packed_unpack. The `reduce` stage (bit packing and a cast:
+# protocol/batch.verdict_pack) has no registered twin: the
+# `verdict_reduce` graph is the retired on-device nonce scan, so its
+# hash must not ride that stage's first-execute note.
 STAGE_GRAPHS: dict[str, str] = {
     "ed": "ed_core",
     "kes": "kes_core",
@@ -436,8 +439,6 @@ STAGE_GRAPHS: dict[str, str] = {
     "relayout": "packed_unpack",
     "relayout_bc": "packed_unpack",
     "unpack": "packed_unpack",
-    "reduce": "verdict_reduce",
-    "reduce_noscan": "verdict_reduce",
     "agg-packed": "aggregate_core",
     "agg-vrf": "aggregate_vrf_core",
     "xla-packed": "verify_praos_core_bc",

@@ -781,7 +781,7 @@ def _check_levers(ctx: _Ctx, rel_to: str) -> list[str]:
     def levers_of(expr: ast.AST, consts: dict,
                   env: dict[str, set[str]]) -> set[str]:
         """Levers an expression is derived from: direct env reads,
-        lever-derived names (`NONCE_SCAN and carry is not None`), and
+        lever-derived names (`PACKED_STAGE and not fused`), and
         predicate calls (`columnar = _columnar_enabled()`)."""
         out = set(_reads_in(expr, consts, levers))
         for t in ast.walk(expr):
@@ -794,7 +794,7 @@ def _check_levers(ctx: _Ctx, rel_to: str) -> list[str]:
                         out.add(L)
         return out
 
-    # phase 2: module-level lever-derived names (`NONCE_SCAN = ...`)
+    # phase 2: module-level lever-derived names (`PACKED_STAGE = ...`)
     mod_vars: dict[str, dict[str, set[str]]] = {}
     for model in ctx.pkg.modules.values():
         mv: dict[str, set[str]] = {}

@@ -401,12 +401,12 @@ def _graph_packed_unpack(t=None):
 
 
 def _graph_verdict_reduce(t=None):
-    """The packed D2H reduction (protocol/batch.verdict_reduce,
-    scan=True): verdict-bit packing + the sequential Blake2b nonce scan
+    """The round-6 packed D2H reduction (protocol/batch.verdict_reduce):
+    verdict-bit packing + the sequential Blake2b nonce scan
     (ops/blake2b.nonce_fold_scan). The scan body is a separate
-    computation (lax.scan fences the chain)."""
-    import functools
-
+    computation (lax.scan fences the chain). No dispatch path runs it
+    since PR 29 (the host folds); it stays registered until its goldens
+    go with it (ROADMAP)."""
     import jax
     from jax import numpy as jnp
 
@@ -421,7 +421,7 @@ def _graph_verdict_reduce(t=None):
         _s(5, b), _s(b, 32), _s(b), _s(),
         _s(32), bl(), _s(32), bl(),
     )
-    return functools.partial(pbatch.verdict_reduce, scan=True), args
+    return pbatch.verdict_reduce, args
 
 
 def _graph_forge_sweep(t=None):

@@ -26,10 +26,9 @@ vLLM-style slot reuse) — to header validation:
     cross-lane state is the sequential fold, which never runs on shared
     lanes: each tenant's epilogue folds its own segment against its own
     state, so lanes from different tenants cannot bleed into each
-    other's verdicts by construction. A window with a single tenant
-    additionally chains the on-device nonce-scan carry from that
-    tenant's host state (`_state_carry`) — the per-chain device carry
-    of the replay plane, preserved per tenant;
+    other's verdicts by construction. Nothing is carried on the device
+    from one window to the next: the nonce fold is the host's, inside
+    each tenant's epilogue;
   * admission is priced (protocol/admission.py): a cold tenant whose
     window shape misses the warm/AOT store rides the warm-compile rung
     ladder instead of stalling warm traffic;
@@ -400,15 +399,7 @@ class ValidationService:
                 self.params, self.lview, self.eta0, whvs,
                 pbatch.window_lanes(self.max_window),
             )
-            carry = None
-            if len(segments) == 1:
-                # solo-tenant window: chain the device nonce scan from
-                # the tenant's host state (the replay plane's per-chain
-                # carry, preserved per tenant)
-                carry = pbatch._state_carry(segments[0][0].state)
-            pre, tagged, b, _carry_out = pbatch.dispatch_prepared(
-                sw, carry=carry
-            )
+            pre, tagged, b = pbatch.dispatch_prepared(sw)
             v = pbatch.materialize_verdicts(tagged, b)
             results = []
             if len(segments) == 1:

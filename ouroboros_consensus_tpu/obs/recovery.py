@@ -116,8 +116,8 @@ def encode_state(st) -> dict:
     """PraosState -> a JSON-safe dict. The checkpoint is the WHOLE
     sequential fold state: nonce carry, per-pool counter map, last
     slot — everything `validate_chain` threads between windows.
-    (Device-resident carry is NOT here by design: resume re-seeds the
-    device nonce scan from this host record — COVERAGE.md §5.16.)"""
+    (Nothing of the fold lives on the device: the host folds the
+    nonces window by window — COVERAGE.md §5.16.)"""
     return {
         "last_slot": st.last_slot,
         "ocert_counters": {k.hex(): int(v)

@@ -269,16 +269,22 @@ def _hash_spans_device(msgs, digest_size: int) -> np.ndarray:
 
 
 def nonce_fold_scan(etas, within, is_real, ev0, ev0_set, cand0, cand0_set):
-    """Device-side Praos nonce fold: `jax.lax.scan` of the evolving /
-    candidate nonce bookkeeping over a window's per-lane eta values,
-    mirroring protocol/nonces.combine + protocol/praos.reupdate exactly.
+    """REFERENCE ONLY (protocol/batch.verdict_reduce; no dispatch path
+    reaches it). Device-side Praos nonce fold: `jax.lax.scan` of the
+    evolving / candidate nonce bookkeeping over a window's per-lane eta
+    values, mirroring protocol/nonces.combine + protocol/praos.reupdate
+    exactly.
 
     The combine is a NON-associative hash fold (eta' = Blake2b-256(eta ‖
-    v), neutral = identity), so the scan is inherently sequential — but
-    running it on device means `materialize_verdicts` transfers ONE
-    32-byte nonce pair per window instead of the full [B, 32] eta column
-    (protocol/batch.py D2H contract; the host epilogue keeps the exact
-    per-lane fold as the slow path).
+    v), neutral = identity), so the scan is one unbatched compression a
+    lane: 0.40 ms a step on a v5e, 3.3 s a window of 8192 lanes, 97.9%
+    of a replay (PERF.md, PRs 27-29). It ran on the device from round 6
+    to save the D2H of the [B, 32] eta column (262 KB a window, under a
+    millisecond on an attached chip); the host folds that column in
+    12 ms (protocol/batch._fold_nonces), so PR 29 took it off every
+    dispatch path. Kept for the analysis goldens that trace it and the
+    test that holds it equal to the host fold; ROADMAP queues its
+    deletion.
 
       etas     [B, 32] int32 bytes — vrfNonceValue per lane
       within   [B] bool — slot within the stability window (candidate

@@ -648,21 +648,14 @@ def _mk_packed_unpack(layout):
     return unpack_limb
 
 
-def _mk_reduce(scan: bool):
-    """Factory for the packed `reduce` stage: verdict-bit packing + the
-    on-device nonce scan (protocol/batch.verdict_reduce) over the finish
-    stage's limb-first outputs."""
+def reduce_fn(flags, eta):
+    """The packed `reduce` stage: verdict-bit packing plus the uint8 eta
+    column (protocol/batch.verdict_pack) over the finish stage's
+    limb-first outputs. No loop: the evolving/candidate nonce fold is a
+    hash chain and runs on the host, in the retire path."""
+    from ...protocol import batch as pbatch
 
-    def reduce_fn(flags, eta, within, n_real, ev0, ev0_set, cand0,
-                  cand0_set):
-        from ...protocol import batch as pbatch
-
-        return pbatch.verdict_reduce(
-            flags, jnp.transpose(eta), within, n_real,
-            ev0, ev0_set, cand0, cand0_set, scan=scan,
-        )
-
-    return reduce_fn
+    return pbatch.verdict_pack(flags, jnp.transpose(eta))
 
 
 def packed_unpack_name(layout) -> str:
@@ -679,19 +672,16 @@ def packed_unpack_name(layout) -> str:
 
 def verify_praos_packed_split(
     layout, body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-    thr_idx, thr_tab, nonce, within, n_real,
-    ev0, ev0_set, cand0, cand0_set, *, scan: bool,
+    thr_idx, thr_tab, nonce,
 ):
     """The packed production dispatch: `unpack` (device limb
     decomposition of the packed wire format) -> the UNCHANGED
     ed/kes/vrf/finish stage jits/AOT executables -> `reduce` (verdict
-    bitmasks + nonce scan). Returns (reduce outputs, flags, eta,
-    leader_value) with the per-lane arrays left on device."""
+    bitmasks + the uint8 eta column). Returns (reduce outputs, flags,
+    eta, leader_value) with the per-lane arrays left on device."""
     kes_depth = layout.kes_depth
     stages = dict(split_stage_fns(kes_depth))
     unpack = _jit1(("unpack", layout), _mk_packed_unpack(layout))
-    reduce_ = _jit1(("reduce", scan), _mk_reduce(scan))
-    reduce_name = "reduce" if scan else "reduce_noscan"
     b = np.asarray(body).shape[0]
     a = _stage_call(
         packed_unpack_name(layout), unpack, b, kes_depth,
@@ -734,8 +724,7 @@ def verify_praos_packed_split(
         l_vrf_c, l_beta, l_tlo, l_thi,
     )
     red = _stage_call(
-        reduce_name, reduce_, b, kes_depth,
-        flags, eta, within, n_real, ev0, ev0_set, cand0, cand0_set,
+        "reduce", _jit1("reduce", reduce_fn), b, kes_depth, flags, eta
     )
     return red, flags, eta, lv
 

@@ -77,7 +77,7 @@ class WindowShape:
     def stage_label(self, lanes: int) -> str:
         """Warmup-vocabulary stage label for preflight pricing (the
         xla-packed label family of protocol/batch._jitted_packed_xla)."""
-        return f"xla-packed:{self.body_len}b:p{self.proof_len}:noscan@{lanes}"
+        return f"xla-packed:{self.body_len}b:p{self.proof_len}@{lanes}"
 
 
 @dataclass(frozen=True)

@@ -15,8 +15,8 @@ ALL the expensive work as one fused device program:
   * nonce range extension Blake2b²("N" ‖ beta)        (Praos/VRF.hs:116)
 
 Only the cheap state-threading (ocert counter monotonicity, nonce fold —
-a NON-associative hash fold, so inherently sequential but ~1µs/header on
-host) remains outside the kernel. Verdicts come back as per-check bitmaps;
+a NON-associative hash fold, so inherently sequential but ~1.5 µs/header
+on host) remains outside the kernel. Verdicts come back as per-check bitmaps;
 the host locates the first failing chain position and reports the exact
 `PraosValidationError` the sequential reference implementation would have
 raised (re-deriving it with the host verifier for the error payload).
@@ -25,10 +25,11 @@ The device boundary itself is packed (round 6, "cut the wire"): windows
 stage as body-sourced u8 columns (`stage_packed` — the KES-signed header
 body is the single wire copy of every field it embeds; SHA padding, the
 VRF alpha and the limb relayout run on device), and results come back as
-u32 verdict bitmask words plus ONE device-scanned evolving/candidate
-nonce pair per window (`verdict_reduce`, ops/blake2b.nonce_fold_scan),
-with the per-lane columns left device-resident for the exact-error slow
-path. Non-qualifying windows (mixed CBOR layouts, synthetic test views)
+u32 verdict bitmask words plus the uint8 eta column (`verdict_pack`),
+which the host folds into the evolving/candidate nonces in retire order
+(`_fold_nonces`: round 6 ran that hash chain on the device, 3.3 s a
+window of 8192 lanes against 12 ms here — PERF.md, PR 29), with the
+per-lane columns left device-resident for the exact-error slow path. Non-qualifying windows (mixed CBOR layouts, synthetic test views)
 fall back to the original staged path — verified byte-for-byte at
 staging time, so both wires are semantically identical.
 
@@ -734,13 +735,11 @@ def _warm_timed(stage: str, fn):
 DEVICE_IMPL = os.environ.get("OCT_DEVICE_IMPL", "")
 
 # the "cut the wire" path: packed body-sourced H2D staging + on-device
-# verdict-bit packing and nonce scan. OCT_PACKED_STAGE=0 restores the
-# round-5 staged-column path end to end; OCT_NONCE_SCAN=0 keeps packed
-# staging but ships the per-lane eta column (packed uint8) back instead
-# of running the sequential on-device nonce fold — the A/B lever if the
-# scan's serial cost ever exceeds the eta transfer it saves.
+# verdict-bit packing; the eta column ships back as uint8 and the host
+# folds the nonces (a hash chain: 8192 serial Blake2b compressions took
+# the chip 3.3 s a window and take the host 12 ms — PERF.md, PR 29).
+# OCT_PACKED_STAGE=0 restores the round-5 staged-column path end to end.
 PACKED_STAGE = os.environ.get("OCT_PACKED_STAGE", "1") != "0"
-NONCE_SCAN = os.environ.get("OCT_NONCE_SCAN", "1") != "0"
 
 
 def _stage_thread_enabled() -> bool:
@@ -961,7 +960,6 @@ class PraosPacked(NamedTuple):
     thr_idx: np.ndarray  # [B] int32 into thr_tab
     thr_tab: np.ndarray  # [Kr, 64] uint8 — thr_lo ‖ thr_hi per pool
     nonce: np.ndarray  # [32] uint8 — epoch nonce bytes (zeros if neutral)
-    within: np.ndarray  # [B] uint8 — stability-window flag (nonce scan)
 
 
 # why the last packed-staging attempt declined (the PR 5 gates were
@@ -1093,9 +1091,6 @@ def stage_packed(
     thr_tab[: len(rows)] = np.stack(rows)
     thr_tab[len(rows) :] = thr_tab[0]
 
-    first_next = (slot // params.epoch_length + 1) * params.epoch_length
-    within = (slot + params.stability_window < first_next).astype(np.uint8)
-
     layout = PraosPackedLayout(
         lb, *offs, depth, params.slots_per_kes_period,
         epoch_nonce is not None, plen,
@@ -1111,7 +1106,6 @@ def stage_packed(
         thr_idx=thr_idx,
         thr_tab=thr_tab,
         nonce=np.frombuffer(epoch_nonce or bytes(32), np.uint8),
-        within=within,
     )
     return layout, packed
 
@@ -1180,9 +1174,6 @@ def stage_packed_columns(
     thr_tab[: len(rows)] = np.stack(rows)
     thr_tab[len(rows) :] = thr_tab[0]
 
-    first_next = (slot // params.epoch_length + 1) * params.epoch_length
-    within = (slot + params.stability_window < first_next).astype(np.uint8)
-
     layout = PraosPackedLayout(
         lb, *offs, depth, params.slots_per_kes_period,
         epoch_nonce is not None, plen,
@@ -1198,7 +1189,6 @@ def stage_packed_columns(
         thr_idx=pre.uniq_inv.astype(np.int32),
         thr_tab=thr_tab,
         nonce=np.frombuffer(epoch_nonce or bytes(32), np.uint8),
-        within=within,
     )
     return layout, packed
 
@@ -1222,7 +1212,6 @@ def pad_packed_to(packed: PraosPacked, size: int) -> PraosPacked:
         counter=_pad(packed.counter),
         c0=_pad(packed.c0),
         thr_idx=_pad(packed.thr_idx),
-        within=_pad(packed.within),
     )
 
 
@@ -1338,28 +1327,46 @@ def _mask_bits(words: np.ndarray, b: int) -> np.ndarray:
     return bits[:b].astype(bool)
 
 
-def verdict_reduce(
-    flags, eta_bt, within, n_real, ev0, ev0_set, cand0, cand0_set,
-    *, scan: bool,
-):
-    """On-device D2H reduction: pack the five verdict bit rows into u32
-    bitmask words and (scan=True) fold the evolving/candidate nonces of
-    the window on device (ops/blake2b.nonce_fold_scan), so materialize
-    transfers O(bits + one nonce pair) instead of O(lanes x 40 B).
+def _verdict_masks(flags):
+    """[5, B] int32 verdict rows -> [5, W] uint32 bitmask words."""
+    return jnp.stack([_pack_bits_u32(flags[i] != 0) for i in range(5)])
+
+
+def verdict_pack(flags, eta_bt):
+    """On-device D2H reduction of every packed dispatch: the five
+    verdict bit rows packed into u32 bitmask words, and the eta column
+    as uint8, so materialize transfers O(bits + 32 B a lane) instead of
+    O(lanes x 40 B of int32). No loop and no carry: the evolving /
+    candidate nonce fold is a hash chain, and the host folds it in the
+    retire path (`_epilogue_columns_fast` / `_epilogue_packed_fast`).
 
       flags [5, B] int32 — rows ok_ocert_sig, ok_kes_sig, ok_vrf,
-        ok_leader, leader_ambiguous; eta_bt [B, 32] int32;
+        ok_leader, leader_ambiguous; eta_bt [B, 32] int32.
+
+    -> (masks [5, W] uint32, eta_u8 [B, 32] uint8)
+    """
+    return _verdict_masks(flags), eta_bt.astype(jnp.uint8)
+
+
+def verdict_reduce(flags, eta_bt, within, n_real, ev0, ev0_set, cand0,
+                   cand0_set):
+    """REFERENCE ONLY — no dispatch path calls this. The round-6 `reduce`
+    program: the verdict bitmasks plus the window's evolving/candidate
+    nonce fold ON DEVICE (ops/blake2b.nonce_fold_scan), one unbatched
+    Blake2b compression a lane: 3.3 s a window of 8192 lanes on a v5e
+    where the host folds the same column in 12 ms (PERF.md, PR 29). Kept
+    as what analysis/graphs._graph_verdict_reduce traces (goldens in
+    analysis/{budgets,certified,costmodel,shapes}.json) and what
+    tests/test_packed_batch.py holds equal to the host fold; ROADMAP
+    queues its deletion together with those goldens.
+
       within [B]; n_real [] int32 (true window size before bucket pad);
       ev0/cand0 [32] int32 + ev0_set/cand0_set [] bool — the carry-in.
 
-    scan=True  -> (masks [5, W] uint32, ev, ev_set, cand, cand_set)
-    scan=False -> (masks, eta_u8 [B, 32] uint8) — the eta column still
-    ships 4x smaller than the int32 layout; the host keeps the fold.
+    -> (masks [5, W] uint32, ev, ev_set, cand, cand_set)
     """
     b = flags.shape[-1]
-    masks = jnp.stack([_pack_bits_u32(flags[i] != 0) for i in range(5)])
-    if not scan:
-        return masks, eta_bt.astype(jnp.uint8)
+    masks = _verdict_masks(flags)
     is_real = jnp.arange(b, dtype=jnp.int32) < n_real
     ev, evs, cand, cands = blake2b.nonce_fold_scan(
         eta_bt.astype(jnp.int32),
@@ -1373,65 +1380,43 @@ def verdict_reduce(
     return masks, ev, evs, cand, cands
 
 
-def _state_carry(state: PraosState):
-    """Host-side nonce-scan carry from a PraosState (the chain seed)."""
+def _packed_xla_fn(layout: PraosPackedLayout):
+    """The RAW (un-jitted) XLA-twin packed program of one layout:
+    unpack -> fused verify -> pack."""
 
-    def arr(n):
-        if n is None:
-            return np.zeros(32, np.int32)
-        return np.frombuffer(n, np.uint8).astype(np.int32)
+    def fn(body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
+           thr_idx, thr_tab, nonce):
+        cols = unpack_packed(
+            layout, body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
+            thr_idx, thr_tab, nonce,
+        )
+        v = verify_praos_any(*cols)
+        flags = jnp.stack(
+            [v.ok_ocert_sig, v.ok_kes_sig, v.ok_vrf, v.ok_leader,
+             v.leader_ambiguous]
+        ).astype(jnp.int32)
+        return verdict_pack(flags, v.eta), flags, v.eta, v.leader_value
 
-    return (
-        arr(state.evolving_nonce), np.bool_(state.evolving_nonce is not None),
-        arr(state.candidate_nonce), np.bool_(state.candidate_nonce is not None),
-    )
+    return fn
 
 
-_ZERO_CARRY = (
-    np.zeros(32, np.int32), np.bool_(False),
-    np.zeros(32, np.int32), np.bool_(False),
-)
-
-
-def _jitted_packed_xla(layout: PraosPackedLayout, scan: bool):
-    """The XLA-twin packed program: unpack -> fused verify -> reduce,
-    one jit per (layout, scan)."""
+def _jitted_packed_xla(layout: PraosPackedLayout):
+    """The XLA-twin packed program, one jit per layout."""
     import jax
 
-    key = ("xla-packed", layout, scan)
+    key = ("xla-packed", layout)
     if key not in _JIT:
-
-        def fn(body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-               thr_idx, thr_tab, nonce, within, n_real,
-               ev0, ev0_set, cand0, cand0_set):
-            cols = unpack_packed(
-                layout, body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-                thr_idx, thr_tab, nonce,
-            )
-            v = verify_praos_any(*cols)
-            flags = jnp.stack(
-                [v.ok_ocert_sig, v.ok_kes_sig, v.ok_vrf, v.ok_leader,
-                 v.leader_ambiguous]
-            ).astype(jnp.int32)
-            red = verdict_reduce(
-                flags, v.eta, within, n_real, ev0, ev0_set, cand0,
-                cand0_set, scan=scan,
-            )
-            return red, flags, v.eta, v.leader_value
-
         _JIT[key] = _warm_timed(
-            f"xla-packed:{layout.body_len}b:p{layout.vrf_proof_len}:"
-            f"{'scan' if scan else 'noscan'}",
-            jax.jit(fn),
+            f"xla-packed:{layout.body_len}b:p{layout.vrf_proof_len}",
+            jax.jit(_packed_xla_fn(layout)),
         )
     return _JIT[key]
 
 
-def _jitted_packed_agg(layout: PraosPackedLayout, scan: bool,
-                       mode: str = "all"):
+def _jitted_packed_agg(layout: PraosPackedLayout, mode: str = "all"):
     """The AGGREGATED packed program (batch-compatible layouts only):
     device unpack -> limb relayout -> the window aggregate ->
-    verdict_reduce. `mode` selects the aggregate:
+    verdict_pack. `mode` selects the aggregate:
 
       "all" — ops/pk/aggregate.aggregate_window, EVERY stage folded
               into one shared-bucket signed-digit MSM (the default;
@@ -1440,7 +1425,7 @@ def _jitted_packed_agg(layout: PraosPackedLayout, scan: bool,
               only the VRF equations aggregated on the unsigned engine
               (the OCT_RLC_ALL=0 kill-switch; label family "agg-vrf").
 
-    One jit per (layout, scan, mode); identical output vocabulary to
+    One jit per (layout, mode); identical output vocabulary to
     the per-lane packed programs, with the aggregate verdict folded
     into the ok mask rows — a window that is not clean under
     aggregation is re-dispatched through the UNCHANGED per-lane stages
@@ -1450,20 +1435,18 @@ def _jitted_packed_agg(layout: PraosPackedLayout, scan: bool,
     names."""
     import jax
 
-    key = ("agg-packed", layout, scan, mode)
+    key = ("agg-packed", layout, mode)
     if key not in _JIT:
         _JIT[key] = _warm_timed(
-            f"{_AGG_STAGE_FAMILY[mode]}:{layout.body_len}b:"
-            f"{'scan' if scan else 'noscan'}",
-            jax.jit(_packed_agg_fn(layout, scan, mode)),
+            f"{_AGG_STAGE_FAMILY[mode]}:{layout.body_len}b",
+            jax.jit(_packed_agg_fn(layout, mode)),
         )
     return _JIT[key]
 
 
-def _packed_agg_fn(layout: PraosPackedLayout, scan: bool,
-                   mode: str = "all"):
-    """The RAW (un-jitted) aggregated stage program for (layout, scan,
-    mode) — the function the jit builder above wraps, exposed so
+def _packed_agg_fn(layout: PraosPackedLayout, mode: str = "all"):
+    """The RAW (un-jitted) aggregated stage program for (layout, mode)
+    — the function the jit builder above wraps, exposed so
     scripts/aot_precompile.py can trace/lower/compile the SAME program
     into the build-pinned store under its `_store_name(label)` row
     (the first execute then loads instead of compiling)."""
@@ -1474,18 +1457,14 @@ def _packed_agg_fn(layout: PraosPackedLayout, scan: bool,
               else pk_aggregate.aggregate_window_vrf)
 
     def fn(body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-           thr_idx, thr_tab, nonce, within, n_real,
-           ev0, ev0_set, cand0, cand0_set):
+           thr_idx, thr_tab, nonce):
         cols = unpack_packed(
             layout, body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
             thr_idx, thr_tab, nonce,
         )
         limb = pk_kernels.staged_to_limb_first_bc(*cols)
         av = agg_fn(*limb, kes_depth=layout.kes_depth)
-        red = verdict_reduce(
-            av.flags, jnp.transpose(av.eta), within, n_real,
-            ev0, ev0_set, cand0, cand0_set, scan=scan,
-        )
+        red = verdict_pack(av.flags, jnp.transpose(av.eta))
         return red, av.flags, av.eta, av.leader_value
 
     return fn
@@ -2019,8 +1998,6 @@ class _Dispatched(NamedTuple):
 
     impl: str  # "pk" | "xla"
     packed: bool
-    carried: bool  # device nonce-scan outputs extend the chain carry
-    scan: bool
     out: tuple  # impl-specific device handles
     meta: _WinMeta | None = None
 
@@ -2105,8 +2082,8 @@ def window_lanes(max_batch: int) -> int | None:
     stream cuts at every CBOR integer-width step), epoch tails and
     width steps look like — each distinct lane count costs a full set
     of stage programs (minutes of set-up on a v5e) against seconds of
-    device work. Padded lanes are masked (`n_real`, `within`), so
-    verdicts do not change. The trade: an epoch tail of ~5,200 headers
+    device work. Padded lanes replicate lane 0 and are sliced off at
+    materialize, so verdicts do not change. The trade: an epoch tail of ~5,200 headers
     runs 8192 lanes instead of 6144 (some 2,000 dead lanes per epoch)
     and each short genesis window costs one full-width pass. Whether
     finer buckets ever pay on the chip is ROADMAP Speed 4's measurement.
@@ -2175,33 +2152,24 @@ def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
                          t0, time.monotonic(), window, thread)
 
 
-def _agg_label(layout, lanes: int, scan: bool,
-               mode: str = "all") -> str:
+def _agg_label(layout, lanes: int, mode: str = "all") -> str:
     """The aggregate monolith's warmup/first-execute label at one
     padded lane count (must match what `_warm_timed` derives from the
     dispatched arguments — the compile gate and the warm ladder key
     their cold/warm decisions on it). `mode` picks the label family:
     "all" -> agg-packed (shared-bucket fold), "vrf" -> agg-vrf (the
     OCT_RLC_ALL=0 vrf-only aggregate)."""
-    return (f"{_AGG_STAGE_FAMILY[mode]}:{layout.body_len}b:"
-            f"{'scan' if scan else 'noscan'}:{lanes}l")
+    return f"{_AGG_STAGE_FAMILY[mode]}:{layout.body_len}b:{lanes}l"
 
 
-def dispatch_prepared(sw: _StagedWindow, carry=None, ladder=None):
+def dispatch_prepared(sw: _StagedWindow, ladder=None):
     """The DEVICE half of dispatch_batch: launch the fused kernel for a
     prepared window WITHOUT waiting (jax dispatch is asynchronous).
-    Must run in window order on one thread — the device nonce-scan
-    carry chains dispatch-to-dispatch.
+    Nothing chains from one dispatch to the next: a packed window
+    returns its verdict bitmasks and its eta column, and the nonce fold
+    is the host's, in retire order (`_epilogue`).
 
-    `carry` is the previous window's device nonce-scan carry (or a host
-    `_state_carry`); when given and the window staged packed, the
-    on-device nonce fold chains through this window and the new carry
-    is returned — the non-associative fold never leaves the device
-    while the pipeline is intact (praos.tick only rotates the epoch
-    nonce, so the chain crosses epoch boundaries untouched).
-
-    Returns (pre, dispatched, b, carry_out); carry_out is None when this
-    window cannot extend the chain (generic fallback or scan disabled).
+    Returns (pre, dispatched, b).
     """
     from ..testing import chaos
 
@@ -2227,22 +2195,18 @@ def dispatch_prepared(sw: _StagedWindow, carry=None, ladder=None):
                 )
                 impl = "xla"
             meta = _win_meta("generic", gate, sw, t_d0)
-            disp = _Dispatched(impl, False, False, False, out, meta)
-            return pre, disp, b, None
+            return pre, _Dispatched(impl, False, out, meta), b
         layout, parr = sw.packed
-        scan_mode = NONCE_SCAN and carry is not None
-        cargs = carry if scan_mode else _ZERO_CARRY
-        n_real = np.int32(b)
         refused_gate = None
         agg_mode = "all" if _rlc_all_enabled() else "vrf"
-        agg_stage = _agg_label(layout, lanes, scan_mode, agg_mode)
+        agg_stage = _agg_label(layout, lanes, agg_mode)
         agg_path = layout.vrf_proof_len == 128 and _agg_enabled()
         if agg_path and ladder is not None:
             # the warm ladder owns the production-bucket compile: hand
             # it the first packed window so the background thread can
             # start warming the target-lane program while the replay
             # serves rung-sized windows
-            ladder.observe(layout, parr, scan_mode)
+            ladder.observe(layout, parr)
         if agg_path:
             # the pk fallback is the per-stage split; the xla fallback
             # is itself the per-lane packed monolith, so name its twin
@@ -2262,40 +2226,28 @@ def dispatch_prepared(sw: _StagedWindow, carry=None, ladder=None):
                 refused_gate = "compile-wall-refused"
         if agg_path and refused_gate is None:
             # the aggregated fast path: ONE RLC/MSM program instead of
-            # the per-lane ladder stages; the eta/nonce outputs are
-            # identical to the per-lane path by construction, so the
-            # scan carry chain is valid even if this window later falls
-            # back (materialize_verdicts re-dispatches per-lane on any
-            # anomaly — the fallback recomputes the same etas)
-            out = _jitted_packed_agg(layout, scan_mode, agg_mode)(
-                *parr, n_real, *cargs
-            )
-            carry_out = tuple(out[0][1:5]) if scan_mode else None
+            # the per-lane ladder stages (materialize_verdicts
+            # re-dispatches per-lane on any anomaly)
+            out = _jitted_packed_agg(layout, agg_mode)(*parr)
             meta = _win_meta("packed-agg", None, sw, t_d0)
-            disp = _Dispatched(
-                "agg", True, scan_mode, scan_mode,
-                (layout, parr, n_real, cargs, out), meta,
-            )
-            return pre, disp, b, carry_out
-        if _impl() == "pk":
-            from ..ops.pk import kernels as pk_kernels
-
-            out = pk_kernels.verify_praos_packed_split(
-                layout, *parr, n_real, *cargs, scan=scan_mode
-            )
-            impl = "pk"
-        else:
-            out = _jitted_packed_xla(layout, scan_mode)(
-                *parr, n_real, *cargs
-            )
-            impl = "xla"
-        carry_out = tuple(out[0][1:5]) if scan_mode else None
+            return pre, _Dispatched("agg", True, (layout, parr, out),
+                                    meta), b
+        impl, out = _dispatch_packed_lanes(layout, parr)
         meta = _win_meta("packed", refused_gate, sw, t_d0)
-        disp = _Dispatched(impl, True, scan_mode, scan_mode, out, meta)
-        return pre, disp, b, carry_out
+        return pre, _Dispatched(impl, True, out, meta), b
 
 
-def dispatch_batch(params, lview, eta0, hvs, carry=None, ladder=None):
+def _dispatch_packed_lanes(layout, parr):
+    """One packed window through the per-lane programs of the current
+    implementation -> (impl, device handles)."""
+    if _impl() == "pk":
+        from ..ops.pk import kernels as pk_kernels
+
+        return "pk", pk_kernels.verify_praos_packed_split(layout, *parr)
+    return "xla", _jitted_packed_xla(layout)(*parr)
+
+
+def dispatch_batch(params, lview, eta0, hvs, ladder=None):
     """Stage a within-epoch window and dispatch the fused kernel WITHOUT
     waiting (the §7.3.6 host/device overlap; the reference's analog is
     the decoupled add-block queue, ChainSel.hs:217-246) — the inline
@@ -2307,7 +2259,7 @@ def dispatch_batch(params, lview, eta0, hvs, carry=None, ladder=None):
         # loops call the halves separately (and ride the supervisor);
         # an external caller of the inline form owns its own recovery,
         # exactly like calling dispatch_prepared directly
-        prepare_window(params, lview, eta0, hvs), carry, ladder
+        prepare_window(params, lview, eta0, hvs), ladder
     )
 
 
@@ -2413,7 +2365,7 @@ class WarmLadder:
 
     # -- dispatch-facing -----------------------------------------------------
 
-    def observe(self, layout, parr, scan: bool) -> None:
+    def observe(self, layout, parr) -> None:
         """First packed window seen: start the background production
         compile (or finish immediately when the production label is
         already warm in this process)."""
@@ -2422,7 +2374,7 @@ class WarmLadder:
         # warm the mode that dispatch will actually serve (agg-packed
         # unless the OCT_RLC_ALL kill-switch pins the vrf-only family)
         mode = "all" if _rlc_all_enabled() else "vrf"
-        label = _agg_label(layout, self.target, scan, mode)
+        label = _agg_label(layout, self.target, mode)
         from ..obs.warmup import WARMUP
 
         if label in WARMUP.stages:
@@ -2437,12 +2389,12 @@ class WarmLadder:
         )
         self._emit("bg-compile-started", self.rung)
         self._bg = threading.Thread(
-            target=self._warm, args=(layout, parr, scan, mode),
+            target=self._warm, args=(layout, parr, mode),
             daemon=True, name="oct-warm-ladder",
         )
         self._bg.start()
 
-    def _warm(self, layout, parr, scan: bool, mode: str = "all") -> None:
+    def _warm(self, layout, parr, mode: str = "all") -> None:
         """Background thread body: pad the observed window's packed
         columns to the production bucket and run the production program
         once, blocking until the compile (and one execute) lands. XLA
@@ -2454,10 +2406,8 @@ class WarmLadder:
 
         t0 = time.monotonic()
         try:
-            parr_t = pad_packed_to(parr, self.target)
-            n_real = np.int32(parr.body.shape[0])
-            out = _jitted_packed_agg(layout, scan, mode)(
-                *parr_t, n_real, *_ZERO_CARRY
+            out = _jitted_packed_agg(layout, mode)(
+                *pad_packed_to(parr, self.target)
             )
             jax.block_until_ready(out)
         except Exception as e:  # noqa: BLE001 — fail-open: the loop
@@ -2531,19 +2481,17 @@ def _maybe_ladder(max_batch: int) -> WarmLadder | None:
 
 
 class PackedVerdicts:
-    """Materialized packed window result: the u32 verdict bitmasks (and
-    the scanned nonce carry, or the packed eta column) on host; the
-    per-lane flags/eta/leader-value stay DEVICE-RESIDENT handles,
-    transferred only by `full()` when the epilogue needs the exact
-    per-lane slow path (a failing or ambiguous lane)."""
+    """Materialized packed window result: the u32 verdict bitmasks and
+    the uint8 eta column on host; the per-lane flags/eta/leader-value
+    stay DEVICE-RESIDENT handles, transferred only by `full()` when the
+    epilogue needs the exact per-lane slow path (a failing or ambiguous
+    lane)."""
 
-    def __init__(self, masks, b, impl, carried, nonces, eta_u8, handles):
+    def __init__(self, masks, b, impl, eta_u8, handles):
         self.masks = masks  # [5, W] uint32
         self.b = b
         self.impl = impl
-        self.carried = carried
-        self.nonces = nonces  # (ev u8[32], ev_set, cand u8[32], cand_set) | None
-        self.eta_u8 = eta_u8  # [b, 32] uint8 | None (scan-off mode)
+        self.eta_u8 = eta_u8  # [b, 32] uint8
         self._handles = handles  # (flags, eta, lv) device arrays
         self._full = None
 
@@ -2574,16 +2522,6 @@ class PackedVerdicts:
             self._row_none_set(4)
         )
 
-    def eta_bytes(self) -> np.ndarray:
-        """[b, 32] uint8 eta column (fetches from device if the scan-off
-        transfer did not already ship it)."""
-        if self.eta_u8 is not None:
-            return self.eta_u8
-        _flags, eta, _lv = self._handles
-        a = np.asarray(eta)
-        a = a[:, : self.b].T if self.impl == "pk" else a[: self.b]
-        return np.ascontiguousarray(a.astype(np.uint8))
-
     def full(self) -> Verdicts:
         """Transfer the per-lane arrays and rebuild the classic Verdicts
         (the slow-path contract of `_epilogue`/`_lane_error`)."""
@@ -2613,10 +2551,9 @@ def materialize_verdicts(tagged, b):
     """Block on a dispatched window's device computation.
 
     Generic windows transfer the full Verdicts (the round-5 contract);
-    packed windows transfer the verdict bitmasks plus either the scanned
-    nonce carry (64 B) or the packed eta column — O(bits + one nonce)
-    instead of O(lanes x 40 B) — and keep the per-lane arrays
-    device-resident for the slow path.
+    packed windows transfer the verdict bitmasks plus the uint8 eta
+    column — O(bits + 32 B a lane) instead of O(lanes x 40 B of int32)
+    — and keep the per-lane arrays device-resident for the slow path.
 
     Aggregated windows ("agg"): when the bitmasks show the window clean
     (every lane passed its cheap checks AND the RLC aggregate was the
@@ -2638,65 +2575,57 @@ def materialize_verdicts(tagged, b):
                        window=window)
         return v
     if tagged.impl == "agg":
-        layout, parr, n_real, cargs, out = tagged.out
-        pv = _materialize_packed(out, b, "pk", tagged.scan, tagged.carried,
-                                 window)
+        layout, parr, out = tagged.out
+        pv = _materialize_packed(out, b, "pk", window)
         if pv.clean():
             return pv
         if BATCH_TRACER is not None:
             from ..utils.trace import AggRedispatch
 
             BATCH_TRACER(AggRedispatch(b))
-        if _impl() == "pk":
-            from ..ops.pk import kernels as pk_kernels
-
-            out2 = pk_kernels.verify_praos_packed_split(
-                layout, *parr, n_real, *cargs, scan=tagged.scan
-            )
-            impl2 = "pk"
-        else:
-            out2 = _jitted_packed_xla(layout, tagged.scan)(
-                *parr, n_real, *cargs
-            )
-            impl2 = "xla"
-        return _materialize_packed(out2, b, impl2, tagged.scan,
-                                   tagged.carried, window)
-    return _materialize_packed(tagged.out, b, tagged.impl, tagged.scan,
-                               tagged.carried, window)
+        impl2, out2 = _dispatch_packed_lanes(layout, parr)
+        return _materialize_packed(out2, b, impl2, window)
+    return _materialize_packed(tagged.out, b, tagged.impl, window)
 
 
-def _materialize_packed(out, b, impl, scan, carried, window=None):
-    red, flags, eta, lv = out
+def _materialize_packed(out, b, impl, window=None):
+    (masks_d, eta_d), flags, eta, lv = out
     # the wait for the device and the D2H copies as two spans: a device
     # still busy reads as `wait`, a transfer that holds the next window
-    # back as `copy` (the copies below would block on `red` anyway)
+    # back as `copy` (the copies below would block on them anyway)
     with _enclose("materialize.wait", window):
-        jax.block_until_ready(red)
+        jax.block_until_ready((masks_d, eta_d))
     with _enclose("materialize.copy", window):
-        if scan:
-            masks_d, ev, evs, cand, cands = red
-            masks = np.asarray(masks_d)
-            nonces_out = (
-                np.ascontiguousarray(np.asarray(ev).astype(np.uint8)),
-                bool(np.asarray(evs)),
-                np.ascontiguousarray(np.asarray(cand).astype(np.uint8)),
-                bool(np.asarray(cands)),
-            )
-            eta_u8 = None
-            d2h = masks.nbytes + 2 * 32 + 2
-        else:
-            masks_d, eta_d = red
-            masks = np.asarray(masks_d)
-            eta_u8 = np.asarray(eta_d)[:b]
-            nonces_out = None
-            d2h = masks.nbytes + eta_u8.nbytes
-    pv = PackedVerdicts(
-        masks, b, impl, carried, nonces_out, eta_u8,
-        (flags, eta, lv),
-    )
-    _emit_transfer("materialize", lanes=b, d2h_bytes=d2h, packed=True,
-                   window=window)
+        masks = np.asarray(masks_d)
+        eta_all = np.asarray(eta_d)  # the padded column: what crosses
+    pv = PackedVerdicts(masks, b, impl, eta_all[:b], (flags, eta, lv))
+    _emit_transfer("materialize", lanes=b, packed=True, window=window,
+                   d2h_bytes=masks.nbytes + eta_all.nbytes)
     return pv
+
+
+def _fold_nonces(params: PraosParams, st: PraosState, slots, etas):
+    """The window's evolving/candidate nonce fold over its eta column
+    ([b, 32] uint8), lane by lane in chain order -> (evolving,
+    candidate). eta' = Blake2b-256(eta ‖ v) is a hash chain, so it is
+    per-header wherever it runs (COVERAGE.md §5.11): ~1.5 us a lane
+    here. The candidate follows the evolving nonce up to the window's
+    last lane inside the stability window (praos.update)."""
+    slots = np.asarray(slots)
+    first_next = (slots // params.epoch_length + 1) * params.epoch_length
+    w_idx = np.flatnonzero(slots + params.stability_window < first_next)
+    k = int(w_idx[-1]) if w_idx.size else -1
+    evolving = st.evolving_nonce
+    candidate = st.candidate_nonce
+    with _enclose("epilogue.fold"):
+        data = np.ascontiguousarray(etas).tobytes()
+        for i in range(k + 1):
+            evolving = nonces.combine(evolving, data[32 * i : 32 * i + 32])
+        if k >= 0:
+            candidate = evolving
+        for i in range(k + 1, len(slots)):
+            evolving = nonces.combine(evolving, data[32 * i : 32 * i + 32])
+    return evolving, candidate
 
 
 def _epilogue_packed_fast(
@@ -2709,9 +2638,9 @@ def _epilogue_packed_fast(
     """The packed-verdict fast path: when the bitmask shows every lane
     clean, no precheck error exists, and the stateful OCert
     counter-monotonicity gate passes, assemble the final state straight
-    from the device-scanned nonces (or one vectorized host fold of the
-    packed eta bytes) — no per-lane error reconstruction, no per-lane
-    device columns transferred. Returns None when ANY gate trips; the
+    from the host fold of the packed eta bytes — no per-lane error
+    reconstruction, no per-lane device columns transferred. Returns
+    None when ANY gate trips; the
     caller then runs the exact sequential slow path on the full
     Verdicts, so failure semantics are byte-identical to the reference
     fold by construction."""
@@ -2731,19 +2660,9 @@ def _epilogue_packed_fast(
         ):
             return None  # slow path reconstructs the exact error
         counters[hk] = hv.ocert.counter
-    if v.carried and v.nonces is not None:
-        ev, evs, cand, cands = v.nonces
-        evolving = ev.tobytes() if evs else None
-        candidate = cand.tobytes() if cands else None
-    else:
-        evolving = st.evolving_nonce
-        candidate = st.candidate_nonce
-        etas = v.eta_bytes()
-        for i, hv in enumerate(hvs):
-            evolving = nonces.combine(evolving, etas[i].tobytes())
-            first_next = params.first_slot_of(params.epoch_of(hv.slot) + 1)
-            if hv.slot + params.stability_window < first_next:
-                candidate = evolving
+    evolving, candidate = _fold_nonces(
+        params, st, [hv.slot for hv in hvs], v.eta_u8
+    )
     state = PraosState(
         last_slot=hvs[-1].slot,
         ocert_counters=counters,
@@ -2784,10 +2703,8 @@ def _epilogue_columns_fast(
     violation, no pool dedup available) — the caller falls back to the
     exact per-header reference fold, so failure semantics are untouched.
 
-    The evolving/candidate nonce fold is the device-scanned carry when
-    the window rode the packed nonce scan; otherwise the sequential
-    Blake2b fold over the eta column runs here — a hash chain is
-    inherently per-header (COVERAGE.md §5.11)."""
+    The evolving/candidate nonce fold over the eta column runs here
+    (`_fold_nonces`, span `epilogue.fold`)."""
     b = len(vc)
     if not isinstance(pre, ColumnChecks) or pre.any_errors():
         return None
@@ -2810,29 +2727,11 @@ def _epilogue_columns_fast(
             return None
         counters[hk] = int(cs[-1])
 
-    carried = isinstance(v, PackedVerdicts) and v.carried and v.nonces is not None
-    if carried:
-        ev, evs, cand, cands = v.nonces
-        evolving = ev.tobytes() if evs else None
-        candidate = cand.tobytes() if cands else None
-    else:
-        etas = (
-            v.eta_bytes() if isinstance(v, PackedVerdicts)
-            else np.ascontiguousarray(np.asarray(v.eta).astype(np.uint8))
-        )
-        first_next = (vc.slot // params.epoch_length + 1) * params.epoch_length
-        within = vc.slot + params.stability_window < first_next
-        w_idx = np.flatnonzero(within)
-        k = int(w_idx[-1]) if w_idx.size else -1
-        evolving = st.evolving_nonce
-        candidate = st.candidate_nonce
-        data = etas.tobytes()
-        for i in range(k + 1):
-            evolving = nonces.combine(evolving, data[32 * i : 32 * i + 32])
-        if k >= 0:
-            candidate = evolving
-        for i in range(k + 1, b):
-            evolving = nonces.combine(evolving, data[32 * i : 32 * i + 32])
+    etas = (
+        v.eta_u8 if isinstance(v, PackedVerdicts)
+        else np.asarray(v.eta).astype(np.uint8)
+    )
+    evolving, candidate = _fold_nonces(params, st, vc.slot, etas)
 
     last = b - 1
     prev = vc.prev_hash[last].tobytes() if vc.has_prev[last] else None
@@ -3147,13 +3046,6 @@ def _validate_chain_loop(
     s_stage = 0  # segment currently being staged
     w = segments[0][1] if segments else 0
     retired = 0  # index of the next header to retire
-    # the on-device nonce-scan carry chain: each packed window's scan
-    # starts from the previous window's device carry (tick never touches
-    # evolving/candidate, so the chain crosses epoch boundaries). A
-    # generic-fallback window breaks the chain; it re-seeds from the
-    # host-folded state once the pipeline drains.
-    carry = _state_carry(state)
-    carry_ok = True
     # warm-while-serving compile ladder: while the production-bucket
     # aggregate monolith compiles on a background thread, windows slice
     # at the rung lane cap; the loop re-tiles the moment it lands
@@ -3180,7 +3072,7 @@ def _validate_chain_loop(
         return _device_loop(
             params, hvs, max_batch, pipeline_depth, pool, stage_pool,
             segments, lview_for, eta_known, inflight, staged, s_stage, w,
-            retired, carry, carry_ok, ladder, state, total_valid, n,
+            retired, ladder, state, total_valid, n,
         )
     finally:
         if stage_pool is not None:
@@ -3192,7 +3084,7 @@ def _validate_chain_loop(
 def _device_loop(
     params, hvs, max_batch, pipeline_depth, pool, stage_pool,
     segments, lview_for, eta_known, inflight, staged, s_stage, w,
-    retired, carry, carry_ok, ladder, state, total_valid, n,
+    retired, ladder, state, total_valid, n,
 ):
     # one lane shape per replay on the chip (window_lanes); a warm
     # ladder re-tiles windows on purpose, so it keeps their own buckets.
@@ -3258,12 +3150,11 @@ def _device_loop(
         return _recovery.enabled() and _recovery.recoverable(exc)
 
     def drain_dispatch():
-        # dispatch staged windows IN ORDER (the device carry chains
-        # dispatch-to-dispatch) while the in-flight side of the double
-        # buffer has room: drain every ready one; when nothing is in
-        # flight, block on the staging head — otherwise let a
-        # materialize retire while the producer keeps staging
-        nonlocal carry, carry_ok
+        # dispatch staged windows IN ORDER (retire order is dispatch
+        # order) while the in-flight side of the double buffer has
+        # room: drain every ready one; when nothing is in flight, block
+        # on the staging head — otherwise let a materialize retire
+        # while the producer keeps staging
         while staged and len(inflight) < pipeline_depth:
             s_w, whvs_w, w_start_w, item, win = staged[0]
             stage_wait_s = 0.0
@@ -3287,7 +3178,6 @@ def _device_loop(
                     staged.popleft()
                     if not _queue_failure(e):
                         raise
-                    carry_ok = False
                     inflight.append(
                         (s_w, whvs_w, w_start_w, None, None,
                          _FailedDispatch(e))
@@ -3295,21 +3185,14 @@ def _device_loop(
                     continue
             staged.popleft()
             try:
-                pre, out, b, carry_out = dispatch_prepared(
-                    item, carry if carry_ok else None, ladder
-                )
+                pre, out, b = dispatch_prepared(item, ladder)
             except Exception as e:  # noqa: BLE001 — gated below
                 if not _queue_failure(e):
                     raise
-                carry_ok = False
                 inflight.append(
                     (s_w, whvs_w, w_start_w, None, None, _FailedDispatch(e))
                 )
                 continue
-            if carry_out is None:
-                carry_ok = False
-            else:
-                carry = carry_out
             meta = out.meta
             if meta is not None and stage_wait_s:
                 meta = meta._replace(stage_wait_s=stage_wait_s)
@@ -3343,9 +3226,6 @@ def _device_loop(
                 params, lview_for(s_stage),
                 _slot_at(hvs, segments[s_stage][1]), state,
             ).state.epoch_nonce
-            if not carry_ok:
-                carry = _state_carry(state)
-                carry_ok = True
             continue
 
         # refill the staging side BEFORE blocking on the retire below:
@@ -3392,10 +3272,7 @@ def _device_loop(
             # the supervisor re-validates JUST this window down the
             # degradation ladder (retry -> stage-split -> xla-twin ->
             # host reference); any rung's result IS the window's
-            # verdict. The device carry chain may have threaded through
-            # the failed computation, so it re-seeds from the host fold
-            # once the pipeline drains (carry_ok gate below).
-            carry_ok = False
+            # verdict
             res = _recovery.supervisor().recover_window(
                 params, ticked, whvs, fail, backend="device",
                 window=win_retired,
@@ -3420,12 +3297,6 @@ def _device_loop(
             # the background production compile landed: record the swap
             # — the NEXT slices re-tile onto the production bucket
             ladder.poll_swap()
-        if not carry_ok and not inflight:
-            # the generic window that broke the chain has retired and
-            # nothing dispatched after it is in flight: re-seed the
-            # device fold from the now-exact host state
-            carry = _state_carry(state)
-            carry_ok = True
 
         nxt = s_b + 1
         if nxt < len(segments) and nxt not in eta_known:

@@ -2,8 +2,8 @@
 
 The full curve graphs take minutes to compile on XLA:CPU; these stubs
 keep every NON-crypto part of the batched pipeline byte-exact — packed
-staging, device unpack, verdict bitmasks, the chained nonce scan,
-carries, epilogue — while replacing the three verifier subgraphs with
+staging, device unpack, verdict bitmasks, the eta column, epilogue —
+while replacing the three verifier subgraphs with
 an all-valid verdict plus the REAL eta / leader-value range extensions
 (the Blake2b tail the nonce fold and leader compare consume). The
 differential suites (tests/test_packed_batch.py, test_columnar.py,
@@ -13,7 +13,7 @@ stubbed-crypto device twin share this one implementation.
 `stub_agg_program` additionally stands in for the aggregated
 (RLC/MSM) window program with the SAME output contract as
 protocol/batch._jitted_packed_agg — limb-first eta/leader-value
-handles, verdict_reduce outputs — wrapped in `_warm_timed` so the
+handles, verdict_pack outputs — wrapped in `_warm_timed` so the
 warm-ladder machinery (first-execute labels, background compile,
 swap) exercises its real code path. An optional per-lane-count delay
 simulates a compile wall (the slow-compile stub of the ladder tests
@@ -67,7 +67,7 @@ def _first_exec_delay(delay_s, seen: set):
 
 def stub_agg_program_builder(delay_s=None):
     """A drop-in for protocol/batch._jitted_packed_agg: same output
-    contract (verdict_reduce outputs + limb-first flags/eta/lv
+    contract (verdict_pack outputs + limb-first flags/eta/lv
     handles), crypto stubbed, `_warm_timed`-wrapped so first-execute
     labels, the compile gate and the warm ladder see the real
     machinery. `delay_s` (float or callable(lanes)->float) injects a
@@ -79,13 +79,12 @@ def stub_agg_program_builder(delay_s=None):
     seen: set = set()
     sleep = _first_exec_delay(delay_s, seen)
 
-    def builder(layout, scan, mode="all"):
-        key = ("stub-agg", layout, scan, bool(delay_s))
+    def builder(layout, mode="all"):
+        key = ("stub-agg", layout, bool(delay_s))
         if key not in pbatch._JIT:
 
             def fn(body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-                   thr_idx, thr_tab, nonce, within, n_real,
-                   ev0, ev0_set, cand0, cand0_set):
+                   thr_idx, thr_tab, nonce):
                 cols = pbatch.unpack_packed(
                     layout, body, kes_rs, kt_idx, kt_tab, slot, counter,
                     c0, thr_idx, thr_tab, nonce,
@@ -95,11 +94,7 @@ def stub_agg_program_builder(delay_s=None):
                     [v.ok_ocert_sig, v.ok_kes_sig, v.ok_vrf, v.ok_leader,
                      v.leader_ambiguous]
                 ).astype(jnp.int32)
-                red = pbatch.verdict_reduce(
-                    flags, v.eta, within, n_real, ev0, ev0_set, cand0,
-                    cand0_set, scan=scan,
-                )
-                return (red, flags, jnp.transpose(v.eta),
+                return (pbatch.verdict_pack(flags, v.eta), flags, jnp.transpose(v.eta),
                         jnp.transpose(v.leader_value))
 
             jitted = jax.jit(fn)
@@ -119,9 +114,7 @@ def stub_agg_program_builder(delay_s=None):
                     return jitted.trace(*a)
 
             pbatch._JIT[key] = pbatch._warm_timed(
-                f"agg-packed:{layout.body_len}b:"
-                f"{'scan' if scan else 'noscan'}",
-                _SlowJit(),
+                f"agg-packed:{layout.body_len}b", _SlowJit(),
             )
         return pbatch._JIT[key]
 

@@ -124,17 +124,11 @@ def packed_sds(params, lview, bucket, rep, sharding):
         a = np.asarray(a)
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
 
-    unpack_in = [sds(c) for c in parr[:10]]  # body .. nonce
+    unpack_in = [sds(c) for c in parr]  # body .. nonce
     i32 = np.int32
     red_in = [
         jax.ShapeDtypeStruct((5, bucket), i32, sharding=sharding),  # flags
         jax.ShapeDtypeStruct((32, bucket), i32, sharding=sharding),  # eta
-        sds(parr.within),
-        jax.ShapeDtypeStruct((), i32, sharding=sharding),  # n_real
-        jax.ShapeDtypeStruct((32,), i32, sharding=sharding),  # ev0
-        jax.ShapeDtypeStruct((), np.bool_, sharding=sharding),  # ev0_set
-        jax.ShapeDtypeStruct((32,), i32, sharding=sharding),  # cand0
-        jax.ShapeDtypeStruct((), np.bool_, sharding=sharding),  # cand0_set
     ]
     return layout, unpack_in, red_in
 
@@ -273,15 +267,16 @@ def main():
         fresh.append(compile_stage("kes", kes_fn, kes_in, bucket, manifest))
         # packed dispatch stages (the production default): unpack
         # replaces relayout on the packed wire format; reduce packs the
-        # verdict bits and runs the device nonce scan. The crypto stages
-        # above are SHARED between the packed and staged paths.
+        # verdict bits and casts the eta column to uint8 (the host folds
+        # the nonces). The crypto stages above are SHARED between the
+        # packed and staged paths.
         pk = packed_sds(params, lview, bucket, rep, shard)
         if pk is not None:
             layout, unpack_in, red_in = pk
             fresh.append(compile_stage(K.packed_unpack_name(layout),
                                        K._mk_packed_unpack(layout),
                                        unpack_in, bucket, manifest))
-            fresh.append(compile_stage("reduce", K._mk_reduce(True),
+            fresh.append(compile_stage("reduce", K.reduce_fn,
                                        red_in, bucket, manifest))
             # UNIFIED aggregated window programs (round 15): the
             # one-RLC monolith ("all", the production default) and the
@@ -289,16 +284,15 @@ def main():
             # EXACT store rows protocol/batch._warm_timed loads —
             # name = _store_name(label), b = padded lanes,
             # kes_depth = tile = 0, sig over the runtime call args
-            # (unpack columns + the verdict_reduce scan tail)
+            # (the unpack columns)
             if layout.vrf_proof_len == 128:
-                agg_in = unpack_in + red_in[2:]
                 for mode in ("all", "vrf"):
                     label = (f"{pbatch._AGG_STAGE_FAMILY[mode]}:"
-                             f"{layout.body_len}b:scan")
+                             f"{layout.body_len}b")
                     fresh.append(compile_stage(
                         pbatch._store_name(label),
-                        pbatch._packed_agg_fn(layout, True, mode),
-                        agg_in, bucket, manifest,
+                        pbatch._packed_agg_fn(layout, mode),
+                        unpack_in, bucket, manifest,
                         kes_depth=0, tile=0, wall_label=label,
                     ))
         # generic-fallback relayout (mixed-layout windows)

@@ -231,9 +231,9 @@ def test_agg_dispatch_clean_vs_fallback(pools, lview, clean, monkeypatch,
     calls = {"fallback": 0}
     orig_xla = pbatch._jitted_packed_xla
 
-    def counting_xla(layout, scan):
+    def counting_xla(layout):
         calls["fallback"] += 1
-        return orig_xla(layout, scan)
+        return orig_xla(layout)
 
     monkeypatch.setattr(pbatch, "_jitted_packed_xla", counting_xla)
     # the per-lane fallback would compile real crypto: stub it too
@@ -469,9 +469,9 @@ def test_mixed_format_chain_segments_before_aggregate(pools, lview,
     seen_plens = []
     orig_agg = pbatch._jitted_packed_agg
 
-    def counting_agg(layout, scan, mode="all"):
+    def counting_agg(layout, mode="all"):
         seen_plens.append(layout.vrf_proof_len)
-        return orig_agg(layout, scan, mode)
+        return orig_agg(layout, mode)
 
     monkeypatch.setattr(pbatch, "_jitted_packed_agg", counting_agg)
 

@@ -97,7 +97,7 @@ def _packed(has_nonce: bool):
         prev = blk.header.hash_
     layout, parr = pbatch.stage_packed(params, lview, nonce, hvs)
     assert layout.vrf_proof_len == 128 and layout.has_nonce is has_nonce
-    return layout, pbatch.pad_packed_to(parr, LANES)[:10]
+    return layout, pbatch.pad_packed_to(parr, LANES)
 
 
 def _sds(sharding, shape, dtype=np.int32):
@@ -139,12 +139,12 @@ def test_unpack_compiles(one_chip, chip_seams, has_nonce):
 
 
 def test_reduce_compiles(one_chip, chip_seams):
+    """Bit packing and a cast: the compiled program holds no loop (the
+    round-6 nonce scan, a `while` of one trip a lane, ran 3.3 s a
+    window on the chip)."""
     s = functools.partial(_sds, one_chip)
-    args = [
-        s((5, LANES)), s((32, LANES)), s((LANES,), np.uint8), s(()),
-        s((32,)), s((), np.bool_), s((32,)), s((), np.bool_),
-    ]
-    _compile(K._mk_reduce(True), args)
+    compiled = _compile(K.reduce_fn, [s((5, LANES)), s((32, LANES))])
+    assert "while" not in compiled.as_text()
 
 
 def test_finish_compiles_with_the_kernel(one_chip, chip_seams):

@@ -229,7 +229,7 @@ def test_stage_packed_columns_equals_stage_packed(pools, lview):
     assert np.array_equal(
         rp.thr_tab[rp.thr_idx], gp.thr_tab[gp.thr_idx]
     )
-    for f in ("slot", "counter", "c0", "within", "nonce"):
+    for f in ("slot", "counter", "c0", "nonce"):
         assert np.array_equal(getattr(rp, f), getattr(gp, f)), f
 
 
@@ -430,8 +430,8 @@ except ImportError:  # seeded fallback: same property, fixed sweep
 def test_validate_chain_columnar_pipeline_equals_fold(pools, lview,
                                                       monkeypatch):
     """The full pipelined device path fed a ViewColumns chain — packed
-    columnar staging, device unpack, bitmask verdicts, the chained
-    nonce scan across windows AND epoch boundaries — agrees with the
+    columnar staging, device unpack, bitmask verdicts, the host nonce
+    fold across windows AND epoch boundaries — agrees with the
     sequential reupdate fold and with the same chain fed as a list.
     Crypto is the hash-only stub (test_packed_batch idiom); the columnar
     epilogue fast path is what's under test."""

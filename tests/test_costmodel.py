@@ -281,14 +281,16 @@ def test_registry_drift_gate_seeded(monkeypatch):
 
 def test_stage_graph_resolution():
     assert costmodel.stage_graph("ed@b8192") == "ed_core"
-    assert costmodel.stage_graph("agg-packed:304b:scan") == "aggregate_core"
-    assert costmodel.stage_graph("xla-packed:304b:p128:scan") == \
+    assert costmodel.stage_graph("agg-packed:304b") == "aggregate_core"
+    assert costmodel.stage_graph("xla-packed:304b:p128") == \
         "verify_praos_core_bc"
     # draft-03 packed windows resolve to the NON-bc composed twin
-    assert costmodel.stage_graph("xla-packed:256b:p80:noscan") == \
+    assert costmodel.stage_graph("xla-packed:256b:p80@64") == \
         "verify_praos_core"
     assert costmodel.stage_graph("unpack_a1b2c3@b8192") == "packed_unpack"
-    assert costmodel.stage_graph("reduce_noscan@b64") == "verdict_reduce"
+    # bit packing and a cast: no registered twin (`verdict_reduce` is
+    # the retired on-device scan, traced for its goldens only)
+    assert costmodel.stage_graph("reduce@b64") is None
     assert costmodel.stage_graph("something-new") is None
 
 
@@ -324,12 +326,12 @@ def test_warmup_note_carries_hash_and_refusals_flush(tmp_path,
     monkeypatch.setenv("OCT_WARMUP_REPORT", path)
     w = WarmupRecorder()
     w.note_stage("ed@b8", 1.5, via="jit", feature_hash="abcd1234")
-    w.note_refusal("agg-packed:304b:scan", 410.0, 90.0,
+    w.note_refusal("agg-packed:304b", 410.0, 90.0,
                    action="stage-split-fallback", detail="graph=aggregate_core")
     rep = json.load(open(path))
     assert rep["stages"]["ed@b8"]["feature_hash"] == "abcd1234"
     (ref,) = rep["refusals"]
-    assert ref["stage"] == "agg-packed:304b:scan"
+    assert ref["stage"] == "agg-packed:304b"
     assert ref["predicted_s"] == 410.0
     assert ref["remaining_s"] == 90.0
     assert ref["action"] == "stage-split-fallback"
@@ -344,7 +346,7 @@ def test_warmup_note_carries_hash_and_refusals_flush(tmp_path,
 
 def test_preflight_admits_without_deadline(monkeypatch, fresh_warmup):
     monkeypatch.delenv("OCT_WALL_DEADLINE", raising=False)
-    assert costmodel.preflight("agg-packed:304b:scan") is True
+    assert costmodel.preflight("agg-packed:304b") is True
     assert fresh_warmup.report()["refusals"] == []
 
 
@@ -355,7 +357,7 @@ def test_preflight_refuses_cold_overbudget_and_records(monkeypatch,
     report (the round JSON banks the decision)."""
     monkeypatch.setenv("OCT_WALL_DEADLINE", "1090.0")
     monkeypatch.setattr(costmodel, "predicted_wall", lambda g: 410.0)
-    stage = "agg-packed:304b:scan"
+    stage = "agg-packed:304b"
     assert costmodel.preflight(stage, now=1000.0) is False
     (ref,) = fresh_warmup.report()["refusals"]
     assert ref["stage"] == stage
@@ -373,7 +375,7 @@ def test_preflight_admits_warm_stage_even_overbudget(monkeypatch,
     the gate must not refuse warm dispatches at the end of the wall."""
     monkeypatch.setenv("OCT_WALL_DEADLINE", "1010.0")
     monkeypatch.setattr(costmodel, "predicted_wall", lambda g: 410.0)
-    stage = "agg-packed:304b:scan"
+    stage = "agg-packed:304b"
     fresh_warmup.note_stage(stage, 123.0, via="xla-jit")
     assert costmodel.preflight(stage, now=1000.0) is True
     assert fresh_warmup.report()["refusals"] == []
@@ -387,7 +389,7 @@ def test_preflight_admits_when_fallback_is_no_cheaper(monkeypatch,
     monkeypatch.setenv("OCT_WALL_DEADLINE", "1090.0")
     monkeypatch.setattr(costmodel, "predicted_wall", lambda g: 410.0)
     assert costmodel.preflight(
-        "agg-packed:304b:scan", now=1000.0,
+        "agg-packed:304b", now=1000.0,
         fallback_graph="verify_praos_core_bc",
     ) is True
     assert fresh_warmup.report()["refusals"] == []
@@ -397,7 +399,7 @@ def test_preflight_admits_when_fallback_is_no_cheaper(monkeypatch,
         lambda g: 410.0 if g == "aggregate_core" else 40.0,
     )
     assert costmodel.preflight(
-        "agg-packed:304b:scan", now=1000.0,
+        "agg-packed:304b", now=1000.0,
         fallback_graph="verify_praos_core_bc",
         action="xla-packed-fallback",
     ) is False
@@ -409,7 +411,7 @@ def test_preflight_gate_kill_switch(monkeypatch, fresh_warmup):
     monkeypatch.setenv("OCT_WALL_DEADLINE", "1001.0")
     monkeypatch.setenv("OCT_COMPILE_GATE", "0")
     monkeypatch.setattr(costmodel, "predicted_wall", lambda g: 1e9)
-    assert costmodel.preflight("agg-packed:304b:scan", now=1000.0) is True
+    assert costmodel.preflight("agg-packed:304b", now=1000.0) is True
 
 
 # ---------------------------------------------------------------------------
@@ -504,12 +506,12 @@ def test_dispatch_refusal_rides_the_fallback_path(monkeypatch,
     agg_calls = []
     monkeypatch.setattr(
         pbatch, "_jitted_packed_agg",
-        lambda layout, scan, mode="all": agg_calls.append(1)
+        lambda layout, mode="all": agg_calls.append(1)
         or pytest.fail("refused aggregate program was still dispatched"),
     )
     before = set(pbatch._JIT)
     try:
-        pre, disp, b, carry = pbatch.dispatch_batch(
+        pre, disp, b = pbatch.dispatch_batch(
             params, lview, nonce, hvs
         )
         assert b == len(hvs)
@@ -527,13 +529,12 @@ def test_dispatch_refusal_rides_the_fallback_path(monkeypatch,
         taken = []
         monkeypatch.setattr(
             pbatch, "_jitted_packed_agg",
-            lambda layout, scan, mode="all": lambda *a: taken.append(1) or (
-                ((np.zeros((5, (len(hvs) + 7) // 8 * 8), np.int64),)
-                 + tuple(np.zeros(1) for _ in range(6))),
+            lambda layout, mode="all": lambda *a: taken.append(1) or (
+                (np.zeros((5, 1), np.uint32), np.zeros((8, 32), np.uint8)),
                 np.zeros((5, 8)), np.zeros((32, 8)), np.zeros((32, 8)),
             ),
         )
-        pre2, disp2, b2, _ = pbatch.dispatch_batch(
+        pre2, disp2, b2 = pbatch.dispatch_batch(
             params, lview, nonce, hvs
         )
         assert taken == [1]

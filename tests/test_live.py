@@ -307,7 +307,7 @@ def test_stall_watchdog_stubbed_clock_names_wedged_dispatch(tmp_path):
     wedged = threading.Event()
     release = threading.Event()
 
-    def dispatch_batch(params, lview, eta0, hvs, carry=None, ladder=None):
+    def dispatch_batch(params, lview, eta0, hvs, ladder=None):
         wedged.set()
         release.wait(30)
 

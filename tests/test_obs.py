@@ -817,13 +817,13 @@ def test_compile_wall_refusal_is_visible_telemetry(monkeypatch):
                         lambda *cols: _stub_verdicts(cols))
     monkeypatch.setattr(
         pbatch, "_jitted_packed_agg",
-        lambda layout, scan, mode="all": pytest.fail(
+        lambda layout, mode="all": pytest.fail(
             "refused aggregate program was still dispatched"),
     )
     before = set(pbatch._JIT)
     rec = obs.install()
     try:
-        _pre, disp, b, _carry = pbatch.dispatch_batch(
+        _pre, disp, b = pbatch.dispatch_batch(
             params, lview2, nonce, hvs
         )
     finally:
@@ -865,10 +865,10 @@ def test_perfetto_warmup_track_slices_and_instants():
     from ouroboros_consensus_tpu.obs.warmup import WARMUP
 
     WARMUP.reset()
-    WARMUP.note_stage("agg-packed:410b:scan", 12.5, via="xla-jit",
+    WARMUP.note_stage("agg-packed:410b", 12.5, via="xla-jit",
                       feature_hash="216e9c5e109f6aa6")
     WARMUP.note_aot("ed", "rejected", 1.0, "serialized executable is incompatible")
-    WARMUP.note_refusal("xla-packed:410b:p128:scan", 410.0, 90.0,
+    WARMUP.note_refusal("xla-packed:410b:p128", 410.0, 90.0,
                         "stage-split-fallback")
     rec = obs.recorder()
     doc = rec.chrome_trace()
@@ -1055,7 +1055,7 @@ def test_perfetto_ladder_track_renders_bg_compile_slice():
                        graph="aggregate_core", predicted_s=757.9,
                        feature_hash="216e9c5e109f6aa6")
     WARMUP.note_ladder("bg-compile-started", rung=1024, target=8192,
-                       stage="agg-packed:410b:scan:8192l")
+                       stage="agg-packed:410b:8192l")
     import time as _time
 
     _time.sleep(0.02)

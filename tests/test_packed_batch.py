@@ -7,9 +7,11 @@ Three layers:
      three column families (ed / kes / vrf), across randomized chains,
      nonces and KES depths; and the limb-first decomposition must equal
      `pk_arrays` of the staged batch;
-  2. the D2H reduction — verdict bitmask packing and the sequential
-     device nonce scan against the host `nonces.combine` fold,
-     including neutral carries and bucket-pad masking;
+  2. the D2H reduction — verdict bitmask packing and the eta column
+     (`verdict_pack`, what every dispatch runs), and the retired
+     on-device nonce scan (`verdict_reduce`, reference only) against
+     the host `nonces.combine` fold, including neutral carries and
+     bucket-pad masking;
   3. epilogue equivalence — windows with invalid lanes at the edges
      (first lane, last lane, epoch-tail boundary) produce identical
      `BatchResult` through the packed-verdict fast path and the
@@ -20,7 +22,6 @@ Three layers:
      test_tools.test_device_revalidation_matches_host).
 """
 
-import functools
 from dataclasses import replace
 from fractions import Fraction
 
@@ -34,6 +35,7 @@ from ouroboros_consensus_tpu.block.forge import forge_block
 from ouroboros_consensus_tpu.ops import blake2b
 from ouroboros_consensus_tpu.protocol import batch as pbatch
 from ouroboros_consensus_tpu.protocol import nonces, praos
+from ouroboros_consensus_tpu.protocol.views import ViewColumns
 from ouroboros_consensus_tpu.testing import fixtures
 
 _COLS_HEAD = [
@@ -236,7 +238,7 @@ def test_kes_tail_table_dedupes(pools, lview):
 
 
 # ---------------------------------------------------------------------------
-# 2. the D2H reduction: bitmasks + nonce scan
+# 2. the D2H reduction: bitmasks + eta column; the reference scan
 # ---------------------------------------------------------------------------
 
 
@@ -248,8 +250,24 @@ def test_pack_bits_roundtrip():
         assert (pbatch._mask_bits(words, b) == bits).all(), b
 
 
+def _state_carry(state):
+    """The reference scan's carry-in from a PraosState."""
+
+    def arr(n):
+        return np.frombuffer(n or bytes(32), np.uint8).astype(np.int32)
+
+    return (
+        arr(state.evolving_nonce), np.bool_(state.evolving_nonce is not None),
+        arr(state.candidate_nonce), np.bool_(state.candidate_nonce is not None),
+    )
+
+
 @pytest.mark.parametrize("seed_state", ["set", "neutral"])
 def test_verdict_reduce_scan_matches_host_fold(seed_state):
+    """The reference comparison: the retired on-device scan
+    (`verdict_reduce`, which no dispatch path reaches) equals the host
+    fold, and `verdict_pack`, which every packed dispatch runs, ships
+    the same masks and the eta column that fold consumes."""
     rng = np.random.default_rng(3)
     b, n_real = 11, 9
     flags = np.ones((5, b), np.int32)
@@ -262,9 +280,8 @@ def test_verdict_reduce_scan_matches_host_fold(seed_state):
         praos.PraosState(evolving_nonce=b"\x01" * 32)
         if seed_state == "set" else praos.PraosState()
     )
-    carry = pbatch._state_carry(st)
-    red = jax.jit(functools.partial(pbatch.verdict_reduce, scan=True))(
-        flags, etas, within, np.int32(n_real), *carry
+    red = jax.jit(pbatch.verdict_reduce)(
+        flags, etas, within, np.int32(n_real), *_state_carry(st)
     )
     masks, ev, evs, cand, cands = (np.asarray(x) for x in red)
     evolving, candidate = st.evolving_nonce, st.candidate_nonce
@@ -280,10 +297,9 @@ def test_verdict_reduce_scan_matches_host_fold(seed_state):
     # masks reflect the raw flags, pad lanes included
     for r in range(5):
         assert (pbatch._mask_bits(masks[r], b) == (flags[r] != 0)).all(), r
-    # scan-off mode ships the packed eta column instead
-    m2, eta_u8 = jax.jit(functools.partial(pbatch.verdict_reduce, scan=False))(
-        flags, etas, within, np.int32(n_real), *carry
-    )
+    # what the dispatch paths run ships the uint8 eta column instead
+    m2, eta_u8 = jax.jit(pbatch.verdict_pack)(flags, etas)
+    assert np.asarray(eta_u8).dtype == np.uint8
     assert (np.asarray(eta_u8) == etas.astype(np.uint8)).all()
     assert (np.asarray(m2) == masks).all()
 
@@ -312,9 +328,9 @@ def _fab_verdicts(hvs, bad=(), ambiguous=()):
                            eta, lv)
 
 
-def _as_packed(v, params, hvs, st, carried):
+def _as_packed(v, hvs):
     """Wrap fabricated Verdicts as the PackedVerdicts materialize would
-    produce (numpy mask packing + host-side reference scan)."""
+    produce (numpy mask packing + the uint8 eta column)."""
     b = len(hvs)
     rows = [v.ok_ocert_sig, v.ok_kes_sig, v.ok_vrf, v.ok_leader,
             v.leader_ambiguous]
@@ -324,26 +340,9 @@ def _as_packed(v, params, hvs, st, carried):
         for i, x in enumerate(np.asarray(bits)):
             if x:
                 masks[r, i // 32] |= np.uint32(1 << (i % 32))
-    nonces_out = None
-    if carried:
-        evolving, candidate = st.evolving_nonce, st.candidate_nonce
-        for i, hv in enumerate(hvs):
-            evolving = nonces.combine(
-                evolving, np.asarray(v.eta)[i].astype(np.uint8).tobytes()
-            )
-            first_next = params.first_slot_of(params.epoch_of(hv.slot) + 1)
-            if hv.slot + params.stability_window < first_next:
-                candidate = evolving
-        nonces_out = (
-            np.frombuffer(evolving or bytes(32), np.uint8),
-            evolving is not None,
-            np.frombuffer(candidate or bytes(32), np.uint8),
-            candidate is not None,
-        )
     flags = np.stack([np.asarray(r).astype(np.int32) for r in rows])
     return pbatch.PackedVerdicts(
-        masks, b, "xla", carried, nonces_out,
-        np.asarray(v.eta).astype(np.uint8),
+        masks, b, "xla", np.asarray(v.eta).astype(np.uint8),
         (flags, np.asarray(v.eta).astype(np.int32),
          np.asarray(v.leader_value).astype(np.int32)),
     )
@@ -358,12 +357,15 @@ def _results_equal(a, b):
     assert a.state == b.state
 
 
-@pytest.mark.parametrize("carried", [True, False])
+@pytest.mark.parametrize("window", ["views", "columns"])
 @pytest.mark.parametrize("bad_at", ["none", "first", "last", "tail-edge"])
-def test_epilogue_packed_fast_equals_slow(pools, lview, bad_at, carried):
+def test_epilogue_packed_fast_equals_slow(pools, lview, bad_at, window):
     """Satellite: invalid lanes at window edges (first lane, last lane,
     epoch-tail boundary) give identical BatchResult.error and nonce
-    state through the packed fast path and the per-lane slow path."""
+    state through the packed fast path and the per-lane slow path,
+    whether the window is a list of views (`_epilogue_packed_fast`) or
+    a ViewColumns (`_epilogue_columns_fast`): one host fold behind
+    both."""
     params = make_params(epoch_length=160)
     nonce = b"\x07" * 32
     if bad_at == "tail-edge":
@@ -377,10 +379,16 @@ def test_epilogue_packed_fast_equals_slow(pools, lview, bad_at, carried):
     v = _fab_verdicts(hvs, bad=bad)
     st = praos.PraosState(epoch_nonce=nonce, evolving_nonce=b"\x02" * 32)
     ticked = praos.TickedPraosState(st, lview)
-    pre = pbatch.host_prechecks(params, lview, hvs)
-    pv = _as_packed(v, params, hvs, st, carried)
-    res_packed = pbatch._epilogue(params, ticked, hvs, pre, pv)
-    res_slow = pbatch._epilogue(params, ticked, hvs, pre, v)
+    whvs = hvs
+    if window == "columns":
+        whvs = ViewColumns.from_views(hvs)
+        assert whvs is not None
+    pre = pbatch.host_prechecks(params, lview, whvs)
+    pv = _as_packed(v, hvs)
+    res_packed = pbatch._epilogue(params, ticked, whvs, pre, pv)
+    res_slow = pbatch._epilogue(
+        params, ticked, hvs, pbatch.host_prechecks(params, lview, hvs), v
+    )
     _results_equal(res_packed, res_slow)
     if bad_at == "none":
         # the all-clean window must have taken the fast path (the slow
@@ -406,7 +414,7 @@ def test_epilogue_counter_gate_routes_to_slow_path(pools, lview):
     st = praos.PraosState(epoch_nonce=nonce)
     ticked = praos.TickedPraosState(st, lview)
     pre = pbatch.host_prechecks(params, lview, hvs)
-    pv = _as_packed(v, params, hvs, st, carried=True)
+    pv = _as_packed(v, hvs)
     res_packed = pbatch._epilogue(params, ticked, hvs, pre, pv)
     res_slow = pbatch._epilogue(params, ticked, hvs, pre, v)
     _results_equal(res_packed, res_slow)
@@ -423,8 +431,8 @@ def _stub_verify(*cols):
     """All-valid crypto stub with the REAL eta / leader-value range
     extensions (hash-only: compiles in seconds on XLA:CPU where the
     full curve graphs take minutes). Keeps every non-crypto part of the
-    packed pipeline — staging, unpack, masks, nonce scan, carries,
-    epilogue — byte-exact against the reupdate fold. Arity-generic
+    packed pipeline — staging, unpack, masks, eta column, epilogue —
+    byte-exact against the reupdate fold. Arity-generic
     (21 draft-03 / 22 batch-compatible columns): beta_decl is always
     the third-from-last column."""
     beta_decl = cols[-3]
@@ -469,10 +477,10 @@ def test_validate_chain_packed_pipeline_equals_fold(
     pools, lview, stubbed_crypto, monkeypatch
 ):
     """The full pipelined device path — packed staging, device unpack,
-    bitmask verdicts, chained on-device nonce scan across windows AND
-    epoch boundaries, fallback windows (CBOR width changes) breaking
-    and re-seeding the carry — against the sequential reupdate fold.
-    Covers packed-on, packed-off and scan-off configurations."""
+    bitmask verdicts and the eta column, the host nonce fold across
+    windows AND epoch boundaries, fallback windows (CBOR width changes)
+    in between — against the sequential reupdate fold. Covers packed-on
+    and packed-off."""
     params = make_params(epoch_length=60)
     st0 = praos.PraosState(epoch_nonce=b"\x07" * 32)
     st = st0
@@ -493,39 +501,48 @@ def test_validate_chain_packed_pipeline_equals_fold(
         blkno += 1
     assert params.epoch_of(hvs[-1].slot) >= 1  # crossed an epoch boundary
 
-    for packed, scan in ((True, True), (True, False), (False, True)):
+    for packed in (True, False):
         monkeypatch.setattr(pbatch, "PACKED_STAGE", packed)
-        monkeypatch.setattr(pbatch, "NONCE_SCAN", scan)
         res = pbatch.validate_chain(
             params, lambda _e: lview, st0, hvs, max_batch=8,
             pipeline_depth=3,
         )
-        assert res.error is None, (packed, scan, repr(res.error))
+        assert res.error is None, (packed, repr(res.error))
         assert res.n_valid == len(hvs)
-        assert res.state == st, (packed, scan)
+        assert res.state == st, packed
+
+
+def _traced_chain(params, lview, st0, hvs, **kw):
+    """validate_chain under a batch tracer -> (result, events)."""
+    events = []
+    pbatch.set_batch_tracer(events.append)
+    try:
+        res = pbatch.validate_chain(params, lambda _e: lview, st0, hvs, **kw)
+    finally:
+        pbatch.set_batch_tracer(None)
+    return res, events
 
 
 def test_transfer_events_report_packed_bytes(
     pools, lview, stubbed_crypto, monkeypatch
 ):
     """The tracer byte accounting: packed windows must report ≥2x fewer
-    H2D bytes than the generic path and ≥8x fewer D2H bytes."""
+    H2D bytes than the generic path, and on the way back exactly the
+    five mask rows plus 32 B a lane against the generic path's five
+    bool rows and two int32 [B, 32] columns: 261 B a lane against
+    32.6, 7.8x at 16 lanes (8.0x less the mask words' rounding). It
+    read "≥8x" while the device folded the nonces and a window shipped
+    two of them (64 B) in place of the eta column."""
     from ouroboros_consensus_tpu.utils.trace import TransferEvent
 
     params = make_params()
     st0 = praos.PraosState(epoch_nonce=b"\x07" * 32)
-    hvs = real_chain(params, pools, 16)
+    b = 16
+    hvs = real_chain(params, pools, b)
 
     def run(packed):
         monkeypatch.setattr(pbatch, "PACKED_STAGE", packed)
-        events = []
-        pbatch.set_batch_tracer(events.append)
-        try:
-            res = pbatch.validate_chain(
-                params, lambda _e: lview, st0, hvs, max_batch=16
-            )
-        finally:
-            pbatch.set_batch_tracer(None)
+        res, events = _traced_chain(params, lview, st0, hvs, max_batch=b)
         assert res.error is None and res.n_valid == len(hvs)
         h2d = sum(e.h2d_bytes for e in events
                   if isinstance(e, TransferEvent))
@@ -536,4 +553,198 @@ def test_transfer_events_report_packed_bytes(
     h2d_packed, d2h_packed = run(True)
     h2d_generic, d2h_generic = run(False)
     assert h2d_packed * 2 <= h2d_generic, (h2d_packed, h2d_generic)
-    assert d2h_packed * 8 <= d2h_generic, (d2h_packed, d2h_generic)
+    assert d2h_packed == 5 * 4 * -(-b // 32) + b * 32
+    assert d2h_generic == b * (5 + 2 * 32 * 4)
+    assert d2h_packed * 7.8 <= d2h_generic, (d2h_packed, d2h_generic)
+
+
+class _SpyNp:
+    """numpy, counting the bytes of every device array `asarray` pulls."""
+
+    def __init__(self):
+        self.pulled = 0
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, a, *args, **kw):
+        if isinstance(a, jax.Array):
+            self.pulled += a.nbytes
+        return np.asarray(a, *args, **kw)
+
+
+def test_packed_d2h_bytes_are_the_bytes_pulled(
+    pools, lview, stubbed_crypto, monkeypatch
+):
+    """A packed window's `TransferEvent.d2h_bytes` is what crossed the
+    wire: the five mask rows and the eta column AS COPIED, padded lanes
+    and all (11 lanes ride a 16-lane bucket), not the slice the
+    epilogue keeps."""
+    from ouroboros_consensus_tpu.utils.trace import TransferEvent
+
+    params = make_params()
+    st0 = praos.PraosState(epoch_nonce=b"\x07" * 32)
+    hvs = real_chain(params, pools, 11)
+    spy = _SpyNp()
+    monkeypatch.setattr(pbatch, "np", spy)
+    res, events = _traced_chain(params, lview, st0, hvs, max_batch=16)
+    assert res.error is None and res.n_valid == 11
+    (mat,) = [e for e in events
+              if isinstance(e, TransferEvent) and e.phase == "materialize"]
+    assert mat.packed and mat.lanes == 11
+    assert mat.d2h_bytes == spy.pulled == 5 * 4 + 16 * 32
+
+
+# ---------------------------------------------------------------------------
+# 4. the nonce fold is the host's: no loop over lanes in any dispatched
+#    program, no carry between dispatches, no lever
+# ---------------------------------------------------------------------------
+
+
+def _loops(jaxpr):
+    """[(primitive, trip count or None)] of every loop in a jaxpr,
+    nested computations included."""
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name in ("scan", "while"):
+            out.append((e.primitive.name, e.params.get("length")))
+        for v in e.params.values():
+            for x in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(x, "jaxpr", x)
+                if hasattr(sub, "eqns"):
+                    out += _loops(sub)
+    return out
+
+
+def test_pk_reduce_stage_holds_no_loop():
+    """The pk `reduce` stage is bit packing and a cast: its jaxpr holds
+    no `scan` and no `while` (the round-6 stage folded the nonces in a
+    `lax.scan` of one trip a lane, 3.3 s a window on a v5e)."""
+    from ouroboros_consensus_tpu.ops.pk import kernels as K
+
+    b = 24
+    flags = np.ones((5, b), np.int32)
+    eta = np.arange(32 * b, dtype=np.int32).reshape(32, b) % 256
+    assert _loops(jax.make_jaxpr(K.reduce_fn)(flags, eta).jaxpr) == []
+    masks, eta_u8 = jax.jit(K.reduce_fn)(flags, eta)
+    assert np.asarray(masks).shape == (5, 1)
+    assert (np.asarray(eta_u8) == eta.T.astype(np.uint8)).all()
+
+
+def test_xla_twin_packed_program_holds_no_loop_over_lanes(
+    pools, lview, stubbed_crypto
+):
+    """The XLA twin's packed program folds nothing either: with the
+    verifiers stubbed (hash-only), what loops are left are the hash
+    rounds of unpack and the stub, none of them `while` and none with
+    a trip a lane; and it returns (masks, uint8 eta column) and no
+    nonce."""
+    params = make_params()
+    b = 11  # no hash has 11 rounds
+    layout, parr = pbatch.stage_packed(
+        params, lview, b"\x07" * 32, real_chain(params, pools, b)
+    )
+    closed = jax.make_jaxpr(pbatch._packed_xla_fn(layout))(*parr)
+    loops = _loops(closed.jaxpr)
+    assert loops and all(p == "scan" and n != b for p, n in loops), loops
+    masks, eta_u8 = closed.out_avals[:2]
+    assert masks.shape == (5, 1) and masks.dtype == np.uint32
+    assert eta_u8.shape == (b, 32) and eta_u8.dtype == np.uint8
+    assert len(closed.out_avals) == 5
+
+
+def _epoch_crossing_chain(params, pools, lview, st0, first_slot, last_slot):
+    """One header a slot through the sequential fold -> (views, the
+    reference's state after each)."""
+    st, hvs, states, prev = st0, [], [], b"\xaa" * 32
+    for k, slot in enumerate(range(first_slot, last_slot + 1)):
+        ticked = praos.tick(params, lview, slot, st)
+        blk = forge_block(
+            params, pools[k % 2], slot=slot, block_no=40 + k,
+            prev_hash=prev, epoch_nonce=ticked.state.epoch_nonce,
+            txs=(b"t",),
+        )
+        hv = blk.header.to_view()
+        st = praos.reupdate(params, hv, slot, ticked)
+        hvs.append(hv)
+        states.append(st)
+        prev = blk.header.hash_
+    return hvs, states
+
+
+@pytest.mark.parametrize("pipeline_depth", [1, 3])
+@pytest.mark.parametrize("first_slot", [59, 50],
+                         ids=["one-lane-first-window", "ten-lane-epoch"])
+def test_host_fold_freezes_mid_window_and_crosses_epochs(
+    pools, lview, stubbed_crypto, first_slot, pipeline_depth
+):
+    """The host fold, window by window in retire order, against the
+    sequential reference's five nonces: epochs of 60 slots, stability
+    window 24, windows of 8 lanes. Epoch 1 (slots 60..119) freezes its
+    candidate at slot 96, the fifth lane of its fifth window; the chain
+    starts in epoch 0 (one lane when it starts at slot 59) and ends in
+    epoch 2, so two rotations consume what the fold left. All slots and
+    block numbers sit in one CBOR width class: every window is packed."""
+    from ouroboros_consensus_tpu.utils.trace import WindowStaged
+
+    params = make_params(epoch_length=60)
+    assert params.stability_window == 24
+    st0 = praos.PraosState(epoch_nonce=b"\x07" * 32)
+    hvs, states = _epoch_crossing_chain(params, pools, lview, st0,
+                                        first_slot, 127)
+    want, end_of_epoch_1 = states[-1], states[119 - first_slot]
+    # the candidate did freeze, so the rotation into epoch 2 tells a
+    # fold that froze at slot 96 from one that did not
+    assert end_of_epoch_1.candidate_nonce != end_of_epoch_1.evolving_nonce
+    res, events = _traced_chain(params, lview, st0, hvs, max_batch=8,
+                                pipeline_depth=pipeline_depth)
+    assert res.error is None and res.n_valid == len(hvs)
+    staged = [e for e in events if isinstance(e, WindowStaged)]
+    assert {e.outcome for e in staged} == {"packed"}
+    lanes = [e.lanes for e in staged]
+    assert lanes[0] == (1 if first_slot == 59 else 8)
+    assert sum(lanes) == len(hvs)
+    for f in ("evolving_nonce", "candidate_nonce", "epoch_nonce",
+              "lab_nonce", "last_epoch_block_nonce"):
+        assert getattr(res.state, f) == getattr(want, f), f
+    assert res.state == want
+
+
+@pytest.mark.parametrize("value", ["0", "1"])
+def test_nonce_scan_variable_is_read_by_nothing(
+    pools, lview, stubbed_crypto, monkeypatch, value
+):
+    """`OCT_NONCE_SCAN` was round 6's lever between the on-device fold
+    and this path. It is gone: setting it changes neither the programs
+    dispatched nor the bytes a window ships back, no module keeps a
+    name for it and no file of the package reads it."""
+    from ouroboros_consensus_tpu.analysis import envlevers
+    from ouroboros_consensus_tpu.obs.warmup import WARMUP
+    from ouroboros_consensus_tpu.utils.trace import (TransferEvent,
+                                                     WindowStaged)
+
+    params = make_params()
+    st0 = praos.PraosState(epoch_nonce=b"\x07" * 32)
+    hvs = real_chain(params, pools, 16)
+
+    def run():
+        res, events = _traced_chain(params, lview, st0, hvs, max_batch=16)
+        assert res.error is None and res.n_valid == len(hvs)
+        return (
+            [(e.outcome, e.gate) for e in events
+             if isinstance(e, WindowStaged)],
+            [e.d2h_bytes for e in events if isinstance(e, TransferEvent)],
+            sorted(k for k in WARMUP.report()["stages"]
+                   if k.startswith(("xla-packed", "agg-", "reduce"))),
+            sorted(repr(k) for k in pbatch._JIT),
+        )
+
+    monkeypatch.delenv("OCT_NONCE_SCAN", raising=False)
+    unset = run()
+    monkeypatch.setenv("OCT_NONCE_SCAN", value)
+    assert run() == unset
+    assert unset[0] == [("packed", None)]
+    assert not hasattr(pbatch, "NONCE_SCAN")
+    assert "OCT_NONCE_SCAN" not in envlevers.scan_reads(
+        envlevers.default_roots()
+    )

@@ -35,7 +35,8 @@ PARAMS = praos.PraosParams(
 MAX_BATCH = 16
 PIPELINE_DEPTH = 3  # validate_chain's default
 WINDOW_LABELS = {"stage", "dispatch", "materialize.wait",
-                 "materialize.copy", "materialize", "tick", "epilogue"}
+                 "materialize.copy", "materialize", "tick", "epilogue",
+                 "epilogue.fold"}
 
 
 def _replay(db):
@@ -140,6 +141,9 @@ def test_spans_name_the_thread_that_did_the_work(traced):
             {"validate-chain"}
     for label in ("open", "segment-wait", "validate-chain", "stream"):
         assert {e.parent for e in _ends(events, label=label)} == {"replay"}
+    # the host's nonce fold: inside the window's `epilogue`, every window
+    assert {e.parent for e in _ends(events, label="epilogue.fold")} == \
+        {"epilogue"}
     assert {e.parent for e in _ends(events, label="stream-mmap")} == \
         {"stream"}
     spans = [e for e in events if isinstance(e, T.WindowSpan)]

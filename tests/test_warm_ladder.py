@@ -131,7 +131,7 @@ def test_ladder_thread_matrix_equals_fold(pools, lview, chain, monkeypatch,
                                           fresh_pipeline, ladder, thread):
     """All four (ladder x staging-thread) combinations: byte-identical
     final state vs the sequential reupdate fold, across an epoch
-    boundary, with the device nonce-scan carry chained throughout."""
+    boundary, with the host nonce fold threaded throughout."""
     st0, hvs, st_ref = chain
     _LVIEW[0] = lview
     monkeypatch.setenv("OCT_WARM_LADDER", ladder)
@@ -371,7 +371,7 @@ def test_stage_pin_graph_resolution(monkeypatch):
         lambda n: ({"feature_hash": "x"} if n == "aggregate_core@1024"
                    else real_pinned(n)),
     )
-    s = "agg-packed:410b:scan:1024l"
+    s = "agg-packed:410b:1024l"
     assert costmodel.stage_graph(s) == "aggregate_core"
     assert costmodel.stage_pin_graph(s, 1024) == "aggregate_core@1024"
     assert costmodel.stage_pin_graph(s, 512) == "aggregate_core"
