@@ -3,10 +3,11 @@
 
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-One process, which holds the chip; no child that imports JAX. The cell, its
-configuration, its traffic mix and its per-layer metrics are found by name
-(benchmark/manifest.py). Context goes on earlier JSON lines; the last line
-of standard output is the result:
+One process, which holds the chip; its one child forges the chain on the
+CPU (`traffic/replay._forge_in_child`) and has ended before set-up goes on.
+The cell, its configuration, its traffic mix and its per-layer metrics are
+found by name (benchmark/manifest.py). Context goes on earlier JSON lines;
+the last line of standard output is the result:
 
     {"correct", "attempted", "failed", "metrics", "device", ["breakdown"], "compared"}
 

@@ -30,9 +30,11 @@ def _run(capsys, cell="replay-bc-2epoch", seed=SEED):
     rc = brun.main(["--workload", cell, "--seed", str(seed), "--seconds",
                     "0.2", "--trace", "0", "--cpu-rehearsal"])
     out, err = capsys.readouterr()
-    last = json.loads(out.strip().splitlines()[-1])
+    lines = [json.loads(x) for x in out.strip().splitlines()]
+    last = lines[-1]
     assert rc == 2 and last["line"] == "rehearsal"  # never a result line
-    return last["would_be"], err
+    window = next(x for x in lines if x["line"] == "window")
+    return last["would_be"], err, window
 
 
 def _plant(monkeypatch, fault):
@@ -49,7 +51,7 @@ def _plant(monkeypatch, fault):
 @pytest.mark.parametrize("cell", ["replay-bc-2epoch",
                                   "replay-draft03-2epoch"])
 def test_sound_run_is_correct(on_cpu, capsys, cell):
-    res, err = _run(capsys, cell)
+    res, err, window = _run(capsys, cell)
     assert res["correct"] is True and res["failed"] == 0
     assert all(c["value"] == 0 and c["limit"] == 0
                for c in res["compared"].values())
@@ -57,6 +59,10 @@ def test_sound_run_is_correct(on_cpu, capsys, cell):
     assert "compared wrong_header_mismatches: value 0 limit 0" in err
     assert err.strip().splitlines()[-1] == "correct: True"
     assert list(res)[-1] == "compared"
+    # the rate is all the headers of the window over all its wall
+    assert res["metrics"]["replay_headers_per_s"]["value"] == \
+        pytest.approx(res["attempted"] / window["seconds"], rel=1e-3)
+    assert res["attempted"] == 12 * window["replays"] >= 12
 
 
 def test_chain_differs_by_seed_and_proof_format(on_cpu, capsys):
@@ -81,7 +87,7 @@ def test_state_returned_unchanged_is_not_correct(on_cpu, capsys, monkeypatch):
         return dataclasses.replace(r, state=state)
 
     _plant(monkeypatch, fault)
-    res, _ = _run(capsys)
+    res, _, _ = _run(capsys)
     assert res["correct"] is False
     assert res["compared"]["state_mismatches"]["value"] > 0
 
@@ -97,7 +103,7 @@ def test_half_the_lanes_left_out_is_not_correct(on_cpu, capsys, monkeypatch):
         return r
 
     _plant(monkeypatch, fault)
-    res, _ = _run(capsys)
+    res, _, _ = _run(capsys)
     assert res["correct"] is False
     assert res["compared"]["wrong_header_mismatches"]["value"] == 3
 
@@ -109,7 +115,7 @@ def test_an_altered_answer_is_not_correct(on_cpu, capsys, monkeypatch):
         return dataclasses.replace(r, n_valid=max(0, r.n_valid - 1))
 
     _plant(monkeypatch, fault)
-    res, _ = _run(capsys)
+    res, _, _ = _run(capsys)
     assert res["correct"] is False and res["failed"] > 0
     assert res["compared"]["n_valid_gap"]["value"] >= 1
 
@@ -129,7 +135,7 @@ def test_the_control_is_not_correct(on_cpu, capsys, monkeypatch):
         return r
 
     _plant(monkeypatch, fault)
-    res, _ = _run(capsys)
+    res, _, _ = _run(capsys)
     assert res["correct"] is False
     assert res["compared"]["wrong_header_mismatches"]["value"] == 1
     assert res["compared"]["state_mismatches"]["value"] == 0

@@ -97,6 +97,73 @@ def test_a_module_run_cut_by_the_stretch_is_left_out(trace, stage_map):
     assert red["busy_s"] == pytest.approx((100 + 250 + 500 + 200) / 1e9)
 
 
+@pytest.fixture(scope="module")
+def ends():
+    with open(os.path.join(HERE, "fixtures", "recorded_trace_ends.json")) as f:
+        return json.load(f)
+
+
+def _whole_stretch(tr):
+    """A stretch that holds every event of the trace (as PR 29's final
+    draft-03 traced run held the event its profiler's stop had cut)."""
+    evs = [e for pl in tr["planes"] for ln in pl["lines"]
+           for e in ln["events"]]
+    return [0, max(s + d for _, s, d in evs) + 1e6]
+
+
+def test_a_program_cut_by_the_profilers_stop_is_not_counted(ends, stage_map):
+    """The end of a trace recorded on the chip: the profiler stopped while
+    `jit_ed_points` ran and shows it as 270 ns long, after the trace's last
+    operation. Counted, it made `kernel_ms_per_window.ed` read 15.26 ms for
+    16.95 (PR 29). The whole tenth run of `unpack` before it is counted."""
+    tr = ends["cut"]
+    mods = tr["planes"][0]["lines"][0]["events"]
+    last = max(mods, key=lambda e: e[1])
+    assert last[0].startswith("jit_ed_points") and last[2] < 1000
+    red = xplane.reduce(tr, stage_map, _whole_stretch(tr))
+    assert red["stage_runs"] == {"unpack": 10, "ed": 9, "kes": 9, "vrf": 9,
+                                 "finish": 9, "reduce": 9}
+    ed = trace_module.read({"stage": "ed", "scale": 1000}, {"trace": red})
+    assert ed == pytest.approx(16.9507, abs=2e-4)
+    assert trace_module.read({"stage": "unpack", "scale": 1000},
+                             {"trace": red}) == pytest.approx(0.4076,
+                                                              abs=5e-4)
+
+
+def test_the_last_program_before_an_idle_stop_is_not_counted_either(
+        ends, stage_map):
+    """The profiler stopped on an idle device: the line's last event is a
+    whole `jit_reduce_fn`, which nothing in the trace tells from a cut
+    one. One whole run fewer; every mean as it was."""
+    tr = ends["idle"]
+    red = xplane.reduce(tr, stage_map, _whole_stretch(tr))
+    assert red["stage_runs"] == {"unpack": 9, "ed": 9, "kes": 9, "vrf": 9,
+                                 "finish": 9, "reduce": 8}
+    assert trace_module.read({"stage": "vrf", "scale": 1000},
+                             {"trace": red}) == pytest.approx(30.1499,
+                                                              abs=2e-4)
+
+
+def test_a_program_cut_after_its_first_operation_is_not_counted(
+        ends, stage_map):
+    """The end of a bc trace recorded on the chip: the profiler stopped
+    17.05 ms into a `jit_vrf_points_bc` of 31.76 ms. It kept the program's
+    first operation, dropped the one that was running, and shows the
+    program as long as it had run. Counted, it would move the mean of
+    eight runs from 31.76 ms to 29.92."""
+    tr = ends["cut_inside"]
+    mods = tr["planes"][0]["lines"][0]["events"]
+    last = max(mods, key=lambda e: e[1])
+    assert last[0].startswith("jit_vrf_points_bc")
+    assert last[2] == pytest.approx(17.0457e6, abs=1e3)
+    red = xplane.reduce(tr, stage_map, _whole_stretch(tr))
+    assert red["stage_runs"] == {"unpack": 8, "ed": 8, "kes": 8, "vrf": 7,
+                                 "finish": 7, "reduce": 7}
+    assert trace_module.read({"stage": "vrf", "scale": 1000},
+                             {"trace": red}) == pytest.approx(31.7613,
+                                                              abs=2e-4)
+
+
 def test_op_names_are_cut_short():
     assert xplane.short_name(
         "%while.44 = (s32[]{:T(128)}, s32[32]{0:T(128)S(1)}) while(...)"

@@ -26,15 +26,19 @@ import dataclasses
 import os
 import random
 import shutil
+import subprocess
+import sys
 import time
 from fractions import Fraction
 
-from benchmark import harness, xplane
+from benchmark import harness, replay_rate, xplane
 from benchmark.harness import FailedRun, emit
 from benchmark.reference import praos as ref
 
-CACHE = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "_cache")
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CACHE = os.path.join(BENCH, "_cache")
+# a forge takes 76-92 s on the chip's host; a child that hangs is ended
+FORGE_TIMEOUT_S = 900
 # the per-lane stage programs of the packed pk dispatch: a first execute
 # outside them means a window took another path (copied from chip_smoke.py)
 PK_STAGES = ("unpack_", "ed@", "kes@", "vrf_bc@", "vrf@", "finish@",
@@ -82,56 +86,89 @@ def mix_of(cell, rehearsal: bool) -> dict:
     return mix
 
 
-def make_inputs(cell, seed: int, rehearsal: bool) -> Inputs:
-    """Pool credentials, ledger view and chain, all from the seed: the
-    pool's index IS the seed, so keys, VRF outputs, leader slots and every
-    header differ by seed. The chain is kept under benchmark/_cache/ with
-    a COMPLETE marker; a second run of a seed in one checkout reuses it."""
+def _deployment(cell, seed: int, rehearsal: bool):
+    """-> (params, pools, ledger view, the chain's home), from the seed:
+    the pool's index IS the seed, so keys, VRF outputs, leader slots and
+    every header differ by seed."""
     from ouroboros_consensus_tpu.protocol import praos
     from ouroboros_consensus_tpu.testing import fixtures
-    from ouroboros_consensus_tpu.tools import db_synthesizer as synth
 
-    cfg, mix = cell.config, mix_of(cell, rehearsal)
+    cfg = cell.config
     proto = _protocol(cfg)
-    params = praos.PraosParams(**proto)
-    depth = proto["kes_depth"]
-    pools = [fixtures.make_pool(seed + i, kes_depth=depth)
+    pools = [fixtures.make_pool(seed + i, kes_depth=proto["kes_depth"])
              for i in range(cfg["pools"])]
-    lview = fixtures.make_ledger_view(pools)
-    pool_distr = {p.pool_id: (e.stake, e.vrf_key_hash)
-                  for p in pools
-                  for e in [lview.pool_distr[p.pool_id]]}
-    limit = (synth.ForgeLimit(blocks=mix["blocks"]) if mix.get("blocks")
-             else synth.ForgeLimit(epochs=mix["epochs"]))
     tag = "rehearsal-" if rehearsal else ""
     home = os.path.join(CACHE, f"{tag}{cell.config_name}-"
                                f"{cell.traffic_name}-s{seed}")
+    return (praos.PraosParams(**proto), pools,
+            fixtures.make_ledger_view(pools), home)
+
+
+def forge_chain(cell, seed: int, rehearsal: bool) -> None:
+    """What the forging child does: the chain of the seed, on disk under
+    benchmark/_cache/ with a COMPLETE marker."""
+    from ouroboros_consensus_tpu.tools import db_synthesizer as synth
+
+    mix = mix_of(cell, rehearsal)
+    params, pools, lview, home = _deployment(cell, seed, rehearsal)
+    limit = (synth.ForgeLimit(blocks=mix["blocks"]) if mix.get("blocks")
+             else synth.ForgeLimit(epochs=mix["epochs"]))
     path = os.path.join(home, "chain")
+    shutil.rmtree(home, ignore_errors=True)
+    os.makedirs(path)
+    # vrf_backend="host": forging must not touch the device
+    res = synth.synthesize(path, params, pools, lview, limit,
+                           vrf_backend="host")
+    with open(os.path.join(home, "COMPLETE"), "w") as f:
+        f.write(str(res.n_blocks))
+
+
+def _forge_in_child(cell, seed: int, rehearsal: bool) -> None:
+    """The chain is forged by a process of its own, held to the CPU, and
+    the timed process opens it from disk, as `db-analyser` opens a chain
+    that a node wrote. Forged in the timed process (until PR 31), a run
+    that forged opened its window in an older process with another heap
+    than one that found its chain cached, met staging's slow phases at
+    other replays, and two sets of one tree differed by up to 3.9% in
+    their medians (PERF.md sections 5 and 6). The configuration's
+    `forge_env` is the child's alone."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               **{k: str(v) for k, v in
+                  cell.config.get("forge_env", {}).items()})
+    cmd = [sys.executable, "-m", "benchmark.traffic.replay", cell.name,
+           str(seed)] + (["--cpu-rehearsal"] if rehearsal else [])
+    try:
+        p = subprocess.run(cmd, cwd=os.path.dirname(BENCH), env=env,
+                           stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+                           text=True, errors="replace",
+                           timeout=FORGE_TIMEOUT_S)
+    except subprocess.TimeoutExpired as e:  # run() has ended the child
+        raise FailedRun("the forging child did not end",
+                        seconds=FORGE_TIMEOUT_S, tail=str(e.stdout)[-2000:])
+    if p.returncode:
+        raise FailedRun("the forging child failed", child_rc=p.returncode,
+                        tail=p.stdout[-2000:])
+
+
+def make_inputs(cell, seed: int, rehearsal: bool) -> Inputs:
+    """Pool credentials, ledger view and chain, all from the seed. The
+    chain is kept under benchmark/_cache/ with a COMPLETE marker; a
+    second run of a seed in one checkout reuses it."""
+    cfg, mix = cell.config, mix_of(cell, rehearsal)
+    params, pools, lview, home = _deployment(cell, seed, rehearsal)
+    pool_distr = {p.pool_id: (e.stake, e.vrf_key_hash)
+                  for p in pools
+                  for e in [lview.pool_distr[p.pool_id]]}
     marker = os.path.join(home, "COMPLETE")
     reused = os.path.exists(marker)
     t0 = time.monotonic()
     if not reused:
-        shutil.rmtree(home, ignore_errors=True)
-        os.makedirs(path)
-        forge_env = {k: str(v) for k, v in cfg.get("forge_env", {}).items()}
-        saved = {k: os.environ.get(k) for k in forge_env}
-        os.environ.update(forge_env)  # round the forge, and only there
-        try:
-            # vrf_backend="host": forging must not touch the device
-            res = synth.synthesize(path, params, pools, lview, limit,
-                                   vrf_backend="host")
-        finally:
-            for k, v in saved.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
-        with open(marker, "w") as f:
-            f.write(str(res.n_blocks))
+        _forge_in_child(cell, seed, rehearsal)
     with open(marker) as f:
         n_blocks = int(f.read())
     max_headers = mix.get("max_headers")
-    return Inputs(path, params, ref.Params(**proto), pools, lview,
+    return Inputs(os.path.join(home, "chain"), params,
+                  ref.Params(**_protocol(cfg)), pools, lview,
                   pool_distr, mix.get("max_batch", cfg["max_batch"]),
                   max_headers, reused, time.monotonic() - t0,
                   min(n_blocks, max_headers or n_blocks))
@@ -576,7 +613,12 @@ def run(cell, args, device: dict) -> dict:
     headers_done = sum(r.n_valid for r in results)
     spans = [e for e in events if isinstance(e, WindowSpan)]
     staged = [e for e in events if isinstance(e, WindowStaged)]
+    # the rate is all the headers of the window over all its wall; what
+    # the replays' walls say beside it are per-layer metrics
+    rate = headers_done / window_s
+    stats = replay_rate.window_stats(headers_done, walls, window_s)
     emit("window", seconds=round(window_s, 4), replays=len(results),
+         replay_headers_per_s=rate, **stats,
          replay_walls_s=[round(w, 3) for w in walls], headers=headers_done,
          # per device window, for the day a run reads far off
          materialize_ms=[round(e.materialize_s * 1e3) for e in spans],
@@ -602,6 +644,7 @@ def run(cell, args, device: dict) -> dict:
             phase_wall[k] = phase_wall.get(k, 0.0) + v
     sources = {
         "replays": len(results),
+        "window_stats": stats,
         "phase_wall": phase_wall,
         "window_spans": [dataclasses.asdict(s) for s in spans],
         "counters": {
@@ -621,9 +664,16 @@ def run(cell, args, device: dict) -> dict:
     return {
         "correct": correct, "attempted": inp.headers * len(results),
         "failed": failed, "compared": compared,
-        "end_to_end": {"replay_headers_per_s": headers_done / window_s,
-                       "setup_s": setup_s},
+        "end_to_end": {"replay_headers_per_s": rate, "setup_s": setup_s},
         "sources": sources, "memory_peak_bytes": peak,
         "trace_path": trace_path, "stretch": stretch,
         "window": (t0, t0 + window_s), "replay_walls": walls,
     }
+
+
+if __name__ == "__main__":
+    # the forging child: python3 -m benchmark.traffic.replay <cell> <seed>
+    from benchmark.manifest import Manifest
+
+    forge_chain(Manifest().cell(sys.argv[1]), int(sys.argv[2]),
+                "--cpu-rehearsal" in sys.argv[3:])
