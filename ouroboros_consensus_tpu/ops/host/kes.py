@@ -49,7 +49,9 @@ def _seed_right(seed: bytes) -> bytes:
     return _h256(b"\x02" + seed)
 
 
-@lru_cache(maxsize=1 << 14)
+# 255 subtrees a depth-7 key: room for the 512 pools of a mainnet-shaped
+# forge, whose slots interleave every pool's tree (2^14 held 64 of them)
+@lru_cache(maxsize=1 << 18)
 def derive_vk(seed: bytes, depth: int) -> bytes:
     """Verification key of the subtree rooted at `seed` with `depth` levels."""
     if depth == 0:

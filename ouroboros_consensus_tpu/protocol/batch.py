@@ -48,6 +48,7 @@ boundaries and threads the tiny PraosState between them.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import threading
@@ -74,6 +75,27 @@ from .views import (
 # ---------------------------------------------------------------------------
 
 
+# fraction bits of the bracket's fixed-point series: its rounding (2^-500
+# of the threshold) is nothing beside the 64-term series' own remainder
+_BRACKET_BITS = 512
+_BRACKET_TERMS = 64
+
+
+def _exp_fixed(x: int, up: bool) -> int:
+    """A bound of exp(x / 2^_BRACKET_BITS) in the same fixed point, from
+    below (every term rounded down) or from above (every term rounded up,
+    plus the tail's geometric bound x^(N+1)/(N+1)!/(1-x)); 0 <= x < 1."""
+    one = 1 << _BRACKET_BITS
+    acc = term = one
+    for n in range(1, _BRACKET_TERMS + 1):
+        term = -(-term * x // (n * one)) if up else term * x // (n * one)
+        acc += term
+    if up:
+        rem = -(-term * x // ((_BRACKET_TERMS + 1) * one))
+        acc += -(-rem * one // (one - x))
+    return acc
+
+
 @lru_cache(maxsize=4096)
 def leader_threshold_bracket(sigma: Fraction, f: Fraction) -> tuple[int, int]:
     """[T_lo, T_hi] integers bracketing 2^256 * (1 - (1-f)^sigma).
@@ -81,20 +103,28 @@ def leader_threshold_bracket(sigma: Fraction, f: Fraction) -> tuple[int, int]:
     leader_value < T_lo  => certainly a leader;
     leader_value >= T_hi => certainly not;
     otherwise undecided (exact host check).  With 64 series terms the
-    bracket width is far below 1 for every realistic (sigma, f), so the
-    ambiguous band is empty in practice.
+    bracket is some 2^-70 of the range wide at f = 1/2, so the ambiguous
+    band is empty in practice.
+
+    The series runs in integer fixed point with directed rounding, not
+    in exact rationals: a stake that is a share of many pools' weights
+    has a denominator of hundreds of digits, which the exact series
+    raised to its 64th power (0.2 s a pool: 100 s for the 512 of a
+    mainnet-shaped ledger view, PERF.md PR 32), and the bracket need
+    only BE a bracket.
     """
     if f == 1:
         return (leader.LEADER_VALUE_MAX, leader.LEADER_VALUE_MAX)
     if sigma == 0:
         return (0, 0)
-    llo, lhi = leader._neg_log1m_interval(f, 64)
-    elo, ehi = leader._exp_interval(sigma * llo, sigma * lhi, 64)
+    llo, lhi = leader._neg_log1m_interval(f, _BRACKET_TERMS)
+    one = 1 << _BRACKET_BITS
+    xlo, xhi = sigma * llo, sigma * lhi
+    elo = _exp_fixed(xlo.numerator * one // xlo.denominator, up=False)
+    ehi = _exp_fixed(-(-xhi.numerator * one // xhi.denominator), up=True)
     # lhs = 2^256/(2^256 - lv) < exp(x)  <=>  lv < 2^256 (1 - 1/exp(x))
-    t_lo = leader.LEADER_VALUE_MAX * (1 - Fraction(1) / elo)
-    t_hi = leader.LEADER_VALUE_MAX * (1 - Fraction(1) / ehi)
-    lo = int(t_lo)  # floor: lv < floor(T_lo) <= T_lo  => leader
-    hi = -int(-t_hi)  # ceil: lv >= ceil(T_hi) >= T_hi => not leader
+    lo = leader.LEADER_VALUE_MAX * (elo - one) // elo  # floor
+    hi = -(-leader.LEADER_VALUE_MAX * (ehi - one) // ehi)  # ceil
     return (lo, hi)
 
 
@@ -986,6 +1016,27 @@ def _table_bucket(k: int, minimum: int = 8) -> int:
     return n
 
 
+def _table_rows(params: PraosParams, ledger_view: LedgerView, slots,
+                kes_tails: int, thr_rows: int) -> tuple[int, int]:
+    """Row counts of a window's two dedup tables (KES tails, thresholds):
+    the bucket of what the window COULD hold, not of what it happens to.
+    A window of b lanes whose slots span k KES periods under a ledger
+    view of P pools holds at most min(b, P * k) tails and min(b, P)
+    threshold rows (more only if a pool changes its hot key or is
+    unknown; then the count itself is bucketed). One pool reads 8 and 8
+    as before. With hundreds of pools the count a window happens to hold
+    crosses a power of two from seed to seed (15 issuers or 17 among the
+    chain's first lanes), so a replay on a warm store met an `unpack`
+    shape nobody had built, built it in set-up, and paid the heap that
+    leaves with a 3.9 s collection inside its window (PERF.md, PR 32)."""
+    b = len(slots)
+    kp = np.asarray(slots) // params.slots_per_kes_period
+    pools = len(ledger_view.pool_distr)
+    periods = int(kp.max() - kp.min()) + 1
+    return (_table_bucket(max(kes_tails, min(b, pools * periods))),
+            _table_bucket(max(thr_rows, min(b, pools))))
+
+
 def _col(parts: Sequence[bytes], n: int) -> np.ndarray:
     b = len(parts)
     return np.frombuffer(b"".join(parts), np.uint8).reshape(b, n)
@@ -1069,11 +1120,6 @@ def stage_packed(
     kt_idx = np.empty(b, np.int32)
     for i, hv in enumerate(hvs):
         kt_idx[i] = tails.setdefault(hv.kes_sig[64:], len(tails))
-    kt_tab = np.zeros((_table_bucket(len(tails)), sig_len - 64), np.uint8)
-    for t, j in tails.items():
-        kt_tab[j] = np.frombuffer(t, np.uint8)
-    kt_tab[len(tails) :] = kt_tab[0]
-
     f = Fraction(params.active_slot_coeff)
     thr_rows: dict = {}
     rows: list[np.ndarray] = []
@@ -1087,7 +1133,13 @@ def stage_packed(
             lo, hi = _threshold_rows(sigma, f)
             rows.append(np.concatenate([lo, hi]))
         thr_idx[i] = j
-    thr_tab = np.zeros((_table_bucket(len(rows)), 64), np.uint8)
+    kt_n, thr_n = _table_rows(params, ledger_view, slot, len(tails),
+                              len(rows))
+    kt_tab = np.zeros((kt_n, sig_len - 64), np.uint8)
+    for t, j in tails.items():
+        kt_tab[j] = np.frombuffer(t, np.uint8)
+    kt_tab[len(tails) :] = kt_tab[0]
+    thr_tab = np.zeros((thr_n, 64), np.uint8)
     thr_tab[: len(rows)] = np.stack(rows)
     thr_tab[len(rows) :] = thr_tab[0]
 
@@ -1164,13 +1216,15 @@ def stage_packed_columns(
 
     kes_rs = np.ascontiguousarray(vc.kes_sig[:, :64])
     kt_rows, kt_idx = _dedup_rows(vc.kes_sig[:, 64:])
-    kt_tab = np.zeros((_table_bucket(kt_rows.shape[0]), sig_len - 64), np.uint8)
+    lo_rows, hi_rows = _uniq_threshold_rows(params, pre)
+    rows = [np.concatenate([lo, hi]) for lo, hi in zip(lo_rows, hi_rows)]
+    kt_n, thr_n = _table_rows(params, ledger_view, slot, kt_rows.shape[0],
+                              len(rows))
+    kt_tab = np.zeros((kt_n, sig_len - 64), np.uint8)
     kt_tab[: kt_rows.shape[0]] = kt_rows
     kt_tab[kt_rows.shape[0] :] = kt_tab[0]
 
-    lo_rows, hi_rows = _uniq_threshold_rows(params, pre)
-    rows = [np.concatenate([lo, hi]) for lo, hi in zip(lo_rows, hi_rows)]
-    thr_tab = np.zeros((_table_bucket(len(rows)), 64), np.uint8)
+    thr_tab = np.zeros((thr_n, 64), np.uint8)
     thr_tab[: len(rows)] = np.stack(rows)
     thr_tab[len(rows) :] = thr_tab[0]
 
@@ -1991,6 +2045,7 @@ class _WinMeta(NamedTuple):
     t_dispatch_start: float
     stage_thread: str
     stage_wait_s: float = 0.0
+    census: "_StageCensus | None" = None
 
 
 class _Dispatched(NamedTuple):
@@ -2026,7 +2081,8 @@ def _win_meta(outcome: str, gate: str | None, sw: "_StagedWindow",
     BATCH_TRACER(WindowStaged(sw.window, sw.b, sw.lanes, outcome, gate,
                               stage_s, dispatch_s))
     return _WinMeta(sw.window, outcome, gate, stage_s, dispatch_s,
-                    sw.lanes, t2, sw.t0, sw.t1, t_d0, sw.thread)
+                    sw.lanes, t2, sw.t0, sw.t1, t_d0, sw.thread,
+                    census=sw.census)
 
 
 def _emit_window_span(meta, lanes: int, n_valid: int, failed: bool,
@@ -2051,7 +2107,35 @@ def _emit_window_span(meta, lanes: int, n_valid: int, failed: bool,
         t_stage_start=meta.t_stage_start, t_stage_end=meta.t_stage_end,
         t_dispatch_start=meta.t_dispatch_start,
         stage_thread=meta.stage_thread,
+        epilogue_counters_s=_COUNTERS_S[0],
+        **(meta.census._asdict() if meta.census is not None else {}),
     ))
+
+
+class _StageCensus(NamedTuple):
+    """Who a window holds, counted while it is staged (only with a
+    tracer installed): what grows with the number of issuers, where a
+    one-pool chain reads 1, 2-3, 1 (WindowSpan fields of these names)."""
+
+    issuers: int  # distinct cold keys
+    kes_tails: int  # rows of the KES tail table before padding
+    thr_rows: int  # rows of the threshold table before padding
+    prechecks_s: float  # span `stage.prechecks`
+
+
+def _stage_census(hvs, pre, packed, prechecks_s: float) -> _StageCensus:
+    if isinstance(pre, ColumnChecks):
+        issuers = len(set(pre.uniq_hk))
+    else:
+        issuers = len({hv.vk_cold for hv in hvs})
+    kes_tails = thr_rows = 0
+    if packed is not None:
+        # every row of a dedup table is some lane's, so the largest
+        # index names the last row (padding replicates lane 0's)
+        parr = packed[1]
+        kes_tails = int(parr.kes_tail_idx.max()) + 1
+        thr_rows = int(parr.thr_idx.max()) + 1
+    return _StageCensus(issuers, kes_tails, thr_rows, prechecks_s)
 
 
 class _StagedWindow(NamedTuple):
@@ -2072,6 +2156,7 @@ class _StagedWindow(NamedTuple):
     t1: float
     window: int  # the window's id (`next_window_id`)
     thread: str  # the thread that staged it
+    census: _StageCensus | None = None
 
 
 def window_lanes(max_batch: int) -> int | None:
@@ -2118,7 +2203,9 @@ def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
     thread = threading.current_thread().name
     t0 = time.monotonic()
     with _enclose("stage", window):
-        pre = host_prechecks(params, lview, hvs)
+        with _enclose("stage.prechecks"):
+            pre = host_prechecks(params, lview, hvs)
+        t_pre = time.monotonic()
         packed = None
         gate = None
         if PACKED_STAGE and not os.environ.get("OCT_PK_FUSED"):
@@ -2137,19 +2224,22 @@ def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
                     gate = _LAST_DECLINE
         else:
             gate = "packed-off"
+        census = (_stage_census(hvs, pre, packed, t_pre - t0)
+                  if BATCH_TRACER is not None else None)
         if packed is None:
             batch = stage_any(params, lview, eta0, hvs, pre)
             padded = pad_batch_to(batch, size)
             h2d = _nbytes(flatten_batch(padded))
             lanes = padded.beta.shape[0]
             return _StagedWindow(pre, None, padded, b, lanes, h2d, gate,
-                                 t0, time.monotonic(), window, thread)
+                                 t0, time.monotonic(), window, thread,
+                                 census)
         layout, parr = packed
         parr = pad_packed_to(parr, size)
         h2d = _nbytes(parr)
         lanes = parr.body.shape[0]
     return _StagedWindow(pre, (layout, parr), None, b, lanes, h2d, gate,
-                         t0, time.monotonic(), window, thread)
+                         t0, time.monotonic(), window, thread, census)
 
 
 def _agg_label(layout, lanes: int, mode: str = "all") -> str:
@@ -2604,6 +2694,23 @@ def _materialize_packed(out, b, impl, window=None):
     return pv
 
 
+# wall of the last `epilogue.counters` span: the retire path clears it
+# before `_epilogue` and reads it into the window's WindowSpan after
+_COUNTERS_S = [0.0]
+
+
+@contextlib.contextmanager
+def _counters_span():
+    """Span `epilogue.counters`: the OCert counter gate of a clean
+    window's epilogue."""
+    t0 = time.monotonic()
+    try:
+        with _enclose("epilogue.counters"):
+            yield
+    finally:
+        _COUNTERS_S[0] = time.monotonic() - t0
+
+
 def _fold_nonces(params: PraosParams, st: PraosState, slots, etas):
     """The window's evolving/candidate nonce fold over its eta column
     ([b, 32] uint8), lane by lane in chain order -> (evolving,
@@ -2653,13 +2760,14 @@ def _epilogue_packed_fast(
     st = ticked.state
     lview = ticked.ledger_view
     counters = dict(st.ocert_counters)
-    for hv in hvs:
-        hk = hash_key(hv.vk_cold)
-        if not _counter_ok(
-            _counter_m(hk, counters, lview.pool_distr), hv.ocert.counter
-        ):
-            return None  # slow path reconstructs the exact error
-        counters[hk] = hv.ocert.counter
+    with _counters_span():
+        for hv in hvs:
+            hk = hash_key(hv.vk_cold)
+            if not _counter_ok(
+                _counter_m(hk, counters, lview.pool_distr), hv.ocert.counter
+            ):
+                return None  # slow path reconstructs the exact error
+            counters[hk] = hv.ocert.counter
     evolving, candidate = _fold_nonces(
         params, st, [hv.slot for hv in hvs], v.eta_u8
     )
@@ -2688,6 +2796,31 @@ def _verdicts_clean(v, b: int) -> bool:
     )
 
 
+def _counters_gate(cnt, inv, uniq_hk, counters, pool_distr) -> dict | None:
+    """The OCert counter gate of a clean window, by issuer: each
+    issuer's counters (`cnt[inv == j]`, in chain order) start at its
+    current one or one above and step by 0 or 1. -> the counters after
+    the window, or None where any issuer's do not (the caller's exact
+    fold then names the error). One stable sort groups the lanes by
+    issuer: a pass an ISSUER over the window was 8 ms of the retire path
+    at 500 issuers (PERF.md, PR 32)."""
+    counters = dict(counters)
+    order = np.argsort(inv, kind="stable")
+    cs, grp = cnt[order], inv[order]
+    new_grp = grp[1:] != grp[:-1]
+    d = np.diff(cs)
+    if ((d < 0) | (d > 1))[~new_grp].any():
+        return None
+    first = np.flatnonzero(np.concatenate([[True], new_grp]))
+    last = np.concatenate([first[1:], [len(cs)]]) - 1
+    for j, hk in enumerate(uniq_hk):
+        m = _counter_m(hk, counters, pool_distr)
+        if m is None or not m <= cs[first[j]] <= m + 1:
+            return None
+        counters[hk] = int(cs[last[j]])
+    return counters
+
+
 def _epilogue_columns_fast(
     params: PraosParams,
     ticked: TickedPraosState,
@@ -2712,20 +2845,13 @@ def _epilogue_columns_fast(
         return None
     st = ticked.state
     lview = ticked.ledger_view
-    counters = dict(st.ocert_counters)
-    cnt = vc.ocert_counter
-    inv = pre.uniq_inv
-    for j, hk in enumerate(pre.uniq_hk):
-        m = _counter_m(hk, counters, lview.pool_distr)
-        if m is None:
-            return None
-        cs = cnt[inv == j]
-        d = np.diff(cs)
-        if not (
-            m <= cs[0] <= m + 1 and (d >= 0).all() and (d <= 1).all()
-        ):
-            return None
-        counters[hk] = int(cs[-1])
+    with _counters_span():
+        counters = _counters_gate(
+            vc.ocert_counter, pre.uniq_inv, pre.uniq_hk,
+            st.ocert_counters, lview.pool_distr,
+        )
+    if counters is None:
+        return None
 
     etas = (
         v.eta_u8 if isinstance(v, PackedVerdicts)
@@ -3260,6 +3386,7 @@ def _device_loop(
                 "lookahead epoch nonce mismatch"
             )
         t_e0 = time.monotonic()
+        _COUNTERS_S[0] = 0.0  # a window off the fast epilogue reads 0
         if fail is None:
             try:
                 with _enclose("epilogue", win):

@@ -30,6 +30,16 @@ vectorized threshold compares, "loop" (`OCT_FORGE_DEVICE=0`) is the
 untouched per-slot reference loop in tools/db_synthesizer. All three
 are byte-identical for the same seed/params (tests/test_forge.py).
 
+On the chip (`batch._impl() == "pk"`) the device engine is the
+LEADER-VALUE sweep (`LeaderSweep`, ops/pk/elect.py): a pair's
+leadership needs only beta, one variable-base multiplication where a
+proof needs three, so the grid is bracketed on the verify side's own
+ladder, two bits a pair come back, and the host proves the pairs that
+won. 512 pools x 86,400 slots is 4.4e7 pairs: hours of host proves,
+about 100 s of that sweep. `synthesize(elector=...)` takes the elected
+(slot, pool) rows from elsewhere, so the election can run in the
+process that holds the chip and the assembly in another.
+
 Failure citizenship: election dispatches ride a recovery ladder
 (retry → host-reference exact loop, obs/recovery.py vocabulary) and
 carry the `forge-dispatch` / `forge` chaos seams (testing/chaos.py).
@@ -39,11 +49,13 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from fractions import Fraction
 from typing import NamedTuple
 
 import numpy as np
 
+from ..block.forge import evaluate_vrf
 from ..ops.host import fast
 from ..ops.host import kes as host_kes
 from ..testing import chaos
@@ -190,6 +202,17 @@ def stage_pools(pools) -> PoolStaging:
     return PoolStaging(x, prefix, pk)
 
 
+def stage_engine(params: PraosParams, pools, engine: str):
+    """What an engine stages once a run: the leader sweep's device
+    columns on the chip, the full-prove sweep's host columns on the XLA
+    twin, nothing on the host engine."""
+    if engine != "device":
+        return None
+    if _leader_sweep_enabled():
+        return LeaderSweep(params, pools)
+    return stage_pools(pools)
+
+
 def pool_thresholds(params: PraosParams, lview: LedgerView, pools):
     """Per-pool (lo_rows [P,32], hi_rows [P,32], sigmas) — the
     unknown-pool sigma-0 convention and clamped bracket encoding of
@@ -287,7 +310,10 @@ def _elect_window_device(params, pools, stg: PoolStaging, thr, slots,
                          eta0) -> list[Elected]:
     """Packed device engine: the whole pools×slots grid through
     forge_sweep in FORGE_BUCKET dispatches (padded to one cached
-    shape), verdict bitmaps and proof columns scattered back."""
+    shape), verdict bitmaps and proof columns scattered back. On the
+    chip `stg` is a `LeaderSweep`: bitmaps alone, winners proved here."""
+    if isinstance(stg, LeaderSweep):
+        return _elect_window_leader(stg, thr, slots, eta0)
     lo_rows, hi_rows, sigmas = thr
     p = len(pools)
     ns = len(slots)
@@ -368,6 +394,148 @@ class _LazyRows:
 
     def __getitem__(self, i):
         return self._rows[int(i)]
+
+
+# lanes of one leader-sweep dispatch: whole slots x every pool, one
+# compiled shape a pool count. Module-level so tests can shrink it.
+SWEEP_LANES = 1 << 18
+# dispatches kept in flight ahead of the one being read
+SWEEP_DEPTH = 3
+# None: the leader-value sweep is the device engine wherever the
+# Pallas kernels are (`batch._impl() == "pk"`); tests set True
+LEADER_SWEEP: bool | None = None
+# test seam (testing/stubs.install_stub_forge): the sweep program
+_LEADER_FN = None
+# what each sweep program cost this process: {"name", "lanes", "via"
+# ("store" | "built" | "jit"), "wall_s"}, for whoever reports set-up
+SWEEP_PROGRAMS: list = []
+
+
+def _leader_sweep_enabled() -> bool:
+    if LEADER_SWEEP is not None:
+        return LEADER_SWEEP
+    from . import batch as pbatch
+
+    return pbatch._impl() == "pk"
+
+
+def _sweep_program(args):
+    """The compiled leader sweep for these argument shapes: loaded
+    from the store of stage programs (ops/pk/aot), else built and, where
+    write-back is on, stored, so that a later process loads it. Its
+    first execute is no warm-up STAGE note: that vocabulary is the
+    replay's ("a first execute outside the per-lane stages" means a
+    window left its path); `SWEEP_PROGRAMS` says what it cost."""
+    from ..ops.pk import aot, elect
+    from ..ops.pk import kernels as pk_kernels
+
+    if _LEADER_FN is not None:  # a stub: the plain jit
+        if "leader_sweep-stub" not in _JITS:
+            import jax
+
+            _JITS["leader_sweep-stub"] = jax.jit(_LEADER_FN)
+        return _JITS["leader_sweep-stub"]
+    lanes = elect.sweep_lanes(args[-1].shape[0], args[0].shape[0])
+    key = ("leader_sweep", aot.sig_of(args))
+    if key in _JITS:
+        return _JITS[key]
+    name = "elect_" + elect.source_tag()
+    t0 = time.monotonic()
+    ex, via = None, "jit"
+    if aot.enabled():
+        ex = aot.load(name, lanes, 0, pk_kernels.TILE, key[1])
+        via = "store" if ex is not None else via
+    if ex is None and aot.writeback_enabled():
+        ex = aot.compile_and_store(name, lanes, 0, pk_kernels.TILE,
+                                   elect.jitted_sweep(), args)
+        via = "built" if ex is not None else via
+    if ex is None:
+        ex = elect.jitted_sweep()
+    SWEEP_PROGRAMS.append({"name": name, "lanes": lanes, "via": via,
+                           "wall_s": round(time.monotonic() - t0, 3)})
+    _JITS[key] = ex
+    return ex
+
+
+class LeaderSweep:
+    """The election of every (slot, pool) pair on the device, by leader
+    value alone (ops/pk/elect.py). The pool columns are staged once and
+    stay on the device; a dispatch ships one alpha row a slot and
+    brings back two bitmaps. Exact: a pair inside the threshold bracket
+    is proved on the host and put to `check_leader_value`, as on the
+    verify side."""
+
+    def __init__(self, params: PraosParams, pools):
+        import jax
+
+        from ..ops.host import ed25519 as he
+
+        self.params = params
+        self.pools = pools
+        self.n_slots = max(1, SWEEP_LANES // len(pools))  # a dispatch
+        x = np.stack([
+            np.frombuffer(he.secret_expand(p.vrf_seed)[0].to_bytes(
+                32, "little"), np.uint8) for p in pools])
+        pk = np.stack([np.frombuffer(p.vrf_vk, np.uint8) for p in pools])
+        self._x, self._pk = jax.device_put(x), jax.device_put(pk)
+
+    def _dispatch(self, thr_dev, chunk, eta0):
+        alpha = np.empty((self.n_slots, 32), np.uint8)
+        for j, s in enumerate(chunk):
+            alpha[j] = np.frombuffer(nonces.mk_input_vrf(s, eta0), np.uint8)
+        alpha[len(chunk):] = alpha[0]  # a short last chunk: one shape
+        args = (self._x, self._pk, *thr_dev, alpha)
+        return _sweep_program(args)(*args)
+
+    def rows(self, thr, slots, eta0):
+        """Yield (chunk, [(slot, pool index), ...]) for `slots` in
+        order, a dispatch's worth a time: each slot's FIRST winning pool
+        in list order (the reference's first-credential-forges rule).
+        `thr` is `pool_thresholds(params, lview, pools)`. Keeps
+        SWEEP_DEPTH dispatches queued behind the one it waits for."""
+        import jax
+
+        lo_rows, hi_rows, sigmas = thr
+        thr_dev = (jax.device_put(lo_rows), jax.device_put(hi_rows))
+        slots = list(slots)
+        chunks = [slots[i:i + self.n_slots]
+                  for i in range(0, len(slots), self.n_slots)]
+        queued: list = []
+        nxt = 0
+        while nxt < len(chunks) or queued:
+            while nxt < len(chunks) and len(queued) <= SWEEP_DEPTH:
+                queued.append(
+                    (chunks[nxt], self._dispatch(thr_dev, chunks[nxt], eta0)))
+                nxt += 1
+            chunk, out = queued.pop(0)
+            yield chunk, self._first_winners(chunk, out, sigmas, eta0)
+
+    def _first_winners(self, chunk, out, sigmas, eta0):
+        p = len(self.pools)
+        win, amb = (np.unpackbits(np.asarray(a), axis=1)[:len(chunk), :p]
+                    .astype(bool) for a in out)
+        f = self.params.active_slot_coeff
+        for j, i in zip(*np.nonzero(amb)):  # empty in practice
+            beta = evaluate_vrf(self.pools[i], chunk[j], eta0).vrf_output
+            win[j, i] = check_leader_value(
+                nonces.vrf_leader_value(beta), sigmas[i], f)
+        first = win.argmax(axis=1)
+        return [(int(chunk[j]), int(first[j]))
+                for j in np.nonzero(win.any(axis=1))[0]]
+
+
+def elected_from_rows(pools, rows, eta0) -> list[Elected]:
+    """The elected (slot, pool index) rows of an election made
+    elsewhere, each proved here: the winners alone, ~1 pair in 1000."""
+    return [Elected(int(s), int(i), evaluate_vrf(pools[i], int(s), eta0))
+            for s, i in rows]
+
+
+def _elect_window_leader(sweep: LeaderSweep, thr, slots,
+                         eta0) -> list[Elected]:
+    rows = [r for _chunk, part in sweep.rows(thr, slots, eta0)
+            for r in part]
+    return elected_from_rows(sweep.pools, rows, eta0)
 
 
 def _elect_window_reference(params, pools, lview, slots,

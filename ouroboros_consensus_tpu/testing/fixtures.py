@@ -73,6 +73,18 @@ def make_pool(n: int, kes_depth: int = hk.DEFAULT_DEPTH) -> PoolCredentials:
     )
 
 
+def capped_zipf_stakes(n: int, exponent: int = 1,
+                       cap_weight: Fraction = Fraction(1, 18)):
+    """Mainnet-shaped stake for `n` pools in rank order: the pool of
+    rank r weighs min(1 / r**exponent, cap_weight), a Zipf tail under a
+    saturation cap (mainnet: ~3,000 pools, saturated at 1/k of the stake
+    with k = 500); the stakes are the weights over their sum, exact."""
+    w = [min(Fraction(1, r ** exponent), Fraction(cap_weight))
+         for r in range(1, n + 1)]
+    total = sum(w)
+    return [x / total for x in w]
+
+
 def make_ledger_view(pools: list[PoolCredentials], stakes=None) -> LedgerView:
     if stakes is None:
         stakes = [Fraction(1, len(pools))] * len(pools)

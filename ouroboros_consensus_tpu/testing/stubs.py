@@ -205,6 +205,39 @@ def make_stub_forge_sweep(plen: int):
     return stub_forge_sweep
 
 
+def make_stub_leader_sweep(plen: int):
+    """The hash-twin of ops/pk/elect.leader_sweep (the chip's
+    leader-value election): same arguments, same packed bitmaps, the
+    pair's beta from the stub family of `make_stub_forge_sweep`, the
+    leader-value tail and the bracket REAL. A new function an install,
+    for the reason given there."""
+
+    def stub_leader_sweep(x_tab, pk_tab, lo_tab, hi_tab, alpha):
+        from ..protocol.batch import _lt_be
+
+        p, s = x_tab.shape[0], alpha.shape[0]
+
+        def grid(tab, by_pool):  # [S * P, 32], slot-major
+            rows = jnp.asarray(tab).astype(jnp.int32)
+            return (jnp.tile(rows, (s, 1)) if by_pool
+                    else jnp.repeat(rows, p, axis=0))
+
+        xa = jnp.concatenate([grid(x_tab, True), grid(alpha, False)], axis=-1)
+        proof = _expand_dev(ord("p"), xa, 64, plen)
+        beta = _expand_dev(ord("b"), blake2b.blake2b_fixed(proof, plen, 32),
+                           32, 64)
+        tag_l = jnp.broadcast_to(jnp.asarray([ord("L")], jnp.int32),
+                                 (s * p, 1))
+        lv = blake2b.blake2b_fixed(
+            jnp.concatenate([tag_l, beta], axis=-1), 65, 32)
+        win = _lt_be(lv, grid(lo_tab, True))
+        amb = ~win & _lt_be(lv, grid(hi_tab, True))
+        return tuple(jnp.packbits(b.reshape(s, p).astype(jnp.uint8), axis=1)
+                     for b in (win, amb))
+
+    return stub_leader_sweep
+
+
 def install_stub_forge(monkeypatch, bucket: int = 256):
     """Stub the forge-side crypto for the tier-1 device differential:
     `fast.ecvrf_prove` / `ecvrf_proof_to_hash` / `ed25519_sign` become
@@ -258,6 +291,8 @@ def install_stub_forge(monkeypatch, bucket: int = 256):
     monkeypatch.setattr(fast, "ecvrf_proof_to_hash", stub_proof_to_hash)
     monkeypatch.setattr(fast, "ed25519_sign", stub_sign)
     monkeypatch.setattr(forge_mod, "_SWEEP_FN", make_stub_forge_sweep(plen))
+    monkeypatch.setattr(forge_mod, "_LEADER_FN", make_stub_leader_sweep(plen))
+    monkeypatch.setattr(forge_mod, "SWEEP_LANES", bucket)
     monkeypatch.setattr(forge_mod, "sign_ocerts_batch", stub_sign_ocerts)
     monkeypatch.setattr(forge_mod, "_JITS", {})
     monkeypatch.setattr(forge_mod, "FORGE_BUCKET", bucket)

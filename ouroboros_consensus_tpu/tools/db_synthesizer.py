@@ -124,6 +124,11 @@ def synthesize(
     # so a killed-and-resumed synthesis converges on the byte-
     # identical chain an uninterrupted run produces
     network_magic: int | None = None,  # chain magic for the DB marker
+    elector=None,  # (slots: range, eta0) -> [(slot, pool index), ...]:
+    # the election made elsewhere (each slot's first winning pool in
+    # list order, as protocol/forge.LeaderSweep.rows gives them), e.g.
+    # by the process that holds the chip; the winners are proved and
+    # the blocks assembled here
 ) -> ForgeResult:
     """The forging loop (Forging.hs:57): tick → leader check per
     credential → forge → append, until the limit trips.
@@ -144,6 +149,11 @@ def synthesize(
         raise ValueError(
             "resume is not supported in ledger mode (the ledger fold "
             "has its own snapshot/replay machinery)"
+        )
+    if elector is not None and ledger is not None:
+        raise ValueError(
+            "an elector needs the view to elect against before the "
+            "blocks exist: ledger mode derives it from them"
         )
     os.makedirs(db_path, exist_ok=True)
     # open as a READER first: the non-empty-DB refusal below must be
@@ -199,7 +209,7 @@ def synthesize(
         out = _synthesize_locked(
             imm, db_path, params, pools, lview, limit, txs_per_block,
             vrf_backend, trace, ledger_view_for_epoch, txs_for_block,
-            ledger, genesis_state,
+            ledger, genesis_state, elector,
         )
     except BaseException:
         # a killed/raising forge leaves DIRTY; the pre-writer refusal
@@ -246,7 +256,7 @@ def _replay_forged_state(params, lview, imm):
 def _forge_pipeline(
     imm, params, pools, lview, limit, res, st, prev_hash, block_no,
     slot, counters, ledger_view_for_epoch, txs_per_block, txs_for_block,
-    engine, trace,
+    engine, trace, elector=None,
 ):
     """The batched forging fast path: elect whole slot windows in one
     sweep (device or batched-host, protocol/forge.py), then run the
@@ -259,7 +269,7 @@ def _forge_pipeline(
     from ..testing import chaos
 
     asm = forge_mod.BlockAssembler(params, pools)
-    stg = forge_mod.stage_pools(pools) if engine == "device" else None
+    stg = forge_mod.stage_engine(params, pools, engine)
     tracer = pbatch.BATCH_TRACER
 
     def done() -> bool:
@@ -295,12 +305,17 @@ def _forge_pipeline(
             wend = min(wend, slot + est)
         wend = max(wend, slot + 1)
         windex = forge_mod.next_window_index()
-        thr = forge_mod.pool_thresholds(params, lv_now, pools)
         t_el = time.monotonic()
-        elected = forge_mod.elect_window_recovering(
-            params, pools, stg, thr, range(slot, wend), eta0, engine,
-            lv_now, windex, tracer=tracer,
-        )
+        if elector is not None:
+            elected = forge_mod.elected_from_rows(
+                pools, elector(range(slot, wend), eta0), eta0
+            )
+        else:
+            thr = forge_mod.pool_thresholds(params, lv_now, pools)
+            elected = forge_mod.elect_window_recovering(
+                params, pools, stg, thr, range(slot, wend), eta0, engine,
+                lv_now, windex, tracer=tracer,
+            )
         elect_s = time.monotonic() - t_el
         if engine == "device" and elected:
             # pre-sign the window's deduped OCert issues through the
@@ -368,15 +383,15 @@ def _forge_pipeline(
 def _synthesize_locked(
     imm, db_path, params, pools, lview, limit, txs_per_block,
     vrf_backend, trace, ledger_view_for_epoch, txs_for_block,
-    ledger, genesis_state,
+    ledger, genesis_state, elector=None,
 ) -> ForgeResult:
 
     from ..protocol import forge as forge_mod
 
-    n_target = limit.slots or limit.blocks or (
-        (limit.epochs or 0) * params.epoch_length
-    )
-    engine = forge_mod.engine_from_env(vrf_backend)
+    # the rows of an election made elsewhere go through the pipeline's
+    # assembly whatever the lever says: there is nothing to elect here
+    engine = "rows" if elector is not None else (
+        forge_mod.engine_from_env(vrf_backend))
     if ledger is not None:
         # the ledger fold derives each epoch's view from state the loop
         # itself threads — the whole-window election has no view to
@@ -473,7 +488,7 @@ def _synthesize_locked(
         st, prev_hash, block_no, slot = _forge_pipeline(
             imm, params, pools, lview, limit, res, st, prev_hash,
             block_no, slot, counters, ledger_view_for_epoch,
-            txs_per_block, txs_for_block, engine, trace,
+            txs_per_block, txs_for_block, engine, trace, elector,
         )
     while not done():
         lv_now = (

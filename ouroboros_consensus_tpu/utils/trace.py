@@ -380,6 +380,13 @@ class WindowSpan:
     t_stage_end: float = 0.0
     t_dispatch_start: float = 0.0
     stage_thread: str = ""
+    # who the window holds: what grows with the number of issuers (a
+    # one-pool chain reads 1, 2-3, 1)
+    issuers: int = 0  # distinct cold keys
+    kes_tails: int = 0  # rows of the KES tail table before padding
+    thr_rows: int = 0  # rows of the threshold table before padding
+    prechecks_s: float = 0.0  # span `stage.prechecks`, on `stage_thread`
+    epilogue_counters_s: float = 0.0  # span `epilogue.counters`
 
 
 # -- the consensus event vocabulary (Tracers' record, condensed) -------------

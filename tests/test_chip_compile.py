@@ -10,7 +10,8 @@ here at 8192 lanes, KES depth 7, 128-byte proofs. A compile that passes
 is not a chip run.
 
 Tier-1: `unpack` (with and without an epoch nonce), `reduce`, `finish`.
-Marked slow (25–90 s each): `ed`, `kes`, `vrf_bc`, `vrf`.
+Marked slow (25–90 s each): `ed`, `kes`, `vrf_bc`, `vrf`, and the forge's
+leader sweep (`ops/pk/elect.py`, 262,144 lanes).
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may hold the TPU library, and every xdist
@@ -208,4 +209,16 @@ def test_vrf_draft03_compiles(one_chip, chip_seams):
     args = [s((32, LANES)), s((32, LANES)), s((16, LANES)),
             s((32, LANES)), s((32, LANES))]
     compiled = _compile(K.vrf_points, args)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.slow
+def test_leader_sweep_compiles(one_chip, chip_seams):
+    """The forge's election (ops/pk/elect.py) at the grid a 512-pool
+    chain dispatches: 512 pools x 512 slots, 262,144 lanes."""
+    from ouroboros_consensus_tpu.ops.pk import elect
+
+    s = functools.partial(_sds, one_chip)
+    tab = s((512, 32), np.uint8)
+    compiled = _compile(elect.leader_sweep, [tab, tab, tab, tab, tab])
     assert "tpu_custom_call" in compiled.as_text()

@@ -34,9 +34,9 @@ PARAMS = praos.PraosParams(
 )
 MAX_BATCH = 16
 PIPELINE_DEPTH = 3  # validate_chain's default
-WINDOW_LABELS = {"stage", "dispatch", "materialize.wait",
+WINDOW_LABELS = {"stage", "stage.prechecks", "dispatch", "materialize.wait",
                  "materialize.copy", "materialize", "tick", "epilogue",
-                 "epilogue.fold"}
+                 "epilogue.counters", "epilogue.fold"}
 
 
 def _replay(db):
@@ -129,6 +129,9 @@ def test_spans_name_the_thread_that_did_the_work(traced):
         if e.label == "stage":
             assert e.thread.startswith("oct-stage"), e
             assert e.parent is None  # joined to its window by the id
+        elif e.label == "stage.prechecks":
+            assert e.thread.startswith("oct-stage"), e
+            assert e.parent == "stage"
         elif e.label.startswith("materialize."):
             assert e.thread.startswith("oct-read"), e
         elif e.label.startswith("stream"):
@@ -142,8 +145,8 @@ def test_spans_name_the_thread_that_did_the_work(traced):
     for label in ("open", "segment-wait", "validate-chain", "stream"):
         assert {e.parent for e in _ends(events, label=label)} == {"replay"}
     # the host's nonce fold: inside the window's `epilogue`, every window
-    assert {e.parent for e in _ends(events, label="epilogue.fold")} == \
-        {"epilogue"}
+    for label in ("epilogue.fold", "epilogue.counters"):
+        assert {e.parent for e in _ends(events, label=label)} == {"epilogue"}
     assert {e.parent for e in _ends(events, label="stream-mmap")} == \
         {"stream"}
     spans = [e for e in events if isinstance(e, T.WindowSpan)]
@@ -237,8 +240,9 @@ def test_main_thread_self_times_sum_to_the_replays_duration(traced):
         assert res.phases["replay.self"] == pytest.approx(selfs["replay"])
         assert res.phases["validate-chain.self"] < \
             res.phases["validate-chain"]
+        # `stage` holds one child on its own thread, the prechecks
         assert res.phases["stage.self"] == pytest.approx(
-            res.phases["stage"])
+            res.phases["stage"] - res.phases["stage.prechecks"])
 
 
 def test_recorder_and_collector_share_the_one_function(traced):
