@@ -67,10 +67,11 @@ def _elect_kernel(x_ref, pk_ref, al_ref, lo_ref, hi_ref, out_ref):
 def elect_points(x, pk, alpha, thr_lo, thr_hi):
     """Five [32, B] int32 byte arrays -> [1, B] int32: bit 0 certain win,
     bit 1 ambiguous."""
+    b = x.shape[-1]
     (out,) = pk_kernels._call(
-        _elect_kernel, "elect_points", x.shape[-1],
+        _elect_kernel, "elect_points", b,
         [(32,)] * 5, [(1,)], (x, pk, alpha, thr_lo, thr_hi),
-        with_base8=False,
+        with_base8=False, n_live=pk_kernels.all_tiles(b),  # a full sweep
     )
     return out
 

@@ -72,13 +72,29 @@ def _full_spec(shape):
     )
 
 
+def live_tiles(lanes: int) -> int:
+    """Lane tiles that hold the first `lanes` lanes of a window."""
+    return -(-lanes // TILE)
+
+
+def all_tiles(b: int):
+    """`_call`'s `n_live` for a caller whose `b` lanes are all live."""
+    return np.full((1,), live_tiles(b), np.int32)
+
+
 def _call(kernel, name: str, b, in_prefixes, out_prefixes, args,
-          with_base8: bool):
+          with_base8: bool, n_live):
     """One pallas_call over lane tiles. `name` is the kernel's name in
     the lowered program and in a device trace (a kernel that is a
-    functools.partial has none of its own)."""
+    functools.partial has none of its own). `n_live` ([1] int32, a
+    run-time operand) is how many of the leading tiles hold live lanes
+    and is the grid's bound: the kernel runs on those and on no other,
+    so the device's time follows it and the program does not change
+    with it. What the outputs hold behind the live tiles is whatever
+    the buffer held: no caller reads it."""
     tile = min(TILE, b)
     assert b % tile == 0
+    grid = jnp.minimum(n_live[0], b // tile)
     const_args = []
     const_specs = []
     if with_base8:
@@ -86,7 +102,7 @@ def _call(kernel, name: str, b, in_prefixes, out_prefixes, args,
         const_specs.append(_full_spec(_BASE8_SHAPE))
     return pl.pallas_call(
         kernel,
-        grid=(b // tile,),
+        grid=(grid,),
         in_specs=const_specs + [_tile_spec(p, tile) for p in in_prefixes],
         out_specs=tuple(_tile_spec(p, tile) for p in out_prefixes),
         out_shape=tuple(
@@ -110,9 +126,10 @@ def _ed_kernel(base8_ref, pk_ref, s_ref, hb_ref, hnb_ref, ok_ref, pt_ref):
         pt_ref[:] = jnp.concatenate([p.x, p.y, p.z, p.t], axis=0)
 
 
-def ed_points(pk, s, hblocks, hnblocks):
+def ed_points(pk, s, hblocks, hnblocks, n_live):
     """pk, s: [32, B]; hblocks [NB, 128, B]; hnblocks [1, B] ->
-    (ok [1, B] int32, point [80, B] int32)."""
+    (ok [1, B] int32, point [80, B] int32). `n_live`, here and in every
+    stage below: `_call`'s live-tile count."""
     nb = hblocks.shape[0]
     b = pk.shape[-1]
     return _call(
@@ -120,7 +137,7 @@ def ed_points(pk, s, hblocks, hnblocks):
         [(32,), (32,), (nb, 128), (1,)],
         [(1,), (80,)],
         (pk, s, hblocks, hnblocks),
-        with_base8=True,
+        with_base8=True, n_live=n_live,
     )
 
 
@@ -136,7 +153,8 @@ def _kes_kernel(depth, base8_ref, vk_ref, per_ref, s_ref,
         pt_ref[:] = jnp.concatenate([p.x, p.y, p.z, p.t], axis=0)
 
 
-def kes_points(vk, period, s, vk_leaf, siblings, hblocks, hnblocks, depth):
+def kes_points(vk, period, s, vk_leaf, siblings, hblocks, hnblocks,
+               n_live, *, depth):
     nb = hblocks.shape[0]
     b = vk.shape[-1]
     return _call(
@@ -144,7 +162,7 @@ def kes_points(vk, period, s, vk_leaf, siblings, hblocks, hnblocks, depth):
         [(32,), (1,), (32,), (32,), (depth, 32), (nb, 128), (1,)],
         [(1,), (80,)],
         (vk, period, s, vk_leaf, siblings, hblocks, hnblocks),
-        with_base8=True,
+        with_base8=True, n_live=n_live,
     )
 
 
@@ -180,7 +198,7 @@ def _vrf_ladder_kernel(base8_ref, c_ref, s_ref, prep_ref, pts_ref):
         )
 
 
-def vrf_points(pk, gamma, c, s, alpha):
+def vrf_points(pk, gamma, c, s, alpha, n_live):
     """Two chained pallas_calls (split compile — module docstring and
     verify.vrf_core_prep rationale); same (ok [1, B], points [400, B])
     contract as the former single kernel."""
@@ -190,14 +208,14 @@ def vrf_points(pk, gamma, c, s, alpha):
         [(32,), (32,), (16,), (32,), (32,)],
         [(1,), (240,)],
         (pk, gamma, c, s, alpha),
-        with_base8=False,
+        with_base8=False, n_live=n_live,
     )
     (pts,) = _call(
         _vrf_ladder_kernel, "vrf_ladder", b,
         [(16,), (32,), (240,)],
         [(400,)],
         (c, s, prep),
-        with_base8=True,
+        with_base8=True, n_live=n_live,
     )
     return ok, pts
 
@@ -225,7 +243,7 @@ def _vrf_bc_prep_kernel(pk_ref, g_ref, u_ref, v_ref, s_ref, al_ref,
         )
 
 
-def vrf_points_bc(pk, gamma, u, v, s, alpha):
+def vrf_points_bc(pk, gamma, u, v, s, alpha, n_live):
     """Batch-compatible vrf stage: prep (derived challenge) chained into
     the UNCHANGED ladder kernel. -> (ok [1, B], c16 [16, B],
     points [400, B]); the derived c16 feeds the unchanged finish stage."""
@@ -235,14 +253,14 @@ def vrf_points_bc(pk, gamma, u, v, s, alpha):
         [(32,), (32,), (32,), (32,), (32,), (32,)],
         [(1,), (16,), (240,)],
         (pk, gamma, u, v, s, alpha),
-        with_base8=False,
+        with_base8=False, n_live=n_live,
     )
     (pts,) = _call(
         _vrf_ladder_kernel, "vrf_ladder", b,
         [(16,), (32,), (240,)],
         [(400,)],
         (c16, s, prep),
-        with_base8=True,
+        with_base8=True, n_live=n_live,
     )
     return ok, c16, pts
 
@@ -275,7 +293,7 @@ def _finish_kernel(edok_ref, edpt_ref, edr_ref, kesok_ref,
 
 
 def finish(ed_ok, ed_pt, ed_r, kes_ok, kes_pt, kes_r, vrf_ok, vrf_pts,
-           c, beta_decl, thr_lo, thr_hi):
+           c, beta_decl, thr_lo, thr_hi, n_live):
     b = c.shape[-1]
     return _call(
         _finish_kernel, "finish", b,
@@ -284,7 +302,7 @@ def finish(ed_ok, ed_pt, ed_r, kes_ok, kes_pt, kes_r, vrf_ok, vrf_pts,
         [(5,), (32,), (32,)],
         (ed_ok, ed_pt, ed_r, kes_ok, kes_pt, kes_r, vrf_ok, vrf_pts,
          c, beta_decl, thr_lo, thr_hi),
-        with_base8=False,
+        with_base8=False, n_live=n_live,
     )
 
 
@@ -308,15 +326,18 @@ def verify_praos_tiles(
     leader_ambiguous — protocol/batch._pk_materialize re-wraps them into
     the Verdicts the sequential epilogue consumes.
     """
-    ed_ok, ed_pt = ed_points(ed_pk, ed_s, ed_hblocks, ed_hnblocks)
+    n_live = all_tiles(vrf_c.shape[-1])
+    ed_ok, ed_pt = ed_points(ed_pk, ed_s, ed_hblocks, ed_hnblocks, n_live)
     kes_ok, kes_pt = kes_points(
         kes_vk, kes_period, kes_s, kes_vk_leaf, kes_siblings,
-        kes_hblocks, kes_hnblocks, kes_depth,
+        kes_hblocks, kes_hnblocks, n_live, depth=kes_depth,
     )
-    vrf_ok, vrf_pts = vrf_points(vrf_pk, vrf_gamma, vrf_c, vrf_s, vrf_alpha)
+    vrf_ok, vrf_pts = vrf_points(
+        vrf_pk, vrf_gamma, vrf_c, vrf_s, vrf_alpha, n_live
+    )
     return finish(
         ed_ok, ed_pt, ed_r, kes_ok, kes_pt, kes_r, vrf_ok, vrf_pts,
-        vrf_c, beta_decl, thr_lo, thr_hi,
+        vrf_c, beta_decl, thr_lo, thr_hi, n_live,
     )
 
 
@@ -670,149 +691,98 @@ def packed_unpack_name(layout) -> str:
     return f"unpack_{tag}"
 
 
+def stage_operands(a, n_live):
+    """`unpack`'s or `relayout`'s limb-first arrays (22 for batch-
+    compatible proofs, 21 for draft-03) cut into the operands of the
+    three point stages, in dispatch order: [(stage, operands), ...].
+    One cut for every dispatch below and for the deviceless builder
+    (scripts/aot_precompile.py): a stored program is found again by
+    `aot.sig_of` of these."""
+    nv = len(a) - 16  # vrf columns: 6 (announced U, V) or 5 (challenge)
+    return [
+        ("ed", [a[0], a[2], a[3], a[4], n_live]),
+        ("kes", [a[5], a[6], a[8], a[9], a[10], a[11], a[12], n_live]),
+        ("vrf_bc" if nv == 6 else "vrf", [*a[13:13 + nv], n_live]),
+    ]
+
+
+def finish_operands(a, ed, kes, vrf, n_live):
+    """The `finish` stage's operands: the limb-first arrays `a` and the
+    point stages' outputs. `vrf_bc` hands on the challenge it derived;
+    draft-03's is a staged column."""
+    nv = len(a) - 16
+    vrf_ok, *c16, vrf_pts = vrf
+    return [
+        ed[0], ed[1], a[1], kes[0], kes[1], a[7],
+        vrf_ok, vrf_pts, c16[0] if c16 else a[15],
+        a[13 + nv], a[14 + nv], a[15 + nv], n_live,
+    ]
+
+
+def _crypto_stages(a, b, kes_depth, n_live):
+    """ed, kes, vrf / vrf_bc and finish over limb-first arrays, one
+    `_stage_call` each -> finish's (flags, eta, leader_value). The same
+    per-stage jits / AOT executables whoever made `a`: the packed
+    `unpack` or the generic `relayout`."""
+    stages = dict(split_stage_fns(kes_depth))
+    outs = [
+        _stage_call(name, stages[name], b, kes_depth, *ops)
+        for name, ops in stage_operands(a, n_live)
+    ]
+    return _stage_call(
+        "finish", stages["finish"], b, kes_depth,
+        *finish_operands(a, *outs, n_live),
+    )
+
+
 def verify_praos_packed_split(
     layout, body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-    thr_idx, thr_tab, nonce,
+    thr_idx, thr_tab, nonce, *, tiles_live: int,
 ):
     """The packed production dispatch: `unpack` (device limb
-    decomposition of the packed wire format) -> the UNCHANGED
-    ed/kes/vrf/finish stage jits/AOT executables -> `reduce` (verdict
-    bitmasks + the uint8 eta column). Returns (reduce outputs, flags,
-    eta, leader_value) with the per-lane arrays left on device."""
+    decomposition of the packed wire format) -> the ed/kes/vrf/finish
+    stage jits/AOT executables -> `reduce` (verdict bitmasks + the
+    uint8 eta column). Returns (reduce outputs, flags, eta,
+    leader_value) with the per-lane arrays left on device.
+    `tiles_live` (`live_tiles` of the window's live lanes) goes to the
+    device once and bounds the grid of every stage kernel: the lanes
+    behind it come back holding anything, and the caller slices them
+    off."""
     kes_depth = layout.kes_depth
-    stages = dict(split_stage_fns(kes_depth))
     unpack = _jit1(("unpack", layout), _mk_packed_unpack(layout))
     b = np.asarray(body).shape[0]
+    n_live = jax.device_put(np.full((1,), tiles_live, np.int32))
     a = _stage_call(
         packed_unpack_name(layout), unpack, b, kes_depth,
         body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
         thr_idx, thr_tab, nonce,
     )
-    if len(a) == 22:  # batch-compatible proof layout (announced U, V)
-        (l_ed_pk, l_ed_r, l_ed_s, l_ed_hb, l_ed_hnb,
-         l_kes_vk, l_kes_per, l_kes_r, l_kes_s, l_kes_leaf, l_kes_sib,
-         l_kes_hb, l_kes_hnb,
-         l_vrf_pk, l_vrf_g, l_vrf_u, l_vrf_v, l_vrf_s, l_vrf_al,
-         l_beta, l_tlo, l_thi) = a
-    else:
-        (l_ed_pk, l_ed_r, l_ed_s, l_ed_hb, l_ed_hnb,
-         l_kes_vk, l_kes_per, l_kes_r, l_kes_s, l_kes_leaf, l_kes_sib,
-         l_kes_hb, l_kes_hnb,
-         l_vrf_pk, l_vrf_g, l_vrf_c, l_vrf_s, l_vrf_al,
-         l_beta, l_tlo, l_thi) = a
-    ed_ok, ed_pt = _stage_call(
-        "ed", stages["ed"], b, kes_depth, l_ed_pk, l_ed_s, l_ed_hb, l_ed_hnb
-    )
-    kes_ok, kes_pt = _stage_call(
-        "kes", stages["kes"], b, kes_depth,
-        l_kes_vk, l_kes_per, l_kes_s, l_kes_leaf, l_kes_sib,
-        l_kes_hb, l_kes_hnb,
-    )
-    if len(a) == 22:
-        vrf_ok, l_vrf_c, vrf_pts = _stage_call(
-            "vrf_bc", stages["vrf_bc"], b, kes_depth,
-            l_vrf_pk, l_vrf_g, l_vrf_u, l_vrf_v, l_vrf_s, l_vrf_al
-        )
-    else:
-        vrf_ok, vrf_pts = _stage_call(
-            "vrf", stages["vrf"], b, kes_depth,
-            l_vrf_pk, l_vrf_g, l_vrf_c, l_vrf_s, l_vrf_al
-        )
-    flags, eta, lv = _stage_call(
-        "finish", stages["finish"], b, kes_depth,
-        ed_ok, ed_pt, l_ed_r, kes_ok, kes_pt, l_kes_r, vrf_ok, vrf_pts,
-        l_vrf_c, l_beta, l_tlo, l_thi,
-    )
+    flags, eta, lv = _crypto_stages(a, b, kes_depth, n_live)
     red = _stage_call(
         "reduce", _jit1("reduce", reduce_fn), b, kes_depth, flags, eta
     )
     return red, flags, eta, lv
 
 
-def verify_praos_split(
-    ed_pk, ed_r, ed_s, ed_hblocks, ed_hnblocks,
-    kes_vk, kes_period, kes_r, kes_s, kes_vk_leaf, kes_siblings,
-    kes_hblocks, kes_hnblocks,
-    vrf_pk, vrf_gamma, vrf_c, vrf_s, vrf_alpha,
-    beta, thr_lo, thr_hi,
-    *, kes_depth: int,
-):
-    """Same contract as verify_praos_staged, per-stage jits (or AOT
-    executables — _stage_call)."""
-    stages = dict(split_stage_fns(kes_depth))
-    b = np.asarray(beta).shape[0]
-    a = _stage_call(
-        "relayout", stages["relayout"], b, kes_depth,
-        ed_pk, ed_r, ed_s, ed_hblocks, ed_hnblocks,
-        kes_vk, kes_period, kes_r, kes_s, kes_vk_leaf, kes_siblings,
-        kes_hblocks, kes_hnblocks,
-        vrf_pk, vrf_gamma, vrf_c, vrf_s, vrf_alpha,
-        beta, thr_lo, thr_hi,
-    )
-    (l_ed_pk, l_ed_r, l_ed_s, l_ed_hb, l_ed_hnb,
-     l_kes_vk, l_kes_per, l_kes_r, l_kes_s, l_kes_leaf, l_kes_sib,
-     l_kes_hb, l_kes_hnb,
-     l_vrf_pk, l_vrf_g, l_vrf_c, l_vrf_s, l_vrf_al,
-     l_beta, l_tlo, l_thi) = a
-    ed_ok, ed_pt = _stage_call(
-        "ed", stages["ed"], b, kes_depth, l_ed_pk, l_ed_s, l_ed_hb, l_ed_hnb
-    )
-    kes_ok, kes_pt = _stage_call(
-        "kes", stages["kes"], b, kes_depth,
-        l_kes_vk, l_kes_per, l_kes_s, l_kes_leaf, l_kes_sib,
-        l_kes_hb, l_kes_hnb,
-    )
-    vrf_ok, vrf_pts = _stage_call(
-        "vrf", stages["vrf"], b, kes_depth,
-        l_vrf_pk, l_vrf_g, l_vrf_c, l_vrf_s, l_vrf_al
-    )
-    return _stage_call(
-        "finish", stages["finish"], b, kes_depth,
-        ed_ok, ed_pt, l_ed_r, kes_ok, kes_pt, l_kes_r, vrf_ok, vrf_pts,
-        l_vrf_c, l_beta, l_tlo, l_thi,
-    )
+def _verify_praos_generic(relayout: str, cols, kes_depth: int):
+    """The generic dispatch: `relayout` / `relayout_bc` of the staged
+    columns, then the crypto stages over every tile (a generic window
+    hands no live count down)."""
+    b = np.asarray(cols[-1]).shape[0]
+    fn = dict(split_stage_fns(kes_depth))[relayout]
+    a = _stage_call(relayout, fn, b, kes_depth, *cols)
+    return _crypto_stages(a, b, kes_depth, jax.device_put(all_tiles(b)))
 
 
-def verify_praos_split_bc(
-    ed_pk, ed_r, ed_s, ed_hblocks, ed_hnblocks,
-    kes_vk, kes_period, kes_r, kes_s, kes_vk_leaf, kes_siblings,
-    kes_hblocks, kes_hnblocks,
-    vrf_pk, vrf_gamma, vrf_u, vrf_v, vrf_s, vrf_alpha,
-    beta, thr_lo, thr_hi,
-    *, kes_depth: int,
-):
-    """verify_praos_split for BATCH-COMPATIBLE staged columns: the vrf
-    stage derives the challenge from the announced U, V; ed/kes/finish
-    dispatch the same per-stage jits/AOT executables as draft-03."""
-    stages = dict(split_stage_fns(kes_depth))
-    b = np.asarray(beta).shape[0]
-    a = _stage_call(
-        "relayout_bc", stages["relayout_bc"], b, kes_depth,
-        ed_pk, ed_r, ed_s, ed_hblocks, ed_hnblocks,
-        kes_vk, kes_period, kes_r, kes_s, kes_vk_leaf, kes_siblings,
-        kes_hblocks, kes_hnblocks,
-        vrf_pk, vrf_gamma, vrf_u, vrf_v, vrf_s, vrf_alpha,
-        beta, thr_lo, thr_hi,
-    )
-    (l_ed_pk, l_ed_r, l_ed_s, l_ed_hb, l_ed_hnb,
-     l_kes_vk, l_kes_per, l_kes_r, l_kes_s, l_kes_leaf, l_kes_sib,
-     l_kes_hb, l_kes_hnb,
-     l_vrf_pk, l_vrf_g, l_vrf_u, l_vrf_v, l_vrf_s, l_vrf_al,
-     l_beta, l_tlo, l_thi) = a
-    ed_ok, ed_pt = _stage_call(
-        "ed", stages["ed"], b, kes_depth, l_ed_pk, l_ed_s, l_ed_hb, l_ed_hnb
-    )
-    kes_ok, kes_pt = _stage_call(
-        "kes", stages["kes"], b, kes_depth,
-        l_kes_vk, l_kes_per, l_kes_s, l_kes_leaf, l_kes_sib,
-        l_kes_hb, l_kes_hnb,
-    )
-    vrf_ok, l_vrf_c, vrf_pts = _stage_call(
-        "vrf_bc", stages["vrf_bc"], b, kes_depth,
-        l_vrf_pk, l_vrf_g, l_vrf_u, l_vrf_v, l_vrf_s, l_vrf_al
-    )
-    return _stage_call(
-        "finish", stages["finish"], b, kes_depth,
-        ed_ok, ed_pt, l_ed_r, kes_ok, kes_pt, l_kes_r, vrf_ok, vrf_pts,
-        l_vrf_c, l_beta, l_tlo, l_thi,
-    )
+def verify_praos_split(*cols, kes_depth: int):
+    """Same contract as verify_praos_staged (its 21 staged columns),
+    per-stage jits (or AOT executables — _stage_call)."""
+    return _verify_praos_generic("relayout", cols, kes_depth)
+
+
+def verify_praos_split_bc(*cols, kes_depth: int):
+    """verify_praos_split for the 22 BATCH-COMPATIBLE staged columns
+    (`staged_to_limb_first_bc`'s): the vrf stage derives the challenge
+    from the announced U, V; ed/kes/finish dispatch the same per-stage
+    jits/AOT executables as draft-03."""
+    return _verify_praos_generic("relayout_bc", cols, kes_depth)

@@ -2044,6 +2044,7 @@ class _WinMeta(NamedTuple):
     t_stage_end: float
     t_dispatch_start: float
     stage_thread: str
+    tiles_live: int  # WindowSpan.tiles_live
     stage_wait_s: float = 0.0
     census: "_StageCensus | None" = None
 
@@ -2069,9 +2070,11 @@ def _emit_transfer(phase: str, **kw) -> None:
 
 
 def _win_meta(outcome: str, gate: str | None, sw: "_StagedWindow",
-              t_d0: float) -> _WinMeta | None:
+              t_d0: float, tiles_live: int = 0) -> _WinMeta | None:
     """Build the per-window telemetry meta and emit the WindowStaged
-    event. Returns None (zero residual cost) when no tracer is set."""
+    event. Returns None (zero residual cost) when no tracer is set.
+    `tiles_live`: the count the window's stage kernels were bounded by
+    (`_dispatch_packed_lanes`), 0 where its live lanes bounded none."""
     if BATCH_TRACER is None:
         return None
     from ..utils.trace import WindowStaged
@@ -2082,7 +2085,7 @@ def _win_meta(outcome: str, gate: str | None, sw: "_StagedWindow",
                               stage_s, dispatch_s))
     return _WinMeta(sw.window, outcome, gate, stage_s, dispatch_s,
                     sw.lanes, t2, sw.t0, sw.t1, t_d0, sw.thread,
-                    census=sw.census)
+                    tiles_live, census=sw.census)
 
 
 def _emit_window_span(meta, lanes: int, n_valid: int, failed: bool,
@@ -2106,7 +2109,7 @@ def _emit_window_span(meta, lanes: int, n_valid: int, failed: bool,
         inflight_behind=inflight_behind, staged_ahead=staged_ahead,
         t_stage_start=meta.t_stage_start, t_stage_end=meta.t_stage_end,
         t_dispatch_start=meta.t_dispatch_start,
-        stage_thread=meta.stage_thread,
+        stage_thread=meta.stage_thread, tiles_live=meta.tiles_live,
         epilogue_counters_s=_COUNTERS_S[0],
         **(meta.census._asdict() if meta.census is not None else {}),
     ))
@@ -2167,11 +2170,15 @@ def window_lanes(max_batch: int) -> int | None:
     stream cuts at every CBOR integer-width step), epoch tails and
     width steps look like — each distinct lane count costs a full set
     of stage programs (minutes of set-up on a v5e) against seconds of
-    device work. Padded lanes replicate lane 0 and are sliced off at
-    materialize, so verdicts do not change. The trade: an epoch tail of ~5,200 headers
-    runs 8192 lanes instead of 6144 (some 2,000 dead lanes per epoch)
-    and each short genesis window costs one full-width pass. Whether
-    finer buckets ever pay on the chip is ROADMAP Speed 4's measurement.
+    device work. Padded lanes replicate lane 0 behind the live ones and
+    are sliced off at materialize, so verdicts do not change; and the
+    stage kernels are told how many lane tiles hold live lanes
+    (`_dispatch_packed_lanes` -> `kernels.live_tiles`), so the device's
+    time follows what a window holds: a 13-header genesis window or a
+    one-header tip window is one tile of 64, an epoch tail of 5,216
+    headers 41. What a short window still pays at full width is the
+    `unpack` and `reduce` programs (XLA over all lanes, 0.42 ms) and
+    the padded columns' transfer.
     None on the XLA twin: windows keep their own `bucket_size`, so no
     CPU test compiles a shape it did not compile before."""
     return bucket_size(max_batch) if _impl() == "pk" else None
@@ -2322,19 +2329,25 @@ def dispatch_prepared(sw: _StagedWindow, ladder=None):
             meta = _win_meta("packed-agg", None, sw, t_d0)
             return pre, _Dispatched("agg", True, (layout, parr, out),
                                     meta), b
-        impl, out = _dispatch_packed_lanes(layout, parr)
-        meta = _win_meta("packed", refused_gate, sw, t_d0)
+        impl, out, tiles_live = _dispatch_packed_lanes(layout, parr, b)
+        meta = _win_meta("packed", refused_gate, sw, t_d0, tiles_live)
         return pre, _Dispatched(impl, True, out, meta), b
 
 
-def _dispatch_packed_lanes(layout, parr):
+def _dispatch_packed_lanes(layout, parr, live: int):
     """One packed window through the per-lane programs of the current
-    implementation -> (impl, device handles)."""
+    implementation -> (impl, device handles, live tiles). Padding put
+    the `live` lanes first (`pad_packed_to`), so the stage kernels run
+    the tiles that hold them and no other; the XLA twin has no tiles
+    (0)."""
     if _impl() == "pk":
         from ..ops.pk import kernels as pk_kernels
 
-        return "pk", pk_kernels.verify_praos_packed_split(layout, *parr)
-    return "xla", _jitted_packed_xla(layout)(*parr)
+        tiles_live = pk_kernels.live_tiles(live)
+        return "pk", pk_kernels.verify_praos_packed_split(
+            layout, *parr, tiles_live=tiles_live
+        ), tiles_live
+    return "xla", _jitted_packed_xla(layout)(*parr), 0
 
 
 def dispatch_batch(params, lview, eta0, hvs, ladder=None):
@@ -2673,7 +2686,7 @@ def materialize_verdicts(tagged, b):
             from ..utils.trace import AggRedispatch
 
             BATCH_TRACER(AggRedispatch(b))
-        impl2, out2 = _dispatch_packed_lanes(layout, parr)
+        impl2, out2, _ = _dispatch_packed_lanes(layout, parr, b)
         return _materialize_packed(out2, b, impl2, window)
     return _materialize_packed(tagged.out, b, tagged.impl, window)
 

@@ -387,6 +387,11 @@ class WindowSpan:
     thr_rows: int = 0  # rows of the threshold table before padding
     prechecks_s: float = 0.0  # span `stage.prechecks`, on `stage_thread`
     epilogue_counters_s: float = 0.0  # span `epilogue.counters`
+    # lane tiles that hold the window's `lanes` (ops/pk/kernels.live_tiles),
+    # where that count bounded its stage kernels: they did no work on the
+    # tiles behind them. 0 where it bounded none: a generic or packed-agg
+    # window runs every tile, and the XLA twin has no tiles
+    tiles_live: int = 0
 
 
 # -- the consensus event vocabulary (Tracers' record, condensed) -------------

@@ -237,29 +237,22 @@ def main():
         limb = jax.eval_shape(relayout_fn, *rel_sds)
         limb = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=shard)
                 for s in limb]
-        ed_in = [limb[0], limb[2], limb[3], limb[4]]
-        kes_in = [limb[5], limb[6], limb[8], limb[9], limb[10], limb[11],
-                  limb[12]]
-        nv = 6 if bc else 5  # vrf column count
-        vrf_in = limb[13:13 + nv]
-        kes_fn = K.kes_points_at(KES_DEPTH)
-        ed_out = jax.eval_shape(K.ed_points, *ed_in)
-        kes_out = jax.eval_shape(kes_fn, *kes_in)
-        vrf_name = "vrf_bc" if bc else "vrf"
-        vrf_fn = K.vrf_points_bc if bc else K.vrf_points
-        vrf_out = jax.eval_shape(vrf_fn, *vrf_in)
+        # the stages' operands as the dispatch cuts them, the live-tile
+        # count ([1] int32, `kernels._call`) last: `aot.sig_of` of these
+        # is how a run finds the program again
         _shard = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
             s.shape, s.dtype, sharding=shard)
-        # the finish stage's challenge column: derived on device for bc
-        # (vrf stage output), staged for draft-03
-        c_sds = _shard(vrf_out[1]) if bc else limb[15]
-        vrf_pts = _shard(vrf_out[2] if bc else vrf_out[1])
-        fin_in = [
-            _shard(ed_out[0]), _shard(ed_out[1]), limb[1],
-            _shard(kes_out[0]), _shard(kes_out[1]), limb[7],
-            _shard(vrf_out[0]), vrf_pts, c_sds,
-            limb[13 + nv], limb[14 + nv], limb[15 + nv],
+        n_live = jax.ShapeDtypeStruct((1,), np.int32, sharding=shard)
+        (_, ed_in), (_, kes_in), (vrf_name, vrf_in) = K.stage_operands(
+            limb, n_live)
+        kes_fn = K.kes_points_at(KES_DEPTH)
+        vrf_fn = K.vrf_points_bc if bc else K.vrf_points
+        outs = [
+            [_shard(o) for o in jax.eval_shape(fn, *ops)]
+            for fn, ops in ((K.ed_points, ed_in), (kes_fn, kes_in),
+                            (vrf_fn, vrf_in))
         ]
+        fin_in = K.finish_operands(limb, *outs, n_live)
         # vrf/finish first: the stages never yet timed on hardware
         fresh.append(compile_stage(vrf_name, vrf_fn, vrf_in, bucket, manifest))
         fresh.append(compile_stage("finish", K.finish, fin_in, bucket, manifest))
@@ -269,7 +262,7 @@ def main():
         # replaces relayout on the packed wire format; reduce packs the
         # verdict bits and casts the eta column to uint8 (the host folds
         # the nonces). The crypto stages above are SHARED between the
-        # packed and staged paths.
+        # packed and staged paths (one program form: `kernels._call`).
         pk = packed_sds(params, lview, bucket, rep, shard)
         if pk is not None:
             layout, unpack_in, red_in = pk

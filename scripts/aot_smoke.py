@@ -82,36 +82,27 @@ def main():
     jax.tree.map(np.asarray, limb)
     print(f"relayout ({'AOT' if rel else 'jit'}): "
           f"{time.monotonic()-t0:.2f}s", flush=True)
-    (l_ed_pk, l_ed_r, l_ed_s, l_ed_hb, l_ed_hnb,
-     l_kes_vk, l_kes_per, l_kes_r, l_kes_s, l_kes_leaf, l_kes_sib,
-     l_kes_hb, l_kes_hnb,
-     l_vrf_pk, l_vrf_g, l_vrf_c, l_vrf_s, l_vrf_al,
-     l_beta, l_tlo, l_thi) = limb
+    import jax.numpy as jnp
+
+    n_live = jax.device_put(K.all_tiles(B))  # every tile: a full window
+    ops = dict(K.stage_operands(limb, n_live))
 
     # vrf FIRST (never measured on hardware)
-    vrf_args = (l_vrf_pk, l_vrf_g, l_vrf_c, l_vrf_s, l_vrf_al)
-    vrf = load("vrf", vrf_args)
-    vrf_out = timed("vrf", vrf or stages["vrf"], *vrf_args)
+    vrf = load("vrf", ops["vrf"])
+    vrf_out = timed("vrf", vrf or stages["vrf"], *ops["vrf"])
 
     # finish next: ed/kes verdict inputs are dummies (zeros) — valid for
     # TIMING; correctness is the composed check below
-    import jax.numpy as jnp
-
-    z_ok = jnp.zeros((1, B), jnp.int32)
-    z_pt = jnp.zeros((80, B), jnp.int32)
-    fin_args = (z_ok, z_pt, l_ed_r, z_ok, z_pt, l_kes_r,
-                vrf_out[0], vrf_out[1], l_vrf_c, l_beta, l_tlo, l_thi)
+    z = (jnp.zeros((1, B), jnp.int32), jnp.zeros((80, B), jnp.int32))
+    fin_args = K.finish_operands(limb, z, z, vrf_out, n_live)
     fin = load("finish", fin_args)
     timed("finish", fin or stages["finish"], *fin_args)
 
-    ed_args = (l_ed_pk, l_ed_s, l_ed_hb, l_ed_hnb)
-    ed = load("ed", ed_args)
-    timed("ed", ed or stages["ed"], *ed_args)
+    ed = load("ed", ops["ed"])
+    timed("ed", ed or stages["ed"], *ops["ed"])
 
-    kes_args = (l_kes_vk, l_kes_per, l_kes_s, l_kes_leaf, l_kes_sib,
-                l_kes_hb, l_kes_hnb)
-    kes = load("kes", kes_args)
-    timed("kes", kes or stages["kes"], *kes_args)
+    kes = load("kes", ops["kes"])
+    timed("kes", kes or stages["kes"], *ops["kes"])
 
     # composed production dispatch (AOT executables via _stage_call) +
     # correctness vs the native verifier on the real (unpadded) lanes

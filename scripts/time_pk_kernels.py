@@ -84,15 +84,16 @@ def timed(name, fn, *a):
 
 
 ed_j = jax.jit(K.ed_points)
-kes_j = jax.jit(lambda *a: K.kes_points(*a, DEPTH))
+kes_j = jax.jit(lambda *a: K.kes_points(*a, depth=DEPTH))
 vrf_j = jax.jit(K.vrf_points)
 fin_j = jax.jit(K.finish)
 
-ed_ok, ed_pt = timed("ed", ed_j, ed_pk, ed_s, ed_hb, ed_hnb)
-kes_ok, kes_pt = timed("kes", kes_j, kes_vk, kes_per, kes_s, kes_leaf, kes_sib, kes_hb, kes_hnb)
-vrf_ok, vrf_pts = timed("vrf", vrf_j, vrf_pk, vrf_g, vrf_c, vrf_s, vrf_al)
+n_live = jax.device_put(K.all_tiles(B))  # every tile: a full window
+ed_ok, ed_pt = timed("ed", ed_j, ed_pk, ed_s, ed_hb, ed_hnb, n_live)
+kes_ok, kes_pt = timed("kes", kes_j, kes_vk, kes_per, kes_s, kes_leaf, kes_sib, kes_hb, kes_hnb, n_live)
+vrf_ok, vrf_pts = timed("vrf", vrf_j, vrf_pk, vrf_g, vrf_c, vrf_s, vrf_al, n_live)
 fin = timed("finish", fin_j, ed_ok, ed_pt, ed_r, kes_ok, kes_pt, kes_r,
-            vrf_ok, vrf_pts, vrf_c, beta, tlo, thi)
+            vrf_ok, vrf_pts, vrf_c, beta, tlo, thi, n_live)
 
 # whole pipeline hot (one dispatch)
 full_j = jax.jit(lambda *a: K.verify_praos_tiles(*a, kes_depth=DEPTH))

@@ -56,20 +56,10 @@ def main():
     limb = stages["relayout"](*cols)
     jax.tree.map(np.asarray, limb)
     print(f"relayout first {time.monotonic()-t0:.2f}s", flush=True)
-    (l_ed_pk, l_ed_r, l_ed_s, l_ed_hb, l_ed_hnb,
-     l_kes_vk, l_kes_per, l_kes_r, l_kes_s, l_kes_leaf, l_kes_sib,
-     l_kes_hb, l_kes_hnb,
-     l_vrf_pk, l_vrf_g, l_vrf_c, l_vrf_s, l_vrf_al,
-     l_beta, l_tlo, l_thi) = limb
-
     import jax.numpy as jnp
 
-    args = {
-        "ed": (l_ed_pk, l_ed_s, l_ed_hb, l_ed_hnb),
-        "kes": (l_kes_vk, l_kes_per, l_kes_s, l_kes_leaf, l_kes_sib,
-                l_kes_hb, l_kes_hnb),
-        "vrf": (l_vrf_pk, l_vrf_g, l_vrf_c, l_vrf_s, l_vrf_al),
-    }
+    n_live = jax.device_put(K.all_tiles(B))  # every tile: a full window
+    args = dict(K.stage_operands(limb, n_live))
 
     outs = {}
     for name in ("vrf", "ed", "kes", "finish"):
@@ -77,10 +67,8 @@ def main():
             continue
         if name == "finish":
             vrf_out = outs.get("vrf") or stages["vrf"](*args["vrf"])
-            z_ok = jnp.zeros((1, B), jnp.int32)
-            z_pt = jnp.zeros((80, B), jnp.int32)
-            a = (z_ok, z_pt, l_ed_r, z_ok, z_pt, l_kes_r,
-                 vrf_out[0], vrf_out[1], l_vrf_c, l_beta, l_tlo, l_thi)
+            z = (jnp.zeros((1, B), jnp.int32), jnp.zeros((80, B), jnp.int32))
+            a = K.finish_operands(limb, z, z, vrf_out, n_live)
         else:
             a = args[name]
         fn = stages[name]

@@ -28,3 +28,20 @@ def test_the_new_cell_is_among_the_guarded(m):  # noqa: F405 - test_manifest's
     # the two cells that were there report them too (no `workloads` key)
     for other in ("replay-bc-2epoch", "replay-draft03-2epoch"):
         assert names == {x.name for x in m.cell(other).per_layer}
+
+
+def test_every_cell_reports_29_per_layer_metrics_by_name(m):  # noqa: F405
+    from benchmark.readers import window_span
+
+    for cell in ("replay-bc-2epoch", "replay-draft03-2epoch",
+                 "replay-stakepools-2epoch"):
+        per_layer = {x.name: x for x in m.cell(cell).per_layer}
+        assert len(per_layer) == 29
+        tiles = per_layer["device_tiles_per_window"]
+        assert tiles.spec == {"kind": "window_span", "key": "tiles_live"}
+        assert (tiles.unit, tiles.layer) == ("tiles", "dispatch and kernels")
+    # read off the program's own spans; a program without the field (the
+    # parent commit's) gives nothing to read, and the line leaves it out
+    spans = [{"lanes": 10, "tiles_live": 1}, {"lanes": 8192, "tiles_live": 64}]
+    assert window_span.read(tiles.spec, {"window_spans": spans}) == 32.5
+    assert window_span.read(tiles.spec, {"window_spans": [{"lanes": 10}]}) is None

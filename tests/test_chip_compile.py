@@ -122,6 +122,12 @@ def _compile(fn, args):
     return compiled
 
 
+def _n_live(one_chip):
+    """The live-tile count, the bound of every stage kernel's grid
+    (`verify_praos_packed_split` puts it on the device once a window)."""
+    return _sds(one_chip, (1,))
+
+
 def _limb(one_chip, has_nonce=True):
     """The 22 limb-first stage inputs `unpack` hands the crypto stages
     (shapes only — eval_shape of the cheap unpack program)."""
@@ -157,6 +163,7 @@ def test_finish_compiles_with_the_kernel(one_chip, chip_seams):
         s((1, LANES)), s((80, LANES)), s((32, LANES)),  # kes ok, point, R
         s((1, LANES)), s((400, LANES)), s((16, LANES)),  # vrf ok, points, c
         s((64, LANES)), s((32, LANES)), s((32, LANES)),  # beta, thr lo/hi
+        _n_live(one_chip),
     ]
     compiled = _compile(K.finish, args)
     assert "tpu_custom_call" in compiled.as_text()
@@ -174,6 +181,7 @@ def test_finish_lowers_with_its_kernel_name(one_chip, chip_seams):
     s = functools.partial(_sds, one_chip)
     args = [s((p, K.TILE))
             for p in (1, 80, 32, 1, 80, 32, 1, 400, 16, 64, 32, 32)]
+    args.append(_n_live(one_chip))
     text = jax.jit(fresh).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
@@ -183,7 +191,10 @@ def test_finish_lowers_with_its_kernel_name(one_chip, chip_seams):
 @pytest.mark.slow
 def test_ed_compiles(one_chip, chip_seams):
     limb = _limb(one_chip)
-    compiled = _compile(K.ed_points, [limb[0], limb[2], limb[3], limb[4]])
+    compiled = _compile(
+        K.ed_points,
+        [limb[0], limb[2], limb[3], limb[4], _n_live(one_chip)],
+    )
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -192,14 +203,17 @@ def test_kes_compiles(one_chip, chip_seams):
     limb = _limb(one_chip)
     compiled = _compile(
         K.kes_points_at(KES_DEPTH),
-        [limb[5], limb[6], limb[8], limb[9], limb[10], limb[11], limb[12]],
+        [limb[5], limb[6], limb[8], limb[9], limb[10], limb[11], limb[12],
+         _n_live(one_chip)],
     )
     assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.slow
 def test_vrf_bc_compiles(one_chip, chip_seams):
-    compiled = _compile(K.vrf_points_bc, _limb(one_chip)[13:19])
+    compiled = _compile(
+        K.vrf_points_bc, [*_limb(one_chip)[13:19], _n_live(one_chip)]
+    )
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -207,7 +221,7 @@ def test_vrf_bc_compiles(one_chip, chip_seams):
 def test_vrf_draft03_compiles(one_chip, chip_seams):
     s = functools.partial(_sds, one_chip)
     args = [s((32, LANES)), s((32, LANES)), s((16, LANES)),
-            s((32, LANES)), s((32, LANES))]
+            s((32, LANES)), s((32, LANES)), _n_live(one_chip)]
     compiled = _compile(K.vrf_points, args)
     assert "tpu_custom_call" in compiled.as_text()
 

@@ -378,6 +378,9 @@ def test_split_dispatch_threads_stages_correctly(monkeypatch):
     eq(g[10], 19); eq(g[11], 20)
     assert g[0].shape == (1, b) and g[1].shape == (80, b)
     assert g[6].shape == (1, b) and g[7].shape == (400, b)
+    # a generic window hands every stage the count of all its tiles
+    for g in captured.values():
+        assert g[-1].tolist() == [K.live_tiles(b)]
 
 
 def test_split_dispatch_bc_threads_stages_correctly(monkeypatch):
@@ -447,6 +450,9 @@ def test_split_dispatch_bc_threads_stages_correctly(monkeypatch):
     assert g[8].shape == (16, b) and (g[8] == 0).all()
     assert g[0].shape == (1, b) and g[1].shape == (80, b)
     assert g[6].shape == (1, b) and g[7].shape == (400, b)
+    # a generic window hands every stage the count of all its tiles
+    for g in captured.values():
+        assert g[-1].tolist() == [K.live_tiles(b)]
 
 
 @pytest.mark.slow

@@ -373,8 +373,9 @@ def test_stage_modules_and_pallas_kernels_have_stable_names(monkeypatch):
         "finish": ("jit_finish", ["finish"]),
     }
     stages = dict(K.split_stage_fns(depth))
+    n_live = jax.ShapeDtypeStruct((1,), i32)  # every stage's last operand
     for stage, (module, kernels) in want.items():
         del seen[:]
-        text = stages[stage].lower(*args[stage]).as_text()
+        text = stages[stage].lower(*args[stage], n_live).as_text()
         assert text.startswith(f"module @{module} "), text[:80]
         assert seen == kernels

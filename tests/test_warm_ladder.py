@@ -108,6 +108,12 @@ def fresh_pipeline(monkeypatch):
     yield
     for k in set(pbatch._JIT) - before:
         del pbatch._JIT[k]
+    # a ladder's background warm-up outlives its replay: wait for it, or
+    # its `bg-compile-done` lands in the next test's report (seen under
+    # a loaded tier-1 run: `[1-0]` and `[0-0]` after their `force` twins)
+    ladder = pbatch._LADDER
+    if ladder is not None and ladder._bg is not None:
+        ladder._bg.join(timeout=120)
     pbatch.reset_warm_ladder()
     WARMUP.reset()
 
