@@ -739,11 +739,6 @@ def run_device_subprocess() -> tuple[dict | None, list]:
         # parent can grep the log for stale-executable rejections
         # between attempts
         child_log_path = os.path.join(CACHE, f"device_child_{attempt}.log")
-        # octwall pre-flight: the child's dispatch gate refuses any COLD
-        # monolith whose predicted compile wall does not fit what is
-        # left of THIS attempt's budget (analysis/costmodel.preflight —
-        # refusals recorded in the warmup report)
-        env["OCT_WALL_DEADLINE"] = str(time.time() + budget)
         # stale beats must never be read as THIS attempt's story: the
         # parent's own native-baseline replay (armed when the watchdog
         # script exports OCT_HEARTBEAT) and attempt 1 both wrote to
@@ -960,11 +955,6 @@ def main() -> None:
             if wr is not None:
                 out["warmup_report"] = wr
         out["probe"] = probe_verdict
-        # a round that banked THROUGH the warm ladder is its own class
-        # of round (perf_report renders it), not a warmup death
-        ladder_evs = (out.get("warmup_report") or {}).get("ladder") or []
-        if ladder_evs:
-            out["laddered"] = True
     else:
         out = {
             "metric": (

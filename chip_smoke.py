@@ -328,8 +328,6 @@ def phase_replay(a, path, params, lview, ref, cc, rec, cache_dir,
     check(not recov and not report["recovery"], ph,
           "the recovery ladder fired: a fallback path produced verdicts",
           events=recov or report["recovery"])
-    check(not report["refusals"], ph, "the compile gate refused a window",
-          refusals=report["refusals"])
     bad = [dataclasses.asdict(e) for e in staged
            if e.outcome != "packed" or e.gate is not None]
     check(not bad, ph, "a window left the packed per-lane path", windows=bad[:8])
@@ -358,7 +356,7 @@ def phase_replay(a, path, params, lview, ref, cc, rec, cache_dir,
     check(built2["programs_built"] == 0 and n_stages[0] == n_stages[1], ph,
           "the warm pass set a program up (compile, cache load or a new "
           "first-execute note)", first_execute_notes=n_stages, **built2)
-    emit(ph, t1, impl=impl, recovery_events=0, gate_refusals=0,
+    emit(ph, t1, impl=impl, recovery_events=0,
          windows=len(staged), all_packed=True, lane_counts=lane_counts,
          stages_set_up=n_stages[1], warm_pass_programs_built=0)
 

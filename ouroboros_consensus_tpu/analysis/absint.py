@@ -74,6 +74,7 @@ import dataclasses
 import json
 import math
 import os
+import re
 
 import numpy as np
 
@@ -84,8 +85,9 @@ _SHAPES_PATH = os.path.join(os.path.dirname(__file__), "shapes.json")
 _CERTIFIED_PATH = os.path.join(os.path.dirname(__file__), "certified.json")
 
 # call-like primitives whose subjaxpr runs once with the caller's values
+# (a jitted call is `pjit` up to JAX 0.6 and `jit` since)
 _CALL_PRIMS = {
-    "pjit", "closed_call", "core_call", "remat", "checkpoint",
+    "jit", "pjit", "closed_call", "core_call", "remat", "checkpoint",
     "custom_jvp_call", "custom_vjp_call", "custom_partitioning",
 }
 # eqns whose (signed) result must fit the dtype — arithmetic that can
@@ -109,17 +111,31 @@ SPMD_AXIS_SCALE = 4096
 ROW_CAP = 512
 
 
+# `<file>:<line>[:<col>] (<qualified name>)`, as JAX summarizes a frame
+_SRC_RE = re.compile(r"^(.*?):(\d+)(?::\d+)? \((.*)\)$")
+
+
+def _normal_src(s: str) -> str:
+    """A source location in the form finding keys are pinned in:
+    repo-relative file, line, innermost function name. The installed
+    JAX decides whether a summary carries a column and a
+    `outer.<locals>.inner` chain; a pin must not."""
+    m = _SRC_RE.match(s)
+    if m:
+        s = f"{m.group(1)}:{m.group(2)} ({m.group(3).rsplit('.', 1)[-1]})"
+    # keep the path repo-relative and stable across checkouts
+    for marker in ("ouroboros_consensus_tpu/", "tests/", "scripts/"):
+        i = s.find(marker)
+        if i > 0:
+            return s[i:]
+    return s
+
+
 def _src_of(eqn) -> str:
     try:
         from jax._src import source_info_util
 
-        s = source_info_util.summarize(eqn.source_info)
-        # keep the path repo-relative and stable across checkouts
-        for marker in ("ouroboros_consensus_tpu/", "tests/", "scripts/"):
-            i = s.find(marker)
-            if i > 0:
-                return s[i:]
-        return s
+        return _normal_src(source_info_util.summarize(eqn.source_info))
     except Exception:
         return "<unknown>"
 

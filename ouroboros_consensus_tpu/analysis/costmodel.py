@@ -7,8 +7,9 @@ repo already proved compile time is *steerable* from jaxpr structure
 (PR 1: fencing the ladders cut the composed graph 355k -> 171k eqns,
 chain depth 900 -> 114). PR 6's warmup recorder measures per-stage
 first-execute walls after the fact; this pass predicts them BEFORE
-anything compiles, so a doomed dispatch is refused pre-flight instead
-of discovered at the wall.
+anything compiles, and ratchets the prediction in the lint sweep. It
+reads the program from outside: nothing on the dispatch path imports
+it.
 
 Three cooperating pieces:
 
@@ -20,10 +21,9 @@ Three cooperating pieces:
             depth, fence (scan/while/pjit) counts and body sizes,
             fan-out, remat width, dot/gather counts, constant bytes.
             A `feature_hash` (blake2s of the canonical feature vector)
-            identifies the exact graph structure, so a measured wall
-            recorded by obs/warmup.py joins its static features
-            EXACTLY — a stale measurement from an older code state
-            simply fails to join.
+            identifies the exact graph structure; a measured wall
+            recorded by obs/warmup.py joins its static features through
+            to the pin its stage label names (`stage_graph`).
 
   model     a small feature-weighted model: predicted cold-compile
             wall = exp(b0 + sum b_i * log1p(feature_i)), coefficients
@@ -38,20 +38,13 @@ Three cooperating pieces:
             prediction against budgets.json's "compile_wall" section
             (scripts/lint.py exit 5, the `cost` CLI subcommand);
             `advisories` flags monolith computations and unfenced
-            chains over budget, naming the source fence to split;
-            `preflight` is the bench attempt gate — a COLD monolithic
-            program whose predicted wall exceeds the remaining wall
-            budget (bench.py exports OCT_WALL_DEADLINE to the device
-            child) is refused, the refusal recorded in the warmup
-            report, and protocol/batch falls back to the per-stage
-            split path whose programs are individually smaller.
+            chains over budget, naming the source fence to split.
 
 What the model does NOT predict: Pallas/Mosaic lowering walls (kernel
 bodies are opaque to the jaxpr), device-side autotuning, persistent-
 cache deserialization time, or the XLA version drift between the
 calibration backend and the deployment runtime — predictions are a
-structural estimate for the admission gate and the ratchet, not a
-profiler (see analysis/README.md, Pass 4)."""
+structural estimate for the ratchet, not a profiler (see analysis/README.md, Pass 4)."""
 
 from __future__ import annotations
 
@@ -93,12 +86,6 @@ MODEL_FEATURES = (
 # a fitted prediction never goes below this (dispatch + tiny-program
 # compile floor) — keeps log-space extrapolation honest on small graphs
 MIN_PREDICTED_S = 0.05
-
-_DEADLINE_ENV = "OCT_WALL_DEADLINE"
-_GATE_ENV = "OCT_COMPILE_GATE"
-# seconds a first-execute must fit under the deadline WITH room to
-# spare for the replay itself
-PREFLIGHT_MARGIN_S = 30.0
 
 
 def _src_of(eqn) -> str:
@@ -267,9 +254,8 @@ _CACHED: dict | None = None
 
 
 def _cached_cost() -> dict | None:
-    """costmodel.json, read once per process (the runtime consumers —
-    stage-note hashes, the preflight gate — must stay dict-lookup
-    cheap). Missing/invalid file -> None, never an exception."""
+    """costmodel.json, read once per process. Missing/invalid file ->
+    None, never an exception."""
     global _CACHED
     if _CACHED is None:
         try:
@@ -463,102 +449,14 @@ def stage_graph(stage: str) -> str | None:
     return STAGE_GRAPHS.get(base)
 
 
-# ---------------------------------------------------------------------------
-# Warm-while-serving compile ladder (protocol/batch.WarmLadder)
-# ---------------------------------------------------------------------------
-
-# the lane rungs the ladder may start a cold replay at while the
-# production-bucket programs compile in a background thread. Every rung
-# program is PINNED in costmodel.json (`<graph>@<rung>` entries, written
-# by scripts/lint.py --update-costs) so lint exit 5 fences each one: on
-# the current kernels the composed graphs are lane-INVARIANT (the
-# fenced MSM chunk scans keep eqn counts flat in N — verified by the
-# identical feature hashes), which means a rung compile costs what the
-# production compile costs and the ladder's win is OVERLAP (replay
-# serves on the small, individually-cheap split-stage programs while
-# the monolith compiles in the background), not a cheaper rung compile.
-# If a future kernel change makes the structure lane-sensitive, these
-# pins are where it shows up — and choose_rung starts discriminating.
-LADDER_RUNGS = (1024, 2048)
-LADDER_GRAPHS = ("aggregate_core", "aggregate_vrf_core",
-                 "verify_praos_core_bc")
-
-
-def ladder_pin_name(graph: str, lanes: int) -> str:
-    return f"{graph}@{lanes}"
-
-
-def ladder_pins() -> list[tuple[str, str, int]]:
-    """[(pin_name, base_graph, lanes)] for every rung program the
-    ladder may compile — the lint cost pass extracts features for each
-    and ratchets them exactly like the registry graphs (compile_wall
-    ceilings + pin freshness; they carry no device_resources pins)."""
-    return [
-        (ladder_pin_name(g, r), g, r)
-        for g in LADDER_GRAPHS for r in LADDER_RUNGS
-    ]
-
-
-def stage_pin_graph(stage: str, lanes: int | None = None) -> str | None:
-    """Like stage_graph, but resolves to the rung pin when the dispatch
-    runs at a ladder rung lane count and that rung is pinned — so the
-    pre-flight gate prices a rung window by its own pin instead of the
-    production graph's."""
-    g = stage_graph(stage)
-    if g is None or lanes is None:
-        return g
-    pin = ladder_pin_name(g, lanes)
-    return pin if pinned(pin) is not None else g
-
-
-def choose_rung(graph: str, *, now: float | None = None,
-                margin_s: float | None = None,
-                rungs: tuple = None) -> int | None:
-    """Starting rung for a cold replay, chosen against the exported
-    $OCT_WALL_DEADLINE: the LARGEST pinned rung whose predicted compile
-    wall fits the remaining budget with margin, else the smallest rung
-    (serve on the smallest windows and let the background compile eat
-    the wall). No deadline -> the largest rung (no pressure, minimize
-    re-tiling overhead). None when no rungs are configured."""
-    rungs = LADDER_RUNGS if rungs is None else rungs
-    if not rungs:
-        return None
-    deadline = wall_deadline()
-    if deadline is None:
-        return max(rungs)
-    now = time.time() if now is None else now
-    margin = PREFLIGHT_MARGIN_S if margin_s is None else margin_s
-    remaining = deadline - now
-    best = None
-    for r in sorted(rungs):
-        pred = predicted_wall(ladder_pin_name(graph, r))
-        if pred is None:
-            # an UNPINNED rung never outranks a pinned one under a
-            # deadline: its wall is unknown, and choosing it risks
-            # exactly the blow-through the ladder exists to avoid
-            continue
-        if pred + margin <= remaining:
-            best = r
-    if best is not None:
-        return best
-    # no pinned rung fits (or none are pinned at all): serve on the
-    # smallest windows and let the background compile eat the wall
-    return min(rungs)
-
-
 def stage_feature_hash(stage: str) -> str | None:
-    """Pinned feature hash for a dispatch stage — recorded on every
-    warmup stage note so fit_costmodel's calibration join is exact
-    (a wall banked by an OLD bench round fails to join once the pins
-    move). Dict lookups only.
-
-    Known one-sidedness: this is the PINNED hash, not one derived from
-    the dispatched program (re-tracing a 300k-eqn graph at note time is
-    the cost this pass exists to avoid), so a kernel edit that outruns
-    its pins would stamp new-structure walls with the old hash. The
-    lint gate closes that window: `check_pins` fails CI whenever the
-    freshly-extracted features drift from costmodel.json, so a bench
-    round on a green tree always stamps current structure."""
+    """Pinned feature hash of the graph a warmup stage label names:
+    scripts/fit_costmodel.py joins a report's stage walls to their
+    static features through it (rounds banked before PR 34 carry the
+    hash on the note; a note without one is joined by its label). Dict
+    lookups only. `check_pins` keeps the pin equal to the tree's
+    freshly-extracted features, so the label's pin is current structure
+    on a green tree."""
     g = stage_graph(stage)
     if g is None:
         return None
@@ -569,9 +467,8 @@ def stage_feature_hash(stage: str) -> str | None:
 def check_pins(features: list[CostFeatures]) -> list[str]:
     """Pin-freshness gate (scripts/lint.py, rides the cost pass): each
     graph's freshly-extracted feature hash must match its
-    costmodel.json pin. A stale pin would make stage notes stamp
-    measured walls with the hash of an OLD structure — exactly the
-    mis-join the note-time hash cannot defend against on its own."""
+    costmodel.json pin. A stale pin would join measured walls to the
+    features of an OLD structure."""
     out: list[str] = []
     for f in features:
         pin = pinned(f.name)
@@ -583,8 +480,8 @@ def check_pins(features: list[CostFeatures]) -> list[str]:
         elif pin.get("feature_hash") != f.hash():
             out.append(
                 f"{f.name}: jaxpr features drifted from the "
-                "costmodel.json pin — stage notes would stamp walls "
-                "with a stale hash (run scripts/lint.py --update-costs)"
+                "costmodel.json pin — measured walls would join a "
+                "stale structure (run scripts/lint.py --update-costs)"
             )
     return out
 
@@ -659,73 +556,3 @@ def advisories(f: CostFeatures, budgets: dict | None = None) -> list[str]:
             "algebraic simplifier chews on it"
         )
     return out
-
-
-# ---------------------------------------------------------------------------
-# Pre-flight admission gate (the bench attempt gate)
-# ---------------------------------------------------------------------------
-
-
-def wall_deadline() -> float | None:
-    """Absolute wall deadline (epoch seconds) exported by bench.py to
-    its device child as $OCT_WALL_DEADLINE; None = no budget set (the
-    gate admits everything)."""
-    v = os.environ.get(_DEADLINE_ENV)
-    if not v:
-        return None
-    try:
-        return float(v)
-    except ValueError:
-        return None
-
-
-def preflight(stage: str, graph: str | None = None, *,
-              now: float | None = None,
-              margin_s: float | None = None,
-              action: str = "stage-split-fallback",
-              fallback_graph: str | None = None,
-              lanes: int | None = None) -> bool:
-    """Admission gate for a COLD program's first execute: True = go.
-
-    Refuses when a wall deadline is set, the stage has not yet recorded
-    a first execute (so its compile is still owed), and the pinned
-    predicted cold-compile wall does not fit the remaining budget with
-    `margin_s` to spare. A refusal is recorded in the warmup report
-    (the round JSON banks the decision either way) and the caller takes
-    `action` — the fallback path it will dispatch instead.
-
-    `fallback_graph` names the registered twin of that fallback when it
-    is itself ONE monolithic program (the per-lane xla-packed twin): a
-    refusal only helps if the fallback is predicted CHEAPER, so the
-    gate admits rather than trade one doomed compile for another. When
-    the fallback is the per-stage split path (fallback_graph=None) the
-    refusal always stands — split programs are individually small and
-    the persistent cache banks each one across retries. No prediction
-    or no deadline -> admit: the gate never blocks on ignorance."""
-    if os.environ.get(_GATE_ENV, "1") == "0":
-        return True
-    deadline = wall_deadline()
-    if deadline is None:
-        return True
-    from ..obs.warmup import WARMUP
-
-    if stage in WARMUP.stages:
-        return True  # already compiled this process: warm dispatch
-    g = graph if graph is not None else stage_pin_graph(stage, lanes)
-    pred = predicted_wall(g) if g else None
-    if pred is None:
-        return True
-    now = time.time() if now is None else now
-    margin = PREFLIGHT_MARGIN_S if margin_s is None else margin_s
-    remaining = deadline - now
-    if pred + margin <= remaining:
-        return True
-    if fallback_graph is not None:
-        fb = predicted_wall(fallback_graph)
-        if fb is None or fb >= pred:
-            return True  # the fallback is no cheaper: refusing gains nothing
-    WARMUP.note_refusal(
-        stage, pred, remaining, action=action,
-        detail=f"graph={g} margin={margin:g}s",
-    )
-    return False

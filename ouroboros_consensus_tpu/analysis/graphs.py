@@ -40,8 +40,9 @@ from typing import Callable
 # families (reassociation/distribution) chew on these
 _MUL_PRIMS = {"mul", "dot_general"}
 # call-like primitives whose subjaxprs are separate XLA computations
+# (a jitted call is `pjit` up to JAX 0.6 and `jit` since)
 _FENCE_PRIMS = {
-    "while", "scan", "cond", "pjit", "closed_call", "core_call",
+    "while", "scan", "cond", "jit", "pjit", "closed_call", "core_call",
     "custom_jvp_call", "custom_vjp_call", "remat", "checkpoint",
     "pallas_call", "shard_map", "custom_partitioning",
 }
@@ -634,6 +635,31 @@ def point_ops(name: str, t: int | None = None) -> dict:
         t = None
     trace_graph(name, t)
     return dict(_POINT_OPS[(name, t)])
+
+
+def measure_graph(name: str, lanes: int | None = None,
+                  compile: bool = True) -> dict:
+    """Lower (and optionally compile) one registered graph at `lanes`
+    and extract its device resources (obs/resources.py's vocabulary).
+    With compile=True the numbers come from the OPTIMIZED executable
+    plus its memory stats — the pin source for scripts/lint.py
+    --update-resources; compile=False stops at the HLO cost analysis
+    (no peak HBM) for a quick look."""
+    import jax
+
+    from ..obs import resources
+
+    fn, args = REGISTRY[name](lanes)
+    lowered = jax.jit(fn).lower(*args)
+    res = resources.from_lowered(lowered) or {}
+    res["source"] = "lowered"
+    if compile:
+        res.update(resources.from_compiled(lowered.compile()) or {})
+        res["source"] = "compiled"
+    res["at_lanes"] = lanes if lanes is not None else (
+        DEFAULT_TILES.get(name)
+    )
+    return res
 
 
 def analyze_registered(names: list[str] | None = None) -> list[GraphReport]:

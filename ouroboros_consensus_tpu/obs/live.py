@@ -12,7 +12,7 @@ in-run surface for the batched pipeline:
   * `Heartbeat` — a daemon thread that atomically rewrites a JSON
     snapshot every ~2 s (`OCT_HEARTBEAT=<file>`): current phase from
     the recorder's last event, retired window index, headers retired,
-    a rolling headers/s, ladder/bg-compile state from the warmup
+    a rolling headers/s, the compile state from the warmup
     notes, and the age since the last observable progress. The bench
     parent reads it to tell *compiling* / *staging* / *running* /
     *stalled* / *dead* apart in real time.
@@ -103,8 +103,6 @@ def phase_of(ev) -> str:
         return "retired"
     if isinstance(ev, T.TransferEvent):
         return ev.phase
-    if isinstance(ev, T.LadderEvent):
-        return "ladder"
     if isinstance(ev, T.AggRedispatch):
         return "agg-redispatch"
     if isinstance(ev, T.RecoveryEvent):
@@ -117,17 +115,9 @@ def phase_of(ev) -> str:
 
 
 def _warmup_live(report: dict) -> dict:
-    """The compile-side slice of the heartbeat: is a first-execute or a
-    background ladder compile in flight right now?"""
+    """The compile-side slice of the heartbeat: is a first-execute in
+    flight right now?"""
     notes = report.get("notes") or []
-    ladder = report.get("ladder") or []
-    bg = None
-    for row in ladder:
-        kind = row.get("kind", "")
-        if kind == "bg-compile-started":
-            bg = "running"
-        elif kind in ("bg-compile-done", "bg-compile-failed", "swap"):
-            bg = kind
     last_note = notes[-1] if notes else None
     # a stage's "<label> first execute starting" note lands BEFORE its
     # compile-inclusive first execute and the completion note_stage
@@ -142,8 +132,6 @@ def _warmup_live(report: dict) -> dict:
         "n_stages": report.get("n_stages", 0),
         "compile_total_s": report.get("compile_total_s", 0.0),
         "last_note": last_note,
-        "ladder": ladder[-1].get("kind") if ladder else None,
-        "bg_compile": bg,
         "compiling_now": compiling_now,
     }
 
@@ -208,7 +196,7 @@ def classify(doc: dict | None, now_unix: float | None = None,
                        (`stalled_now`; the cumulative `stalls` count is
                        informational — a recovered run classifies by
                        its live phase again)
-        compiling      a stage first-execute / bg ladder compile is the
+        compiling      a stage first-execute is the
                        freshest activity (warmup moving, no spans yet,
                        or the last note names an in-flight compile)
         staging        host-side window prep (stage/stream/prechecks)
@@ -229,13 +217,12 @@ def classify(doc: dict | None, now_unix: float | None = None,
         # a foreground first-execute is compiling RIGHT NOW, whatever
         # phase the dispatch loop froze in when it hit the cold stage
         or wu.get("compiling_now")
-        or (wu.get("bg_compile") == "running" and phase in ("idle",))
     ):
         return "compiling"
     if phase in ("stage", "stream", "prechecks"):
         return "staging"
     if phase in ("dispatch", "materialize", "epilogue", "retired",
-                 "ladder", "agg-redispatch", "recovery"):
+                 "agg-redispatch", "recovery"):
         return "running"
     if phase == "stalled":
         return "stalled"
@@ -275,7 +262,7 @@ class StallWatchdog:
 
         with WARMUP._lock:
             wu = (len(WARMUP.stages), len(WARMUP.notes),
-                  len(WARMUP.ladder), len(WARMUP.aot_events),
+                  len(WARMUP.aot_events),
                   # recovery-ladder transitions ARE progress: a window
                   # being walked down the degradation ladder must not
                   # read as a wedge (and a stall episode re-arms the

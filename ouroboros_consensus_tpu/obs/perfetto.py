@@ -16,7 +16,7 @@ and D2H bytes per window, and a "warmup" row rebuilt from the warmup
 recorder (obs/warmup.py): one slice per stage FIRST execute (the
 compile wall that dominates cold runs — previously invisible in the
 very tool meant to visualize walls) plus instants for every pk-AOT
-load outcome and octwall pre-flight refusal. The warmup rows need the
+load outcome. The warmup rows need the
 recorder's own monotonic t0 to share the event stream's timeline, so
 they appear when exporting from a live process (FlightRecorder
 .chrome_trace / scripts/profile_replay.py --trace-out), not when
@@ -74,8 +74,8 @@ def to_chrome_trace(timed_events: Iterable[tuple[float, object]],
 
     `warmup_report` (with `warmup_t0`, the recorder's monotonic epoch —
     report timestamps are relative to it) adds the warmup track:
-    per-stage first-execute slices with aot/jit attribution, pk-AOT
-    load-outcome instants, and octwall pre-flight refusal instants."""
+    per-stage first-execute slices with aot/jit attribution and pk-AOT
+    load-outcome instants."""
     timed = list(timed_events)
     tids = dict(_TIDS)
 
@@ -101,8 +101,7 @@ def to_chrome_trace(timed_events: Iterable[tuple[float, object]],
             cand = warmup_t0 + float(row.get("t", 0.0)) - float(
                 row.get("wall_s", 0.0))
             t_zero = cand if t_zero is None else min(t_zero, cand)
-        for ev_row in (wu.get("aot_events", []) + wu.get("refusals", [])
-                       + wu.get("ladder", [])):
+        for ev_row in wu.get("aot_events", []):
             cand = warmup_t0 + float(ev_row.get("t", 0.0))
             t_zero = cand if t_zero is None else min(t_zero, cand)
     if t_zero is None:
@@ -168,8 +167,6 @@ def to_chrome_trace(timed_events: Iterable[tuple[float, object]],
             end = warmup_t0 + float(row.get("t", 0.0))
             args = {"via": row.get("via", "jit"),
                     "wall_s": wall}
-            if row.get("feature_hash"):
-                args["feature_hash"] = row["feature_hash"]
             events.append({
                 "name": f"{stage} first-execute [{row.get('via', 'jit')}]",
                 "cat": "warmup", "ph": "X",
@@ -183,45 +180,6 @@ def to_chrome_trace(timed_events: Iterable[tuple[float, object]],
                 "cat": "warmup", "ph": "i", "s": "t",
                 "ts": us(warmup_t0 + float(ev_row.get("t", 0.0))),
                 "pid": PID, "tid": wtid,
-            })
-        for ref in wu.get("refusals", []):
-            events.append({
-                "name": (f"compile-wall refused: {ref.get('stage', '?')} "
-                         f"(predicted {ref.get('predicted_s', '?')}s > "
-                         f"remaining {ref.get('remaining_s', '?')}s)"),
-                "cat": "warmup", "ph": "i", "s": "t",
-                "ts": us(warmup_t0 + float(ref.get("t", 0.0))),
-                "pid": PID, "tid": wtid,
-            })
-        # the warm-ladder trajectory: the background production compile
-        # renders as a SLICE (bg-compile-started -> bg-compile-done, the
-        # wall the ladder hides behind served windows), every other
-        # event as an instant carrying its rung/hash args
-        bg_start = None
-        for lad in wu.get("ladder", []):
-            kind = lad.get("kind", "?")
-            t_abs = warmup_t0 + float(lad.get("t", 0.0))
-            if kind == "bg-compile-started":
-                bg_start = t_abs
-            if kind in ("bg-compile-done", "bg-compile-failed") and \
-                    bg_start is not None:
-                events.append({
-                    "name": f"ladder background compile [{kind[11:]}]",
-                    "cat": "warmup", "ph": "X",
-                    "ts": us(bg_start),
-                    "dur": max(0.0, (t_abs - bg_start) * 1e6),
-                    "pid": PID, "tid": wtid,
-                    "args": {k: v for k, v in lad.items() if k != "t"},
-                })
-                bg_start = None
-                continue
-            events.append({
-                "name": f"ladder: {kind}"
-                        + (f" rung={lad['rung']}" if lad.get("rung") else "")
-                        + (f" -> {lad['target']}"
-                           if kind == "swap" and lad.get("target") else ""),
-                "cat": "warmup", "ph": "i", "s": "t",
-                "ts": us(t_abs), "pid": PID, "tid": wtid,
             })
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 

@@ -36,9 +36,9 @@ import threading
 import time
 
 from ..utils.trace import (
-    AggRedispatch, CheckpointEvent, EncloseEvent, ForgeSpan, LadderEvent,
-    RecoveryEvent, RepairEvent, ShardSpan, SidecarEvent, StallEvent,
-    TransferEvent, WindowSpan, WindowStaged,
+    AggRedispatch, CheckpointEvent, EncloseEvent, ForgeSpan, RecoveryEvent,
+    RepairEvent, ShardSpan, SidecarEvent, StallEvent, TransferEvent,
+    WindowSpan, WindowStaged,
 )
 from . import registry as _registry
 
@@ -66,10 +66,6 @@ class FlightRecorder:
         self._redisp = r.counter(
             "oct_agg_redispatch_total",
             "aggregate windows re-dispatched per-lane",
-        )
-        self._ladder = r.counter(
-            "oct_ladder_events_total",
-            "warm-ladder transitions (engaged/bg-compile/swap)", ("kind",),
         )
         self._h2d = r.counter("oct_h2d_bytes_total", "bytes staged to device")
         self._d2h = r.counter("oct_d2h_bytes_total", "bytes returned to host")
@@ -165,13 +161,6 @@ class FlightRecorder:
             self._windows.labels(outcome=ev.outcome).inc()
             if ev.outcome == "generic":
                 self._gates.labels(gate=ev.gate or "packed-off").inc()
-            elif ev.gate:
-                # a non-generic outcome can still carry a gate: the
-                # octwall pre-flight refusal ("compile-wall-refused")
-                # rides a PACKED window that fell back off the
-                # aggregate path — it must be countable, not only
-                # visible to someone reading raw event streams
-                self._gates.labels(gate=ev.gate).inc()
         elif isinstance(ev, WindowSpan):
             with self._lock:
                 if ev.index > self._last_span_index:
@@ -186,8 +175,6 @@ class FlightRecorder:
             )
         elif isinstance(ev, AggRedispatch):
             self._redisp.inc()
-        elif isinstance(ev, LadderEvent):
-            self._ladder.labels(kind=ev.kind).inc()
         elif isinstance(ev, TransferEvent):
             if ev.phase == "dispatch":
                 self._h2d.inc(ev.h2d_bytes)

@@ -21,13 +21,14 @@ Two capture paths, one vocabulary:
             the default metrics registry, and are banked into the bench
             round JSON / run ledger as `device_resources`.
 
-  static    `measure_graph` lowers (and, for the ratchet pins,
-            compiles) a registered analysis graph at its certification
-            tile on whatever backend is pinned — the numbers behind the
-            budgets.json `device_resources` section, regenerated by
-            `scripts/lint.py --update-resources` and checked (exit 6)
-            by `check_device_resources`: every registry graph must
-            carry a pin whose octwall `feature_hash` matches the
+  static    `analysis/graphs.measure_graph` lowers (and, for the
+            ratchet pins, compiles) a registered analysis graph at its
+            certification tile on whatever backend is pinned — the
+            numbers behind the budgets.json `device_resources` section,
+            regenerated by `scripts/lint.py --update-resources` and
+            checked (exit 6) by `check_device_resources`: every
+            registry graph must carry a pin whose octwall
+            `feature_hash` matches the
             freshly-extracted features (a stale-structure pin fails
             loudly instead of silently vouching for a program that no
             longer exists), and pinned values must stay under their
@@ -161,7 +162,6 @@ class StageResources:
 
     def note_stage(self, stage: str, lanes: int | None, depth: int | None,
                    res: dict, via: str = "jit",
-                   feature_hash: str | None = None,
                    capture_s: float | None = None) -> bool:
         """Record one stage program's resources. `stage` already carries
         the caller's bucket qualifier (e.g. "ed@b8192"); lanes/depth
@@ -177,8 +177,6 @@ class StageResources:
             row["lanes"] = int(lanes)
         if depth is not None:
             row["depth"] = int(depth)
-        if feature_hash:
-            row["feature_hash"] = feature_hash
         if capture_s is not None:
             row["capture_s"] = round(float(capture_s), 3)
         with self._lock:
@@ -243,11 +241,6 @@ def register_families(reg, stage: str | None = None,
     return flops, byts, hbm
 
 
-# jit-path capture re-traces the program (seconds for the composed
-# XLA twins): never spend that inside a wall-budgeted bench attempt
-# whose deadline is closer than this — telemetry must not be the thing
-# that pushes a near-deadline attempt over the wall
-CAPTURE_DEADLINE_MARGIN_S = 120.0
 # captures slower than this leave a warmup-report breadcrumb, so the
 # forensics can explain every second of a warmup wall, including ours
 CAPTURE_NOTE_THRESHOLD_S = 1.0
@@ -259,7 +252,7 @@ def capture_stage(stage: str, fn, args, *, lanes: int | None = None,
     warmup hooks guarantee once-per-key). `fn` is the jitted callable
     on the jit path (one re-lower = a trace, no XLA compile — but the
     trace itself costs seconds on the big composed twins, hence the
-    deadline guard and the self-accounting below) or the loaded
+    self-accounting below) or the loaded
     executable on the AOT path (analyses are free). Never raises —
     resource accounting must not break dispatch."""
     if not enabled():
@@ -271,21 +264,11 @@ def capture_stage(stage: str, fn, args, *, lanes: int | None = None,
         # an already-compiled executable under via "xla-aot"/"jit" too
         # (round 10) — its analyses are free; only a jitted callable
         # (has .lower) needs the re-lower
-        is_compiled = not hasattr(fn, "lower")
-        if not is_compiled:
-            # the re-trace is skippable telemetry; a bench attempt's
-            # wall budget (analysis/costmodel OCT_WALL_DEADLINE) is not
-            from ..analysis import costmodel
-
-            deadline = costmodel.wall_deadline()
-            if (deadline is not None
-                    and deadline - time.time() < CAPTURE_DEADLINE_MARGIN_S):
-                return False
         t0 = time.monotonic()
-        if is_compiled:
-            res = from_compiled(fn)
-        else:
+        if hasattr(fn, "lower"):
             res = from_lowered(fn.lower(*args))
+        else:
+            res = from_compiled(fn)
         capture_s = time.monotonic() - t0
         if not res:
             return False
@@ -298,12 +281,8 @@ def capture_stage(stage: str, fn, args, *, lanes: int | None = None,
             WARMUP.note(
                 f"{stage} resource capture took {capture_s:.1f}s"
             )
-        from ..analysis import costmodel
-
         return RESOURCES.note_stage(
-            stage, lanes, depth, res, via=via,
-            feature_hash=costmodel.stage_feature_hash(stage),
-            capture_s=capture_s,
+            stage, lanes, depth, res, via=via, capture_s=capture_s,
         )
     except Exception:  # noqa: BLE001 — fail-soft by contract
         return False
@@ -312,31 +291,6 @@ def capture_stage(stage: str, fn, args, *, lanes: int | None = None,
 # ---------------------------------------------------------------------------
 # Static measurement + the budgets.json "device_resources" ratchet
 # ---------------------------------------------------------------------------
-
-
-def measure_graph(name: str, lanes: int | None = None,
-                  compile: bool = True) -> dict:
-    """Lower (and optionally compile) one registered analysis graph at
-    `lanes` and extract its resources. With compile=True the numbers
-    come from the OPTIMIZED executable plus its memory stats — the pin
-    source for --update-resources; compile=False stops at the HLO cost
-    analysis (no peak HBM) for a quick look."""
-    import jax
-
-    from ..analysis import graphs
-
-    fn, args = graphs.REGISTRY[name](lanes)
-    lowered = jax.jit(fn).lower(*args)
-    res = from_lowered(lowered) or {}
-    res["source"] = "lowered"
-    if compile:
-        compiled = lowered.compile()
-        res.update(from_compiled(compiled) or {})
-        res["source"] = "compiled"
-    res["at_lanes"] = lanes if lanes is not None else (
-        graphs.DEFAULT_TILES.get(name)
-    )
-    return res
 
 
 def pin_payload(measurements: dict[str, dict],

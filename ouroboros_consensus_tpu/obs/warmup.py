@@ -45,14 +45,6 @@ class WarmupRecorder:
         # aot outcome counts + the per-stage detail rows
         self.aot: dict[str, int] = {}  # guarded-by: _lock
         self.aot_events: list[dict] = []  # guarded-by: _lock
-        # pre-flight refusals (analysis/costmodel.preflight): dispatches
-        # whose PREDICTED cold-compile wall did not fit the remaining
-        # bench budget — the decision is forensics too
-        self.refusals: list[dict] = []  # guarded-by: _lock
-        # warm-while-serving compile ladder (protocol/batch.WarmLadder):
-        # engagement, background-compile start/land and every rung swap,
-        # each with the octwall feature hash of the program involved
-        self.ladder: list[dict] = []  # guarded-by: _lock
         self.cache_probe: dict | None = None  # guarded-by: _lock
         self.notes: list[str] = []  # guarded-by: _lock
         # recovery-supervisor episodes (obs/recovery.py): every ladder
@@ -69,59 +61,20 @@ class WarmupRecorder:
 
     # -- recording ----------------------------------------------------------
 
-    def note_stage(self, stage: str, wall_s: float, via: str = "jit",
-                   feature_hash: str | None = None) -> bool:
+    def note_stage(self, stage: str, wall_s: float,
+                   via: str = "jit") -> bool:
         """Record a stage's FIRST execute wall (compile-inclusive).
-        Returns True when this call was the first for `stage`.
-        `feature_hash` is the costmodel jaxpr feature digest of the
-        dispatched program, so scripts/fit_costmodel.py can join this
-        measured wall EXACTLY to the static features it belongs to."""
+        Returns True when this call was the first for `stage`."""
         with self._lock:
             if stage in self.stages:
                 return False
-            row = {
+            self.stages[stage] = {
                 "wall_s": round(wall_s, 3),
                 "via": via,
                 "t": round(time.monotonic() - self.t0, 3),
             }
-            if feature_hash:
-                row["feature_hash"] = feature_hash
-            self.stages[stage] = row
         self._flush()
         return True
-
-    def note_refusal(self, stage: str, predicted_s: float,
-                     remaining_s: float, action: str,
-                     detail: str = "") -> None:
-        """One pre-flight refusal: a cold first-execute whose predicted
-        compile wall exceeded the remaining wall budget (the caller
-        takes `action` — e.g. the per-stage split fallback — instead)."""
-        with self._lock:
-            self.refusals.append({
-                "stage": stage,
-                "predicted_s": round(predicted_s, 1),
-                "remaining_s": round(remaining_s, 1),
-                "action": action,
-                "detail": detail[:200],
-                "t": round(time.monotonic() - self.t0, 3),
-            })
-        self._flush()
-
-    def note_ladder(self, kind: str, **fields) -> None:
-        """One warm-ladder event, first-class in the report: kind is
-        engaged | bg-compile-started | bg-compile-done | bg-compile-failed
-        | swap. Fields carry the rung/target lane counts, the production
-        stage label and the octwall feature_hash of the program the
-        event is about, so a ladder trajectory joins the cost pins the
-        same way stage first-executes do."""
-        row = {"kind": kind,
-               "t": round(time.monotonic() - self.t0, 3)}
-        for k, v in fields.items():
-            if v is not None:
-                row[k] = round(v, 3) if isinstance(v, float) else v
-        with self._lock:
-            self.ladder.append(row)
-        self._flush()
 
     def note_aot(self, stage: str, outcome: str, wall_s: float = 0.0,
                  detail: str = "") -> None:
@@ -213,8 +166,9 @@ class WarmupRecorder:
                 "stages": stages,
                 "aot": dict(self.aot),
                 "aot_events": list(self.aot_events),
-                "refusals": [dict(r) for r in self.refusals],
-                "ladder": [dict(r) for r in self.ladder],
+                # nothing refuses a dispatch since PR 34; the key
+                # stays, empty, while benchmark/ reads it
+                "refusals": [],
                 "cache_probe": self.cache_probe,
                 "recovery": [dict(r) for r in self.recovery],
                 "repairs": [dict(r) for r in self.repairs],
@@ -244,8 +198,6 @@ class WarmupRecorder:
             self.stages.clear()
             self.aot.clear()
             self.aot_events.clear()
-            self.refusals.clear()
-            self.ladder.clear()
             self.cache_probe = None
             self.recovery.clear()
             self.repairs.clear()

@@ -387,6 +387,24 @@ def save(name: str, b: int, kes_depth: int, tile: int, sig: str, compiled,
 _LOADED: dict = {}  # guarded-by: _LOAD_LOCK
 
 
+def _deserialize(path: str):
+    """One artifact file -> a loaded executable on the device it was
+    compiled for. Every stored program is a one-device program built
+    for the process's first device, so that is where it is loaded:
+    without `execution_devices` JAX spreads it over every local device
+    and its first call dies ("expected N shards"). A sharded program
+    would have to carry its own device list in the artifact."""
+    import jax
+    from jax.experimental import serialize_executable as se
+
+    with open(path, "rb") as f:
+        blob = pickle.load(f)
+    return se.deserialize_and_load(
+        blob["ser"], blob["in_tree"], blob["out_tree"],
+        execution_devices=jax.devices()[:1],
+    )
+
+
 def load(name: str, b: int, kes_depth: int, tile: int, sig: str):
     """Deserialize-and-load a store entry onto the live backend.
 
@@ -406,8 +424,8 @@ def load(name: str, b: int, kes_depth: int, tile: int, sig: str):
     # the read is GIL-atomic, and taking _LOAD_LOCK here would park a
     # warm caller behind a concurrent multi-second deserialize; misses
     # re-check under the lock below.
-    if key in _LOADED:  # octsync: disable=SYNC203
-        return _LOADED[key]  # octsync: disable=SYNC203
+    if key in _LOADED:
+        return _LOADED[key]
     if not enabled():
         return None
     from ...testing import chaos
@@ -462,13 +480,7 @@ def load(name: str, b: int, kes_depth: int, tile: int, sig: str):
             return None
         t0 = time.monotonic()
         try:
-            from jax.experimental import serialize_executable as se
-
-            with open(path, "rb") as f:
-                blob = pickle.load(f)
-            result = se.deserialize_and_load(
-                blob["ser"], blob["in_tree"], blob["out_tree"]
-            )
+            result = _deserialize(path)
             _note_aot(name, "loaded", time.monotonic() - t0)
         except Exception as e:  # noqa: BLE001 — fail-soft by contract
             import sys
@@ -572,13 +584,7 @@ def check_store(slug: str | None = None) -> tuple[int, list[str]]:
             )
             continue
         try:
-            from jax.experimental import serialize_executable as se
-
-            with open(path, "rb") as f:
-                blob = pickle.load(f)
-            se.deserialize_and_load(
-                blob["ser"], blob["in_tree"], blob["out_tree"]
-            )
+            _deserialize(path)
             ok += 1
         except Exception as e:  # noqa: BLE001 — report, don't crash
             problems.append(f"{key}: deserialize failed: {e!r}")

@@ -467,14 +467,9 @@ def _note_first_exec(stage: str, wall_s: float, via: str) -> None:
     if stage in _FIRST_EXEC:
         return
     _FIRST_EXEC.add(stage)
-    from ...analysis import costmodel
     from ...obs.warmup import WARMUP
 
-    # the costmodel feature hash of the dispatched program (pinned in
-    # analysis/costmodel.json — a dict lookup, no tracing) rides the
-    # note so fit_costmodel's calibration join is exact
-    WARMUP.note_stage(stage, wall_s, via=via,
-                      feature_hash=costmodel.stage_feature_hash(stage))
+    WARMUP.note_stage(stage, wall_s, via=via)
 
 
 def _begin_first_exec(stage: str) -> None:

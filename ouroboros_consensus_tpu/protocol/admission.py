@@ -1,33 +1,21 @@
-"""Priced admission for the serving plane: warm shapes go straight to
-the device; cold shapes ride the rung ladder instead of stalling warm
-traffic.
+"""Admission for the serving plane: warm shapes go straight to the
+device at full size; cold shapes climb a rung ladder instead of
+stalling warm traffic.
 
 The serving scheduler (node/serve.py) fills shared packed windows from
 whatever lanes are pending across tenants. Every window pads to a
 power-of-two-family bucket (protocol/batch.bucket_size), and each
 DISTINCT (proof format, body length, bucket) shape is one compiled
 device program: the first dispatch of a shape pays its compile wall.
-On a TPU session that wall is minutes (PERF.md round 6) — letting one
-cold tenant's odd shape compile INLINE would stall every warm tenant
-behind it, the exact head-of-line blocking the round-10 warm ladder
-exists to avoid during replays.
+Letting one cold tenant's odd shape compile INLINE at full size would
+stall every warm tenant behind it.
 
-This module is the serving-side twin of that ladder, as an admission
-decision instead of a window re-tiler:
-
-  * a WARM shape (its bucket has already dispatched this process, or
-    an AOT-pinned rung program covers it) is admitted at full size;
-  * a COLD shape is CAPPED to the warm-compile rung ladder
-    (analysis/costmodel.LADDER_RUNGS, the same rungs the replay ladder
-    compiles and octwall pins): the tenant serves on rung-sized
-    windows — individually cheap compiles, promoted bucket by bucket
-    as each retires warm — and escalates to its full requested shape
-    only once the ladder has walked there;
-  * pricing is the octwall surface: `costmodel.predicted_wall` for the
-    shape's registered graph twin and `costmodel.preflight` under an
-    exported $OCT_WALL_DEADLINE, with the per-stage
-    `obs.resources.RESOURCES` device-resources rows attached to the
-    decision so the SLO surface can show WHY a tenant is rung-capped.
+  * a WARM shape (its bucket has already retired a window in this
+    process) is admitted at full size;
+  * a COLD shape is CAPPED to the rung ladder (`RUNGS`): the tenant
+    starts at the largest rung and escalates one rung per warm window
+    until its requested bucket is reachable. Warmth is EARNED by a
+    retired window, never assumed.
 
 Malformed submissions are REFUSED at the door (`AdmissionRefused`,
 disposition REFUSE in node/exit.DISPOSITIONS): an empty suffix, a
@@ -45,6 +33,10 @@ from dataclasses import dataclass
 from .batch import bucket_size
 
 _DEVICE_ENV = "OCT_SERVE_DEVICE"
+
+# the lane counts a cold shape may serve at before its requested bucket
+# has been earned
+RUNGS = (1024, 2048)
 
 
 class AdmissionRefused(Exception):
@@ -67,29 +59,15 @@ class WindowShape:
     proof_len: int  # 80 draft-03 | 128 batch-compatible
     body_len: int  # KES-signed body bytes (packed layout body column)
 
-    def graph(self) -> str:
-        """Registered costmodel graph twin of this shape's packed
-        program (the xla-packed path's structural twin — the serving
-        rig's dispatch impl)."""
-        return ("verify_praos_core" if self.proof_len == 80
-                else "verify_praos_core_bc")
-
-    def stage_label(self, lanes: int) -> str:
-        """Warmup-vocabulary stage label for preflight pricing (the
-        xla-packed label family of protocol/batch._jitted_packed_xla)."""
-        return f"xla-packed:{self.body_len}b:p{self.proof_len}@{lanes}"
-
 
 @dataclass(frozen=True)
 class AdmissionDecision:
-    """One priced admission: how many lanes this shape may fill in the
-    next shared window, and why."""
+    """One admission: how many lanes this shape may fill in the next
+    shared window, and why."""
 
     mode: str  # "warm" | "rung" | "host"
     lane_cap: int  # max lanes of this shape in the next window
     bucket: int  # the padded bucket the cap dispatches as
-    predicted_wall_s: float | None  # octwall price of that bucket (cold)
-    device_resources: dict | None  # per-stage ledger rows, when banked
 
 
 def shape_of(tenant_id: str, hvs) -> WindowShape:
@@ -129,116 +107,47 @@ def shape_of(tenant_id: str, hvs) -> WindowShape:
 class AdmissionPolicy:
     """Warm-shape tracking + rung-ladder capping for one service.
 
-    `admit(shape, requested)` prices the shape's next window;
+    `admit(shape, requested)` caps the shape's next window;
     `note_window(shape, lanes)` marks the dispatched bucket warm after
     the window retires (promotion is EARNED, never assumed — a shed or
     recovered window does not warm its bucket). One scheduler thread
     owns the instance; no locks by design."""
 
     def __init__(self, rungs: tuple | None = None):
-        from ..analysis import costmodel
-
-        self._costmodel = costmodel
-        self.rungs = tuple(sorted(rungs if rungs is not None
-                                  else costmodel.LADDER_RUNGS))
+        self.rungs = tuple(sorted(rungs if rungs is not None else RUNGS))
         # shape -> set of buckets proven warm in this process
         self._warm: dict[WindowShape, set] = {}
         self.decisions: dict[str, int] = {"warm": 0, "rung": 0, "host": 0}
 
-    # -- warm-set bookkeeping ----------------------------------------------
-
-    def is_warm(self, shape: WindowShape, bucket: int) -> bool:
-        if bucket in self._warm.get(shape, ()):
-            return True
-        # an octwall rung pin covers the bucket: the program was
-        # AOT-priced and its compile is known to fit the rung budget —
-        # treat the PINNED rungs as warm-startable, exactly like the
-        # replay ladder does when choosing its first rung
-        pin = self._costmodel.ladder_pin_name(shape.graph(), bucket)
-        return self._costmodel.pinned(pin) is not None
-
     def note_window(self, shape: WindowShape, lanes: int) -> None:
         """A window of this shape retired cleanly at `lanes`: its
-        bucket (and every smaller one — bucket_size is monotone) is
-        warm for the rest of the process."""
+        bucket is warm for the rest of the process."""
         self._warm.setdefault(shape, set()).add(bucket_size(lanes))
-
-    def warm_buckets(self, shape: WindowShape) -> tuple:
-        return tuple(sorted(self._warm.get(shape, ())))
-
-    # -- pricing ------------------------------------------------------------
-
-    def price(self, shape: WindowShape, bucket: int) -> float | None:
-        """Predicted cold-compile wall of this shape at `bucket` lanes:
-        the rung pin when octwall has one, else the base graph pin.
-        None = unpriced (the gate never blocks on ignorance)."""
-        cm = self._costmodel
-        pred = cm.predicted_wall(cm.ladder_pin_name(shape.graph(), bucket))
-        if pred is None:
-            pred = cm.predicted_wall(shape.graph())
-        return pred
-
-    def _resources_rows(self, shape: WindowShape) -> dict | None:
-        """The per-stage device-resources ledger rows banked for this
-        shape's graph family, when the resources plane is armed —
-        attached to decisions so the SLO surface can show the price."""
-        from ..obs.resources import RESOURCES
-
-        report = RESOURCES.report()
-        if not report:
-            return None
-        base = shape.graph()
-        rows = {k: v for k, v in report.items() if base in k}
-        return rows or None
-
-    # -- the decision -------------------------------------------------------
 
     def admit(self, shape: WindowShape, requested: int) -> AdmissionDecision:
         """Lane cap for this shape's next window.
 
-        Warm bucket -> full size. Cold -> the rung ladder: serve at the
-        largest already-warm bucket of this shape, else at the
-        octwall-chosen starting rung (`costmodel.choose_rung` against
-        $OCT_WALL_DEADLINE), escalating one rung per warm window until
-        the requested bucket is reachable. With the device plane
-        kill-switched (OCT_SERVE_DEVICE=0) every shape is mode="host":
-        the host fold has no compile wall to price."""
+        Warm bucket -> full size. Cold -> the rung ladder: one rung
+        past the largest bucket this shape has earned, else the largest
+        rung, until the requested bucket is reachable. With the device
+        plane kill-switched (OCT_SERVE_DEVICE=0) every shape is
+        mode="host": the host fold has no compile wall to climb."""
         requested = max(1, int(requested))
+        bucket = bucket_size(requested)
         if os.environ.get(_DEVICE_ENV, "1") == "0":
             self.decisions["host"] += 1
-            return AdmissionDecision("host", requested,
-                                     bucket_size(requested), None, None)
-        bucket = bucket_size(requested)
-        if self.is_warm(shape, bucket):
+            return AdmissionDecision("host", requested, bucket)
+        warm = sorted(self._warm.get(shape, ()))
+        if bucket in warm:
             self.decisions["warm"] += 1
-            return AdmissionDecision("warm", requested, bucket,
-                                     self.price(shape, bucket), None)
-        warm = self.warm_buckets(shape)
+            return AdmissionDecision("warm", requested, bucket)
         if warm:
-            # escalate one rung past the largest earned bucket; the
-            # ladder positions are the octwall rungs plus the requested
+            # the ladder positions are the rungs plus the requested
             # bucket as its top
-            ladder = sorted({*(r for r in self.rungs), bucket})
-            nxt = next((r for r in ladder if r > warm[-1]), bucket)
-            cap = min(requested, nxt)
+            ladder = sorted({*self.rungs, bucket})
+            cap = next((r for r in ladder if r > warm[-1]), bucket)
         else:
-            start = self._costmodel.choose_rung(shape.graph())
-            cap = min(requested, start if start else min(self.rungs))
-        # octwall preflight on the capped shape: under a wall deadline a
-        # rung whose own compile does not fit sheds further down
-        while cap > 1 and not self._costmodel.preflight(
-            shape.stage_label(bucket_size(cap)),
-            graph=self._costmodel.ladder_pin_name(
-                shape.graph(), bucket_size(cap)),
-            action="serve-rung-shed",
-        ):
-            lower = [r for r in self.rungs if r < cap]
-            if not lower:
-                break
-            cap = lower[-1]
+            cap = self.rungs[-1]
+        cap = min(requested, cap)
         self.decisions["rung"] += 1
-        return AdmissionDecision(
-            "rung", cap, bucket_size(cap),
-            self.price(shape, bucket_size(cap)),
-            self._resources_rows(shape),
-        )
+        return AdmissionDecision("rung", cap, bucket_size(cap))

@@ -675,8 +675,8 @@ def _warm_timed(stage: str, fn):
     died without attribution; this is the per-stage black box.
 
     The first-execute label is qualified by the padded LANE count
-    (`<stage>:<lanes>l`): the warm ladder dispatches the same program
-    family at rung and production lane counts, and the compile gate /
+    (`<stage>:<lanes>l`): one program family runs at more than one lane
+    count (a window keeps its own bucket on the XLA twin), and the
     warmup report must attribute each shape's first execute separately
     (a 1024-lane first execute does not make the 8192-lane program
     warm). The first execute also consults the build-pinned AOT store
@@ -740,10 +740,7 @@ def _warm_timed(stage: str, fn):
             warm_exec[label] = (pk_aot.sig_of(a), ex)
         wall = time.monotonic() - t0
         _WARM_SEEN.add(label)
-        from ..analysis import costmodel
-
-        WARMUP.note_stage(label, wall, via=via,
-                          feature_hash=costmodel.stage_feature_hash(label))
+        WARMUP.note_stage(label, wall, via=via)
         # device resource accounting rides the same first-execute gate:
         # one re-lower (trace only, no XLA compile) while capture is
         # enabled — lanes read off the leading batch axis. AFTER the
@@ -780,36 +777,6 @@ def _stage_thread_enabled() -> bool:
     staging — the differential kill-switch; read per call so tests can
     A/B both paths in one process."""
     return os.environ.get("OCT_STAGE_THREAD", "1") != "0"
-
-
-def _compile_gate_admit(stage: str, action: str,
-                        fallback_graph: str | None,
-                        lanes: int | None = None) -> bool:
-    """octwall pre-flight (analysis/costmodel.preflight): when bench.py
-    has exported a wall deadline ($OCT_WALL_DEADLINE), a COLD monolith
-    program whose PREDICTED cold-compile wall does not fit the
-    remaining budget is refused here — the window rides the fallback
-    path named by `action` instead, and the refusal lands in the warmup
-    report. On the pk impl that fallback is the per-stage split
-    (individually small programs, each banked by the persistent cache
-    across retries); on the xla impl it is the per-lane packed monolith,
-    so `fallback_graph` names its twin and the gate only refuses when
-    that twin is predicted CHEAPER (trading one doomed compile for
-    another helps nobody). No deadline / no model / OCT_COMPILE_GATE=0
-    -> always admit; the gate must never break dispatch."""
-    if os.environ.get("OCT_COMPILE_GATE", "1") == "0":
-        return True
-    try:
-        from ..analysis import costmodel
-
-        return costmodel.preflight(stage, action=action,
-                                   fallback_graph=fallback_graph,
-                                   lanes=lanes)
-    except Exception:  # noqa: BLE001 # octflow: disable=FLOW303 —
-        # fail-open by contract: the compile-wall gate must never
-        # break dispatch; admitting is the no-gate behavior, and the
-        # window's verdict still comes from the full validation
-        return True
 
 
 def _agg_enabled() -> bool:
@@ -1524,8 +1491,8 @@ def _packed_agg_fn(layout: PraosPackedLayout, mode: str = "all"):
     return fn
 
 
-# warmup/compile-gate label families of the two aggregate modes (the
-# family prefix is what analysis/costmodel.STAGE_GRAPHS keys on)
+# warmup label families of the two aggregate modes (the family prefix
+# is what analysis/costmodel.STAGE_GRAPHS keys on)
 _AGG_STAGE_FAMILY = {"all": "agg-packed", "vrf": "agg-vrf"}
 
 
@@ -2249,17 +2216,7 @@ def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
                          t0, time.monotonic(), window, thread, census)
 
 
-def _agg_label(layout, lanes: int, mode: str = "all") -> str:
-    """The aggregate monolith's warmup/first-execute label at one
-    padded lane count (must match what `_warm_timed` derives from the
-    dispatched arguments — the compile gate and the warm ladder key
-    their cold/warm decisions on it). `mode` picks the label family:
-    "all" -> agg-packed (shared-bucket fold), "vrf" -> agg-vrf (the
-    OCT_RLC_ALL=0 vrf-only aggregate)."""
-    return f"{_AGG_STAGE_FAMILY[mode]}:{layout.body_len}b:{lanes}l"
-
-
-def dispatch_prepared(sw: _StagedWindow, ladder=None):
+def dispatch_prepared(sw: _StagedWindow):
     """The DEVICE half of dispatch_batch: launch the fused kernel for a
     prepared window WITHOUT waiting (jax dispatch is asynchronous).
     Nothing chains from one dispatch to the next: a packed window
@@ -2294,43 +2251,17 @@ def dispatch_prepared(sw: _StagedWindow, ladder=None):
             meta = _win_meta("generic", gate, sw, t_d0)
             return pre, _Dispatched(impl, False, out, meta), b
         layout, parr = sw.packed
-        refused_gate = None
-        agg_mode = "all" if _rlc_all_enabled() else "vrf"
-        agg_stage = _agg_label(layout, lanes, agg_mode)
-        agg_path = layout.vrf_proof_len == 128 and _agg_enabled()
-        if agg_path and ladder is not None:
-            # the warm ladder owns the production-bucket compile: hand
-            # it the first packed window so the background thread can
-            # start warming the target-lane program while the replay
-            # serves rung-sized windows
-            ladder.observe(layout, parr)
-        if agg_path:
-            # the pk fallback is the per-stage split; the xla fallback
-            # is itself the per-lane packed monolith, so name its twin
-            # and only refuse when that twin is predicted cheaper
-            impl_is_pk = _impl() == "pk"
-            if not _compile_gate_admit(
-                agg_stage,
-                action=("stage-split-fallback" if impl_is_pk
-                        else "xla-packed-fallback"),
-                fallback_graph=(None if impl_is_pk
-                                else "verify_praos_core_bc"),
-                lanes=lanes,
-            ):
-                # predicted compile wall over budget AND the fallback
-                # path is cheaper: skip the 330k-eqn aggregate monolith
-                # (decision in warmup report)
-                refused_gate = "compile-wall-refused"
-        if agg_path and refused_gate is None:
+        if layout.vrf_proof_len == 128 and _agg_enabled():
             # the aggregated fast path: ONE RLC/MSM program instead of
             # the per-lane ladder stages (materialize_verdicts
             # re-dispatches per-lane on any anomaly)
+            agg_mode = "all" if _rlc_all_enabled() else "vrf"
             out = _jitted_packed_agg(layout, agg_mode)(*parr)
             meta = _win_meta("packed-agg", None, sw, t_d0)
             return pre, _Dispatched("agg", True, (layout, parr, out),
                                     meta), b
         impl, out, tiles_live = _dispatch_packed_lanes(layout, parr, b)
-        meta = _win_meta("packed", refused_gate, sw, t_d0, tiles_live)
+        meta = _win_meta("packed", None, sw, t_d0, tiles_live)
         return pre, _Dispatched(impl, True, out, meta), b
 
 
@@ -2350,7 +2281,7 @@ def _dispatch_packed_lanes(layout, parr, live: int):
     return "xla", _jitted_packed_xla(layout)(*parr), 0
 
 
-def dispatch_batch(params, lview, eta0, hvs, ladder=None):
+def dispatch_batch(params, lview, eta0, hvs):
     """Stage a within-epoch window and dispatch the fused kernel WITHOUT
     waiting (the §7.3.6 host/device overlap; the reference's analog is
     the decoupled add-block queue, ChainSel.hs:217-246) — the inline
@@ -2362,225 +2293,8 @@ def dispatch_batch(params, lview, eta0, hvs, ladder=None):
         # loops call the halves separately (and ride the supervisor);
         # an external caller of the inline form owns its own recovery,
         # exactly like calling dispatch_prepared directly
-        prepare_window(params, lview, eta0, hvs), ladder
+        prepare_window(params, lview, eta0, hvs)
     )
-
-
-# ---------------------------------------------------------------------------
-# Warm-while-serving compile ladder
-# ---------------------------------------------------------------------------
-
-# OCT_WARM_LADDER: "0" = off (windows always slice at max_batch and the
-# production program compiles synchronously at first dispatch — the
-# pre-round-10 behavior, verdict-identical by construction since window
-# re-tiling never changes verdicts); "1"/unset = auto (engage only when
-# a wall deadline is exported and the production aggregate monolith is
-# predicted not to fit it); "force" = engage whenever the production
-# program is cold (tests, profiling).
-
-
-class WarmLadder:
-    """Warm-while-serving compile ladder (round 10 tentpole).
-
-    When the production-bucket aggregate monolith is cold and predicted
-    over the remaining wall (octwall), the replay does NOT gamble the
-    budget on one synchronous compile: the validate_chain loop slices
-    windows at a small RUNG lane count — chosen by
-    analysis/costmodel.choose_rung against $OCT_WALL_DEADLINE — and a
-    background thread compiles the production-lane program off the
-    first window's packed columns. The moment it lands, the loop
-    re-tiles onto the production bucket (`swap`). Replay progress and
-    the monolith compile overlap instead of serializing, so the bench
-    child banks a provisional device checkpoint while the big program
-    is still in XLA.
-
-    Verdict-identical by construction: the rung only changes WINDOW
-    SLICING, and validate_batch is segmentation-invariant (same
-    verdicts, same first error, same nonce carry — the differential
-    suite drives all four ladder x staging-thread combinations).
-
-    Every transition is first-class warmup forensics
-    (obs/warmup.note_ladder + LadderEvent through the batch tracer):
-    engaged / bg-compile-started / bg-compile-done / bg-compile-failed
-    / swap, each carrying the octwall feature hash of the program
-    involved."""
-
-    def __init__(self, target: int, rung: int, graph: str,
-                 predicted_s: float | None):
-        self.target = target
-        self.rung = rung
-        self.graph = graph
-        self.predicted_s = predicted_s
-        # the ladder's transition latches cross threads (the loop reads
-        # what the background compile writes) — serialize them so the
-        # serving tier can drive poll_swap from more than one thread
-        self._state_lock = threading.Lock()
-        self._engaged = False  # guarded-by: _state_lock
-        self._done = threading.Event()
-        self._bg: threading.Thread | None = None
-        self._swapped = False  # guarded-by: _state_lock
-        self.failed = False  # guarded-by: _state_lock
-
-    # -- loop-facing ---------------------------------------------------------
-
-    def cap(self) -> int | None:
-        """Lane cap for the next window slice (None = production)."""
-        with self._state_lock:
-            if self._swapped or self._done.is_set():
-                return None
-            return self.rung
-
-    def note_engaged_once(self) -> None:
-        """Record engagement the first time a slice is actually capped
-        (a chain shorter than the rung never engages — no noise)."""
-        with self._state_lock:
-            if self._engaged:
-                return
-            self._engaged = True
-        from ..analysis import costmodel
-        from ..obs.warmup import WARMUP
-
-        rung_pin = costmodel.pinned(
-            costmodel.ladder_pin_name(self.graph, self.rung)
-        )
-        WARMUP.note_ladder(
-            "engaged", rung=self.rung, target=self.target,
-            graph=self.graph, predicted_s=self.predicted_s,
-            feature_hash=(rung_pin or {}).get("feature_hash"),
-        )
-        self._emit("engaged", self.rung)
-
-    def poll_swap(self) -> bool:
-        """True exactly once, when the background compile has landed
-        and the loop should re-tile onto the production bucket."""
-        with self._state_lock:
-            if (self._swapped or not self._engaged
-                    or not self._done.is_set()):
-                return False
-            self._swapped = True
-            failed = self.failed
-        from ..obs.warmup import WARMUP
-
-        WARMUP.note_ladder("swap", rung=self.rung, target=self.target,
-                           failed=failed or None)
-        self._emit("swap", None)
-        return True
-
-    # -- dispatch-facing -----------------------------------------------------
-
-    def observe(self, layout, parr) -> None:
-        """First packed window seen: start the background production
-        compile (or finish immediately when the production label is
-        already warm in this process)."""
-        if self._bg is not None or self._done.is_set():
-            return
-        # warm the mode that dispatch will actually serve (agg-packed
-        # unless the OCT_RLC_ALL kill-switch pins the vrf-only family)
-        mode = "all" if _rlc_all_enabled() else "vrf"
-        label = _agg_label(layout, self.target, mode)
-        from ..obs.warmup import WARMUP
-
-        if label in WARMUP.stages:
-            self._done.set()
-            return
-        from ..analysis import costmodel
-
-        WARMUP.note_ladder(
-            "bg-compile-started", rung=self.rung, target=self.target,
-            stage=label,
-            feature_hash=costmodel.stage_feature_hash(label),
-        )
-        self._emit("bg-compile-started", self.rung)
-        self._bg = threading.Thread(
-            target=self._warm, args=(layout, parr, mode),
-            daemon=True, name="oct-warm-ladder",
-        )
-        self._bg.start()
-
-    def _warm(self, layout, parr, mode: str = "all") -> None:
-        """Background thread body: pad the observed window's packed
-        columns to the production bucket and run the production program
-        once, blocking until the compile (and one execute) lands. XLA
-        compiles outside the GIL, so the replay keeps serving rung
-        windows meanwhile; the execute itself is one window of device
-        time. Bypasses the compile gate by design — eating this wall in
-        the background is the ladder's whole purpose."""
-        import jax
-
-        t0 = time.monotonic()
-        try:
-            out = _jitted_packed_agg(layout, mode)(
-                *pad_packed_to(parr, self.target)
-            )
-            jax.block_until_ready(out)
-        except Exception as e:  # noqa: BLE001 — fail-open: the loop
-            # simply dispatches the production program synchronously
-            with self._state_lock:
-                self.failed = True
-            from ..obs.warmup import WARMUP
-
-            WARMUP.note_ladder("bg-compile-failed", rung=self.rung,
-                               target=self.target, detail=repr(e)[:200])
-            self._emit("bg-compile-failed", self.rung)
-        else:
-            from ..obs.warmup import WARMUP
-
-            WARMUP.note_ladder(
-                "bg-compile-done", rung=self.rung, target=self.target,
-                wall_s=time.monotonic() - t0,
-            )
-            self._emit("bg-compile-done", self.rung)
-        finally:
-            self._done.set()
-
-    def _emit(self, kind: str, rung: int | None) -> None:
-        if BATCH_TRACER is not None:
-            from ..utils.trace import LadderEvent
-
-            BATCH_TRACER(LadderEvent(kind, rung, self.target))
-
-
-_LADDER: WarmLadder | None = None
-
-
-def reset_warm_ladder() -> None:
-    """Test isolation: forget the process-wide ladder."""
-    global _LADDER
-    _LADDER = None
-
-
-def _maybe_ladder(max_batch: int) -> WarmLadder | None:
-    """Create (once per process) or return the warm ladder for a device
-    replay. Engages only when the production path is the aggregate
-    monolith (OCT_VRF_AGG on, bc windows — on every other path the cold
-    programs are the individually-small split stages and re-tiling buys
-    nothing) and, in auto mode, only when an exported wall deadline
-    says the monolith's predicted compile does not fit."""
-    global _LADDER
-    mode = os.environ.get("OCT_WARM_LADDER", "1")
-    if mode == "0":
-        return None
-    if _LADDER is not None:
-        return _LADDER
-    if not _agg_enabled():
-        return None
-    from ..analysis import costmodel
-
-    target = bucket_size(max_batch)
-    rungs = tuple(r for r in costmodel.LADDER_RUNGS if r < target)
-    if not rungs:
-        return None
-    graph = "aggregate_core"
-    pred = costmodel.predicted_wall(graph)
-    if mode != "force":
-        deadline = costmodel.wall_deadline()
-        if deadline is None or pred is None:
-            return None
-        if pred + costmodel.PREFLIGHT_MARGIN_S <= deadline - time.time():
-            return None  # the monolith fits: compile it up front
-    rung = costmodel.choose_rung(graph, rungs=rungs)
-    _LADDER = WarmLadder(target, rung, graph, pred)
-    return _LADDER
 
 
 class PackedVerdicts:
@@ -3185,12 +2899,6 @@ def _validate_chain_loop(
     s_stage = 0  # segment currently being staged
     w = segments[0][1] if segments else 0
     retired = 0  # index of the next header to retire
-    # warm-while-serving compile ladder: while the production-bucket
-    # aggregate monolith compiles on a background thread, windows slice
-    # at the rung lane cap; the loop re-tiles the moment it lands
-    # (poll_swap after each retire). Window re-tiling never changes
-    # verdicts — validate_batch is segmentation-invariant.
-    ladder = _maybe_ladder(max_batch)
     # producer thread: prechecks + packed staging + padding run ahead
     # of dispatch (prepare_window is fold-independent), overlapping the
     # staging wall with device compute and the retire-side epilogue.
@@ -3211,7 +2919,7 @@ def _validate_chain_loop(
         return _device_loop(
             params, hvs, max_batch, pipeline_depth, pool, stage_pool,
             segments, lview_for, eta_known, inflight, staged, s_stage, w,
-            retired, ladder, state, total_valid, n,
+            retired, state, total_valid, n,
         )
     finally:
         if stage_pool is not None:
@@ -3223,17 +2931,15 @@ def _validate_chain_loop(
 def _device_loop(
     params, hvs, max_batch, pipeline_depth, pool, stage_pool,
     segments, lview_for, eta_known, inflight, staged, s_stage, w,
-    retired, ladder, state, total_valid, n,
+    retired, state, total_valid, n,
 ):
-    # one lane shape per replay on the chip (window_lanes); a warm
-    # ladder re-tiles windows on purpose, so it keeps their own buckets.
-    # Resolved HERE, on the dispatching thread: the staging thread does
-    # not see this thread's recovery overrides
-    lanes = window_lanes(max_batch) if ladder is None else None
+    # one lane shape per replay on the chip (window_lanes). Resolved
+    # HERE, on the dispatching thread: the staging thread does not see
+    # this thread's recovery overrides
+    lanes = window_lanes(max_batch)
 
     def enqueue_staging():
         nonlocal s_stage, w
-        cap = ladder.cap() if ladder is not None else None
         while (
             s_stage < len(segments)
             and (
@@ -3248,16 +2954,11 @@ def _device_loop(
             and s_stage in eta_known
         ):
             _, _, seg_end = segments[s_stage]
-            j_full = min(w + max_batch, seg_end)
-            j = j_full
-            if cap is not None and j - w > cap:
-                j = w + cap
-                ladder.note_engaged_once()
             # a window must stage a uniform proof column: break at the
             # first 80/128-byte format change (the reference fold
             # length-dispatches per header, so mixed chains stay valid;
             # segmentation never changes verdicts or the first error)
-            j = _proof_break(hvs, w, j)
+            j = _proof_break(hvs, w, min(w + max_batch, seg_end))
             whvs = hvs[w:j]
             # the window's id: staging order is dispatch order
             win = next_window_id()
@@ -3324,7 +3025,7 @@ def _device_loop(
                     continue
             staged.popleft()
             try:
-                pre, out, b = dispatch_prepared(item, ladder)
+                pre, out, b = dispatch_prepared(item)
             except Exception as e:  # noqa: BLE001 — gated below
                 if not _queue_failure(e):
                     raise
@@ -3433,10 +3134,6 @@ def _device_loop(
         _recovery.note_window(state, res.n_valid)
         _chaos.fire("retire")
         win_retired += 1
-        if ladder is not None:
-            # the background production compile landed: record the swap
-            # — the NEXT slices re-tile onto the production bucket
-            ladder.poll_swap()
 
         nxt = s_b + 1
         if nxt < len(segments) and nxt not in eta_known:

@@ -69,8 +69,7 @@ def stub_agg_program_builder(delay_s=None):
     """A drop-in for protocol/batch._jitted_packed_agg: same output
     contract (verdict_pack outputs + limb-first flags/eta/lv
     handles), crypto stubbed, `_warm_timed`-wrapped so first-execute
-    labels, the compile gate and the warm ladder see the real
-    machinery. `delay_s` (float or callable(lanes)->float) injects a
+    labels and the store see the real machinery. `delay_s` (float or callable(lanes)->float) injects a
     simulated compile wall on the first execute per lane count."""
     import jax
 

@@ -210,19 +210,6 @@ class WindowStaged:
 
 
 @dataclass(frozen=True)
-class LadderEvent:
-    """One warm-while-serving compile-ladder transition
-    (protocol/batch.WarmLadder): the replay engaged a rung, the
-    background production-bucket compile started/landed, or the loop
-    re-tiled windows onto the production executables (`swap`)."""
-
-    kind: str  # "engaged" | "bg-compile-started" | "bg-compile-done"
-    # | "bg-compile-failed" | "swap"
-    rung: int | None  # active rung lane cap (None = production)
-    target: int  # production bucket lane count
-
-
-@dataclass(frozen=True)
 class StallEvent:
     """The live-plane stall watchdog (obs/live.py) tripped: no
     recorder/warmup progress for `age_s` seconds against the

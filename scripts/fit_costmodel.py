@@ -24,13 +24,13 @@ features of the graph structure it was measured against:
      production stages;
   2. the per-stage first-execute walls the warmup recorder banks into
      BENCH round JSONs (`parsed.warmup_report.stages` — via=jit rows
-     carry a feature_hash since PR 8; earlier rounds predate the hash
-     and are reported as unjoinable, not silently dropped).
+     of PRs 8-33 carry a feature_hash and join only while it equals
+     the pin's; a row without one joins the pin its stage label names).
 
 The model extrapolates to the composed monoliths (aggregate_core at
 330k eqns) from the measured small/medium spread — that extrapolation
-is exactly what the bench pre-flight gate needs: a structural estimate
-good to ~2x, not a profiler.
+is what the compile-wall ratchet needs: a structural estimate good to
+~2x, not a profiler.
 """
 
 from __future__ import annotations
@@ -212,9 +212,9 @@ def measure(full: bool = False) -> list[dict]:
 
 
 def bench_rows(pattern: str) -> tuple[list[dict], int]:
-    """Joinable (feature-hash-matched) warmup-report stage walls from
-    banked BENCH round JSONs; second result = rows seen but NOT
-    joinable (no hash, aot via, or hash drifted from the current pin)."""
+    """Joinable warmup-report stage walls from banked BENCH round JSONs;
+    second result = rows seen but NOT joinable (no registered twin, or
+    a carried hash that drifted from the current pin)."""
     rows, unjoined = [], 0
     for path in sorted(glob.glob(pattern)):
         try:
@@ -227,7 +227,10 @@ def bench_rows(pattern: str) -> tuple[list[dict], int]:
         for stage, info in (wr.get("stages") or {}).items():
             if info.get("via") == "aot":
                 continue  # an AOT load, not a compile
-            h = info.get("feature_hash")
+            # rounds banked before PR 34 carry the hash on the note (a
+            # stale one fails to join); later notes join by their label
+            h = (info.get("feature_hash")
+                 or costmodel.stage_feature_hash(stage))
             g = costmodel.stage_graph(stage)
             pin = costmodel.pinned(g) if g else None
             if not h or not pin or pin.get("feature_hash") != h:

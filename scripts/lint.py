@@ -400,17 +400,6 @@ def main(argv: list[str] | None = None) -> int:
             names = _select_graphs(_changed_files())
         todo = names if names is not None else absint.certifiable_graphs()
         budgets = graphs.load_budgets()
-        # warm-ladder rung pins (costmodel.ladder_pins): every rung
-        # program the ladder may compile gets its own cost features,
-        # ratcheted by the SAME compile_wall + pin-freshness passes as
-        # the registry graphs (they carry no device_resources pins —
-        # structurally they are the base graphs at rung lane counts).
-        # --changed selects them through their base graph, so an edit
-        # to the aggregate/msm sources re-fences every rung; the ladder
-        # ORCHESTRATION lives in protocol/batch.py, which already maps
-        # onto packed_unpack/verdict_reduce (cost re-extract) and the
-        # instrumentation-purity differential.
-        ladder_features = []
         for name in todo:
             # one trace per graph serves certification, jaxpr budgets,
             # point-op budgets and compile-cost features (trace_graph
@@ -433,11 +422,6 @@ def main(argv: list[str] | None = None) -> int:
                 budget_violations += graphs.check_point_ops(
                     budgets, names=[name]
                 )
-        for pin_name, base, lanes in costmodel.ladder_pins():
-            if base in todo:
-                ladder_features.append(costmodel.extract_features(
-                    graphs.trace_graph(base, lanes), pin_name
-                ))
         budget_violations += graphs.check_budgets(reports, budgets)
         # instrumentation purity: the registry graphs built from the
         # telemetry-instrumented host modules must gain ZERO equations
@@ -463,14 +447,11 @@ def main(argv: list[str] | None = None) -> int:
                 return 2
             model = (costmodel._cached_cost() or {}).get("model")
             costmodel.write_cost(
-                graphs_section=costmodel.pin_payload(
-                    cost_features + ladder_features, model
-                )
+                graphs_section=costmodel.pin_payload(cost_features, model)
             )
-            _update_compile_wall_budgets(cost_features + ladder_features)
+            _update_compile_wall_budgets(cost_features)
             print(f"costmodel.json pins updated: "
-                  f"{len(cost_features)} graph(s) + "
-                  f"{len(ladder_features)} ladder rung pin(s)")
+                  f"{len(cost_features)} graph(s)")
             return 0
         if args.update_resources:
             if names is not None:
@@ -486,7 +467,7 @@ def main(argv: list[str] | None = None) -> int:
                 print(f"# measuring {f.name}"
                       f"@{lanes if lanes is not None else 'tile'} "
                       "(lower + compile)...", flush=True)
-                measurements[f.name] = obs_res.measure_graph(
+                measurements[f.name] = graphs.measure_graph(
                     f.name, lanes, compile=True
                 )
             path = graphs._BUDGET_PATH
@@ -503,15 +484,10 @@ def main(argv: list[str] | None = None) -> int:
                   f"{len(measurements)} graph(s)")
             return 0
         cert_violations = absint.check_certified(cert_reports)
-        cost_violations = costmodel.check_compile_wall(
-            cost_features + ladder_features, budgets
-        )
-        # pin freshness: stale pins would stamp warmup stage notes with
-        # an old structure's hash and mis-join calibration walls (the
-        # ladder rung pins are held to the same freshness)
-        cost_violations += costmodel.check_pins(
-            cost_features + ladder_features
-        )
+        cost_violations = costmodel.check_compile_wall(cost_features, budgets)
+        # pin freshness: stale pins would join calibration walls to an
+        # old structure's features
+        cost_violations += costmodel.check_pins(cost_features)
         # sixth ratchet: device-resource pins (hash-freshness + ceiling
         # compares only — no lowering, no compiling)
         from ouroboros_consensus_tpu.obs import resources as obs_res

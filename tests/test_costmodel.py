@@ -1,26 +1,15 @@
 """octwall tier-1 gate (Pass 4): compile-cost feature extraction, the
 fitted model + its pinned calibration (the within-2x acceptance), the
 compile_wall ratchet + pathology advisories, the registry drift gate,
-and the bench pre-flight refusal path (stubbed clock + a real
-dispatch_batch window riding the fallback with the refusal recorded in
-the warmup report)."""
+and bench.py's attempt-2 estimate."""
 
-import json
-import os
-import time
-from dataclasses import replace
-from fractions import Fraction
-
-import numpy as np
 import pytest
 
 import jax
 from jax import lax, numpy as jnp
 
 from ouroboros_consensus_tpu.analysis import absint, costmodel, graphs
-from ouroboros_consensus_tpu.obs.warmup import WARMUP, WarmupRecorder
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _sds(*shape):
@@ -47,15 +36,6 @@ def _fenced_chain(depth):
         return lax.fori_loop(0, depth, lambda _, v: v * v + v, x)
 
     return fn
-
-
-@pytest.fixture
-def fresh_warmup():
-    """Snapshot-and-restore the process-wide warmup recorder around a
-    test that records refusals/stages into it."""
-    WARMUP.reset()
-    yield WARMUP
-    WARMUP.reset()
 
 
 # ---------------------------------------------------------------------------
@@ -320,100 +300,6 @@ def test_stage_feature_hash_joins_to_the_pin():
     assert costmodel.stage_feature_hash("no-such-stage") is None
 
 
-def test_warmup_note_carries_hash_and_refusals_flush(tmp_path,
-                                                     monkeypatch):
-    path = str(tmp_path / "wr.json")
-    monkeypatch.setenv("OCT_WARMUP_REPORT", path)
-    w = WarmupRecorder()
-    w.note_stage("ed@b8", 1.5, via="jit", feature_hash="abcd1234")
-    w.note_refusal("agg-packed:304b", 410.0, 90.0,
-                   action="stage-split-fallback", detail="graph=aggregate_core")
-    rep = json.load(open(path))
-    assert rep["stages"]["ed@b8"]["feature_hash"] == "abcd1234"
-    (ref,) = rep["refusals"]
-    assert ref["stage"] == "agg-packed:304b"
-    assert ref["predicted_s"] == 410.0
-    assert ref["remaining_s"] == 90.0
-    assert ref["action"] == "stage-split-fallback"
-    w.reset()
-    assert w.report()["refusals"] == []
-
-
-# ---------------------------------------------------------------------------
-# Pre-flight admission gate (stubbed clock)
-# ---------------------------------------------------------------------------
-
-
-def test_preflight_admits_without_deadline(monkeypatch, fresh_warmup):
-    monkeypatch.delenv("OCT_WALL_DEADLINE", raising=False)
-    assert costmodel.preflight("agg-packed:304b") is True
-    assert fresh_warmup.report()["refusals"] == []
-
-
-def test_preflight_refuses_cold_overbudget_and_records(monkeypatch,
-                                                       fresh_warmup):
-    """The bench attempt gate, stubbed clock: predicted 410 s against
-    90 s of remaining wall -> refused, and the refusal is IN the warmup
-    report (the round JSON banks the decision)."""
-    monkeypatch.setenv("OCT_WALL_DEADLINE", "1090.0")
-    monkeypatch.setattr(costmodel, "predicted_wall", lambda g: 410.0)
-    stage = "agg-packed:304b"
-    assert costmodel.preflight(stage, now=1000.0) is False
-    (ref,) = fresh_warmup.report()["refusals"]
-    assert ref["stage"] == stage
-    assert ref["predicted_s"] == 410.0
-    assert ref["remaining_s"] == 90.0
-    assert "aggregate_core" in ref["detail"]
-    # plenty of remaining wall -> admitted, no second refusal
-    assert costmodel.preflight(stage, now=1090.0 - 500.0) is True
-    assert len(fresh_warmup.report()["refusals"]) == 1
-
-
-def test_preflight_admits_warm_stage_even_overbudget(monkeypatch,
-                                                     fresh_warmup):
-    """A stage that already recorded its first execute owes no compile:
-    the gate must not refuse warm dispatches at the end of the wall."""
-    monkeypatch.setenv("OCT_WALL_DEADLINE", "1010.0")
-    monkeypatch.setattr(costmodel, "predicted_wall", lambda g: 410.0)
-    stage = "agg-packed:304b"
-    fresh_warmup.note_stage(stage, 123.0, via="xla-jit")
-    assert costmodel.preflight(stage, now=1000.0) is True
-    assert fresh_warmup.report()["refusals"] == []
-
-
-def test_preflight_admits_when_fallback_is_no_cheaper(monkeypatch,
-                                                      fresh_warmup):
-    """A monolithic fallback that is predicted no cheaper than the
-    refused program gains nothing: the gate must admit rather than
-    trade one doomed compile for another (the xla-impl shape)."""
-    monkeypatch.setenv("OCT_WALL_DEADLINE", "1090.0")
-    monkeypatch.setattr(costmodel, "predicted_wall", lambda g: 410.0)
-    assert costmodel.preflight(
-        "agg-packed:304b", now=1000.0,
-        fallback_graph="verify_praos_core_bc",
-    ) is True
-    assert fresh_warmup.report()["refusals"] == []
-    # a genuinely cheaper monolithic fallback -> refusal stands
-    monkeypatch.setattr(
-        costmodel, "predicted_wall",
-        lambda g: 410.0 if g == "aggregate_core" else 40.0,
-    )
-    assert costmodel.preflight(
-        "agg-packed:304b", now=1000.0,
-        fallback_graph="verify_praos_core_bc",
-        action="xla-packed-fallback",
-    ) is False
-    assert fresh_warmup.report()["refusals"][0]["action"] == \
-        "xla-packed-fallback"
-
-
-def test_preflight_gate_kill_switch(monkeypatch, fresh_warmup):
-    monkeypatch.setenv("OCT_WALL_DEADLINE", "1001.0")
-    monkeypatch.setenv("OCT_COMPILE_GATE", "0")
-    monkeypatch.setattr(costmodel, "predicted_wall", lambda g: 1e9)
-    assert costmodel.preflight("agg-packed:304b", now=1000.0) is True
-
-
 # ---------------------------------------------------------------------------
 # bench.py consumers
 # ---------------------------------------------------------------------------
@@ -450,96 +336,3 @@ def test_bench_cold_wall_refuses_partial_pins(monkeypatch):
         lambda g: None if g == "aggregate_core" else 2.0,
     )
     assert bench._predicted_cold_wall() is None
-
-
-# ---------------------------------------------------------------------------
-# The dispatch harness: a real window refused onto the fallback path
-# ---------------------------------------------------------------------------
-
-
-def _hash_tail(beta_decl_bt):
-    from ouroboros_consensus_tpu.ops import blake2b
-
-    bd = jnp.asarray(beta_decl_bt).astype(jnp.int32)
-    b = bd.shape[0]
-    tag_l = jnp.broadcast_to(jnp.asarray([ord("L")], jnp.int32), (b, 1))
-    lv = blake2b.blake2b_fixed(
-        jnp.concatenate([tag_l, bd], axis=-1), 65, 32)
-    tag_n = jnp.broadcast_to(jnp.asarray([ord("N")], jnp.int32), (b, 1))
-    eta1 = blake2b.blake2b_fixed(
-        jnp.concatenate([tag_n, bd], axis=-1), 65, 32)
-    eta = blake2b.blake2b_fixed(eta1, 32, 32)
-    return eta, lv
-
-
-def test_dispatch_refusal_rides_the_fallback_path(monkeypatch,
-                                                  fresh_warmup):
-    """End-to-end harness (acceptance): a qualifying packed bc window
-    whose aggregate program is COLD and predicted over the remaining
-    wall budget is refused pre-flight — dispatch_batch rides the
-    per-lane packed path instead, the aggregate jit is NEVER built, and
-    the refusal is recorded in the warmup report."""
-    from ouroboros_consensus_tpu.protocol import batch as pbatch
-    from ouroboros_consensus_tpu.protocol import praos
-    from tests.test_aggregate import _stub_verdicts, make_params, real_chain
-    from ouroboros_consensus_tpu.testing import fixtures
-
-    pools = [fixtures.make_pool(50 + i, kes_depth=3) for i in range(2)]
-    lview = fixtures.make_ledger_view(pools)
-    params = make_params()
-    nonce, hvs = real_chain(params, pools, lview, 8)
-    assert len(hvs[0].vrf_proof) == 128  # batch-compatible window
-
-    monkeypatch.delenv("OCT_VRF_AGG", raising=False)
-    # 40 s of wall left, 500 s predicted for the aggregate, 50 s for
-    # the per-lane xla twin (the fallback this CPU dispatch takes):
-    # must refuse — the fallback is predicted 10x cheaper
-    monkeypatch.setenv("OCT_WALL_DEADLINE", str(time.time() + 40.0))
-    monkeypatch.setattr(
-        costmodel, "predicted_wall",
-        lambda g: 500.0 if g == "aggregate_core" else 50.0,
-    )
-    # the per-lane fallback would compile real crypto: stub the verify
-    # (PR-2 pattern — the dispatch plumbing is what is under test)
-    monkeypatch.setattr(pbatch, "verify_praos_any",
-                        lambda *cols: _stub_verdicts(cols))
-    agg_calls = []
-    monkeypatch.setattr(
-        pbatch, "_jitted_packed_agg",
-        lambda layout, mode="all": agg_calls.append(1)
-        or pytest.fail("refused aggregate program was still dispatched"),
-    )
-    before = set(pbatch._JIT)
-    try:
-        pre, disp, b = pbatch.dispatch_batch(
-            params, lview, nonce, hvs
-        )
-        assert b == len(hvs)
-        assert disp.impl != "agg"
-        assert agg_calls == []
-        refs = fresh_warmup.report()["refusals"]
-        assert len(refs) == 1
-        assert refs[0]["stage"].startswith("agg-packed:")
-        # on the xla impl the recorded action is the per-lane packed
-        # monolith, not the pk stage split
-        assert refs[0]["action"] == "xla-packed-fallback"
-        # and with wall to spare the SAME window takes the agg path
-        monkeypatch.setenv("OCT_WALL_DEADLINE",
-                           str(time.time() + 10_000.0))
-        taken = []
-        monkeypatch.setattr(
-            pbatch, "_jitted_packed_agg",
-            lambda layout, mode="all": lambda *a: taken.append(1) or (
-                (np.zeros((5, 1), np.uint32), np.zeros((8, 32), np.uint8)),
-                np.zeros((5, 8)), np.zeros((32, 8)), np.zeros((32, 8)),
-            ),
-        )
-        pre2, disp2, b2 = pbatch.dispatch_batch(
-            params, lview, nonce, hvs
-        )
-        assert taken == [1]
-        assert disp2.impl == "agg"
-        assert len(fresh_warmup.report()["refusals"]) == 1  # no new one
-    finally:
-        for k in set(pbatch._JIT) - before:
-            del pbatch._JIT[k]

@@ -115,10 +115,6 @@ def test_live_snapshot_warmup_side():
         assert doc["phase"] == "warmup"
         assert "first execute starting" in doc["warmup"]["last_note"]
         assert live.classify(doc) == "compiling"
-        WARMUP.note_ladder("bg-compile-started", rung=1024, target=8192)
-        doc = live.live_snapshot(obs.recorder())
-        assert doc["warmup"]["bg_compile"] == "running"
-        assert doc["warmup"]["ladder"] == "bg-compile-started"
     finally:
         WARMUP.reset()
 
@@ -307,7 +303,7 @@ def test_stall_watchdog_stubbed_clock_names_wedged_dispatch(tmp_path):
     wedged = threading.Event()
     release = threading.Event()
 
-    def dispatch_batch(params, lview, eta0, hvs, ladder=None):
+    def dispatch_batch(params, lview, eta0, hvs):
         wedged.set()
         release.wait(30)
 
@@ -391,7 +387,7 @@ def test_heartbeat_stalled_now_recovers_with_progress(tmp_path):
 
 def test_stall_watchdog_warmup_notes_count_as_progress(tmp_path):
     """A 400 s compile is NOT a stall: warmup notes (first executes,
-    AOT outcomes, ladder events) advance the progress fingerprint."""
+    AOT outcomes) advance the progress fingerprint."""
     from ouroboros_consensus_tpu.obs.warmup import WARMUP
 
     WARMUP.reset()

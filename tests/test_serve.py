@@ -233,11 +233,10 @@ def test_admission_rung_ladder_escalates_one_rung_per_warm_window():
     assert pol.decisions == {"warm": 1, "rung": 2, "host": 0}
 
 
-def test_admission_kill_switch_prices_nothing(monkeypatch):
+def test_admission_kill_switch_caps_nothing(monkeypatch):
     monkeypatch.setenv("OCT_SERVE_DEVICE", "0")
     d = admission.AdmissionPolicy().admit(_shape(), 12)
     assert d.mode == "host" and d.lane_cap == 12
-    assert d.predicted_wall_s is None
 
 
 def test_admission_refuses_malformed_at_the_door(stub_crypto):
