@@ -180,8 +180,9 @@ def _emit(ev) -> None:
 
 
 class ProgressWriter:
-    """Accumulates the global chain position across `validate_chain`
-    invocations (revalidate calls it once per epoch segment) and
+    """Accumulates the global chain position across the replay's
+    retired windows (one `validate_stream` pipeline on the device
+    backend, one `validate_chain` call per epoch segment elsewhere) and
     atomically rewrites the progress record per retired window —
     tmp+rename, the same crash contract as the heartbeat and warmup
     report. One tiny JSON write per window (~hundreds per replay), so

@@ -2746,39 +2746,96 @@ def validate_chain(
     `ledger_view_for_epoch(epoch) -> LedgerView` supplies the forecastable
     per-epoch pool distribution (constant within an epoch).
 
-    Device backend: up to `pipeline_depth` windows of the same epoch are
-    in flight at once — window w+1 is staged (host CBOR→SoA + H2D) while
-    window w executes, because staging depends only on the epoch nonce.
-    The pipeline drains at epoch boundaries (the next epoch's nonce needs
-    the previous epoch's fold) and on the first invalid header (in-flight
-    successors are discarded, exactly like queued blocks after a failed
-    chain selection in the reference's add-block queue).
+    Device backend: the run is the stream of ONE piece through
+    `validate_stream`'s pipeline — the same loop a whole replay's
+    stream of segments goes through (tools/db_analyser).
     """
-    # one worker thread owns the BLOCKING device reads: the main thread
-    # keeps staging/dispatching while the worker waits, so host staging
-    # hides behind device execution even when the backend only makes
-    # progress under a blocking read
+    if backend == "device":
+        return validate_stream(
+            params, ledger_view_for_epoch, state, iter((hvs,)),
+            max_batch, pipeline_depth,
+        )
     with _enclose("validate-chain"):
-        pool = None
-        if backend == "device":
-            from concurrent.futures import ThreadPoolExecutor
+        return _validate_chain_loop(
+            params, ledger_view_for_epoch, state, hvs, max_batch, backend,
+            mesh,
+        )
 
-            pool = ThreadPoolExecutor(
-                max_workers=1, thread_name_prefix="oct-read"
+
+def validate_stream(
+    params: PraosParams,
+    ledger_view_for_epoch,
+    state: PraosState,
+    stream,
+    max_batch: int = 8192,
+    pipeline_depth: int = 3,  # see validate_chain
+) -> BatchResult:
+    """Device backend: ONE window pipeline over a STREAM of chain
+    pieces (an iterator of ViewColumns / header lists in chain order:
+    what `db_analyser._epoch_window_segments` yields, or validate_chain's
+    single run). A piece is cut at epoch boundaries, at `max_batch` and
+    at a proof-format change; windows never span pieces.
+
+    Up to `pipeline_depth` windows are staged (host CBOR→SoA) ahead of
+    the dispatch and up to `pipeline_depth` are in flight on the device,
+    across pieces and across epochs: staging a window needs only its
+    epoch's nonce, which is known before the epoch before it has
+    drained (`_device_loop`). Retire order is dispatch order is chain
+    order. On the first invalid header the result is that header's
+    error and everything staged or in flight behind it, in whatever
+    piece, is discarded (like queued blocks after a failed chain
+    selection in the reference's add-block queue); the stream is closed
+    and both thread pools are shut down.
+
+    The next piece is pulled when the staging side has room and the
+    piece being cut is exhausted. A stream with a `poll()` method (next
+    piece, None while none is ready, StopIteration at the end:
+    `db_analyser._prefetch_iter`) is never waited for while a window is
+    staged or in flight; only an empty pipeline waits, under the span
+    `segment-wait`. Any other iterator is pulled with `next()`, on this
+    thread.
+
+    Memory is bounded by design: at most `pipeline_depth` windows
+    staged and `pipeline_depth` in flight, so the loop holds at most
+    2 x `pipeline_depth` pieces (that many only where every piece is a
+    single window), the one being cut among them; what the stream
+    itself buffers beyond that is the stream's (the prefetch queue: two
+    pieces and the one in the pump's hand).
+    """
+    from concurrent.futures import ThreadPoolExecutor
+
+    with _enclose("validate-chain"):
+        # one worker thread owns the BLOCKING device reads: the main
+        # thread keeps staging/dispatching while the worker waits, so
+        # host staging hides behind device execution even when the
+        # backend only makes progress under a blocking read
+        pool = ThreadPoolExecutor(max_workers=1, thread_name_prefix="oct-read")
+        # producer thread: prechecks + packed staging + padding run
+        # ahead of dispatch (prepare_window is fold-independent),
+        # overlapping the staging wall with device compute and the
+        # retire-side epilogue
+        stage_pool = None
+        if _stage_thread_enabled():
+            stage_pool = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="oct-stage"
             )
         try:
-            return _validate_chain_loop(
-                params, ledger_view_for_epoch, state, hvs, max_batch,
-                backend, pipeline_depth, mesh, pool,
+            return _device_loop(
+                params, ledger_view_for_epoch, state, stream, max_batch,
+                pipeline_depth, pool, stage_pool,
             )
         finally:
-            if pool is not None:
-                # cancel_futures: on an early error return the queued
-                # materialize futures belong to DISCARDED windows —
-                # without it the worker keeps issuing blocking device
-                # reads for results nobody wants and the atexit join
-                # stalls exit
-                pool.shutdown(wait=False, cancel_futures=True)
+            # cancel_futures: on an early error return the queued
+            # materialize / staging futures belong to DISCARDED windows
+            # — without it the reader keeps issuing blocking device
+            # reads for results nobody wants and the atexit join stalls
+            # exit
+            pool.shutdown(wait=False, cancel_futures=True)
+            if stage_pool is not None:
+                stage_pool.shutdown(wait=False, cancel_futures=True)
+            close = getattr(stream, "close", None)
+            if close is not None:
+                close()  # stops the prefetch pump; closes a generator
 
 
 def _epoch_segments_idx(params, hvs) -> list[tuple[int, int, int]]:
@@ -2822,165 +2879,186 @@ def _proof_break(hvs, w: int, j: int) -> int:
 
 
 def _validate_chain_loop(
-    params, ledger_view_for_epoch, state, hvs, max_batch, backend,
-    pipeline_depth, mesh, pool,
+    params, ledger_view_for_epoch, state, hvs, max_batch, backend, mesh,
 ):
+    """The host-side backends (native / sharded / host): one window at
+    a time, no pipeline."""
     from ..obs import recovery as _recovery
     from ..testing import chaos as _chaos
 
     total_valid = 0
-    i = 0
-    n = len(hvs)
     win_idx = 0  # retire-order window index (RecoveryEvent / checkpoints)
-    if backend != "device":
-        for epoch, i, seg_end in _epoch_segments_idx(params, hvs):
-            lview = ledger_view_for_epoch(epoch)
-            while i < seg_end:
-                j = min(i + max_batch, seg_end)
-                ticked = praos.tick(params, lview, _slot_at(hvs, i), state)
-                try:
-                    res = validate_batch(
-                        params, ticked, hvs[i:j], backend=backend, mesh=mesh
-                    )
-                except Exception as e:  # noqa: BLE001 — supervisor gates
-                    # the degradation ladder (obs/recovery.py): re-raises
-                    # unrecoverable classes / OCT_RECOVERY=0 unchanged
-                    res = _recovery.supervisor().recover_window(
-                        params, ticked, hvs[i:j], e, backend=backend,
-                        mesh=mesh, window=win_idx,
-                    )
-                state = res.state
-                total_valid += res.n_valid
-                if res.error is not None:
-                    return BatchResult(state, total_valid, res.error)
-                # crash-consistent progress record per retired window
-                # (one None check when OCT_CHECKPOINT is unset), THEN
-                # the sigkill seam — a chaos kill lands AFTER the
-                # checkpoint, the exactly-once window boundary
-                _recovery.note_window(state, res.n_valid)
-                _chaos.fire("retire")
-                win_idx += 1
-                i = j
-        return BatchResult(state, total_valid, None)
-
-    # Device backend: ONE pipeline across epoch boundaries. Staging a
-    # window needs only (epoch nonce, ledger view); the next epoch's
-    # nonce is tick's rotation combine(candidate, last_epoch_block_nonce)
-    # (Praos.hs:407-432), whose inputs are final well before the current
-    # epoch drains: candidate_nonce freezes at the stability window
-    # (last update from a header with slot < first_slot(e+1) - 3k/f,
-    # Praos.hs:497) and last_epoch_block_nonce was latched at the
-    # PREVIOUS boundary. So once the fold retires past the freeze slot,
-    # the next epoch's first windows dispatch while this epoch's tail is
-    # still on device — no drain bubble per boundary (~one batch wall
-    # each, ~46 boundaries on the 1M bench chain). The retire-time tick
-    # asserts the staged nonce byte-for-byte.
-    from collections import deque
-
-    segments = _epoch_segments_idx(params, hvs)
-
-    lviews: dict[int, object] = {}
-
-    def lview_for(s: int):
-        if s not in lviews:
-            lviews[s] = ledger_view_for_epoch(segments[s][0])
-        return lviews[s]
-
-    eta_known: dict[int, object] = {}
-    if segments:
-        eta_known[0] = praos.tick(
-            params, lview_for(0), _slot_at(hvs, segments[0][1]), state
-        ).state.epoch_nonce
-
-    inflight: deque = deque()  # (seg_idx, window_hvs, window_start, pre, future)
-    # windows staged (possibly on the producer thread) but not yet
-    # dispatched: (seg_idx, window_hvs, window_start, staged-or-future)
-    staged: deque = deque()
-    s_stage = 0  # segment currently being staged
-    w = segments[0][1] if segments else 0
-    retired = 0  # index of the next header to retire
-    # producer thread: prechecks + packed staging + padding run ahead
-    # of dispatch (prepare_window is fold-independent), overlapping the
-    # staging wall with device compute and the retire-side epilogue.
-    # Backpressure at pipeline_depth on EACH side of the double buffer:
-    # up to pipeline_depth windows staged-but-undispatched AND up to
-    # pipeline_depth dispatched-but-unretired (without the thread the
-    # staged deque never exceeds one window, so the memory bound is the
-    # round-9 one; with it, at most 2 x pipeline_depth windows are
-    # alive — ~8 MB packed each at 8192 lanes, still far under HBM).
-    stage_pool = None
-    if _stage_thread_enabled():
-        from concurrent.futures import ThreadPoolExecutor
-
-        stage_pool = ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="oct-stage"
-        )
-    try:
-        return _device_loop(
-            params, hvs, max_batch, pipeline_depth, pool, stage_pool,
-            segments, lview_for, eta_known, inflight, staged, s_stage, w,
-            retired, state, total_valid, n,
-        )
-    finally:
-        if stage_pool is not None:
-            # discarded staging futures belong to windows nobody will
-            # dispatch (early error return) — never block exit on them
-            stage_pool.shutdown(wait=False, cancel_futures=True)
+    for epoch, i, seg_end in _epoch_segments_idx(params, hvs):
+        lview = ledger_view_for_epoch(epoch)
+        while i < seg_end:
+            j = min(i + max_batch, seg_end)
+            ticked = praos.tick(params, lview, _slot_at(hvs, i), state)
+            try:
+                res = validate_batch(
+                    params, ticked, hvs[i:j], backend=backend, mesh=mesh
+                )
+            except Exception as e:  # noqa: BLE001 — supervisor gates
+                # the degradation ladder (obs/recovery.py): re-raises
+                # unrecoverable classes / OCT_RECOVERY=0 unchanged
+                res = _recovery.supervisor().recover_window(
+                    params, ticked, hvs[i:j], e, backend=backend,
+                    mesh=mesh, window=win_idx,
+                )
+            state = res.state
+            total_valid += res.n_valid
+            if res.error is not None:
+                return BatchResult(state, total_valid, res.error)
+            # crash-consistent progress record per retired window
+            # (one None check when OCT_CHECKPOINT is unset), THEN
+            # the sigkill seam — a chaos kill lands AFTER the
+            # checkpoint, the exactly-once window boundary
+            _recovery.note_window(state, res.n_valid)
+            _chaos.fire("retire")
+            win_idx += 1
+            i = j
+    return BatchResult(state, total_valid, None)
 
 
 def _device_loop(
-    params, hvs, max_batch, pipeline_depth, pool, stage_pool,
-    segments, lview_for, eta_known, inflight, staged, s_stage, w,
-    retired, state, total_valid, n,
+    params, ledger_view_for_epoch, state, stream, max_batch,
+    pipeline_depth, pool, stage_pool,
 ):
+    """ONE pipeline across pieces and epoch boundaries. Staging a
+    window needs only (epoch nonce, ledger view). A row-width step
+    inside an epoch keeps the nonce; the next epoch's is tick's rotation
+    combine(candidate, last_epoch_block_nonce) (Praos.hs:407-432), whose
+    inputs are final well before the current epoch drains:
+    candidate_nonce freezes at the stability window (last update from a
+    header with slot < first_slot(e+1) - 3k/f, Praos.hs:497) and
+    last_epoch_block_nonce was latched at the PREVIOUS boundary. So
+    once the fold retires past the freeze slot, the next epoch's first
+    windows dispatch while this epoch's tail is still on device — no
+    drain bubble per boundary. The retire-time tick asserts every
+    window's staged nonce byte-for-byte."""
+    from collections import deque
+
+    from ..obs import recovery as _recovery
+    from ..testing import chaos as _chaos
+
     # one lane shape per replay on the chip (window_lanes). Resolved
     # HERE, on the dispatching thread: the staging thread does not see
     # this thread's recovery overrides
     lanes = window_lanes(max_batch)
 
+    # Backpressure at pipeline_depth on EACH side of the double buffer:
+    # up to pipeline_depth windows staged-but-undispatched AND up to
+    # pipeline_depth dispatched-but-unretired (without the staging
+    # thread the staged deque never exceeds one window; with it, at
+    # most 2 x pipeline_depth windows are alive — ~8 MB packed each at
+    # 8192 lanes, still far under HBM).
+    staged: deque = deque()  # (epoch, eta, window_hvs, staged-or-future, id)
+    inflight: deque = deque()  # (epoch, eta, window_hvs, pre, meta, future)
+    total_valid = 0
+
+    stream_done = False
+    piece = None  # the piece being cut (None: exhausted, pull the next)
+    cuts: list = []  # its [(epoch, start, end)], and where the cut stands
+    k = w = 0
+    cut_epoch = cut_eta = None  # of the segment cut last
+    # (epoch, nonce of the epoch after it), published by the retire
+    # path once `epoch`'s candidate nonce is frozen: the lookahead
+    rotation = None
+    unknown = object()  # no nonce yet (None is one: the neutral nonce)
+    progress = 0  # pieces pulled + windows cut (the fixpoint's witness)
+    lviews: dict[int, object] = {}
+
+    def lview_for(epoch: int):
+        if epoch not in lviews:
+            live = {epoch, *(x[0] for x in staged), *(x[0] for x in inflight)}
+            for e in [e for e in lviews if e not in live]:
+                del lviews[e]
+            lviews[epoch] = ledger_view_for_epoch(epoch)
+        return lviews[epoch]
+
+    def segment_eta(epoch: int, slot: int):
+        """The epoch nonce the windows of a new segment stage with;
+        `unknown` while it is not derivable (its predecessors must
+        retire further: past the freeze slot, or all of them)."""
+        if not staged and not inflight:
+            # everything cut so far has retired: the fold's own rotation
+            return praos.tick(
+                params, lview_for(epoch), slot, state
+            ).state.epoch_nonce
+        if epoch == cut_epoch:
+            return cut_eta  # a row-width step: no rotation inside an epoch
+        if rotation is not None and rotation[0] == cut_epoch < epoch:
+            return rotation[1]
+        return unknown
+
     def enqueue_staging():
-        nonlocal s_stage, w
+        nonlocal stream_done, piece, cuts, k, w, cut_epoch, cut_eta, progress
         while (
-            s_stage < len(segments)
-            and (
-                # producer thread: stage ahead up to pipeline_depth
-                # regardless of the in-flight side (double buffer)
-                len(staged) < pipeline_depth
-                if stage_pool is not None
-                # inline (OCT_STAGE_THREAD=0): stage only what can
-                # dispatch immediately — the round-9 loop exactly
-                else not staged and len(inflight) < pipeline_depth
-            )
-            and s_stage in eta_known
+            # producer thread: stage ahead up to pipeline_depth
+            # regardless of the in-flight side (double buffer)
+            len(staged) < pipeline_depth
+            if stage_pool is not None
+            # inline (OCT_STAGE_THREAD=0): stage only what can
+            # dispatch immediately — the round-9 loop exactly
+            else not staged and len(inflight) < pipeline_depth
         ):
-            _, _, seg_end = segments[s_stage]
+            if piece is None:
+                if stream_done:
+                    return
+                # never wait for the stream behind a busy pipeline: a
+                # piece that is not there yet is asked for again after
+                # the next retire
+                poll = (
+                    getattr(stream, "poll", None)
+                    if staged or inflight else None
+                )
+                try:
+                    if poll is not None:
+                        nxt = poll()
+                        if nxt is None:
+                            return
+                    else:
+                        with _enclose("segment-wait"):
+                            nxt = next(stream)
+                except StopIteration:
+                    stream_done = True
+                    return
+                progress += 1
+                cuts = _epoch_segments_idx(params, nxt)
+                if not cuts:
+                    continue
+                piece, k, w = nxt, 0, cuts[0][1]
+            epoch, start, seg_end = cuts[k]
+            if w == start:
+                eta = segment_eta(epoch, _slot_at(piece, start))
+                if eta is unknown:
+                    return
+                cut_epoch, cut_eta = epoch, eta
             # a window must stage a uniform proof column: break at the
             # first 80/128-byte format change (the reference fold
             # length-dispatches per header, so mixed chains stay valid;
             # segmentation never changes verdicts or the first error)
-            j = _proof_break(hvs, w, min(w + max_batch, seg_end))
-            whvs = hvs[w:j]
+            j = _proof_break(piece, w, min(w + max_batch, seg_end))
+            whvs = piece[w:j]
             # the window's id: staging order is dispatch order
             win = next_window_id()
             if stage_pool is not None:
                 item = stage_pool.submit(
-                    prepare_window, params, lview_for(s_stage),
-                    eta_known[s_stage], whvs, lanes, win,
+                    prepare_window, params, lview_for(epoch), cut_eta,
+                    whvs, lanes, win,
                 )
             else:
                 item = prepare_window(
-                    params, lview_for(s_stage), eta_known[s_stage], whvs,
-                    lanes, win,
+                    params, lview_for(epoch), cut_eta, whvs, lanes, win,
                 )
-            staged.append((s_stage, whvs, w, item, win))
+            staged.append((epoch, cut_eta, whvs, item, win))
+            progress += 1
             w = j
             if w >= seg_end:
-                s_stage += 1
-                if s_stage < len(segments):
-                    w = segments[s_stage][1]
-
-    from ..obs import recovery as _recovery
-    from ..testing import chaos as _chaos
+                k += 1
+                if k < len(cuts):
+                    w = cuts[k][1]
+                else:
+                    piece = None
 
     def _queue_failure(exc: BaseException) -> bool:
         """True when the supervisor may absorb `exc`: the window rides
@@ -2990,83 +3068,82 @@ def _device_loop(
         return _recovery.enabled() and _recovery.recoverable(exc)
 
     def drain_dispatch():
-        # dispatch staged windows IN ORDER (retire order is dispatch
-        # order) while the in-flight side of the double buffer has
-        # room: drain every ready one; when nothing is in flight, block
-        # on the staging head — otherwise let a materialize retire
-        # while the producer keeps staging
-        while staged and len(inflight) < pipeline_depth:
-            s_w, whvs_w, w_start_w, item, win = staged[0]
-            stage_wait_s = 0.0
-            if stage_pool is not None and hasattr(item, "result"):
-                late = not item.done()
-                if late and inflight:
-                    break
-                try:
-                    if late:
-                        # staging-thread lateness: nothing is in flight
-                        # and the head window is not staged yet
-                        t_w0 = time.monotonic()
-                        with _enclose("stage-wait", win):
-                            item = item.result()
-                        stage_wait_s = time.monotonic() - t_w0
-                    else:
-                        item = item.result()
-                except Exception as e:  # noqa: BLE001 — gated below
-                    # the staging producer died mid-prepare: the window
-                    # recovers at its retire slot (full re-validation)
-                    staged.popleft()
-                    if not _queue_failure(e):
-                        raise
-                    inflight.append(
-                        (s_w, whvs_w, w_start_w, None, None,
-                         _FailedDispatch(e))
-                    )
-                    continue
-            staged.popleft()
+        # dispatch the HEAD staged window (retire order is dispatch
+        # order) if the in-flight side of the double buffer has room.
+        # ONE a call: the caller refills the staging side between two
+        # dispatches (7 ms of this thread each), or the producer idles
+        # through a run of them. When nothing is in flight, block on
+        # the staging head — otherwise let a materialize retire while
+        # the producer keeps staging
+        if not staged or len(inflight) >= pipeline_depth:
+            return
+        epoch_w, eta_w, whvs_w, item, win = staged[0]
+        stage_wait_s = 0.0
+        if stage_pool is not None and hasattr(item, "result"):
+            late = not item.done()
+            if late and inflight:
+                return
             try:
-                pre, out, b = dispatch_prepared(item)
+                if late:
+                    # staging-thread lateness: nothing is in flight
+                    # and the head window is not staged yet
+                    t_w0 = time.monotonic()
+                    with _enclose("stage-wait", win):
+                        item = item.result()
+                    stage_wait_s = time.monotonic() - t_w0
+                else:
+                    item = item.result()
             except Exception as e:  # noqa: BLE001 — gated below
+                # the staging producer died mid-prepare: the window
+                # recovers at its retire slot (full re-validation)
+                staged.popleft()
                 if not _queue_failure(e):
                     raise
                 inflight.append(
-                    (s_w, whvs_w, w_start_w, None, None, _FailedDispatch(e))
+                    (epoch_w, eta_w, whvs_w, None, None, _FailedDispatch(e))
                 )
-                continue
-            meta = out.meta
-            if meta is not None and stage_wait_s:
-                meta = meta._replace(stage_wait_s=stage_wait_s)
+                return
+        staged.popleft()
+        try:
+            pre, out, b = dispatch_prepared(item)
+        except Exception as e:  # noqa: BLE001 — gated below
+            if not _queue_failure(e):
+                raise
             inflight.append(
-                (s_w, whvs_w, w_start_w, pre, meta,
-                 pool.submit(materialize_verdicts, out, b))
+                (epoch_w, eta_w, whvs_w, None, None, _FailedDispatch(e))
             )
+            return
+        meta = out.meta
+        if meta is not None and stage_wait_s:
+            meta = meta._replace(stage_wait_s=stage_wait_s)
+        inflight.append(
+            (epoch_w, eta_w, whvs_w, pre, meta,
+             pool.submit(materialize_verdicts, out, b))
+        )
 
     win_retired = 0  # retire-order window index (recovery/checkpoints)
-    while retired < n or inflight or staged:
-        # alternate stage/dispatch to a FIXPOINT: the inline
-        # (OCT_STAGE_THREAD=0) mode stages one window at a time and
+    while True:
+        # alternate stage/dispatch to a FIXPOINT, a window at a time:
+        # every ready staged window is dispatched while the in-flight
+        # side has room, and the staging side is refilled before each
+        # (the inline, OCT_STAGE_THREAD=0, mode stages one window and
         # dispatches it immediately, so the in-flight side still fills
-        # to pipeline_depth exactly as the round-9 loop did (staging a
-        # single window per outer iteration would cap the pipeline at
-        # ONE window in flight); the threaded mode reaches the same
-        # fixpoint in one or two rounds
+        # to pipeline_depth exactly as the round-9 loop did)
         while True:
-            before = (len(staged), len(inflight), w, s_stage)
+            before = (len(staged), len(inflight), progress)
             enqueue_staging()
             drain_dispatch()
-            if (len(staged), len(inflight), w, s_stage) == before:
+            if (len(staged), len(inflight), progress) == before:
                 break
 
         if not inflight:
-            # eta for s_stage not derivable before its predecessor fully
-            # retires (no header past the freeze slot) — the retire path
-            # below will publish it; nothing staged or in flight means we
-            # can compute it right now from the fully-folded state
-            eta_known[s_stage] = praos.tick(
-                params, lview_for(s_stage),
-                _slot_at(hvs, segments[s_stage][1]), state,
-            ).state.epoch_nonce
-            continue
+            # an empty pipeline waits for the stream and ticks a new
+            # segment's nonce from the fully-folded state
+            # (enqueue_staging), and a staged head is dispatched
+            # whenever nothing is in flight (drain_dispatch): only the
+            # end of the stream leaves nothing in flight here
+            assert stream_done and piece is None and not staged
+            return BatchResult(state, total_valid, None)
 
         # refill the staging side BEFORE blocking on the retire below:
         # dispatching just freed buffer room, and the producer must be
@@ -3074,7 +3151,7 @@ def _device_loop(
         # thread idled during every retire block (the whole overlap)
         enqueue_staging()
 
-        s_b, whvs, w_start, pre, meta, fut = inflight.popleft()
+        epoch, eta, whvs, pre, meta, fut = inflight.popleft()
         win = meta.index if meta is not None else None
         # the pipeline's fill as this window's retire wait begins
         inflight_behind, staged_ahead = len(inflight), len(staged)
@@ -3091,14 +3168,13 @@ def _device_loop(
         t_m1 = time.monotonic()
         with _enclose("tick", win):
             ticked = praos.tick(
-                params, lview_for(s_b), _slot_at(whvs, 0), state
+                params, lview_for(epoch), _slot_at(whvs, 0), state
             )
-        if w_start == segments[s_b][1]:
-            # first batch of a segment staged with a LOOKAHEAD nonce:
-            # the real rotation must agree (internal invariant)
-            assert ticked.state.epoch_nonce == eta_known[s_b], (
-                "lookahead epoch nonce mismatch"
-            )
+        # the window staged with a nonce carried over or looked AHEAD:
+        # the real rotation must agree (internal invariant)
+        assert ticked.state.epoch_nonce == eta, (
+            "lookahead epoch nonce mismatch"
+        )
         t_e0 = time.monotonic()
         _COUNTERS_S[0] = 0.0  # a window off the fast epilogue reads 0
         if fail is None:
@@ -3127,7 +3203,6 @@ def _device_loop(
         )
         if res.error is not None:
             return BatchResult(state, total_valid, res.error)
-        retired += len(whvs)
         # progress record BEFORE the sigkill seam: a chaos (or real)
         # kill after this point loses nothing — the resume re-seeds
         # from exactly this retired window (obs/recovery.py)
@@ -3135,24 +3210,13 @@ def _device_loop(
         _chaos.fire("retire")
         win_retired += 1
 
-        nxt = s_b + 1
-        if nxt < len(segments) and nxt not in eta_known:
-            epoch, _, seg_end = segments[s_b]
-            if retired >= seg_end:
-                eta_known[nxt] = praos.tick(
-                    params, lview_for(nxt), _slot_at(hvs, segments[nxt][1]),
-                    state,
-                ).state.epoch_nonce
-            else:
-                freeze = (
-                    params.first_slot_of(epoch + 1)
-                    - params.stability_window
-                )
-                if _slot_at(hvs, retired) >= freeze:
-                    # candidate is frozen and the LAB component was
-                    # latched a boundary ago: the rotation is decided
-                    eta_known[nxt] = nonces.combine(
-                        state.candidate_nonce,
-                        state.last_epoch_block_nonce,
-                    )
-    return BatchResult(state, total_valid, None)
+        if (rotation is None or rotation[0] != epoch) and (
+            state.last_slot + 1
+            >= params.first_slot_of(epoch + 1) - params.stability_window
+        ):
+            # every later header of the epoch has a larger slot, so the
+            # candidate is frozen, and the LAB component was latched a
+            # boundary ago: the next epoch's rotation is decided
+            rotation = (epoch, nonces.combine(
+                state.candidate_nonce, state.last_epoch_block_nonce
+            ))

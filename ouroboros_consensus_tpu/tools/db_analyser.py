@@ -23,6 +23,8 @@ a db-synthesizer chain.
 from __future__ import annotations
 
 import os
+import queue
+import threading
 import time
 from dataclasses import dataclass, field
 
@@ -536,17 +538,58 @@ def _epoch_window_segments(params: PraosParams, wins):
         yield from flush(acc)
 
 
-def _prefetch_iter(gen, depth: int = 2):
+class _Prefetched:
+    """The consumer's end of `_prefetch_iter`'s bounded queue: an
+    iterator (`next()` waits for the pump) that can also be POLLED —
+    `poll()` hands the next item if the pump has one ready and None
+    otherwise — so a consumer with work of its own never waits for the
+    stream (protocol/batch.validate_stream). `close()` stops the pump."""
+
+    def __init__(self, q, stop, end, thread):
+        self._q, self._stop, self._end = q, stop, end
+        self.thread = thread  # the pump, for whoever must see it end
+
+    def __iter__(self):
+        return self
+
+    def _take(self, block: bool):
+        if self._stop.is_set():
+            raise StopIteration
+        try:
+            item = self._q.get(block)
+        except queue.Empty:
+            return None
+        if item is self._end:
+            self._stop.set()
+            raise StopIteration
+        if isinstance(item, BaseException):
+            self._stop.set()
+            raise item
+        return item
+
+    def __next__(self):
+        return self._take(True)
+
+    def poll(self):
+        return self._take(False)
+
+    def close(self) -> None:
+        self._stop.set()
+
+
+def _prefetch_iter(gen, depth: int = 2) -> _Prefetched:
     """Pull a generator on a background thread through a bounded queue:
     the view-stream (disk read + integrity walk + native column
-    extraction) of segment k+1 runs while segment k validates on
-    device — part of the round-10 threaded staging pipeline
-    (OCT_STAGE_THREAD=0 restores the inline pull). Exceptions from the
-    stream are forwarded to the consumer; an early consumer exit
-    (first-failure truncation) stops the pump without blocking."""
-    import queue
-    import threading
-
+    extraction) of the segments ahead runs while the device pipeline
+    stages, dispatches and retires the ones before them — part of the
+    round-10 threaded staging pipeline (OCT_STAGE_THREAD=0 restores the
+    inline pull). The consumer is `validate_stream`'s loop, which polls
+    while it has windows staged or in flight and waits only with an
+    empty pipeline. Exceptions from the stream are forwarded to the
+    consumer; an early consumer exit (first-failure truncation) closes
+    the iterator, which stops the pump without blocking and closes the
+    generator on the pump's thread. At most `depth` items wait in the
+    queue and one more in the pump's hand."""
     q: queue.Queue = queue.Queue(maxsize=max(1, depth))
     stop = threading.Event()
     end = object()
@@ -568,19 +611,14 @@ def _prefetch_iter(gen, depth: int = 2):
             _put(end)
         except BaseException as e:  # noqa: BLE001 — forwarded, re-raised
             _put(e)
+        finally:
+            close = getattr(gen, "close", None)
+            if close is not None:
+                close()
 
     t = threading.Thread(target=pump, daemon=True, name="oct-prefetch")
     t.start()
-    try:
-        while True:
-            item = q.get()
-            if item is end:
-                return
-            if isinstance(item, BaseException):
-                raise item
-            yield item
-    finally:
-        stop.set()
+    return _Prefetched(q, stop, end, t)
 
 
 def revalidate(
@@ -796,10 +834,14 @@ def _revalidate_body(
     """The revalidate body (wrapped by `revalidate` for attribution and
     by `_revalidate_impl` for the store crash protocol).
 
-    backend="device": epoch-segmented batches through the fused kernel
-    (further split at max_batch to bound device memory; the jit caches
-    per padded shape).
-    backend="native": same segmentation through the C++ verifier
+    backend="device": the stream of epoch segments through ONE window
+    pipeline a replay (`protocol/batch.validate_stream`: windows split
+    at max_batch to bound device memory; the jit caches per padded
+    shape). Memory stays bounded: the pipeline holds the segments of at
+    most 2 x pipeline_depth windows, the prefetch queue two more and
+    its pump one.
+    backend="native": same segmentation, one `validate_chain` call a
+    segment (one segment buffered at a time), through the C++ verifier
     (native/hostcrypto.cpp) — the measured single-core CPU baseline.
     backend="sharded": multi-chip SPMD — the batch axis sharded over a
     jax.sharding.Mesh of ALL visible devices with psum/pmin verdict
@@ -901,44 +943,55 @@ def _revalidate_body(
                 res.n_valid = int(rec_doc["headers"])
                 res.resumed_headers = int(rec_doc["headers"])
                 _recovery.note_resume(rec_doc)
-            # one epoch segment buffered at a time (bounded memory on
-            # real chains); validate_chain pipelines staging against
-            # device execution within each segment. Segments flow
-            # COLUMNAR (ViewColumns) end-to-end from the native chunk
-            # scan; HeaderView lists appear only without the native
-            # library / OCT_COLUMNAR=0
+            # Segments flow COLUMNAR (ViewColumns) end-to-end from the
+            # native chunk scan; HeaderView lists appear only without
+            # the native library / OCT_COLUMNAR=0
             wins = _stream_windows(imm, res)
             if max_headers is not None:
                 wins = _cap_windows(wins, max_headers)
             if res.resumed_headers:
                 wins = _skip_headers(wins, res.resumed_headers)
             segs = _epoch_window_segments(params, wins)
-            if backend == "device" and pbatch._stage_thread_enabled():
-                # prefetch the NEXT epoch segment's disk/parse/column
-                # work while this one validates — the device loop's
-                # staging thread then overlaps prechecks+staging within
-                # the segment
-                segs = _prefetch_iter(segs, depth=2)
-            segs, end = iter(segs), object()
-            while True:
-                # the main thread's wait for the stream (the prefetch
-                # thread's, or the stream itself when inline)
-                with pbatch._enclose("segment-wait"):
-                    seg = next(segs, end)
-                if seg is end:
-                    break
+            if backend == "device":
+                # ONE window pipeline a replay (validate_stream): the
+                # next segment's first windows are staged and
+                # dispatched while this one's last are on the device
+                if pbatch._stage_thread_enabled():
+                    # disk/parse/column work of the segments ahead, on
+                    # its own thread; the loop polls it and waits
+                    # (span `segment-wait`) only with an empty pipeline
+                    segs = _prefetch_iter(segs, depth=2)
                 ts = time.monotonic()
-                result = pbatch.validate_chain(
-                    params, lambda _e: lview, st, seg,
-                    max_batch=max_batch, backend=backend,
+                result = pbatch.validate_stream(
+                    params, lambda _e: lview, st, segs, max_batch=max_batch,
                 )
                 res.device_s += time.monotonic() - ts
                 st = result.state
                 res.n_valid += result.n_valid
-                if result.error is not None:
-                    res.error = result.error
-                    break
-                trace(f"validated {res.n_valid} headers")
+                res.error = result.error
+                if res.error is None:
+                    trace(f"validated {res.n_valid} headers")
+            else:
+                # native / sharded: one epoch segment buffered at a time
+                segs, end = iter(segs), object()
+                while True:
+                    # the main thread's wait for the stream
+                    with pbatch._enclose("segment-wait"):
+                        seg = next(segs, end)
+                    if seg is end:
+                        break
+                    ts = time.monotonic()
+                    result = pbatch.validate_chain(
+                        params, lambda _e: lview, st, seg,
+                        max_batch=max_batch, backend=backend,
+                    )
+                    res.device_s += time.monotonic() - ts
+                    st = result.state
+                    res.n_valid += result.n_valid
+                    if result.error is not None:
+                        res.error = result.error
+                        break
+                    trace(f"validated {res.n_valid} headers")
             w = _recovery._WRITER
             if w is not None:
                 # mark the record COMPLETE (cleanly or at a validation

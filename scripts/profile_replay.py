@@ -379,13 +379,15 @@ def overlap_ab():
     # (measured there: thread-on is CPU-bound at ~1.2x). On a
     # multi-core host / real device run with OCT_AB_DEPTH=3.
     depth = int(os.environ.get("OCT_AB_DEPTH", "1"))
-    orig_vc = pbatch.validate_chain
+    # the entry the replay calls (db_analyser hands it the whole stream
+    # of segments; validate_chain goes through it too)
+    orig_vs = pbatch.validate_stream
 
-    def vc_depth(*a, **k):
+    def vs_depth(*a, **k):
         k.setdefault("pipeline_depth", depth)
-        return orig_vc(*a, **k)
+        return orig_vs(*a, **k)
 
-    pbatch.validate_chain = vc_depth
+    pbatch.validate_stream = vs_depth
     print(f"overlap A/B: stubbed crypto, twin device latency "
           f"{twin_ms:.0f} ms/window, max_batch={max_batch}, "
           f"pipeline_depth={depth}", flush=True)

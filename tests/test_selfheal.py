@@ -301,7 +301,10 @@ def test_checkpoint_resume_differential(synth_db, stubbed, monkeypatch,
     base = _revalidate(synth_db)
     assert base.error is None and base.n_valid == 80
 
-    for fault_at, resume_batch in ((1, 8), (5, 16)):
+    # the pipeline fills to its depth (3 windows in flight, across
+    # segments) before the first window retires: a fault at one of the
+    # first three dispatches leaves no record to resume from
+    for fault_at, resume_batch in ((3, 8), (5, 16)):
         ck = str(tmp_path / f"ckpt_{fault_at}.json")
         monkeypatch.setenv("OCT_CHECKPOINT", ck)
         monkeypatch.setenv("OCT_RECOVERY", "0")  # die, don't degrade

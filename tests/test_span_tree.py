@@ -138,11 +138,13 @@ def test_spans_name_the_thread_that_did_the_work(traced):
             assert e.thread == "oct-prefetch", e
         else:
             assert e.thread == "MainThread", e
-    for label in ("stage-wait", "dispatch", "materialize", "tick",
-                  "epilogue"):
+    # ONE pipeline a replay: the wait for the stream is the pipeline's
+    # own (an empty one's), beside its windows' spans
+    for label in ("segment-wait", "stage-wait", "dispatch", "materialize",
+                  "tick", "epilogue"):
         assert {e.parent for e in _ends(events, label=label)} == \
             {"validate-chain"}
-    for label in ("open", "segment-wait", "validate-chain", "stream"):
+    for label in ("open", "validate-chain", "stream"):
         assert {e.parent for e in _ends(events, label=label)} == {"replay"}
     # the host's nonce fold: inside the window's `epilogue`, every window
     for label in ("epilogue.fold", "epilogue.counters"):
@@ -174,6 +176,9 @@ def test_each_replay_has_one_id_and_one_root(traced):
     assert all(r.parent is None and r.window is None for r in roots)
     ids = {e.replay for e in _ends(events)}
     assert ids == {r.replay for r in roots}
+    # and ONE `validate-chain` span, whatever the stream's segments
+    pipelines = _ends(events, label="validate-chain")
+    assert [p.replay for p in pipelines] == [r.replay for r in roots]
     assert pbatch._REPLAY is None  # no replay in progress any more
 
 
@@ -276,7 +281,7 @@ def test_window_span_stamps_are_ordered_and_sums_fit_the_wall(traced):
         wall = s.t_done - (s.t_dispatch_start - s.stage_wait_s)
         assert (s.stage_wait_s + s.dispatch_s + s.materialize_s + s.tick_s
                 + s.epilogue_s) <= wall + 1e-3
-    # a segment's last window drains the pipeline: nothing behind it
+    # a replay's last window drains the pipeline: nothing behind it
     assert any(s.inflight_behind == 0 for s in spans)
     assert any(s.stage_wait_s > 0 for s in spans)
 
