@@ -195,7 +195,10 @@ int ocx_extract_headers(
     int64_t* ocert_kes_period, int64_t* ocert_sigma_off,
     int64_t* ocert_sigma_len, int64_t* pv_major, int64_t* pv_minor,
     int64_t* kes_sig_off, int64_t* kes_sig_len,
-    int64_t* signed_off, int64_t* signed_len) {
+    int64_t* signed_off, int64_t* signed_len,
+    uint8_t* vrf_two /* n: 1 = a TPraos body, two VRF certificates */,
+    uint8_t* vrf_leader_output /* n*64 */,
+    uint8_t* vrf_leader_proof /* n*80 */) {
     for (int i = 0; i < n; i++) {
         Cursor c{buf, len, (size_t)offsets[i], true};
         uint64_t na;
@@ -204,8 +207,12 @@ int ocx_extract_headers(
         // header = [body, kes_sig]
         if (!expect_array(c, &na) || na != 2) return i + 1;
         size_t body_start = c.off;
-        // body = [...10 fields...]
-        if (!expect_array(c, &na) || na != 10) return i + 1;
+        // body = [...10 fields...]; a TPraos (Shelley-era) body has 11:
+        // the nonce certificate where Praos has its one, then the
+        // leader certificate (both 64-byte output + 80-byte proof)
+        if (!expect_array(c, &na) || (na != 10 && na != 11)) return i + 1;
+        bool two = na == 11;
+        vrf_two[i] = two ? 1 : 0;
         if (!read_uint(c, &block_no[i])) return i + 1;
         if (!read_uint(c, &slot[i])) return i + 1;
         if (!read_bytes_fixed(c, prev_hash + 32 * i, 32, &has_prev[i])) return i + 1;
@@ -215,6 +222,14 @@ int ocx_extract_headers(
         if (!read_bytes_fixed(c, vrf_output + 64 * i, 64, nullptr)) return i + 1;
         if (!read_bytes_either(c, vrf_proof + 128 * i, 80, 128, 128,
                                &vrf_proof_len[i])) return i + 1;
+        if (two) {
+            if (vrf_proof_len[i] != 80) return i + 1;
+            if (!expect_array(c, &na) || na != 2) return i + 1;
+            if (!read_bytes_fixed(c, vrf_leader_output + 64 * i, 64, nullptr))
+                return i + 1;
+            if (!read_bytes_fixed(c, vrf_leader_proof + 80 * i, 80, nullptr))
+                return i + 1;
+        }
         if (!read_uint(c, &body_size[i])) return i + 1;
         if (!read_bytes_fixed(c, body_hash + 32 * i, 32, nullptr)) return i + 1;
         if (!expect_array(c, &na) || na != 4) return i + 1;

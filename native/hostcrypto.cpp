@@ -1191,6 +1191,52 @@ extern "C" long oc_validate_praos2(
     return -1;
 }
 
+// TPraos (Shelley..Alonzo): two 80-byte draft-03 proofs a header under
+// the one VRF key, each with its own input and declared output; the nonce
+// contribution is blake2b-256(beta_eta) and the leader value the raw
+// beta_L, which the caller already holds (it is compared here). fail_kind
+// in {1:ocert, 2:kes, 3:nonce proof, 4:leader proof}.
+extern "C" long oc_validate_tpraos(
+    long n, const u8* cold_vk, const u8* ocert_sig, const u8* ocert_msg,
+    const u8* kes_vk, const long* kes_t, const u8* kes_sig, long kes_depth,
+    const u8* body, const long* body_off, const u8* vrf_vk,
+    const u8* eta_proof, const u8* eta_alpha, const u8* eta_output,
+    const u8* l_proof, const u8* l_alpha, const u8* l_output,
+    u8* eta_out,              // out: n*32 blake2b-256(beta_eta), or NULL
+    long* fail_kind) {
+    size_t kes_siglen = 96 + 32 * (size_t)kes_depth;
+    if (fail_kind) *fail_kind = 0;
+    for (long i = 0; i < n; i++) {
+        if (!oc_ed25519_verify(cold_vk + 32 * i, ocert_sig + 64 * i,
+                               ocert_msg + 48 * i, 48)) {
+            if (fail_kind) *fail_kind = 1;
+            return i;
+        }
+        const u8* b = body + body_off[i];
+        size_t blen = (size_t)(body_off[i + 1] - body_off[i]);
+        if (!oc_kes_verify(kes_vk + 32 * i, (int)kes_depth, (u64)kes_t[i], b,
+                           blen, kes_sig + kes_siglen * i, kes_siglen)) {
+            if (fail_kind) *fail_kind = 2;
+            return i;
+        }
+        u8 beta[64];
+        if (!oc_ecvrf_verify(vrf_vk + 32 * i, eta_proof + 80 * i,
+                             eta_alpha + 32 * i, 32, beta) ||
+            memcmp(beta, eta_output + 64 * i, 64) != 0) {
+            if (fail_kind) *fail_kind = 3;
+            return i;
+        }
+        if (eta_out) blake2b(beta, 64, eta_out + 32 * i, 32);
+        if (!oc_ecvrf_verify(vrf_vk + 32 * i, l_proof + 80 * i,
+                             l_alpha + 32 * i, 32, beta) ||
+            memcmp(beta, l_output + 64 * i, 64) != 0) {
+            if (fail_kind) *fail_kind = 4;
+            return i;
+        }
+    }
+    return -1;
+}
+
 // legacy ABI: fixed 80-byte draft-03 proofs
 extern "C" long oc_validate_praos(
     long n, const u8* cold_vk, const u8* ocert_sig, const u8* ocert_msg,

@@ -70,6 +70,8 @@ def forge_block(
         body_hash=body_hash(txs),
         ocert=ocert,
         protocol_version=protocol_version,
+        vrf_leader_output=is_leader.vrf_leader_output,
+        vrf_leader_proof=is_leader.vrf_leader_proof,
     )
     if hotkey is not None:
         kes_sig = hotkey.sign(kp, body.signed_bytes)

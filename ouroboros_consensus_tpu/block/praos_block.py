@@ -42,8 +42,19 @@ class HeaderBody:
     body_hash: bytes  # 32
     ocert: OCert
     protocol_version: tuple[int, int] = (9, 0)
+    # a TPraos (Shelley..Alonzo) body carries TWO VRF certificates under
+    # the one key (BHBody bheaderEta, bheaderL): `vrf_output`/`vrf_proof`
+    # are then the NONCE certificate and these the LEADER certificate,
+    # and the body serialises as 11 fields (the repo's own CBOR: the
+    # ledger's BHBody inlines the OCert and the version into 15)
+    vrf_leader_output: bytes | None = None  # 64
+    vrf_leader_proof: bytes | None = None  # 80 (draft-03)
 
     def to_cbor_obj(self):
+        leader = (
+            [] if self.vrf_leader_proof is None
+            else [[self.vrf_leader_output, self.vrf_leader_proof]]
+        )
         return [
             self.block_no,
             self.slot,
@@ -51,6 +62,7 @@ class HeaderBody:
             self.issuer_vk,
             self.vrf_vk,
             [self.vrf_output, self.vrf_proof],
+            *leader,
             self.body_size,
             self.body_hash,
             [self.ocert.vk_hot, self.ocert.counter, self.ocert.kes_period, self.ocert.sigma],
@@ -59,6 +71,10 @@ class HeaderBody:
 
     @classmethod
     def from_cbor_obj(cls, obj) -> "HeaderBody":
+        lout = lproof = None
+        if len(obj) == 11:  # TPraos: the leader certificate follows
+            obj = list(obj)
+            lout, lproof = (bytes(x) for x in obj.pop(6))
         (bn, slot, prev, ivk, vvk, (vout, vproof), bsz, bh, oc, pv) = obj
         return cls(
             block_no=bn, slot=slot,
@@ -68,6 +84,7 @@ class HeaderBody:
             body_size=bsz, body_hash=bytes(bh),
             ocert=OCert(bytes(oc[0]), oc[1], oc[2], bytes(oc[3])),
             protocol_version=(pv[0], pv[1]),
+            vrf_leader_output=lout, vrf_leader_proof=lproof,
         )
 
     @cached_property
@@ -124,6 +141,8 @@ class Header:
             slot=b.slot,
             signed_bytes=b.signed_bytes,
             kes_sig=self.kes_sig,
+            vrf_leader_output=b.vrf_leader_output,
+            vrf_leader_proof=b.vrf_leader_proof,
         )
 
     @classmethod

@@ -38,6 +38,7 @@ from ..block.praos_block import Block as PraosBlock
 from ..ops import ed25519_batch
 from ..protocol import batch as pbatch
 from ..protocol import nonces, praos, tpraos
+from ..protocol.leader import check_leader_value
 from ..protocol.instances import PBftParams, PBftProtocol, PraosProtocol
 from ..protocol.views import hash_vrf_vk
 from ..storage.immutable import ImmutableDB
@@ -759,6 +760,7 @@ def synthesize(path: str, cfg: CardanoMockConfig, n_slots: int, chunk_size: int 
         else:
             params = cm.inner_params[era]
             eta0 = ticked.inner.state.epoch_nonce
+            is_leader = None  # Praos: forge_block proves its one proof
             if cm.is_tpraos_era(era):
                 a = tpraos.overlay_slot_assignment(
                     cm.tpraos_params, cfg.n_delegs, slot
@@ -771,6 +773,20 @@ def synthesize(path: str, cfg: CardanoMockConfig, n_slots: int, chunk_size: int 
                 else:
                     creds = cm.pools[0]
                 inner_params = cm.tpraos_params.praos
+                # a Shelley-era header: the nonce and the leader
+                # certificate (f = 1 in these eras: the pool wins every
+                # lottery slot)
+                is_leader = tpraos.prove_certificates(
+                    creds.vrf_seed, slot, eta0)
+                if a is None and inner_params.active_slot_coeff != 1:
+                    # f < 1: the 512-bit lottery on the raw leader output
+                    entry = cm.tpraos_view.pool_distr.get(creds.pool_id)
+                    if entry is None or not check_leader_value(
+                        int.from_bytes(is_leader.vrf_leader_output, "big"),
+                        entry.stake, inner_params.active_slot_coeff,
+                        tpraos.LEADER_VALUE_MAX,
+                    ):
+                        continue
             else:
                 creds = cm.pools[0]
                 inner_params = params
@@ -792,7 +808,7 @@ def synthesize(path: str, cfg: CardanoMockConfig, n_slots: int, chunk_size: int 
             blk = praos_forge.forge_block(
                 inner_params, creds,
                 slot=slot, block_no=block_no, prev_hash=prev,
-                epoch_nonce=eta0,
+                epoch_nonce=eta0, is_leader=is_leader,
                 txs=(
                     (chain.tx_for(era),) if chain is not None
                     else (b"tx-%d" % slot,)

@@ -53,7 +53,7 @@ def load():
     lib.ocx_extract_headers.argtypes = [
         ctypes.c_char_p, ctypes.c_size_t,  # buf, len
         ctypes.c_void_p, ctypes.c_int,  # offsets, n
-        *([ctypes.c_void_p] * 22),
+        *([ctypes.c_void_p] * 25),
     ]
     lib.ocx_crc32_first_bad.restype = ctypes.c_int64
     lib.ocx_crc32_first_bad.argtypes = [
@@ -198,6 +198,11 @@ def load_crypto():
         [ctypes.c_long] + [ctypes.c_void_p] * 6 + [ctypes.c_long]
         + [ctypes.c_void_p] * 4 + [ctypes.c_long]
         + [ctypes.c_void_p] * 4 + [ctypes.POINTER(ctypes.c_long)]
+    )
+    lib.oc_validate_tpraos.restype = ctypes.c_long
+    lib.oc_validate_tpraos.argtypes = (
+        [ctypes.c_long] + [ctypes.c_void_p] * 6 + [ctypes.c_long]
+        + [ctypes.c_void_p] * 10 + [ctypes.POINTER(ctypes.c_long)]
     )
     lib.oc_ecvrf_verify_bc.restype = ctypes.c_int
     lib.oc_ecvrf_verify_bc.argtypes = [
@@ -377,6 +382,37 @@ def native_validate_praos(
     return int(rc), int(kind.value), lv, eta
 
 
+def native_validate_tpraos(
+    cold_vk, ocert_sig, ocert_msg, kes_vk, kes_t, kes_sig, kes_depth: int,
+    body: bytes, body_off,
+    vrf_vk, eta_proof, eta_alpha, eta_output, l_proof, l_alpha, l_output,
+):
+    """`native_validate_praos` for two-certificate (TPraos) headers:
+    (first_bad_index or -1, fail_kind 0|1:ocert|2:kes|3:nonce proof|
+    4:leader proof, etas [n, 32] = Blake2b-256 of each nonce output)."""
+    lib = load_crypto()
+    assert lib is not None
+    n = len(cold_vk)
+    eta = np.zeros((n, 32), np.uint8)
+
+    def u8(a):
+        return np.ascontiguousarray(a, np.uint8)
+
+    body_arr = np.frombuffer(body, np.uint8) if body else np.zeros(1, np.uint8)
+    arrs = [u8(cold_vk), u8(ocert_sig), u8(ocert_msg), u8(kes_vk),
+            np.ascontiguousarray(kes_t, np.int64), u8(kes_sig)]
+    tail = [body_arr, np.ascontiguousarray(body_off, np.int64), u8(vrf_vk),
+            u8(eta_proof), u8(eta_alpha), u8(eta_output),
+            u8(l_proof), u8(l_alpha), u8(l_output), eta]
+    kind = ctypes.c_long(0)
+    rc = lib.oc_validate_tpraos(
+        n, *[a.ctypes.data_as(ctypes.c_void_p) for a in arrs], kes_depth,
+        *[a.ctypes.data_as(ctypes.c_void_p) for a in tail],
+        ctypes.byref(kind),
+    )
+    return int(rc), int(kind.value), eta
+
+
 class MalformedBlock(ValueError):
     """extract_headers hit an unparseable block; `.index` is its
     position in the offsets array (blocks before it parsed clean)."""
@@ -451,6 +487,11 @@ class HeaderColumns:
     kes_len: np.ndarray  # [n] int64
     sgn_off: np.ndarray  # [n] int64 — KES-signed body span
     sgn_len: np.ndarray  # [n] int64
+    # TPraos bodies (11 fields): the leader certificate beside the nonce
+    # certificate that `vrf_output` / `vrf_proof` then hold
+    vrf_two: np.ndarray = None  # [n] uint8 — 1 = two certificates
+    vrf_leader_output: np.ndarray = None  # [n, 64]
+    vrf_leader_proof: np.ndarray = None  # [n, 80]
 
     def _span_list(self, off, ln) -> list:
         buf = self.raw
@@ -510,6 +551,8 @@ def extract_headers(buf: bytes, offsets: np.ndarray) -> HeaderColumns | None:
     pv_major, pv_minor = i64(), i64()
     kes_off, kes_len = i64(), i64()
     sgn_off, sgn_len = i64(), i64()
+    vrf_two = np.zeros(n, np.uint8)
+    leader_out, leader_proof = u8(64), u8(80)
 
     def ptr(a):
         return a.ctypes.data_as(ctypes.c_void_p)
@@ -526,6 +569,7 @@ def extract_headers(buf: bytes, offsets: np.ndarray) -> HeaderColumns | None:
         ptr(cols["ocert_kes_period"]), ptr(sig_off), ptr(sig_len),
         ptr(pv_major), ptr(pv_minor),
         ptr(kes_off), ptr(kes_len), ptr(sgn_off), ptr(sgn_len),
+        ptr(vrf_two), ptr(leader_out), ptr(leader_proof),
     )
     if rc != 0:
         raise MalformedBlock(rc - 1)
@@ -538,5 +582,7 @@ def extract_headers(buf: bytes, offsets: np.ndarray) -> HeaderColumns | None:
         sig_off=sig_off, sig_len=sig_len,
         kes_off=kes_off, kes_len=kes_len,
         sgn_off=sgn_off, sgn_len=sgn_len,
+        vrf_two=vrf_two, vrf_leader_output=leader_out,
+        vrf_leader_proof=leader_proof,
         **cols,
     )

@@ -449,8 +449,9 @@ def host_reference_fold(params, ticked, hvs):
     against, with no device in the loop at all."""
     from ..protocol import praos
     from ..protocol.views import ViewColumns
-    from ..protocol.batch import BatchResult
+    from ..protocol.batch import BatchResult, rules_of
 
+    update = rules_of(params).update  # the protocol's own reference
     views = hvs.views() if isinstance(hvs, ViewColumns) else hvs
     lview = ticked.ledger_view
     st = ticked.state
@@ -459,7 +460,7 @@ def host_reference_fold(params, ticked, hvs):
         if i:
             t = praos.tick(params, lview, hv.slot, st)
         try:
-            new_st = praos.update(params, hv, hv.slot, t)
+            new_st = update(params, hv, hv.slot, t)
         except praos.PraosValidationError as e:
             return BatchResult(st, i, e, None)
         st = new_st

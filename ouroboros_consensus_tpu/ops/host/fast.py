@@ -62,6 +62,16 @@ def ecvrf_prove(seed: bytes, alpha: bytes) -> bytes:
     return _ecvrf.prove(seed, alpha)
 
 
+def ecvrf_prove_draft03(seed: bytes, alpha: bytes) -> bytes:
+    """An 80-byte draft-03 proof whatever `vrf_batch_compat` says: the
+    one format a TPraos header's two certificates have."""
+    if _lib() is not None:
+        from ... import native_loader
+
+        return native_loader.native_ecvrf_prove(seed, alpha)
+    return _ecvrf.prove(seed, alpha)
+
+
 def ecvrf_proof_to_hash(pi: bytes) -> bytes:
     lib = _lib()
     if lib is not None:

@@ -306,6 +306,61 @@ def finish(ed_ok, ed_pt, ed_r, kes_ok, kes_pt, kes_r, vrf_ok, vrf_pts,
     )
 
 
+def _finish_tp_kernel(edok_ref, edpt_ref, edr_ref, kesok_ref, kespt_ref,
+                      kesr_ref, eok_ref, epts_ref, ce_ref, lok_ref,
+                      lpts_ref, cl_ref, be_ref, bl_ref, tlo_ref, thi_ref,
+                      over_ref, out_ref, eta_ref, lv_ref, vrf_ref):
+    tile = ce_ref.shape[-1]
+    with fe.kernel_consts(tile):
+        e_flat, l_flat = epts_ref[:], lpts_ref[:]
+        v = pv.finish_tp_core(
+            edok_ref[:][0] != 0, _unstack_point(edpt_ref[:]), edr_ref[:],
+            kesok_ref[:][0] != 0, _unstack_point(kespt_ref[:]), kesr_ref[:],
+            eok_ref[:][0] != 0,
+            [_unstack_point(e_flat[80 * i: 80 * (i + 1)]) for i in range(5)],
+            ce_ref[:],
+            lok_ref[:][0] != 0,
+            [_unstack_point(l_flat[80 * i: 80 * (i + 1)]) for i in range(5)],
+            cl_ref[:],
+            be_ref[:], bl_ref[:], tlo_ref[:], thi_ref[:], over_ref[:][0],
+        )
+        out_ref[:] = jnp.stack(
+            [
+                v.ok_ocert_sig.astype(jnp.int32),
+                v.ok_kes_sig.astype(jnp.int32),
+                v.ok_vrf.astype(jnp.int32),
+                v.ok_leader.astype(jnp.int32),
+                v.leader_ambiguous.astype(jnp.int32),
+            ],
+            axis=0,
+        )
+        eta_ref[:] = v.eta
+        lv_ref[:] = v.leader_value
+        vrf_ref[:] = jnp.stack(
+            [v.ok_vrf_nonce.astype(jnp.int32),
+             v.ok_vrf_leader.astype(jnp.int32)], axis=0)
+
+
+def finish_tp(ed_ok, ed_pt, ed_r, kes_ok, kes_pt, kes_r,
+              eta_ok, eta_pts, c_eta, l_ok, l_pts, c_l,
+              beta_eta, beta_l, thr_lo, thr_hi, overlay, n_live):
+    """The TPraos `finish` stage (a header's two VRF certificates; the
+    `vrf` stage ran once for each) -> (flags [5, B] with `finish`'s
+    rows, eta [32, B], the raw leader value [64, B], and which proof
+    held [2, B]: nonce, leader)."""
+    b = c_eta.shape[-1]
+    return _call(
+        _finish_tp_kernel, "finish_tp", b,
+        [(1,), (80,), (32,), (1,), (80,), (32,),
+         (1,), (400,), (16,), (1,), (400,), (16,),
+         (64,), (64,), (64,), (64,), (1,)],
+        [(5,), (32,), (64,), (2,)],
+        (ed_ok, ed_pt, ed_r, kes_ok, kes_pt, kes_r, eta_ok, eta_pts, c_eta,
+         l_ok, l_pts, c_l, beta_eta, beta_l, thr_lo, thr_hi, overlay),
+        with_base8=False, n_live=n_live,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Fused driver (one jit = one host dispatch)
 # ---------------------------------------------------------------------------
@@ -422,6 +477,22 @@ def staged_to_limb_first_bc(
     )
 
 
+def staged_to_limb_first_tp(*cols):
+    """The TPraos relayout: `unpack_packed`'s 27 staged columns of a
+    two-certificate window -> 27 limb-first arrays. The first 18 are
+    draft-03's to the letter (ed, kes, the NONCE proof's vrf columns);
+    then the LEADER proof's (gamma, c, s, alpha), both declared outputs,
+    the 64-byte threshold rows and the overlay row [1, B]."""
+    b = cols[22].shape[0]
+    head = staged_to_limb_first(*cols[:18], cols[22], cols[24], cols[25])
+    return (
+        *head[:18],
+        *(_bf(c) for c in cols[18:22]),
+        head[18], _bf(cols[23]), head[19], head[20],
+        jnp.asarray(cols[26]).astype(jnp.int32).reshape(1, b),
+    )
+
+
 def verify_praos_staged(
     ed_pk, ed_r, ed_s, ed_hblocks, ed_hnblocks,
     kes_vk, kes_period, kes_r, kes_s, kes_vk_leaf, kes_siblings,
@@ -504,12 +575,14 @@ def _jit1(key, fn):
     return _SPLIT_JIT[key]
 
 
-def _stage_call(name, fn, b, kes_depth, *args):
+def _stage_call(name, fn, b, kes_depth, *args, span=None):
     """`_run_stage` inside the span `dispatch.<stage>` (the window's id
-    and the parent `dispatch` come from the enclosing span)."""
+    and the parent `dispatch` come from the enclosing span). `span`
+    names it where one program runs under two (a TPraos window's two
+    `vrf` runs) or a program's name is not its stage's (`finish_tp`)."""
     from ...protocol import batch as pbatch
 
-    stage = "unpack" if name.startswith("unpack_") else name
+    stage = span or ("unpack" if name.startswith("unpack_") else name)
     with pbatch._enclose("dispatch." + stage):
         return _run_stage(name, fn, b, kes_depth, *args)
 
@@ -611,6 +684,7 @@ def split_stage_fns(kes_depth: int):
         ("vrf", _jit1("vrf", vrf_points)),
         ("vrf_bc", _jit1("vrf_bc", vrf_points_bc)),
         ("finish", _jit1("finish", finish)),
+        ("finish_tp", _jit1("finish_tp", finish_tp)),
     ]
 
 
@@ -641,25 +715,21 @@ def _mk_packed_unpack(layout):
     The four crypto stages and their AOT executables are untouched.
     The KES hash column is padded to `kes_hash_blocks(body_len)`."""
 
-    def unpack_limb(body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-                    thr_idx, thr_tab, nonce):
+    def unpack_limb(*packed):
         from ...protocol import batch as pbatch
 
-        staged = pbatch.unpack_packed(
-            layout, body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-            thr_idx, thr_tab, nonce,
-        )
+        staged = pbatch.unpack_packed(layout, *packed)
         hb = staged[_KES_HBLOCKS]  # [B, NB, 16, 2] SHA-512 word blocks
         spare = kes_hash_blocks(layout.body_len) - hb.shape[1]
         if spare > 0:
             hb = jnp.pad(hb, ((0, 0), (0, spare), (0, 0), (0, 0)))
             staged = (*staged[:_KES_HBLOCKS], hb,
                       *staged[_KES_HBLOCKS + 1:])
-        relayout = (
-            staged_to_limb_first_bc if len(staged) == 22
-            else staged_to_limb_first
-        )
-        return relayout(*staged)
+        if layout.proofs == 2:
+            return staged_to_limb_first_tp(*staged)
+        if layout.vrf_proof_len == 128:
+            return staged_to_limb_first_bc(*staged)
+        return staged_to_limb_first(*staged)
 
     return unpack_limb
 
@@ -686,27 +756,41 @@ def packed_unpack_name(layout) -> str:
     return f"unpack_{tag}"
 
 
-def stage_operands(a, n_live):
+def stage_operands(a, n_live, proofs: int = 1):
     """`unpack`'s or `relayout`'s limb-first arrays (22 for batch-
-    compatible proofs, 21 for draft-03) cut into the operands of the
-    three point stages, in dispatch order: [(stage, operands), ...].
-    One cut for every dispatch below and for the deviceless builder
-    (scripts/aot_precompile.py): a stored program is found again by
-    `aot.sig_of` of these."""
+    compatible proofs, 21 for draft-03; a TPraos window's, `proofs` = 2
+    from its layout, hold two draft-03 proofs) cut into the operands of
+    the point stages, in dispatch order: [(stage, operands), ...]
+    (TPraos: the `vrf` stage twice, the nonce proof then the leader
+    proof, the one program). One cut for every dispatch below and for
+    the deviceless builder (scripts/aot_precompile.py): a stored program
+    is found again by `aot.sig_of` of these."""
+    ed = ("ed", [a[0], a[2], a[3], a[4], n_live])
+    kes = ("kes", [a[5], a[6], a[8], a[9], a[10], a[11], a[12], n_live])
+    if proofs == 2:
+        return [ed, kes, ("vrf", [*a[13:18], n_live]),
+                ("vrf", [a[13], *a[18:22], n_live])]
     nv = len(a) - 16  # vrf columns: 6 (announced U, V) or 5 (challenge)
-    return [
-        ("ed", [a[0], a[2], a[3], a[4], n_live]),
-        ("kes", [a[5], a[6], a[8], a[9], a[10], a[11], a[12], n_live]),
-        ("vrf_bc" if nv == 6 else "vrf", [*a[13:13 + nv], n_live]),
-    ]
+    return [ed, kes, ("vrf_bc" if nv == 6 else "vrf",
+                      [*a[13:13 + nv], n_live])]
 
 
-def finish_operands(a, ed, kes, vrf, n_live):
-    """The `finish` stage's operands: the limb-first arrays `a` and the
-    point stages' outputs. `vrf_bc` hands on the challenge it derived;
-    draft-03's is a staged column."""
+def finish_operands(a, ed, kes, *vrfs_then_n_live):
+    """The `finish` stage's operands: the limb-first arrays `a`, the
+    point stages' outputs in `stage_operands`' order, then `n_live`.
+    `vrf_bc` hands on the challenge it derived; draft-03's is a staged
+    column. Two `vrf` outputs (a TPraos window's: the nonce proof's,
+    the leader proof's) give `finish_tp`'s operands."""
+    *vrfs, n_live = vrfs_then_n_live
+    if len(vrfs) == 2:
+        vrf_eta, vrf_l = vrfs
+        return [
+            ed[0], ed[1], a[1], kes[0], kes[1], a[7],
+            vrf_eta[0], vrf_eta[1], a[15], vrf_l[0], vrf_l[1], a[19],
+            *a[22:27], n_live,
+        ]
     nv = len(a) - 16
-    vrf_ok, *c16, vrf_pts = vrf
+    vrf_ok, *c16, vrf_pts = vrfs[0]
     return [
         ed[0], ed[1], a[1], kes[0], kes[1], a[7],
         vrf_ok, vrf_pts, c16[0] if c16 else a[15],
@@ -714,49 +798,53 @@ def finish_operands(a, ed, kes, vrf, n_live):
     ]
 
 
-def _crypto_stages(a, b, kes_depth, n_live):
+def _crypto_stages(a, b, kes_depth, n_live, proofs: int = 1):
     """ed, kes, vrf / vrf_bc and finish over limb-first arrays, one
     `_stage_call` each -> finish's (flags, eta, leader_value). The same
     per-stage jits / AOT executables whoever made `a`: the packed
-    `unpack` or the generic `relayout`."""
+    `unpack` or the generic `relayout`. `proofs` = 2 (a TPraos layout's)
+    runs `vrf` twice and `finish_tp` for `finish`."""
     stages = dict(split_stage_fns(kes_depth))
+    tp = proofs == 2
+    spans = ("ed", "kes", "vrf_eta", "vrf_leader") if tp else (None,) * 3
     outs = [
-        _stage_call(name, stages[name], b, kes_depth, *ops)
-        for name, ops in stage_operands(a, n_live)
+        _stage_call(name, stages[name], b, kes_depth, *ops, span=span)
+        for (name, ops), span in zip(stage_operands(a, n_live, proofs), spans)
     ]
+    name = "finish_tp" if tp else "finish"
     return _stage_call(
-        "finish", stages["finish"], b, kes_depth,
-        *finish_operands(a, *outs, n_live),
+        name, stages[name], b, kes_depth,
+        *finish_operands(a, *outs, n_live), span="finish",
     )
 
 
-def verify_praos_packed_split(
-    layout, body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-    thr_idx, thr_tab, nonce, *, tiles_live: int,
-):
+def verify_praos_packed_split(layout, *packed, tiles_live: int):
     """The packed production dispatch: `unpack` (device limb
     decomposition of the packed wire format) -> the ed/kes/vrf/finish
     stage jits/AOT executables -> `reduce` (verdict bitmasks + the
     uint8 eta column). Returns (reduce outputs, flags, eta,
-    leader_value) with the per-lane arrays left on device.
+    leader_value) with the per-lane arrays left on device; `packed` is
+    the window's `PraosPacked` columns. A two-certificate (TPraos)
+    layout's `TPraosPacked` window takes the same road with its own
+    `unpack`, the `vrf` program twice and `finish_tp` for `finish`, and
+    brings a fifth handle back (which proof held).
     `tiles_live` (`live_tiles` of the window's live lanes) goes to the
     device once and bounds the grid of every stage kernel: the lanes
     behind it come back holding anything, and the caller slices them
     off."""
     kes_depth = layout.kes_depth
     unpack = _jit1(("unpack", layout), _mk_packed_unpack(layout))
-    b = np.asarray(body).shape[0]
+    b = np.asarray(packed[0]).shape[0]
     n_live = jax.device_put(np.full((1,), tiles_live, np.int32))
     a = _stage_call(
-        packed_unpack_name(layout), unpack, b, kes_depth,
-        body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-        thr_idx, thr_tab, nonce,
+        packed_unpack_name(layout), unpack, b, kes_depth, *packed
     )
-    flags, eta, lv = _crypto_stages(a, b, kes_depth, n_live)
+    flags, eta, *rest = _crypto_stages(a, b, kes_depth, n_live,
+                                       layout.proofs)
     red = _stage_call(
         "reduce", _jit1("reduce", reduce_fn), b, kes_depth, flags, eta
     )
-    return red, flags, eta, lv
+    return (red, flags, eta, *rest)
 
 
 def _verify_praos_generic(relayout: str, cols, kes_depth: int):

@@ -13,6 +13,12 @@ The four stages mirror the fused XLA path (protocol/batch.verify_praos):
                 compare-on-bytes checks, Blake2b leader/nonce range
                 extensions (Praos/VRF.hs:103,116) and the bracketed
                 leader-threshold compare.
+  finish_tp_core — the TPraos finish (Shelley..Alonzo headers carry two
+                VRF certificates; vrf_core runs once a certificate): 12
+                points in the one inversion, both proofs' challenge and
+                output checks, the RAW 64-byte leader output against
+                512-bit brackets with the overlay bit in the threshold's
+                place, eta = Blake2b-256(beta_eta).
 
 Layout: batch tile T last everywhere (bytes [n, T] int32, points
 [20, T] limb coordinates). All control flow is batch-uniform; failures
@@ -322,6 +328,66 @@ def finish_core(
     certain_loss = ~_lt_be(lv, thr_hi)
     ambiguous = ~certain_win & ~certain_loss
     return CoreVerdicts(ok_ed, ok_kes, ok_vrf, certain_win, ambiguous, eta, lv)
+
+
+class TPraosCoreVerdicts(NamedTuple):
+    ok_ocert_sig: jnp.ndarray  # [T] bool
+    ok_kes_sig: jnp.ndarray
+    ok_vrf: jnp.ndarray  # both certificates
+    ok_leader: jnp.ndarray
+    leader_ambiguous: jnp.ndarray
+    eta: jnp.ndarray  # [32, T] Blake2b-256(beta_eta)
+    leader_value: jnp.ndarray  # [64, T] the raw beta_L (big-endian value)
+    ok_vrf_nonce: jnp.ndarray  # [T] the nonce certificate alone
+    ok_vrf_leader: jnp.ndarray  # [T] the leader certificate alone
+
+
+def _proof_ok(ok_pre, encs, c, beta_decl):
+    """One ECVRF proof's tail over its five compressed points (H, Γ, U,
+    V, 8Γ): the challenge recomputed and the output compared with the
+    declared one."""
+    t = c.shape[-1]
+    h_enc, gamma_enc, u_enc, v_enc, g8_enc = encs
+    p2 = ph.const_rows([SUITE, 0x02], t)
+    cdata = jnp.concatenate([p2, h_enc, gamma_enc, u_enc, v_enc], axis=0)
+    c_prime = ph.sha512_fixed(cdata)[:16]
+    p3 = ph.const_rows([SUITE, 0x03], t)
+    beta = ph.sha512_fixed(jnp.concatenate([p3, g8_enc], axis=0))
+    return (ok_pre & jnp.all(c_prime == c.astype(jnp.int32), axis=0)
+            & jnp.all(beta == beta_decl, axis=0))
+
+
+def finish_tp_core(
+    ok_ed_pre, ed_point, ed_r,
+    ok_kes_pre, kes_point, kes_r,
+    ok_eta_pre, eta_points, c_eta,
+    ok_l_pre, l_points, c_l,
+    beta_eta, beta_l, thr_lo, thr_hi, overlay,
+):
+    """The TPraos finish. Byte arrays [n, T] int32 (`beta_*`, `thr_*`
+    64 rows: the leader rule is nat(beta_L) / 2^512 < 1 - (1-f)^sigma on
+    the RAW output, cardano-protocol-tpraos `checkLeaderValue`);
+    `overlay` [T], nonzero on a lane whose slot the overlay schedule
+    gave its issuer: there the threshold is not consulted (`ok_leader`
+    forced, `leader_ambiguous` cleared; pbftVrfChecks). eta is
+    Blake2b-256(beta_eta), the UPDN rule's contribution."""
+    encs = pc.compress_many(
+        [ed_point, kes_point, *eta_points, *l_points])
+    ok_ed = ok_ed_pre & jnp.all(encs[0] == ed_r.astype(jnp.int32), axis=0)
+    ok_kes = ok_kes_pre & jnp.all(encs[1] == kes_r.astype(jnp.int32), axis=0)
+    beta_eta = beta_eta.astype(jnp.int32)
+    beta_l = beta_l.astype(jnp.int32)
+    ok_e = _proof_ok(ok_eta_pre, encs[2:7], c_eta, beta_eta)
+    ok_l = _proof_ok(ok_l_pre, encs[7:12], c_l, beta_l)
+    eta = ph.blake2b_fixed(beta_eta, 64, 32)
+    over = overlay != 0
+    certain_win = _lt_be(beta_l, thr_lo.astype(jnp.int32))
+    ambiguous = (~certain_win & _lt_be(beta_l, thr_hi.astype(jnp.int32))
+                 & ~over)
+    return TPraosCoreVerdicts(
+        ok_ed, ok_kes, ok_e & ok_l, certain_win | over, ambiguous, eta,
+        beta_l, ok_e, ok_l,
+    )
 
 
 def verify_praos_core(

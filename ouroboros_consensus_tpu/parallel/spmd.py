@@ -139,7 +139,9 @@ def sharded_stage_run(
     per-header objects), per-view otherwise — then shard and verify over
     the mesh. Returns `sharded_run_batch`'s (Verdicts, first_bad, n_ok)."""
     batch = pbatch.stage_any(params, lview, eta0, hvs, pre)
-    return sharded_run_batch(batch, mesh)
+    return sharded_run_batch(  # octflow: disable=FLOW304 — reached from
+        # `validate_batch` through `batch.PraosRules.run_sharded`
+        batch, mesh)
 
 
 # process-wide sharded-dispatch sequence (the ShardSpan `index`); only

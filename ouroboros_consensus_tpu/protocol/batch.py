@@ -40,6 +40,15 @@ to ~2⁻⁴⁰ relative width (protocol/leader.py series bounds). The device
 does the big-endian compare against both brackets; the measure-zero band
 in between falls back to the exact host check (`leader_ambiguous` mask).
 
+One loop, two protocols: the window loop, packed staging and the epilogue
+take the protocol's rules from the params (`rules_of`: `PraosRules` here,
+`protocol/tpraos.TPraosRules` for the Shelley-to-Alonzo eras' TPraos, whose
+headers carry two VRF certificates: its packed layout holds both
+(`PraosPackedLayout.proofs == 2`, `TPraosPacked`), its window runs the
+draft-03 `vrf` stage twice and `finish_tp`, its leader rule compares the
+raw 64-byte leader output against 512-bit brackets, and an overlay column
+says which lanes the BFT schedule, not the lottery, gave their slot).
+
 Epoch segmentation (SURVEY.md §5.7): the epoch nonce and pool distribution
 are constant within an epoch, so a batch spans at most one epoch; the
 chain driver (storage/ledgerdb, tools/db_analyser) cuts batches at epoch
@@ -53,7 +62,7 @@ import itertools
 import os
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from fractions import Fraction
 from functools import lru_cache
 from typing import Mapping, NamedTuple, Sequence
@@ -97,8 +106,11 @@ def _exp_fixed(x: int, up: bool) -> int:
 
 
 @lru_cache(maxsize=4096)
-def leader_threshold_bracket(sigma: Fraction, f: Fraction) -> tuple[int, int]:
-    """[T_lo, T_hi] integers bracketing 2^256 * (1 - (1-f)^sigma).
+def leader_threshold_bracket(sigma: Fraction, f: Fraction,
+                             bits: int = 256) -> tuple[int, int]:
+    """[T_lo, T_hi] integers bracketing 2^bits * (1 - (1-f)^sigma):
+    bits = 256 for Praos's hashed leader value, 512 for TPraos's raw
+    64-byte VRF output (`checkLeaderValue` over 2^512).
 
     leader_value < T_lo  => certainly a leader;
     leader_value >= T_hi => certainly not;
@@ -113,8 +125,9 @@ def leader_threshold_bracket(sigma: Fraction, f: Fraction) -> tuple[int, int]:
     mainnet-shaped ledger view, PERF.md PR 32), and the bracket need
     only BE a bracket.
     """
+    vmax = 1 << bits
     if f == 1:
-        return (leader.LEADER_VALUE_MAX, leader.LEADER_VALUE_MAX)
+        return (vmax, vmax)
     if sigma == 0:
         return (0, 0)
     llo, lhi = leader._neg_log1m_interval(f, _BRACKET_TERMS)
@@ -123,8 +136,8 @@ def leader_threshold_bracket(sigma: Fraction, f: Fraction) -> tuple[int, int]:
     elo = _exp_fixed(xlo.numerator * one // xlo.denominator, up=False)
     ehi = _exp_fixed(-(-xhi.numerator * one // xhi.denominator), up=True)
     # lhs = 2^256/(2^256 - lv) < exp(x)  <=>  lv < 2^256 (1 - 1/exp(x))
-    lo = leader.LEADER_VALUE_MAX * (elo - one) // elo  # floor
-    hi = -(-leader.LEADER_VALUE_MAX * (ehi - one) // ehi)  # ceil
+    lo = vmax * (elo - one) // elo  # floor
+    hi = -(-vmax * (ehi - one) // ehi)  # ceil
     return (lo, hi)
 
 
@@ -178,6 +191,10 @@ class ColumnChecks(HostChecks):
     uniq_hk: tuple  # per-unique KeyHash bytes
     uniq_entry: tuple  # per-unique IndividualPoolStake | None
     clean: bool = False  # True = no precheck error in any lane
+    # a protocol with a BFT overlay (`PraosRules.overlay`): [B] uint8,
+    # 1 = the lane's slot is an active overlay slot; None under Praos
+    overlay: np.ndarray | None = None
+    overlay_s: float = 0.0  # wall of the span `stage.overlay`
 
     def any_errors(self) -> bool:
         return not self.clean
@@ -260,13 +277,9 @@ def _dedup_rows(rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return uniq, inv
 
 
-def host_prechecks_columns(
-    params: PraosParams,
-    ledger_view: LedgerView,
-    vc: ViewColumns,
-) -> ColumnChecks:
-    """Columnar host_prechecks: same verdicts and error objects, zero
-    per-header Python on the clean path."""
+def kes_window_checks(params: PraosParams, vc: ViewColumns):
+    """The OCert KES-window checks of a window, whole-column:
+    -> (per-lane errors, evolution index [B] int32, any error)."""
     n = len(vc)
     c0 = vc.ocert_kes_period
     kp = vc.slot // params.slots_per_kes_period
@@ -275,18 +288,24 @@ def host_prechecks_columns(
     bad_window = before | after
     evol = np.where(bad_window, 0, kp - c0).astype(np.int32)
     kes_errors: list = [None] * n
-    if bad_window.any():
+    bad = bool(bad_window.any())
+    if bad:
         for i in np.flatnonzero(before).tolist():
             kes_errors[i] = praos.KESBeforeStartOCERT(int(c0[i]), int(kp[i]))
         for i in np.flatnonzero(after).tolist():
             kes_errors[i] = praos.KESAfterEndOCERT(
                 int(kp[i]), int(c0[i]), params.max_kes_evolutions
             )
+    return kes_errors, evol, bad
 
-    # pool lookups once per unique (cold key, vrf key) pair: real chains
-    # have a handful of issuers per window, so the Blake2b-224 hash_key,
-    # the pool_distr probe and the vrf-key-hash equality run O(pools)
-    # times instead of O(headers)
+
+def pool_pairs(ledger_view: LedgerView, vc: ViewColumns):
+    """The pool lookups of a window, once per unique (cold key, vrf key)
+    pair: real chains have a handful of issuers per window, so the
+    Blake2b-224 hash_key, the pool_distr probe and the vrf-key-hash
+    equality run O(pools) times instead of O(headers).
+    -> (unique pair rows [k, 64], lane -> pair [B], per-pair key hash,
+    per-pair IndividualPoolStake | None, per-pair lookup error | None)"""
     pair = np.concatenate([vc.vk_cold, vc.vrf_vk], axis=1)
     uniq, inv = _dedup_rows(pair)
     hks, entries, uerrs = [], [], []
@@ -306,11 +325,24 @@ def host_prechecks_columns(
                 ))
             else:
                 uerrs.append(None)
+    return uniq, inv, hks, entries, uerrs
+
+
+def host_prechecks_columns(
+    params: PraosParams,
+    ledger_view: LedgerView,
+    vc: ViewColumns,
+) -> ColumnChecks:
+    """Columnar host_prechecks: same verdicts and error objects, zero
+    per-header Python on the clean path."""
+    n = len(vc)
+    kes_errors, evol, bad_window = kes_window_checks(params, vc)
+    _uniq, inv, hks, entries, uerrs = pool_pairs(ledger_view, vc)
     if any(e is not None for e in uerrs):
         vrf_errors = [uerrs[j] for j in inv.tolist()]
     else:
         vrf_errors = [None] * n
-    clean = not bad_window.any() and all(e is None for e in uerrs)
+    clean = not bad_window and all(e is None for e in uerrs)
     return ColumnChecks(
         kes_errors, vrf_errors, evol,
         inv.astype(np.int32), tuple(hks), tuple(entries), clean,
@@ -318,17 +350,18 @@ def host_prechecks_columns(
 
 
 @lru_cache(maxsize=4096)
-def _threshold_rows(sigma: Fraction, f: Fraction):
+def _threshold_rows(sigma: Fraction, f: Fraction, bits: int = 256):
     """Encoded (lo, hi) threshold byte rows per (sigma, f) — the
     bracket itself is lru_cached too, but the per-header Fraction wrap
     + 32-byte to_bytes/frombuffer encoding dominated staging before
-    this was hoisted. Clamped to the 256-bit compare domain: a
-    threshold of 2^256 means "every value wins", encoded as all-0xFF +
+    this was hoisted. Clamped to the `bits`-bit compare domain: a
+    threshold of 2^bits means "every value wins", encoded as all-0xFF +
     the hi-inclusive trick."""
-    lo, hi = leader_threshold_bracket(sigma, f)
+    lo, hi = leader_threshold_bracket(sigma, f, bits)
+    top, nb = (1 << bits) - 1, bits // 8
     return (
-        np.frombuffer(min(lo, (1 << 256) - 1).to_bytes(32, "big"), np.uint8),
-        np.frombuffer(min(hi, (1 << 256) - 1).to_bytes(32, "big"), np.uint8),
+        np.frombuffer(min(lo, top).to_bytes(nb, "big"), np.uint8),
+        np.frombuffer(min(hi, top).to_bytes(nb, "big"), np.uint8),
     )
 
 
@@ -338,6 +371,7 @@ def stage(
     epoch_nonce: nonces.Nonce,
     hvs: Sequence[HeaderView],
     evolution: np.ndarray,
+    alpha_of=nonces.mk_input_vrf,  # (slot, epoch nonce) -> the VRF input
 ) -> PraosBatch:
     """Columnarize header views for the fused device kernel."""
     b = len(hvs)
@@ -356,7 +390,7 @@ def stage(
     vrf = ecvrf_batch.stage_np(
         [hv.vrf_vk for hv in hvs],
         [hv.vrf_proof for hv in hvs],
-        [nonces.mk_input_vrf(hv.slot, epoch_nonce) for hv in hvs],
+        [alpha_of(hv.slot, epoch_nonce) for hv in hvs],
     )
     assert all(len(hv.vrf_output) == 64 for hv in hvs)
     beta = np.frombuffer(
@@ -381,7 +415,7 @@ def _be8_np(a: np.ndarray) -> np.ndarray:
 
 
 def _uniq_threshold_rows(
-    params: PraosParams, pre: ColumnChecks
+    params: PraosParams, pre: ColumnChecks, bits: int = 256
 ) -> tuple[list[np.ndarray], list[np.ndarray]]:
     """Per-UNIQUE-pool (lo, hi) threshold byte rows from the precheck
     dedup — the one place the unknown-pool sigma-0 convention and the
@@ -390,18 +424,18 @@ def _uniq_threshold_rows(
     lo_rows, hi_rows = [], []
     for entry in pre.uniq_entry:
         sigma = entry.stake if entry is not None else Fraction(0)
-        lo, hi = _threshold_rows(sigma, f)
+        lo, hi = _threshold_rows(sigma, f, bits)
         lo_rows.append(lo)
         hi_rows.append(hi)
     return lo_rows, hi_rows
 
 
 def _uniq_threshold_tables(
-    params: PraosParams, pre: ColumnChecks
+    params: PraosParams, pre: ColumnChecks, bits: int = 256
 ) -> tuple[np.ndarray, np.ndarray]:
-    """(thr_lo [B, 32], thr_hi [B, 32]): the per-unique rows gathered
-    per lane."""
-    lo_rows, hi_rows = _uniq_threshold_rows(params, pre)
+    """(thr_lo [B, bits/8], thr_hi [B, bits/8]): the per-unique rows
+    gathered per lane."""
+    lo_rows, hi_rows = _uniq_threshold_rows(params, pre, bits)
     inv = pre.uniq_inv
     return np.stack(lo_rows)[inv], np.stack(hi_rows)[inv]
 
@@ -524,6 +558,22 @@ class Verdicts(NamedTuple):
     leader_value: jnp.ndarray  # [B, 32] big-endian Blake2b("L" ‖ beta)
 
 
+class TPraosVerdicts(NamedTuple):
+    """`Verdicts` of a TPraos window (two proofs a header): `ok_vrf` is
+    both proofs, `eta` Blake2b-256(beta_eta), `leader_value` the raw
+    64-byte beta_L, and `ok_vrf_nonce` the NONCE proof alone, so that
+    the error names which one failed."""
+
+    ok_ocert_sig: jnp.ndarray
+    ok_kes_sig: jnp.ndarray
+    ok_vrf: jnp.ndarray
+    ok_leader: jnp.ndarray
+    leader_ambiguous: jnp.ndarray
+    eta: jnp.ndarray  # [B, 32]
+    leader_value: jnp.ndarray  # [B, 64]
+    ok_vrf_nonce: jnp.ndarray  # [B]
+
+
 def _leader_nonce_tail(beta_decl, thr_lo, thr_hi):
     """Shared tail of the fused verifiers: leader-value + eta range
     extensions (Praos/VRF.hs:103,116) on the DECLARED beta — ok_vrf
@@ -633,6 +683,57 @@ def verify_praos_bc(
         beta_decl, thr_lo, thr_hi
     )
     return Verdicts(ok_ed, ok_kes, ok_vrf, certain_win, ambiguous, eta, lv)
+
+
+def verify_tpraos(
+    ed_pk, ed_r, ed_s, ed_hblocks, ed_hnblocks,
+    kes_vk, kes_period, kes_r, kes_s, kes_vk_leaf, kes_siblings,
+    kes_hblocks, kes_hnblocks,
+    vrf_pk, eta_gamma, eta_c, eta_s, eta_alpha,
+    l_gamma, l_c, l_s, l_alpha,
+    beta_eta, beta_l, thr_lo, thr_hi, overlay,
+) -> TPraosVerdicts:
+    """The XLA twin of the TPraos window (ops/pk: ed, kes, `vrf` twice,
+    `finish_tp`): both certificates' proofs and declared outputs, the
+    512-bit leader rule on the RAW beta_L with the overlay bit in place
+    of the threshold, eta = Blake2b-256(beta_eta). Twelve points share
+    the one inversion."""
+    from ..ops import curve
+
+    ok_ed_pre, ed_point = ed25519_batch.verify_point(
+        ed_pk, ed_s, ed_hblocks, ed_hnblocks
+    )
+    ok_kes_pre, kes_point = kes_batch.verify_point(
+        kes_vk, kes_period, kes_s, kes_vk_leaf, kes_siblings,
+        kes_hblocks, kes_hnblocks,
+    )
+    ok_e_pre, e_points = ecvrf_batch.verify_points(
+        vrf_pk, eta_gamma, eta_c, eta_s, eta_alpha
+    )
+    ok_l_pre, l_points = ecvrf_batch.verify_points(
+        vrf_pk, l_gamma, l_c, l_s, l_alpha
+    )
+    encs = curve.compress_many([ed_point, kes_point, *e_points, *l_points])
+    ok_ed = ok_ed_pre & jnp.all(
+        encs[0] == jnp.asarray(ed_r).astype(jnp.int32), axis=-1
+    )
+    ok_kes = ok_kes_pre & jnp.all(
+        encs[1] == jnp.asarray(kes_r).astype(jnp.int32), axis=-1
+    )
+    ok_e, b_e = ecvrf_batch.finish(ok_e_pre, eta_c, encs[2:7])
+    ok_l, b_l = ecvrf_batch.finish(ok_l_pre, l_c, encs[7:12])
+    beta_eta = jnp.asarray(beta_eta).astype(jnp.int32)
+    beta_l = jnp.asarray(beta_l).astype(jnp.int32)
+    ok_e = ok_e & jnp.all(b_e == beta_eta, axis=-1)
+    ok_l = ok_l & jnp.all(b_l == beta_l, axis=-1)
+    eta = blake2b.blake2b_fixed(beta_eta, 64, 32)
+    over = jnp.asarray(overlay) != 0
+    thr_lo = jnp.asarray(thr_lo).astype(jnp.int32)
+    thr_hi = jnp.asarray(thr_hi).astype(jnp.int32)
+    certain_win = _lt_be(beta_l, thr_lo)
+    ambiguous = ~certain_win & _lt_be(beta_l, thr_hi) & ~over
+    return TPraosVerdicts(ok_ed, ok_kes, ok_e & ok_l, certain_win | over,
+                          ambiguous, eta, beta_l, ok_e)
 
 
 def verify_praos_any(*cols) -> Verdicts:
@@ -933,6 +1034,18 @@ class PraosPackedLayout(NamedTuple):
     slots_per_kes: int
     has_nonce: bool  # False = neutral epoch nonce (genesis)
     vrf_proof_len: int = 80  # 80 = draft-03, 128 = batch-compatible
+    # a TPraos body's LEADER certificate (64-byte output, 80-byte
+    # draft-03 proof); `o_vrf_out` / `o_vrf_proof` are then its NONCE
+    # certificate. -1: a one-certificate (Praos) body
+    o_vrf_leader_out: int = -1
+    o_vrf_leader_proof: int = -1
+
+    @property
+    def proofs(self) -> int:
+        """VRF proofs the device verifies a lane: the count the layout
+        fixes, and with it the window's programs (`unpack`, `finish` or
+        `finish_tp`; the `vrf` stage once or twice)."""
+        return 2 if self.o_vrf_leader_proof >= 0 else 1
 
 
 class PraosPacked(NamedTuple):
@@ -957,6 +1070,26 @@ class PraosPacked(NamedTuple):
     thr_idx: np.ndarray  # [B] int32 into thr_tab
     thr_tab: np.ndarray  # [Kr, 64] uint8 — thr_lo ‖ thr_hi per pool
     nonce: np.ndarray  # [32] uint8 — epoch nonce bytes (zeros if neutral)
+
+
+class TPraosPacked(NamedTuple):
+    """`PraosPacked` for a two-certificate (TPraos) window: the body
+    column embeds both certificates, the threshold rows are 512-bit
+    (`thr_tab` [Kr, 128]: the raw 64-byte leader output is compared,
+    not its hash) and each lane says whether the overlay schedule, not
+    the lottery, gave it its slot."""
+
+    body: np.ndarray
+    kes_rs: np.ndarray
+    kes_tail_idx: np.ndarray
+    kes_tail_tab: np.ndarray
+    slot: np.ndarray
+    counter: np.ndarray
+    c0: np.ndarray
+    thr_idx: np.ndarray
+    thr_tab: np.ndarray  # [Kr, 128] uint8 — thr_lo ‖ thr_hi, 64 bytes each
+    nonce: np.ndarray
+    overlay: np.ndarray  # [B] int32 — 1 = an active overlay slot's lane
 
 
 # why the last packed-staging attempt declined (the PR 5 gates were
@@ -998,7 +1131,7 @@ def _table_rows(params: PraosParams, ledger_view: LedgerView, slots,
     leaves with a 3.9 s collection inside its window (PERF.md, PR 32)."""
     b = len(slots)
     kp = np.asarray(slots) // params.slots_per_kes_period
-    pools = len(ledger_view.pool_distr)
+    pools = rules_of(params).issuers(ledger_view)
     periods = int(kp.max() - kp.min()) + 1
     return (_table_bucket(max(kes_tails, min(b, pools * periods))),
             _table_bucket(max(thr_rows, min(b, pools))))
@@ -1028,6 +1161,9 @@ def stage_packed(
     test views whose signed bytes do not embed the fields fall back."""
     if not hvs:
         return _decline("empty-window")
+    if hvs[0].vrf_leader_proof is not None:
+        # TPraos windows stage columnar (`stage_packed_columns`)
+        return _decline("two-certificates")
     b = len(hvs)
     h0 = hvs[0]
     body0 = h0.signed_bytes
@@ -1165,9 +1301,13 @@ def stage_packed_columns(
     # not matter, the byte-equality below makes extraction correct)
     body0 = body[0].tobytes()
     proof_ref = np.ascontiguousarray(vc.vrf_proof[:, :plen])
+    two = vc.two_certs
+    if two and (plen != 80 or pre.overlay is None):
+        return _decline("proof-format")
     refs = (
         vc.vk_cold, vc.vrf_vk, vc.vrf_output, proof_ref,
         vc.ocert_vk_hot, vc.ocert_sigma,
+        *((vc.vrf_leader_output, vc.vrf_leader_proof) if two else ()),
     )
     offs = tuple(body0.find(r[0].tobytes()) for r in refs)
     if min(offs) < 0:
@@ -1183,7 +1323,8 @@ def stage_packed_columns(
 
     kes_rs = np.ascontiguousarray(vc.kes_sig[:, :64])
     kt_rows, kt_idx = _dedup_rows(vc.kes_sig[:, 64:])
-    lo_rows, hi_rows = _uniq_threshold_rows(params, pre)
+    lo_rows, hi_rows = _uniq_threshold_rows(
+        params, pre, 512 if two else 256)
     rows = [np.concatenate([lo, hi]) for lo, hi in zip(lo_rows, hi_rows)]
     kt_n, thr_n = _table_rows(params, ledger_view, slot, kt_rows.shape[0],
                               len(rows))
@@ -1191,13 +1332,13 @@ def stage_packed_columns(
     kt_tab[: kt_rows.shape[0]] = kt_rows
     kt_tab[kt_rows.shape[0] :] = kt_tab[0]
 
-    thr_tab = np.zeros((thr_n, 64), np.uint8)
+    thr_tab = np.zeros((thr_n, rows[0].shape[0]), np.uint8)
     thr_tab[: len(rows)] = np.stack(rows)
     thr_tab[len(rows) :] = thr_tab[0]
 
     layout = PraosPackedLayout(
-        lb, *offs, depth, params.slots_per_kes_period,
-        epoch_nonce is not None, plen,
+        lb, *offs[:6], depth, params.slots_per_kes_period,
+        epoch_nonce is not None, plen, *offs[6:],
     )
     packed = PraosPacked(
         body=np.ascontiguousarray(body),
@@ -1211,6 +1352,9 @@ def stage_packed_columns(
         thr_tab=thr_tab,
         nonce=np.frombuffer(epoch_nonce or bytes(32), np.uint8),
     )
+    if two:
+        packed = TPraosPacked(
+            *packed, overlay=np.asarray(pre.overlay).astype(np.int32))
     return layout, packed
 
 
@@ -1225,15 +1369,10 @@ def pad_packed_to(packed: PraosPacked, size: int) -> PraosPacked:
     def _pad(x):
         return np.concatenate([x, np.repeat(x[:1], size - b, axis=0)], axis=0)
 
-    return packed._replace(
-        body=_pad(packed.body),
-        kes_rs=_pad(packed.kes_rs),
-        kes_tail_idx=_pad(packed.kes_tail_idx),
-        slot=_pad(packed.slot),
-        counter=_pad(packed.counter),
-        c0=_pad(packed.c0),
-        thr_idx=_pad(packed.thr_idx),
-    )
+    return packed._replace(**{
+        f: _pad(getattr(packed, f)) for f in packed._fields
+        if f not in ("kes_tail_tab", "thr_tab", "nonce")
+    })
 
 
 def _be8(x):
@@ -1243,16 +1382,29 @@ def _be8(x):
     return bi.be8_rows(x).astype(jnp.uint8)
 
 
+# mkSeed's universal constants (cardano-protocol-tpraos BHeader.hs
+# `seedEta` / `seedL` = mkNonceFromNumber 0 / 1 = Blake2b-256 of the
+# number's 8 big-endian bytes): a TPraos header's two VRF inputs are the
+# slot-and-nonce hash XORed with one each
+SEED_ETA = nonces.mk_input_vrf(0, None)
+SEED_L = nonces.mk_input_vrf(1, None)
+
+
 def unpack_packed(
     layout: PraosPackedLayout,
     body, kes_rs, kes_tail_idx, kes_tail_tab, slot, counter, c0,
-    thr_idx, thr_tab, nonce,
+    thr_idx, thr_tab, nonce, overlay=None,
 ):
     """The device-side unpack: packed columns -> the 21 staged columns
     in flatten_batch order, byte-identical to what `stage` builds on the
     host (the packed round-trip property, tests/test_packed_batch.py).
     Runs inside the jit — limb decomposition for the pk path continues
-    through ops/pk/kernels.staged_to_limb_first on these outputs."""
+    through ops/pk/kernels.staged_to_limb_first on these outputs.
+
+    A two-certificate (TPraos) layout yields 27: the second proof's
+    (gamma, c, s, alpha) behind the first's, both declared outputs, the
+    64-byte threshold rows and the overlay column
+    (`kernels.staged_to_limb_first_tp`)."""
     body = jnp.asarray(body).astype(jnp.uint8)
     bsz = body.shape[0]
 
@@ -1286,7 +1438,8 @@ def unpack_packed(
     thr = jnp.take(
         jnp.asarray(thr_tab).astype(jnp.uint8), jnp.asarray(thr_idx), axis=0
     )
-    thr_lo, thr_hi = thr[:, :32], thr[:, 32:]
+    tw = thr.shape[1] // 2  # 32, or 64 under the 512-bit leader rule
+    thr_lo, thr_hi = thr[:, :tw], thr[:, tw:]
 
     slot = jnp.asarray(slot).astype(jnp.int32)
     counter = jnp.asarray(counter).astype(jnp.int32)
@@ -1311,6 +1464,22 @@ def unpack_packed(
     # in the reference's error order
     period = slot // layout.slots_per_kes - c0
 
+    if layout.proofs == 2:
+        # mkSeed(uc, slot, eta0) = H(be8(slot) ‖ eta0) XOR uc: one hash a
+        # lane serves both inputs
+        seed_e = jnp.asarray(np.frombuffer(SEED_ETA, np.uint8))
+        seed_l = jnp.asarray(np.frombuffer(SEED_L, np.uint8))
+        beta_l = _slice(layout.o_vrf_leader_out, 64)
+        proof_l = _slice(layout.o_vrf_leader_proof, 80)
+        return (
+            issuer, ed_r, ed_s, ed_hb, ed_hnb,
+            vk_hot, period, kes_r, kes_s, vk_leaf, siblings, kes_hb, kes_hnb,
+            vrf_vk, gamma, vrf_c, vrf_s, alpha ^ seed_e,
+            proof_l[:, :32], proof_l[:, 32:48], proof_l[:, 48:],
+            alpha ^ seed_l,
+            beta, beta_l, thr_lo, thr_hi,
+            jnp.asarray(overlay).astype(jnp.int32),
+        )
     if bc:
         return (
             issuer, ed_r, ed_s, ed_hb, ed_hnb,
@@ -1405,18 +1574,21 @@ def _packed_xla_fn(layout: PraosPackedLayout):
     """The RAW (un-jitted) XLA-twin packed program of one layout:
     unpack -> fused verify -> pack."""
 
-    def fn(body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-           thr_idx, thr_tab, nonce):
-        cols = unpack_packed(
-            layout, body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-            thr_idx, thr_tab, nonce,
-        )
-        v = verify_praos_any(*cols)
+    def fn(*packed):
+        cols = unpack_packed(layout, *packed)
+        two = layout.proofs == 2
+        v = verify_tpraos(*cols) if two else verify_praos_any(*cols)
         flags = jnp.stack(
             [v.ok_ocert_sig, v.ok_kes_sig, v.ok_vrf, v.ok_leader,
              v.leader_ambiguous]
         ).astype(jnp.int32)
-        return verdict_pack(flags, v.eta), flags, v.eta, v.leader_value
+        out = (verdict_pack(flags, v.eta), flags, v.eta, v.leader_value)
+        if not two:
+            return out
+        # TPraos: which proof failed, for the error's name ([2, B], as
+        # `finish_tp` ships it: the nonce proof, the leader proof)
+        return (*out, jnp.stack([v.ok_vrf_nonce, v.ok_vrf]
+                                ).astype(jnp.int32))
 
     return fn
 
@@ -1428,7 +1600,8 @@ def _jitted_packed_xla(layout: PraosPackedLayout):
     key = ("xla-packed", layout)
     if key not in _JIT:
         _JIT[key] = _warm_timed(
-            f"xla-packed:{layout.body_len}b:p{layout.vrf_proof_len}",
+            f"xla-packed:{layout.body_len}b:p{layout.vrf_proof_len}"
+            + ("x2" if layout.proofs == 2 else ""),
             jax.jit(_packed_xla_fn(layout)),
         )
     return _JIT[key]
@@ -1863,40 +2036,49 @@ def validate_batch(
         return BatchResult(ticked.state, 0, None, [] if collect_states else None)
     lview = ticked.ledger_view
     eta0 = ticked.state.epoch_nonce
-
-    if not _proof_len_uniform(hvs):
+    rules = rules_of(params)
+    runs = rules.runs(hvs)
+    if len(runs) == 1 and not _proof_len_uniform(runs[0]):
         # a run mixing 80- and 128-byte proofs cannot stage as one
         # uniform proof column; segment at format boundaries — the
         # reference fold length-dispatches per header, and segmentation
         # never changes per-lane verdicts or the first error
+        hvs, runs, i = runs[0], [], 0
+        while i < len(hvs):
+            j = _proof_break(hvs, i, len(hvs))
+            runs.append(hvs[i:j])
+            i = j
+    if len(runs) > 1:
         states = [] if collect_states else None
         total = 0
-        i = 0
-        n = len(hvs)
-        while True:
-            j = _proof_break(hvs, i, n)
+        for k, run in enumerate(runs):
+            if k:
+                ticked = praos.tick(params, lview, _slot_at(run, 0),
+                                    res.state)
             res = validate_batch(
-                params, ticked, hvs[i:j], collect_states, backend, mesh
+                params, ticked, run, collect_states, backend, mesh
             )
             total += res.n_valid
             if collect_states:
                 states.extend(res.states or [])
-            if res.error is not None or j == n:
-                return BatchResult(res.state, total, res.error, states)
-            i = j
-            ticked = praos.tick(params, lview, _slot_at(hvs, i), res.state)
+            if res.error is not None:
+                break
+        return BatchResult(res.state, total, res.error, states)
+    hvs = runs[0]
 
-    pre = host_prechecks(params, lview, hvs)
+    if backend == "device" and rules.packed_only:
+        # no un-packed device path for this protocol: the one window
+        # through the staged dispatch the replay's windows take
+        pre, out, b = dispatch_batch(params, lview, eta0, hvs)
+        return _epilogue(params, ticked, hvs, pre,
+                         materialize_verdicts(out, b), collect_states)
+    pre = rules.prechecks(params, lview, hvs)
     if backend == "native":
-        v = run_batch_native(params, lview, eta0, hvs, pre)
+        v = rules.run_native(params, lview, eta0, hvs, pre)
     elif backend == "sharded":
         # multi-chip SPMD: batch axis over the device mesh, psum/pmin
         # verdict collectives (parallel/spmd.py; SURVEY.md §5.8)
-        from ..parallel import spmd
-
-        v, _first_bad, _n_ok = spmd.sharded_stage_run(
-            params, lview, eta0, hvs, pre, mesh
-        )
+        v = rules.run_sharded(params, lview, eta0, hvs, pre, mesh)
     else:
         batch = stage_any(params, lview, eta0, hvs, pre)
         v = run_batch(batch)
@@ -2014,6 +2196,7 @@ class _WinMeta(NamedTuple):
     tiles_live: int  # WindowSpan.tiles_live
     stage_wait_s: float = 0.0
     census: "_StageCensus | None" = None
+    proofs: int = 1  # VRF proofs the device verifies a lane
 
 
 class _Dispatched(NamedTuple):
@@ -2037,7 +2220,8 @@ def _emit_transfer(phase: str, **kw) -> None:
 
 
 def _win_meta(outcome: str, gate: str | None, sw: "_StagedWindow",
-              t_d0: float, tiles_live: int = 0) -> _WinMeta | None:
+              t_d0: float, tiles_live: int = 0,
+              proofs: int = 1) -> _WinMeta | None:
     """Build the per-window telemetry meta and emit the WindowStaged
     event. Returns None (zero residual cost) when no tracer is set.
     `tiles_live`: the count the window's stage kernels were bounded by
@@ -2052,7 +2236,7 @@ def _win_meta(outcome: str, gate: str | None, sw: "_StagedWindow",
                               stage_s, dispatch_s))
     return _WinMeta(sw.window, outcome, gate, stage_s, dispatch_s,
                     sw.lanes, t2, sw.t0, sw.t1, t_d0, sw.thread,
-                    tiles_live, census=sw.census)
+                    tiles_live, census=sw.census, proofs=proofs)
 
 
 def _emit_window_span(meta, lanes: int, n_valid: int, failed: bool,
@@ -2078,6 +2262,7 @@ def _emit_window_span(meta, lanes: int, n_valid: int, failed: bool,
         t_dispatch_start=meta.t_dispatch_start,
         stage_thread=meta.stage_thread, tiles_live=meta.tiles_live,
         epilogue_counters_s=_COUNTERS_S[0],
+        vrf_proofs=lanes * meta.proofs,
         **(meta.census._asdict() if meta.census is not None else {}),
     ))
 
@@ -2091,6 +2276,8 @@ class _StageCensus(NamedTuple):
     kes_tails: int  # rows of the KES tail table before padding
     thr_rows: int  # rows of the threshold table before padding
     prechecks_s: float  # span `stage.prechecks`
+    overlay_lanes: int = 0  # TPraos: live lanes in active overlay slots
+    overlay_s: float = 0.0  # TPraos: span `stage.overlay`
 
 
 def _stage_census(hvs, pre, packed, prechecks_s: float) -> _StageCensus:
@@ -2105,7 +2292,12 @@ def _stage_census(hvs, pre, packed, prechecks_s: float) -> _StageCensus:
         parr = packed[1]
         kes_tails = int(parr.kes_tail_idx.max()) + 1
         thr_rows = int(parr.thr_idx.max()) + 1
-    return _StageCensus(issuers, kes_tails, thr_rows, prechecks_s)
+    census = _StageCensus(issuers, kes_tails, thr_rows, prechecks_s)
+    if isinstance(pre, ColumnChecks) and pre.overlay is not None:
+        census = census._replace(
+            overlay_lanes=int(np.count_nonzero(pre.overlay)),
+            overlay_s=pre.overlay_s)
+    return census
 
 
 class _StagedWindow(NamedTuple):
@@ -2170,6 +2362,8 @@ def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
     # exactly like a real mid-prepare death; disarmed it is one module
     # bool test
     chaos.fire("stage")
+    rules = rules_of(params)
+    hvs = rules.window(hvs)
     b = len(hvs)
     size = bucket_size(b) if lanes is None or lanes < b else lanes
     if window is None:
@@ -2178,7 +2372,7 @@ def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
     t0 = time.monotonic()
     with _enclose("stage", window):
         with _enclose("stage.prechecks"):
-            pre = host_prechecks(params, lview, hvs)
+            pre = rules.prechecks(params, lview, hvs)
         t_pre = time.monotonic()
         packed = None
         gate = None
@@ -2201,6 +2395,11 @@ def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
         census = (_stage_census(hvs, pre, packed, t_pre - t0)
                   if BATCH_TRACER is not None else None)
         if packed is None:
+            if rules.packed_only:
+                raise RuntimeError(
+                    f"a {rules.name} window left the packed path "
+                    f"({gate}): it has no other device path"
+                )
             batch = stage_any(params, lview, eta0, hvs, pre)
             padded = pad_batch_to(batch, size)
             h2d = _nbytes(flatten_batch(padded))
@@ -2261,7 +2460,8 @@ def dispatch_prepared(sw: _StagedWindow):
             return pre, _Dispatched("agg", True, (layout, parr, out),
                                     meta), b
         impl, out, tiles_live = _dispatch_packed_lanes(layout, parr, b)
-        meta = _win_meta("packed", None, sw, t_d0, tiles_live)
+        meta = _win_meta("packed", None, sw, t_d0, tiles_live,
+                         layout.proofs)
         return pre, _Dispatched(impl, True, out, meta), b
 
 
@@ -2288,13 +2488,7 @@ def dispatch_batch(params, lview, eta0, hvs):
     composition of `prepare_window` + `dispatch_prepared`; the
     pipelined validate_chain loop calls the halves separately so a
     producer thread can stage ahead of dispatch."""
-    return dispatch_prepared(  # octflow: disable=FLOW304 — public
-        # composition seam with no in-package caller: the pipelined
-        # loops call the halves separately (and ride the supervisor);
-        # an external caller of the inline form owns its own recovery,
-        # exactly like calling dispatch_prepared directly
-        prepare_window(params, lview, eta0, hvs)
-    )
+    return dispatch_prepared(prepare_window(params, lview, eta0, hvs))
 
 
 class PackedVerdicts:
@@ -2343,7 +2537,7 @@ class PackedVerdicts:
         """Transfer the per-lane arrays and rebuild the classic Verdicts
         (the slow-path contract of `_epilogue`/`_lane_error`)."""
         if self._full is None:
-            flags, eta, lv = self._handles
+            flags, eta, lv, *aux = self._handles
             f = np.asarray(flags)
             b = self.b
             if self.impl == "pk":
@@ -2352,15 +2546,12 @@ class PackedVerdicts:
             else:
                 eta_np = np.asarray(eta)[:b]
                 lv_np = np.asarray(lv)[:b]
-            self._full = Verdicts(
-                ok_ocert_sig=f[0, :b] != 0,
-                ok_kes_sig=f[1, :b] != 0,
-                ok_vrf=f[2, :b] != 0,
-                ok_leader=f[3, :b] != 0,
-                leader_ambiguous=f[4, :b] != 0,
-                eta=eta_np,
-                leader_value=lv_np,
-            )
+            rows = [f[r, :b] != 0 for r in range(5)]
+            if aux:  # a TPraos window: which proof held
+                self._full = TPraosVerdicts(
+                    *rows, eta_np, lv_np, np.asarray(aux[0])[0, :b] != 0)
+            else:
+                self._full = Verdicts(*rows, eta_np, lv_np)
         return self._full
 
 
@@ -2406,7 +2597,7 @@ def materialize_verdicts(tagged, b):
 
 
 def _materialize_packed(out, b, impl, window=None):
-    (masks_d, eta_d), flags, eta, lv = out
+    (masks_d, eta_d), *handles = out  # flags, eta, lv (+ TPraos's vrf_ok)
     # the wait for the device and the D2H copies as two spans: a device
     # still busy reads as `wait`, a transfer that holds the next window
     # back as `copy` (the copies below would block on them anyway)
@@ -2415,7 +2606,7 @@ def _materialize_packed(out, b, impl, window=None):
     with _enclose("materialize.copy", window):
         masks = np.asarray(masks_d)
         eta_all = np.asarray(eta_d)  # the padded column: what crosses
-    pv = PackedVerdicts(masks, b, impl, eta_all[:b], (flags, eta, lv))
+    pv = PackedVerdicts(masks, b, impl, eta_all[:b], tuple(handles))
     _emit_transfer("materialize", lanes=b, packed=True, window=window,
                    d2h_bytes=masks.nbytes + eta_all.nbytes)
     return pv
@@ -2485,27 +2676,26 @@ def _epilogue_packed_fast(
     if any(e is not None for e in pre.vrf_lookup_errors):
         return None
     st = ticked.state
-    lview = ticked.ledger_view
+    known = rules_of(params).counter_known(ticked.ledger_view)
     counters = dict(st.ocert_counters)
     with _counters_span():
         for hv in hvs:
             hk = hash_key(hv.vk_cold)
             if not _counter_ok(
-                _counter_m(hk, counters, lview.pool_distr), hv.ocert.counter
+                _counter_m(hk, counters, known), hv.ocert.counter
             ):
                 return None  # slow path reconstructs the exact error
             counters[hk] = hv.ocert.counter
     evolving, candidate = _fold_nonces(
         params, st, [hv.slot for hv in hvs], v.eta_u8
     )
-    state = PraosState(
+    state = replace(
+        st,
         last_slot=hvs[-1].slot,
         ocert_counters=counters,
         evolving_nonce=evolving,
         candidate_nonce=candidate,
-        epoch_nonce=st.epoch_nonce,
         lab_nonce=nonces.prev_hash_to_nonce(hvs[-1].prev_hash),
-        last_epoch_block_nonce=st.last_epoch_block_nonce,
     )
     return BatchResult(state, len(hvs), None, None)
 
@@ -2571,11 +2761,11 @@ def _epilogue_columns_fast(
     if not _verdicts_clean(v, b):
         return None
     st = ticked.state
-    lview = ticked.ledger_view
     with _counters_span():
         counters = _counters_gate(
             vc.ocert_counter, pre.uniq_inv, pre.uniq_hk,
-            st.ocert_counters, lview.pool_distr,
+            st.ocert_counters,
+            rules_of(params).counter_known(ticked.ledger_view),
         )
     if counters is None:
         return None
@@ -2588,14 +2778,13 @@ def _epilogue_columns_fast(
 
     last = b - 1
     prev = vc.prev_hash[last].tobytes() if vc.has_prev[last] else None
-    state = PraosState(
+    state = replace(
+        st,
         last_slot=int(vc.slot[last]),
         ocert_counters=counters,
         evolving_nonce=evolving,
         candidate_nonce=candidate,
-        epoch_nonce=st.epoch_nonce,
         lab_nonce=nonces.prev_hash_to_nonce(prev),
-        last_epoch_block_nonce=st.last_epoch_block_nonce,
     )
     return BatchResult(state, b, None, None)
 
@@ -2611,8 +2800,9 @@ def _epilogue(
 ) -> BatchResult:
     """Sequential epilogue: counters + nonce fold, stop at first failure.
 
-    `lane_error` defaults to the Praos `_lane_error`; TPraos passes an
-    overlay-aware variant (protocol/tpraos.py). A PackedVerdicts `v`
+    `lane_error` defaults to the protocol's own (`rules_of(params)`:
+    the Praos `_lane_error`, or TPraos's overlay-aware one with the
+    genesis delegates' counter default). A PackedVerdicts `v`
     first tries the bitmask fast path (_epilogue_packed_fast) and only
     materializes the per-lane columns when a gate trips. A ViewColumns
     window first tries the fully-columnar fast path; HeaderViews
@@ -2636,8 +2826,11 @@ def _epilogue(
             if res is not None:
                 return res
         v = v.full()
-    if lane_error is None:
-        lane_error = _lane_error
+    rules = rules_of(params)
+    default_errors = lane_error is None
+    if default_errors:
+        lane_error = rules.lane_error
+    known = rules.counter_known(ticked.ledger_view)
     lview = ticked.ledger_view
     eta0 = ticked.state.epoch_nonce
     st = ticked.state
@@ -2654,9 +2847,7 @@ def _epilogue(
     # where every verdict bit is set and no precomputed error exists
     # only need the stateful counter-monotonicity check — `lane_error`
     # is the slow path that reconstructs the exact reference error.
-    # (TPraos passes its own lane_error with different counter
-    # semantics: it always takes the full path.)
-    if lane_error is _lane_error:
+    if default_errors:
         fast_ok = (
             np.asarray(v.ok_ocert_sig) & np.asarray(v.ok_kes_sig)
             & np.asarray(v.ok_vrf) & np.asarray(v.ok_leader)
@@ -2672,7 +2863,7 @@ def _epilogue(
             and pre.vrf_lookup_errors[i] is None
         ):
             hk = hash_key(hv.vk_cold)
-            m = _counter_m(hk, counters, lview.pool_distr)
+            m = _counter_m(hk, counters, known)
             if _counter_ok(m, hv.ocert.counter):
                 err = None
             else:
@@ -2680,14 +2871,13 @@ def _epilogue(
         else:
             err = lane_error(params, lview, eta0, hv, pre, v, i, counters)
         if err is not None:
-            state = PraosState(
+            state = replace(
+                st,
                 last_slot=last_slot,
                 ocert_counters=counters,
                 evolving_nonce=evolving,
                 candidate_nonce=candidate,
-                epoch_nonce=st.epoch_nonce,
                 lab_nonce=lab,
-                last_epoch_block_nonce=st.last_epoch_block_nonce,
             )
             return BatchResult(state, i, err, states_out)
         # reupdate bookkeeping (Praos.hs:468-502) with the device-computed
@@ -2703,27 +2893,102 @@ def _epilogue(
         last_slot = slot
         if states_out is not None:
             states_out.append(
-                PraosState(
+                replace(
+                    st,
                     last_slot=last_slot,
                     ocert_counters=dict(counters),
                     evolving_nonce=evolving,
                     candidate_nonce=candidate,
-                    epoch_nonce=st.epoch_nonce,
                     lab_nonce=lab,
-                    last_epoch_block_nonce=st.last_epoch_block_nonce,
                 )
             )
 
-    state = PraosState(
+    state = replace(
+        st,
         last_slot=last_slot,
         ocert_counters=counters,
         evolving_nonce=evolving,
         candidate_nonce=candidate,
-        epoch_nonce=st.epoch_nonce,
         lab_nonce=lab,
-        last_epoch_block_nonce=st.last_epoch_block_nonce,
     )
     return BatchResult(state, len(hvs), None, states_out)
+
+
+class PraosRules:
+    """What the window loop, the staging and the epilogue are
+    parameterised by: a protocol's host prechecks, whether its windows
+    have another device path than the packed one, who has an OCert
+    counter before they have issued, its error taxonomy, its host-side
+    backends and its sequential reference. One loop, one staging and one
+    epilogue serve every protocol that gives these (`rules_of(params)`;
+    protocol/tpraos.TPraosRules is the other one), and it is the ONE
+    place a caller learns the protocol of a chain from: the tools' forge
+    and config ask it too (`tick`, `reupdate`, `overlay`, `protocol`)."""
+
+    name = "praos"
+    protocol = "Praos"  # the name in a chain DB's config (tools/config)
+    packed_only = False  # windows may fall back to the staged columns
+    overlay = False  # no BFT overlay schedule: every slot is the lottery's
+
+    def initial_state(self) -> PraosState:
+        return PraosState()
+
+    def tick(self, params, lview, slot, state):
+        return praos.tick(params, lview, slot, state)
+
+    def reupdate(self, params, hv, slot, ticked):
+        """The crypto-free fold of a trusted header (the forge's)."""
+        return praos.reupdate(params, hv, slot, ticked)
+
+    def issuers(self, lview) -> int:
+        """How many credentials may issue a block under `lview`."""
+        return len(lview.pool_distr)
+
+    def window(self, hvs):
+        """The representation a window is staged from."""
+        return hvs
+
+    def runs(self, hvs) -> list:
+        """A within-epoch run of headers as the runs `validate_batch`
+        can each take whole."""
+        return [hvs]
+
+    def prechecks(self, params, lview, hvs):
+        return host_prechecks(params, lview, hvs)
+
+    def counter_known(self, lview):
+        """Issuers whose OCert counter starts at 0 (Praos.hs:585-590)."""
+        return lview.pool_distr
+
+    def lane_error(self, *a):
+        return _lane_error(*a)
+
+    # the two host-side backends are `validate_batch`'s, reached through
+    # `rules_of(params)`: under the supervisor's ladder (recover_window
+    # -> validate_batch) like the direct calls they replaced, which a
+    # static call graph cannot see through the rules object
+    def run_native(self, params, lview, eta0, hvs, pre):
+        return run_batch_native(  # octflow: disable=FLOW304
+            params, lview, eta0, hvs, pre)
+
+    def run_sharded(self, params, lview, eta0, hvs, pre, mesh):
+        from ..parallel import spmd
+
+        return spmd.sharded_stage_run(  # octflow: disable=FLOW304
+            params, lview, eta0, hvs, pre, mesh)[0]
+
+    def update(self, params, hv, slot, ticked):
+        """The sequential reference (the recovery ladder's last rung)."""
+        return praos.update(params, hv, slot, ticked)
+
+
+PRAOS_RULES = PraosRules()
+
+
+def rules_of(params) -> PraosRules:
+    """The protocol a run of headers is validated under: the params say
+    (a `TPraosParams` carries `batch_rules`)."""
+    return getattr(params, "batch_rules", PRAOS_RULES)
 
 
 def validate_chain(
@@ -2944,6 +3209,9 @@ def _device_loop(
     # HERE, on the dispatching thread: the staging thread does not see
     # this thread's recovery overrides
     lanes = window_lanes(max_batch)
+    # the protocol's prechecks, wire format and epilogue: what this loop
+    # is parameterised by (`prepare_window` and `_epilogue` ask again)
+    rules = rules_of(params)
 
     # Backpressure at pipeline_depth on EACH side of the double buffer:
     # up to pipeline_depth windows staged-but-undispatched AND up to
@@ -2956,6 +3224,7 @@ def _device_loop(
     total_valid = 0
 
     stream_done = False
+    pending: deque = deque()  # runs of a pulled piece still to be cut
     piece = None  # the piece being cut (None: exhausted, pull the next)
     cuts: list = []  # its [(epoch, start, end)], and where the cut stands
     k = w = 0
@@ -3001,6 +3270,10 @@ def _device_loop(
             # dispatch immediately — the round-9 loop exactly
             else not staged and len(inflight) < pipeline_depth
         ):
+            if piece is None and pending:
+                nxt = pending.popleft()
+                cuts = _epoch_segments_idx(params, nxt)
+                piece, k, w = nxt, 0, cuts[0][1]
             if piece is None:
                 if stream_done:
                     return
@@ -3023,6 +3296,12 @@ def _device_loop(
                     stream_done = True
                     return
                 progress += 1
+                if not len(nxt):
+                    continue
+                # the runs the protocol's windows can be cut from (a
+                # piece whole, but for a TPraos list of ragged views)
+                nxt, *more = rules.runs(nxt)
+                pending.extend(more)
                 cuts = _epoch_segments_idx(params, nxt)
                 if not cuts:
                     continue
@@ -3142,7 +3421,8 @@ def _device_loop(
             # (enqueue_staging), and a staged head is dispatched
             # whenever nothing is in flight (drain_dispatch): only the
             # end of the stream leaves nothing in flight here
-            assert stream_done and piece is None and not staged
+            assert (stream_done and piece is None and not staged
+                    and not pending)
             return BatchResult(state, total_valid, None)
 
         # refill the staging side BEFORE blocking on the retire below:

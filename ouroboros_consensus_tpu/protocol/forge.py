@@ -563,6 +563,66 @@ def _elect_window_reference(params, pools, lview, slots,
     return out
 
 
+def tpraos_delegate_credentials(lview, pools) -> list[int]:
+    """Index into `pools` of each genesis delegate's credentials, in the
+    ledger view's order (an overlay slot's block is its delegate's)."""
+    by_cold = {p.vk_cold: i for i, p in enumerate(pools)}
+    try:
+        return [by_cold[d.vk_cold] for d in lview.gen_delegs]
+    except KeyError:
+        raise ValueError(
+            "a genesis delegate of the ledger view has no credentials "
+            "among the forging pools"
+        ) from None
+
+
+def elect_slot_tpraos(params, lview, pools, slot: int, eta0,
+                      deleg_creds=None) -> Elected | None:
+    """Who forges `slot` of a TPraos chain, and both its certificates:
+    an active overlay slot is its genesis delegate's, an inactive one
+    stays empty, any other slot is the lottery's (the first pool in list
+    order whose raw 64-byte leader output wins under 2^512).
+    `deleg_creds` is `tpraos_delegate_credentials(lview, pools)` where
+    the caller elects many slots."""
+    from . import tpraos
+
+    assign = tpraos.overlay_slot_assignment(
+        params, len(lview.gen_delegs), slot)
+    if assign is not None:
+        active, j = assign
+        if not active:
+            return None
+        if deleg_creds is None:
+            deleg_creds = tpraos_delegate_credentials(lview, pools)
+        i = deleg_creds[j]
+        return Elected(slot, i, tpraos.prove_certificates(
+            pools[i].vrf_seed, slot, eta0))
+    f = params.active_slot_coeff
+    for i, pool in enumerate(pools):
+        entry = lview.pool_distr.get(pool.pool_id)
+        if entry is None:
+            continue  # no stake this epoch (every delegate: none ever)
+        leader = tpraos.leader_certificate(pool.vrf_seed, slot, eta0)
+        if tpraos.wins_lottery(leader[0], entry.stake, f):
+            return Elected(slot, i, tpraos.prove_certificates(
+                pool.vrf_seed, slot, eta0, leader))
+    return None
+
+
+def elect_window_tpraos(params, lview, pools, slots, eta0) -> list[Elected]:
+    """A window's TPraos election, on the host: `elect_slot_tpraos` a
+    slot (one leader proof a lottery slot and pool; N-pool chains want
+    the leader-value kernel, ops/pk/elect.py, taught this protocol's
+    value: ROADMAP Reach 5)."""
+    deleg_creds = tpraos_delegate_credentials(lview, pools)
+    out = []
+    for s in slots:
+        el = elect_slot_tpraos(params, lview, pools, s, eta0, deleg_creds)
+        if el is not None:
+            out.append(el)
+    return out
+
+
 def elect_window(params, pools, stg, thr, slots, eta0,
                  engine: str) -> list[Elected]:
     """One window's election dispatch (the `forge-dispatch` chaos
@@ -729,6 +789,8 @@ class BlockAssembler:
             body_hash=body_hash(txs),
             ocert=ocert,
             protocol_version=protocol_version,
+            vrf_leader_output=is_leader.vrf_leader_output,
+            vrf_leader_proof=is_leader.vrf_leader_proof,
         )
         leaf_seed, tail = self._leaf(pool_i, kp - kp0)
         kes_sig = fast.ed25519_sign(leaf_seed, body.signed_bytes) + tail

@@ -55,11 +55,16 @@ def _exp_interval(lo: Fraction, hi: Fraction, terms: int) -> tuple[Fraction, Fra
     return lo_sum, hi_sum + hi_rem
 
 
-def check_leader_value(leader_value: int, sigma: Fraction, active_slot_coeff: Fraction) -> bool:
+def check_leader_value(leader_value: int, sigma: Fraction,
+                       active_slot_coeff: Fraction,
+                       value_max: int = LEADER_VALUE_MAX) -> bool:
     """True iff `leader_value` wins the slot for relative stake `sigma`.
 
     active_slot_coeff is f in (0, 1]; f == 1 means every slot is active for
     everyone (reference: activeSlotVal == maxBound short-circuit).
+    `value_max` is the range of the leader value: 2^256 for Praos's
+    hashed value, 2^512 for TPraos, whose value is the raw 64-byte VRF
+    output (cardano-protocol-tpraos `checkLeaderValue`).
     """
     f = Fraction(active_slot_coeff)
     sigma = Fraction(sigma)
@@ -68,7 +73,7 @@ def check_leader_value(leader_value: int, sigma: Fraction, active_slot_coeff: Fr
     if sigma == 0:
         # exp(0) = 1 and 1/(1-p) >= 1 always: never a leader
         return False
-    lhs = Fraction(LEADER_VALUE_MAX, LEADER_VALUE_MAX - leader_value)
+    lhs = Fraction(value_max, value_max - leader_value)
     for terms in (8, 16, 32, 64, 128):
         llo, lhi = _neg_log1m_interval(f, terms)
         xlo, xhi = sigma * llo, sigma * lhi

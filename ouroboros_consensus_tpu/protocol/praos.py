@@ -328,11 +328,15 @@ def validate_vrf_signature(
 
 
 def reupdate(
-    params: PraosParams, hv: HeaderView, slot: int, ticked: TickedPraosState
+    params: PraosParams, hv: HeaderView, slot: int, ticked: TickedPraosState,
+    eta: bytes | None = None,
 ) -> PraosState:
-    """reupdateChainDepState (Praos.hs:468-502): bookkeeping, no crypto."""
+    """reupdateChainDepState (Praos.hs:468-502): bookkeeping, no crypto.
+    `eta` is the header's nonce contribution where it is not Praos's
+    (TPraos: Blake2b-256 of the nonce certificate's output)."""
     cs = ticked.state
-    eta = nonces.vrf_nonce_value(hv.vrf_output)
+    if eta is None:
+        eta = nonces.vrf_nonce_value(hv.vrf_output)
     new_evolving = nonces.combine(cs.evolving_nonce, eta)
     first_slot_next_epoch = params.first_slot_of(params.epoch_of(slot) + 1)
     within_stability = slot + params.stability_window < first_slot_next_epoch
@@ -384,6 +388,10 @@ class PraosIsLeader:
 
     vrf_output: bytes  # 64
     vrf_proof: bytes  # 80 (draft-03) or 128 (batch-compatible)
+    # TPraos proves two certificates a block: the two above are then the
+    # nonce certificate and these the leader certificate (tpraos.py)
+    vrf_leader_output: bytes | None = None
+    vrf_leader_proof: bytes | None = None
 
 
 def check_is_leader(

@@ -68,6 +68,20 @@ class HeaderView:
     slot: int
     signed_bytes: bytes  # KES-signed representation (header body CBOR)
     kes_sig: bytes  # CompactSum signature (64 + 32 + 32*depth)
+    # TPraos (Shelley..Alonzo) headers carry TWO certified VRF results
+    # under the one VRF key (BHBody bheaderEta / bheaderL): there
+    # `vrf_output`/`vrf_proof` above are the NONCE certificate and these
+    # the LEADER certificate. None on a Praos header (one certificate
+    # serves both).
+    vrf_leader_output: bytes | None = None  # 64
+    vrf_leader_proof: bytes | None = None  # 80 (draft-03)
+
+
+def _no_leader_cert(n: int) -> tuple[np.ndarray, np.ndarray]:
+    """The leader-certificate columns of a window of one-certificate
+    (Praos) headers: zero-width, so they slice and concatenate like any
+    column and cost nothing."""
+    return np.zeros((n, 0), np.uint8), np.zeros((n, 0), np.uint8)
 
 
 @dataclass
@@ -106,6 +120,21 @@ class ViewColumns:
     ocert_sigma: np.ndarray  # [n, 64] uint8
     kes_sig: np.ndarray  # [n, 96 + 32*depth] uint8
     signed_bytes: np.ndarray  # [n, body_len] uint8
+    # the TPraos leader certificate (HeaderView.vrf_leader_*): [n, 64] and
+    # [n, 80], or zero-width on a window of one-certificate headers
+    vrf_leader_output: np.ndarray = None  # type: ignore[assignment]
+    vrf_leader_proof: np.ndarray = None  # type: ignore[assignment]
+
+    def __post_init__(self):
+        if self.vrf_leader_output is None:
+            self.vrf_leader_output, self.vrf_leader_proof = _no_leader_cert(
+                int(self.slot.shape[0])
+            )
+
+    @property
+    def two_certs(self) -> bool:
+        """True on a window of TPraos (two-certificate) headers."""
+        return self.vrf_leader_output.shape[1] != 0
 
     def __len__(self) -> int:
         return int(self.slot.shape[0])
@@ -137,6 +166,14 @@ class ViewColumns:
             slot=int(self.slot[i]),
             signed_bytes=self.signed_bytes[i].tobytes(),
             kes_sig=self.kes_sig[i].tobytes(),
+            vrf_leader_output=(
+                self.vrf_leader_output[i].tobytes() if self.two_certs
+                else None
+            ),
+            vrf_leader_proof=(
+                self.vrf_leader_proof[i].tobytes() if self.two_certs
+                else None
+            ),
         )
 
     def views(self) -> list[HeaderView]:
@@ -162,6 +199,9 @@ class ViewColumns:
         counters = self.ocert_counter.tolist()
         periods = self.ocert_kes_period.tolist()
         plens = self.vrf_proof_len.tolist()
+        two = self.two_certs
+        lout_b = np.ascontiguousarray(self.vrf_leader_output).tobytes()
+        lprf_b = np.ascontiguousarray(self.vrf_leader_proof).tobytes()
         out = []
         for i in range(n):
             o32 = 32 * i
@@ -180,6 +220,8 @@ class ViewColumns:
                 slot=slots[i],
                 signed_bytes=sgn_b[sw * i:sw * (i + 1)],
                 kes_sig=kes_b[kw * i:kw * (i + 1)],
+                vrf_leader_output=lout_b[64 * i:64 * i + 64] if two else None,
+                vrf_leader_proof=lprf_b[80 * i:80 * i + 80] if two else None,
             ))
         return out
 
@@ -192,7 +234,7 @@ class ViewColumns:
             return parts[0]
         if len({p.signed_bytes.shape[1] for p in parts}) > 1 or len(
             {p.kes_sig.shape[1] for p in parts}
-        ) > 1:
+        ) > 1 or len({p.two_certs for p in parts}) > 1:
             return None
         return cls(*(
             np.concatenate([getattr(p, f.name) for p in parts], axis=0)
@@ -238,7 +280,21 @@ class ViewColumns:
             ocert_sigma=sigma,
             kes_sig=kes,
             signed_bytes=body,
+            **cls._leader_cert_of(hc, s),
         )
+
+    @staticmethod
+    def _leader_cert_of(hc, s: slice) -> dict:
+        """The leader-certificate columns of a chunk scan's rows `s`
+        (HeaderColumns or SidecarColumns): present where every row of
+        the range carries one (a chain is one protocol; a range that
+        mixes the two header shapes reads as one-certificate and its
+        TPraos rows fail their checks loudly)."""
+        two = getattr(hc, "vrf_two", None)
+        if two is None or not len(two[s]) or not two[s].all():
+            return {}
+        return dict(vrf_leader_output=hc.vrf_leader_output[s],
+                    vrf_leader_proof=hc.vrf_leader_proof[s])
 
     @classmethod
     def pieces_from_header_columns(cls, hc) -> "list[ViewColumns] | None":
@@ -247,7 +303,10 @@ class ViewColumns:
         steps move the signed-body length a few times per chain). None
         when even a uniform-width run cannot columnarize (malformed
         sigma width) — the caller streams per-view lists instead."""
-        widths = np.stack([hc.sig_len, hc.kes_len, hc.sgn_len], axis=1)
+        widths = np.stack(
+            [hc.sig_len, hc.kes_len, hc.sgn_len,
+             hc.vrf_two.astype(np.int64)], axis=1,
+        )
         chg = np.flatnonzero((widths[1:] != widths[:-1]).any(axis=1)) + 1
         bounds = [0, *chg.tolist(), hc.n]
         out = []
@@ -277,6 +336,12 @@ class ViewColumns:
             proof[i, : plen[i]] = np.frombuffer(hv.vrf_proof, np.uint8)
         sw = len(hvs[0].signed_bytes)
         if any(len(hv.signed_bytes) != sw for hv in hvs):
+            return None
+        two = hvs[0].vrf_leader_proof is not None
+        if any((hv.vrf_leader_proof is not None) != two for hv in hvs):
+            return None
+        if two and any(len(hv.vrf_leader_proof) != 80
+                       or len(hv.vrf_leader_output) != 64 for hv in hvs):
             return None
 
         def col(get, w):
@@ -308,6 +373,10 @@ class ViewColumns:
             ocert_sigma=col(lambda hv: hv.ocert.sigma, 64),
             kes_sig=col(lambda hv: hv.kes_sig, kw),
             signed_bytes=col(lambda hv: hv.signed_bytes, sw),
+            **(dict(
+                vrf_leader_output=col(lambda hv: hv.vrf_leader_output, 64),
+                vrf_leader_proof=col(lambda hv: hv.vrf_leader_proof, 80),
+            ) if two else {}),
         )
 
 

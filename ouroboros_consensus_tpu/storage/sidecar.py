@@ -83,6 +83,11 @@ FLAG_UNIFORM = 1
 # Unwalked seals (a shallow replay's backfill) keep the full sweep —
 # rot that predates the build would otherwise change the verdict.
 FLAG_WALKED = 2
+# Every row is a TPraos (two-certificate) header: the leader
+# certificate's [n, 64] output and [n, 80] proof matrices close the
+# payload. A Praos chunk's sidecar is byte for byte what it was.
+FLAG_TWO_CERTS = 4
+_LEADER_CERT_BYTES = 64 + 80
 
 # magic, version, flags, n, kes_w, sgn_w, chunk_len, chunk_crc,
 # payload_crc, layout digest
@@ -221,6 +226,9 @@ def build_bytes(hc, chunk_bytes, walked: bool = False) -> bytes | None:
     )
     kes_w = int(hc.kes_len[0]) if uniform else 0
     sgn_w = int(hc.sgn_len[0]) if uniform else 0
+    two = np.asarray(hc.vrf_two).astype(bool)
+    if two.any() and not two.all():
+        return None  # one header shape a chunk, or the parse owns it
     cols = {
         "slot": hc.slot,
         "prev_hash": hc.prev_hash,
@@ -259,6 +267,12 @@ def build_bytes(hc, chunk_bytes, walked: bool = False) -> bytes | None:
             flags |= FLAG_UNIFORM
             parts.append(np.ascontiguousarray(kes, np.uint8).tobytes())
             parts.append(np.ascontiguousarray(sgn, np.uint8).tobytes())
+    if two.all():
+        flags |= FLAG_TWO_CERTS
+        parts.append(
+            np.ascontiguousarray(hc.vrf_leader_output, np.uint8).tobytes())
+        parts.append(
+            np.ascontiguousarray(hc.vrf_leader_proof, np.uint8).tobytes())
     payload = b"".join(parts)
     header = _HEADER.pack(
         MAGIC, VERSION, flags, n, kes_w, sgn_w,
@@ -355,6 +369,8 @@ def _payload_size(n: int, kes_w: int, sgn_w: int, flags: int) -> int:
     size = n * _ROW_BYTES
     if flags & FLAG_UNIFORM:
         size += n * (kes_w + sgn_w)
+    if flags & FLAG_TWO_CERTS:
+        size += n * _LEADER_CERT_BYTES
     return size
 
 
@@ -391,6 +407,9 @@ class SidecarColumns:
     kes_sig: np.ndarray | None = None
     signed_bytes: np.ndarray | None = None
     walked: bool = False
+    # FLAG_TWO_CERTS: the TPraos leader certificate, [n, 64] / [n, 80]
+    vrf_leader_output: np.ndarray | None = None
+    vrf_leader_proof: np.ndarray | None = None
     _keepalive: tuple = field(default=(), repr=False)
 
     def pieces(self, data) -> list | None:
@@ -421,6 +440,10 @@ class SidecarColumns:
                 ocert_sigma=a["ocert_sigma"][lo:hi],
                 kes_sig=kes,
                 signed_bytes=sgn,
+                **({} if self.vrf_leader_output is None else dict(
+                    vrf_leader_output=self.vrf_leader_output[lo:hi],
+                    vrf_leader_proof=self.vrf_leader_proof[lo:hi],
+                )),
             )
 
         if self.uniform:
@@ -496,10 +519,18 @@ def load_sidecar(fs, db_dir: str, chunk: int, chunk_bytes,
         sgn = np.frombuffer(
             buf, np.uint8, count=n * sgn_w, offset=off
         ).reshape(n, sgn_w)
+        off += n * sgn_w
+    lout = lprf = None
+    if flags & FLAG_TWO_CERTS:
+        lout = np.frombuffer(
+            buf, np.uint8, count=n * 64, offset=off).reshape(n, 64)
+        lprf = np.frombuffer(
+            buf, np.uint8, count=n * 80, offset=off + n * 64).reshape(n, 80)
     sc = SidecarColumns(
         n=n, uniform=bool(flags & FLAG_UNIFORM), arrays=arrays,
         kes_sig=kes, signed_bytes=sgn,
-        walked=bool(flags & FLAG_WALKED), _keepalive=keep,
+        walked=bool(flags & FLAG_WALKED),
+        vrf_leader_output=lout, vrf_leader_proof=lprf, _keepalive=keep,
     )
     return sc, "hit"
 

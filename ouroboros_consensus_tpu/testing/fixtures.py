@@ -160,3 +160,30 @@ def forge_header_view(
         signed_bytes=body_bytes,
         kes_sig=kes_sig,
     )
+
+
+def forge_tpraos_header_view(
+    params: PraosParams,
+    pool: PoolCredentials,
+    slot: int,
+    epoch_nonce: nonces.Nonce,
+    prev_hash: bytes | None,
+    body_bytes: bytes = b"",
+    ocert_counter: int = 0,
+) -> HeaderView:
+    """`forge_header_view` for a TPraos (Shelley-era) header: the nonce
+    and the leader certificate, both 80-byte draft-03 proofs under the
+    pool's one VRF key (protocol/tpraos.prove_certificates). The leader
+    check and the overlay schedule are the caller's to consult."""
+    from dataclasses import replace
+
+    from ..protocol import tpraos
+
+    hv = forge_header_view(params, pool, slot, epoch_nonce, prev_hash,
+                           body_bytes, ocert_counter)
+    il = tpraos.prove_certificates(pool.vrf_seed, slot, epoch_nonce)
+    return replace(
+        hv, vrf_output=il.vrf_output, vrf_proof=il.vrf_proof,
+        vrf_leader_output=il.vrf_leader_output,
+        vrf_leader_proof=il.vrf_leader_proof,
+    )

@@ -28,6 +28,22 @@ from jax import numpy as jnp
 from ..ops import blake2b
 
 
+def stub_verify_tpraos(*cols):
+    """`stub_verify` for a TPraos window (batch.verify_tpraos's
+    columns): eta is Blake2b-256 of the nonce output, the leader value
+    the raw leader output."""
+    from ..protocol import batch as pbatch
+
+    *_, beta_eta, beta_l, _thr_lo, _thr_hi, _overlay = cols
+    be = jnp.asarray(beta_eta).astype(jnp.int32)
+    ones = jnp.ones((be.shape[0],), bool)
+    return pbatch.TPraosVerdicts(
+        ones, ones, ones, ones, ~ones,
+        blake2b.blake2b_fixed(be, 64, 32),
+        jnp.asarray(beta_l).astype(jnp.int32), ones,
+    )
+
+
 def stub_verify(*cols):
     """All-valid crypto stub with the real eta / leader-value range
     extensions. Arity-generic (21 draft-03 / 22 batch-compatible
@@ -317,6 +333,7 @@ def install_stub_crypto(monkeypatch=None, agg_delay_s=None):
     setattr_("verify_praos", stub_verify)
     setattr_("verify_praos_bc", stub_verify)
     setattr_("verify_praos_any", stub_verify)
+    setattr_("verify_tpraos", stub_verify_tpraos)
 
     def patched_jv(bc=False):
         key = ("fn-stub", bc)

@@ -8,11 +8,14 @@ from which `mkProtocolInfo` assembles the protocol configuration.
 
 This framework's single-protocol analog:
 
-  config.json            {"Protocol": "Praos",
+  config.json            {"Protocol": "Praos" | "TPraos",
                           "GenesisFile": "genesis.json",
                           "CredentialsFile": "credentials.json"?}
   genesis.json           protocol parameters + pool distribution
-                         (verification side: what validation needs)
+                         (verification side: what validation needs);
+                         a TPraos chain's also holds `decentralisation`
+                         [num, den] and `genDelegs` (cold key + VRF key
+                         hash of each genesis delegate, in order)
   credentials.json       signing seeds per pool (synthesizer side only,
                          the analog of the bulk credentials file
                          DBSynthesizer/Run.hs loads)
@@ -28,6 +31,8 @@ import json
 import os
 from fractions import Fraction
 
+from ..protocol import batch as pbatch
+from ..protocol import tpraos
 from ..protocol.praos import PraosParams
 from ..protocol.views import IndividualPoolStake, LedgerView
 from ..testing.fixtures import PoolCredentials
@@ -67,6 +72,7 @@ def write_genesis_files(
     """Write config.json + genesis.json (+ credentials.json when signing
     material is provided). Returns the config.json path."""
     os.makedirs(dir_path, exist_ok=True)
+    rules = pbatch.rules_of(params)
     genesis = {
         "params": _params_to_json(params),
         "poolDistr": [
@@ -78,9 +84,16 @@ def write_genesis_files(
             for pid, ips in sorted(lview.pool_distr.items())
         ],
     }
+    if rules.overlay:
+        d = params.decentralization
+        genesis["decentralisation"] = [d.numerator, d.denominator]
+        genesis["genDelegs"] = [
+            {"vkCold": g.vk_cold.hex(), "vrfKeyHash": g.vrf_key_hash.hex()}
+            for g in lview.gen_delegs
+        ]
     with open(os.path.join(dir_path, "genesis.json"), "w") as f:
         json.dump(genesis, f, indent=1, sort_keys=True)
-    config = {"Protocol": "Praos", "GenesisFile": "genesis.json"}
+    config = {"Protocol": rules.protocol, "GenesisFile": "genesis.json"}
     if pools is not None:
         creds = [
             {
@@ -101,24 +114,43 @@ def write_genesis_files(
 
 
 def load_config(config_path: str):
-    """mkProtocolInfo analog: (params, ledger_view, pools|None)."""
+    """mkProtocolInfo analog: (params, ledger_view, pools|None). The
+    config's `Protocol` says what the params and the view are: a TPraos
+    chain's are `TPraosParams` / `TPraosLedgerView`, and every tool that
+    takes them (db_synthesizer.synthesize, db_analyser.revalidate)
+    forges or validates by that protocol's rules."""
     base = os.path.dirname(os.path.abspath(config_path))
     with open(config_path) as f:
         config = json.load(f)
-    if config.get("Protocol", "Praos") != "Praos":
-        raise ValueError(f"unsupported Protocol {config.get('Protocol')!r}")
+    protocol = config.get("Protocol", "Praos")
+    if protocol not in ("Praos", "TPraos"):
+        raise ValueError(
+            f"unsupported Protocol {protocol!r}: this tool takes "
+            '"Praos" and "TPraos"'
+        )
     with open(os.path.join(base, config["GenesisFile"])) as f:
         genesis = json.load(f)
     params = _params_from_json(genesis["params"])
-    lview = LedgerView(
-        pool_distr={
-            bytes.fromhex(e["poolId"]): IndividualPoolStake(
-                Fraction(e["stake"][0], e["stake"][1]),
-                bytes.fromhex(e["vrfKeyHash"]),
-            )
-            for e in genesis["poolDistr"]
-        }
-    )
+    pool_distr = {
+        bytes.fromhex(e["poolId"]): IndividualPoolStake(
+            Fraction(e["stake"][0], e["stake"][1]),
+            bytes.fromhex(e["vrfKeyHash"]),
+        )
+        for e in genesis["poolDistr"]
+    }
+    if protocol == "TPraos":
+        params = tpraos.TPraosParams(
+            params, Fraction(*genesis["decentralisation"]))
+        lview = tpraos.TPraosLedgerView(
+            pool_distr=pool_distr,
+            gen_delegs=tuple(
+                tpraos.GenDeleg(bytes.fromhex(g["vkCold"]),
+                                bytes.fromhex(g["vrfKeyHash"]))
+                for g in genesis["genDelegs"]
+            ),
+        )
+    else:
+        lview = LedgerView(pool_distr=pool_distr)
     pools = None
     if "CredentialsFile" in config:
         with open(os.path.join(base, config["CredentialsFile"])) as f:

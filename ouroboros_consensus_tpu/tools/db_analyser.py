@@ -227,6 +227,7 @@ def _views_from_columns(cols):
     kes_periods = cols.ocert_kes_period.tolist()
     slots = cols.slot.tolist()
     prf_lens = cols.vrf_proof_len.tolist()
+    two = cols.vrf_two.tolist()
     out = []
     for i in range(n):
         o32 = 32 * i
@@ -246,6 +247,12 @@ def _views_from_columns(cols):
                 slot=slots[i],
                 signed_bytes=cols.signed_bytes[i],
                 kes_sig=cols.kes_sig[i],
+                vrf_leader_output=(
+                    cols.vrf_leader_output[i].tobytes() if two[i] else None
+                ),
+                vrf_leader_proof=(
+                    cols.vrf_leader_proof[i].tobytes() if two[i] else None
+                ),
             )
         )
     return out
@@ -856,7 +863,11 @@ def _revalidate_body(
 
         return itertools.islice(_stream_views(imm, res), max_headers)
 
-    st = PraosState()
+    # the chain's protocol: the params say (a DB's own config.json makes
+    # them, tools/config.load_config), and the rules give its empty
+    # state, its sequential reference and its batched path
+    rules = pbatch.rules_of(params)
+    st = rules.initial_state()
     if ledger is not None and getattr(ledger, "view_for_epoch", None):
         # ledger-derived epoch views: stream BLOCKS (the ledger replay
         # needs tx bodies), segment at epoch boundaries, and feed each
@@ -916,7 +927,7 @@ def _revalidate_body(
         try:
             for hv in stream_views(imm, res):
                 ticked = praos.tick(params, lview, hv.slot, st)
-                st = praos.update(params, hv, hv.slot, ticked)
+                st = rules.update(params, hv, hv.slot, ticked)
                 res.n_valid += 1
         except praos.PraosValidationError as e:
             res.error = e
@@ -939,7 +950,8 @@ def _revalidate_body(
         )
         try:
             if rec_doc is not None:
-                st = _recovery.decode_state(rec_doc["state"])
+                st = type(st)(**vars(
+                    _recovery.decode_state(rec_doc["state"])))
                 res.n_valid = int(rec_doc["headers"])
                 res.resumed_headers = int(rec_doc["headers"])
                 _recovery.note_resume(rec_doc)

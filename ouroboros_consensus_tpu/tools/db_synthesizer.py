@@ -1,4 +1,5 @@
-"""db-synthesizer: forge a synthetic Praos chain as fast as possible.
+"""db-synthesizer: forge a synthetic Praos (or TPraos) chain as fast as
+possible.
 
 Reference: `Cardano.Tools.DBSynthesizer` — the `runForge` loop
 (Tools/DBSynthesizer/Forging.hs:54-57 "mirrors the forging loop from
@@ -9,6 +10,11 @@ crypto-free path — we produced the signatures ourselves).
 
 Limits mirror the reference's `ForgeLimit` (Types.hs): slot count, block
 count, or epoch count.
+
+A `TPraosParams` (with a `TPraosLedgerView` and the genesis delegates'
+credentials among `pools`) forges the Shelley-era protocol: two VRF
+certificates a block, an active overlay slot's block its delegate's, an
+inactive one left empty, the lottery elsewhere.
 """
 
 from __future__ import annotations
@@ -19,7 +25,8 @@ from dataclasses import dataclass, field
 from fractions import Fraction
 
 from ..block.forge import evaluate_vrf, forge_block
-from ..protocol import nonces, praos
+from ..protocol import batch as pbatch
+from ..protocol import nonces, tpraos
 from ..protocol.leader import check_leader_value
 from ..protocol.praos import PraosParams, PraosState
 from ..protocol.views import LedgerView
@@ -62,6 +69,24 @@ def default_params(kes_depth: int = 7) -> PraosParams:
 def make_credentials(n_pools: int, kes_depth: int = 7):
     pools = [fixtures.make_pool(i, kes_depth=kes_depth) for i in range(n_pools)]
     return pools, fixtures.make_ledger_view(pools)
+
+
+def make_tpraos(params: PraosParams, pools, lview, n_delegs: int,
+                d: Fraction, first_seed: int = 1000):
+    """A TPraos deployment over a Praos one: (TPraosParams, the pools'
+    credentials followed by the genesis delegates', TPraosLedgerView).
+    Delegate j is `fixtures.make_pool(first_seed + j)`."""
+    from ..protocol.views import hash_vrf_vk
+
+    delegs = [fixtures.make_pool(first_seed + j, kes_depth=params.kes_depth)
+              for j in range(n_delegs)]
+    view = tpraos.TPraosLedgerView(
+        pool_distr=lview.pool_distr,
+        gen_delegs=tuple(
+            tpraos.GenDeleg(g.vk_cold, hash_vrf_vk(g.vrf_vk)) for g in delegs
+        ),
+    )
+    return tpraos.TPraosParams(params, Fraction(d)), [*pools, *delegs], view
 
 
 _VRF_BUCKET = 4096
@@ -238,14 +263,15 @@ def _replay_forged_state(params, lview, imm):
     threads."""
     from ..block.praos_block import Block
 
-    st = PraosState()
+    rules = pbatch.rules_of(params)
+    st = rules.initial_state()
     prev_hash = None
     block_no = 0
     slot = 0
     for _entry, raw in imm.stream_all():
         b = Block.from_bytes(raw)
-        ticked = praos.tick(params, lview, b.slot, st)
-        st = praos.reupdate(params, b.header.to_view(), b.slot, ticked)
+        ticked = rules.tick(params, lview, b.slot, st)
+        st = rules.reupdate(params, b.header.to_view(), b.slot, ticked)
         prev_hash = b.hash_
         block_no = b.block_no + 1
         slot = b.slot + 1
@@ -264,10 +290,10 @@ def _forge_pipeline(
     state-identical to the per-slot loop below for the same inputs
     (tests/test_forge.py holds the equation); returns the threaded
     (st, prev_hash, block_no, slot)."""
-    from ..protocol import batch as pbatch
     from ..protocol import forge as forge_mod
     from ..testing import chaos
 
+    rules = pbatch.rules_of(params)
     asm = forge_mod.BlockAssembler(params, pools)
     stg = forge_mod.stage_engine(params, pools, engine)
     tracer = pbatch.BATCH_TRACER
@@ -291,7 +317,7 @@ def _forge_pipeline(
         # the whole (epoch-clamped) window's elections; the per-block
         # reupdate below re-ticks at each forged slot exactly as the
         # reference loop does
-        ticked0 = praos.tick(params, lv_now, slot, st)
+        ticked0 = rules.tick(params, lv_now, slot, st)
         eta0 = ticked0.state.epoch_nonce
         epoch_end = (params.epoch_of(slot) + 1) * params.epoch_length
         wend = min(epoch_end, slot + forge_mod.window_slots(len(pools)))
@@ -306,7 +332,11 @@ def _forge_pipeline(
         wend = max(wend, slot + 1)
         windex = forge_mod.next_window_index()
         t_el = time.monotonic()
-        if elector is not None:
+        if rules.overlay:
+            elected = forge_mod.elect_window_tpraos(
+                params, lv_now, pools, range(slot, wend), eta0
+            )
+        elif elector is not None:
             elected = forge_mod.elected_from_rows(
                 pools, elector(range(slot, wend), eta0), eta0
             )
@@ -337,7 +367,7 @@ def _forge_pipeline(
             if limit.blocks is not None and block_no >= limit.blocks:
                 break
             s = el.slot
-            ticked = praos.tick(params, lv_now, s, st)
+            ticked = rules.tick(params, lv_now, s, st)
             n = counters.get(pools[el.pool].pool_id, 0)
             if txs_for_block is not None:
                 txs = tuple(txs_for_block(s, block_no))
@@ -350,7 +380,7 @@ def _forge_pipeline(
                 txs=txs, ocert_counter=n, is_leader=el.is_leader,
             )
             imm.append_block(s, block_no, block.hash_, block.bytes_)
-            st = praos.reupdate(params, block.header.to_view(), s, ticked)
+            st = rules.reupdate(params, block.header.to_view(), s, ticked)
             counters[pools[el.pool].pool_id] = n
             prev_hash = block.hash_
             block_no += 1
@@ -392,6 +422,17 @@ def _synthesize_locked(
     # assembly whatever the lever says: there is nothing to elect here
     engine = "rows" if elector is not None else (
         forge_mod.engine_from_env(vrf_backend))
+    rules = pbatch.rules_of(params)
+    st = rules.initial_state()
+    if rules.overlay:
+        if elector is not None or ledger is not None:
+            raise ValueError("a TPraos chain is elected here, on the "
+                             "host, against a constant ledger view")
+        if "device" in (engine, vrf_backend):
+            raise ValueError("no TPraos device forge yet (no leader-"
+                             "value sweep for this protocol): pass "
+                             "vrf_backend=\"host\" and leave "
+                             "OCT_FORGE_DEVICE unset or 0")
     if ledger is not None:
         # the ledger fold derives each epoch's view from state the loop
         # itself threads — the whole-window election has no view to
@@ -406,7 +447,6 @@ def _synthesize_locked(
 
     res = ForgeResult()
     t0 = time.monotonic()
-    st = PraosState()
     prev_hash: bytes | None = None
     block_no = 0
     slot = 0
@@ -496,7 +536,7 @@ def _synthesize_locked(
             if ledger_view_for_epoch is not None
             else lview
         )
-        ticked = praos.tick(params, lv_now, slot, st)
+        ticked = rules.tick(params, lv_now, slot, st)
         eta0 = ticked.state.epoch_nonce
         if vrf_backend == "device" and slot >= span_end:
             # next span: up to the epoch boundary (eta0 is epoch-constant)
@@ -511,17 +551,28 @@ def _synthesize_locked(
                 est = int(2 * need / float(params.active_slot_coeff)) + 64
                 span_end = min(span_end, slot + est)
             span_proofs = _prove_span(pools, range(slot, span_end), eta0)
-        for pi, pool in enumerate(pools):
-            if vrf_backend == "device":
+        if rules.overlay:
+            # the overlay schedule or the 512-bit lottery says who forges
+            el = forge_mod.elect_slot_tpraos(params, lv_now, pools, slot, eta0)
+            slot_pools = [] if el is None else [(el.pool, pools[el.pool])]
+        else:
+            el, slot_pools = None, enumerate(pools)
+        for pi, pool in slot_pools:
+            if el is not None:
+                is_leader = el.is_leader
+            elif vrf_backend == "device":
                 is_leader = span_proofs[(slot, pi)]
             else:  # host: lazy per-slot evaluation (small runs)
                 is_leader = evaluate_vrf(pool, slot, eta0)
-            lv_val = nonces.vrf_leader_value(is_leader.vrf_output)
-            entry = lv_now.pool_distr.get(pool.pool_id)
-            if entry is None:
-                continue  # pool has no stake this epoch
-            if not check_leader_value(lv_val, entry.stake, params.active_slot_coeff):
-                continue
+            if el is None:
+                lv_val = nonces.vrf_leader_value(is_leader.vrf_output)
+                entry = lv_now.pool_distr.get(pool.pool_id)
+                if entry is None:
+                    continue  # pool has no stake this epoch
+                if not check_leader_value(
+                    lv_val, entry.stake, params.active_slot_coeff
+                ):
+                    continue
             n = counters.get(pool.pool_id, 0)
             if txs_for_block is not None:
                 txs = tuple(txs_for_block(slot, block_no))
@@ -546,7 +597,7 @@ def _synthesize_locked(
                 # invalid block on disk
                 lst = ledger.tick_then_apply(lst, block)
             imm.append_block(slot, block_no, block.hash_, block.bytes_)
-            st = praos.reupdate(params, block.header.to_view(), slot, ticked)
+            st = rules.reupdate(params, block.header.to_view(), slot, ticked)
             counters[pool.pool_id] = n
             prev_hash = block.hash_
             block_no += 1
@@ -603,6 +654,14 @@ def main(argv=None) -> None:
     p.add_argument("--config", default=None,
                    help="node config.json (with CredentialsFile) instead "
                         "of --pools/--kes-depth generated credentials")
+    p.add_argument("--protocol", choices=("praos", "tpraos"),
+                   default="praos",
+                   help="tpraos: the Shelley-era protocol (two VRF "
+                        "certificates a block, the BFT overlay)")
+    p.add_argument("--delegates", type=int, default=7,
+                   help="tpraos: genesis delegates (mainnet: 7)")
+    p.add_argument("--decentralisation", default="1/2",
+                   help="tpraos: d, the overlay's share of the slots")
     p.add_argument("--cardano", action="store_true",
                    help="forge the multi-era composite (era-tagged "
                         "blocks crossing the Byron/Shelley/Babbage "
@@ -635,6 +694,10 @@ def main(argv=None) -> None:
     else:
         params = default_params(kes_depth=a.kes_depth)
         pools, lview = make_credentials(a.pools, kes_depth=a.kes_depth)
+        if a.protocol == "tpraos":
+            params, pools, lview = make_tpraos(
+                params, pools, lview, a.delegates,
+                Fraction(a.decentralisation), first_seed=a.pools)
     res = synthesize(
         a.out, params, pools, lview,
         ForgeLimit(slots=a.slots, blocks=a.blocks, epochs=a.epochs),

@@ -379,6 +379,16 @@ class WindowSpan:
     # tiles behind them. 0 where it bounded none: a generic or packed-agg
     # window runs every tile, and the XLA twin has no tiles
     tiles_live: int = 0
+    # VRF proofs the device verified for the window: one a live lane
+    # under Praos, two under TPraos (the nonce and the leader
+    # certificate); exact
+    vrf_proofs: int = 0
+    # TPraos: live lanes whose slot is an active overlay slot (their
+    # issuer a genesis delegate, no threshold), and the wall of the
+    # columnar overlay pass (span `stage.overlay`, child of
+    # `stage.prechecks`, on `stage_thread`); 0 under Praos
+    overlay_lanes: int = 0
+    overlay_s: float = 0.0
 
 
 # -- the consensus event vocabulary (Tracers' record, condensed) -------------

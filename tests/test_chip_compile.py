@@ -169,6 +169,79 @@ def test_finish_compiles_with_the_kernel(one_chip, chip_seams):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@functools.lru_cache(maxsize=None)
+def _packed_tpraos():
+    """(layout, unpack-argument arrays padded to LANES) of a real TPraos
+    window: two 80-byte certificates a body, half its lanes overlay."""
+    from ouroboros_consensus_tpu.protocol import tpraos
+    from ouroboros_consensus_tpu.protocol.views import ViewColumns
+    from ouroboros_consensus_tpu.tools import db_synthesizer as synth
+
+    pool = fixtures.make_pool(0, kes_depth=KES_DEPTH)
+    params, creds, lview = synth.make_tpraos(
+        _params(), [pool], fixtures.make_ledger_view([pool]), 7,
+        Fraction(1, 2))
+    nonce = b"\x07" * 32
+    hvs, prev = [], b"\xaa" * 32
+    for i in range(8):
+        slot = 1000 + i
+        a = tpraos.overlay_slot_assignment(params, 7, slot)
+        who = creds[1 + a[1]] if a and a[0] else pool
+        blk = forge_block(
+            params, who, slot=slot, block_no=500 + i, prev_hash=prev,
+            epoch_nonce=nonce,
+            is_leader=tpraos.prove_certificates(who.vrf_seed, slot, nonce))
+        hvs.append(blk.header.to_view())
+        prev = blk.header.hash_
+    vc = ViewColumns.from_views(hvs)
+    pre = tpraos.host_prechecks(params, lview, vc)
+    layout, parr = pbatch.stage_packed_columns(params, lview, nonce, vc, pre)
+    assert layout.proofs == 2 and parr.thr_tab.shape[1] == 128
+    assert 0 < int(parr.overlay.sum()) < 8
+    return layout, pbatch.pad_packed_to(parr, LANES)
+
+
+def test_tpraos_unpack_compiles(one_chip, chip_seams):
+    """The TPraos `unpack` layout: 27 limb-first arrays out (the second
+    proof's columns, 64-byte threshold rows, the overlay row), of which
+    the `vrf` stage's two operand sets have the draft-03 program's
+    shapes: one stored executable serves both runs."""
+    layout, cols = _packed_tpraos()
+    args = [_sds(one_chip, c.shape, c.dtype) for c in map(np.asarray, cols)]
+    _compile(K._mk_packed_unpack(layout), args)
+    out = jax.eval_shape(K._mk_packed_unpack(layout), *cols)
+    assert len(out) == 27
+    n_live = jax.ShapeDtypeStruct((1,), np.int32)
+    (_, _ed), (_, _kes), (n1, v1), (n2, v2) = K.stage_operands(
+        out, n_live, layout.proofs)
+    assert n1 == n2 == "vrf"
+    assert [(a.shape, a.dtype) for a in v1] == [(a.shape, a.dtype)
+                                               for a in v2]
+    assert [a.shape for a in v1[:5]] == [
+        (32, LANES), (32, LANES), (16, LANES), (32, LANES), (32, LANES)]
+
+
+def test_finish_tp_compiles_with_the_kernel(one_chip, chip_seams):
+    """`finish_tp` for the v5e at the production lane count: twelve
+    points in the one inversion, four SHA-512 and one Blake2b a lane,
+    the 64-byte compare; under its own kernel name (the trace reads it
+    through `^jit_finish`)."""
+    s = functools.partial(_sds, one_chip)
+    args = [
+        s((1, LANES)), s((80, LANES)), s((32, LANES)),  # ed ok, point, R
+        s((1, LANES)), s((80, LANES)), s((32, LANES)),  # kes ok, point, R
+        s((1, LANES)), s((400, LANES)), s((16, LANES)),  # nonce proof
+        s((1, LANES)), s((400, LANES)), s((16, LANES)),  # leader proof
+        s((64, LANES)), s((64, LANES)),  # both declared outputs
+        s((64, LANES)), s((64, LANES)),  # 512-bit brackets
+        s((1, LANES)),  # overlay
+        _n_live(one_chip),
+    ]
+    compiled = _compile(K.finish_tp, args)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "finish_tp" in text
+
+
 def test_finish_lowers_with_its_kernel_name(one_chip, chip_seams):
     """What a device trace shows of a Pallas kernel is its `name` in the
     Mosaic custom call (tests/test_span_tree.py holds the module names).

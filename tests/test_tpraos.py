@@ -66,13 +66,24 @@ def test_overlay_schedule_density_and_assignment():
     assert tpraos.overlay_position(p0, 17) is None
 
 
+def forge_header(params, creds, slot, nonce, prev, block_no=0):
+    """One real-codec two-certificate header (the packed device path
+    reads every field out of the KES-signed body)."""
+    from ouroboros_consensus_tpu.block.forge import forge_block
+
+    return forge_block(
+        params.praos, creds, slot=slot, block_no=block_no, prev_hash=prev,
+        epoch_nonce=nonce,
+        is_leader=tpraos.prove_certificates(creds.vrf_seed, slot, nonce),
+    ).header.to_view()
+
+
 def forge_chain(params, pool, delegs, lview, n_slots):
     """Forge the deterministic TPraos chain: scheduled delegate on active
     overlay slots, the pool elsewhere (f=1 so it always wins)."""
     nonce = b"\x09" * 32
     hvs = []
     prev = None
-    counters = {}
     for slot in range(1, n_slots):
         a = tpraos.overlay_slot_assignment(params, len(delegs), slot)
         if a is None:
@@ -82,12 +93,7 @@ def forge_chain(params, pool, delegs, lview, n_slots):
             if not active:
                 continue
             creds = delegs[j]
-        c = counters.setdefault(creds.pool_id, 0)
-        hv = fixtures.forge_header_view(
-            params.praos, creds, slot=slot, epoch_nonce=nonce,
-            prev_hash=prev, body_bytes=b"body-%d" % slot,
-        )
-        hvs.append(hv)
+        hvs.append(forge_header(params, creds, slot, nonce, prev, len(hvs)))
         prev = b"%032d" % slot
     return nonce, hvs
 
@@ -145,10 +151,8 @@ def test_wrong_delegate_rejected(chain):
         if a is not None and a[0]:
             j = a[1]
             other = delegs[1 - j]
-            bad = fixtures.forge_header_view(
-                params.praos, other, slot=hv.slot, epoch_nonce=nonce,
-                prev_hash=hv.prev_hash, body_bytes=b"evil",
-            )
+            bad = forge_header(params, other, hv.slot, nonce,
+                               hv.prev_hash, idx)
             bad_hvs = list(hvs[: idx]) + [bad]
             break
     else:
@@ -185,7 +189,7 @@ def test_inactive_overlay_slot_rejected():
         s for s in range(1, 200)
         if tpraos.overlay_slot_assignment(params, 2, s) == (False, None)
     )
-    hv = fixtures.forge_header_view(
+    hv = fixtures.forge_tpraos_header_view(
         params.praos, pool, slot=slot, epoch_nonce=nonce,
         prev_hash=None, body_bytes=b"x",
     )
