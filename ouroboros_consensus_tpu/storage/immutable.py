@@ -29,10 +29,14 @@ per block — the OS page cache does the batching; `fsync` on chunk close.
 
 from __future__ import annotations
 
+import contextlib
 import os
+import sys
 import zlib
 from dataclasses import dataclass
 from typing import Callable, Iterator
+
+import numpy as np
 
 from ..block.abstract import Point
 from ..testing import chaos
@@ -64,6 +68,121 @@ class IndexEntry:
     @classmethod
     def from_cbor_obj(cls, o):
         return cls(o[0], o[1], bytes(o[2]), o[3], o[4], o[5])
+
+
+class ChunkIndex:
+    """One chunk's secondary index, held as columns from the native
+    parse to its last reader: `slot`, `block_no`, `offset`, `size`,
+    `crc32` (int64) and `hash_` ((n, 32) uint8), in `IndexEntry`'s field
+    order. A replay reads the columns; an `IndexEntry` is built only
+    where a caller asks for ONE row (`idx[i]`, iteration). A slice is a
+    view of the rows it names. The writer appends in place (capacity
+    doubling); written rows never change and a view has no spare
+    capacity, so a view stays true while the index it was cut from
+    grows, and an append to a view copies first."""
+
+    __slots__ = ("_cols", "_n")
+
+    def __init__(self, slot=(), block_no=(), hash_=(), offset=(), size=(),
+                 crc32=()):
+        n = len(slot)
+        self._cols = tuple(
+            np.asarray(c, np.uint8).reshape(n, 32) if i == 2
+            else np.asarray(c, np.int64)
+            for i, c in enumerate((slot, block_no, hash_, offset, size, crc32))
+        )
+        self._n = n
+
+    @classmethod
+    def from_entries(cls, entries) -> "ChunkIndex":
+        """The columns of a list of entries (the Python CBOR loop, an
+        index rebuilt from chunk bytes): one conversion at its end."""
+        return cls(
+            [e.slot for e in entries], [e.block_no for e in entries],
+            np.frombuffer(b"".join(e.hash_ for e in entries), np.uint8),
+            [e.offset for e in entries], [e.size for e in entries],
+            [e.crc32 for e in entries],
+        )
+
+    @property
+    def _live(self) -> tuple:
+        """The columns without the writer's spare capacity."""
+        return tuple(c[: self._n] for c in self._cols)
+
+    slot = property(lambda self: self._cols[0][: self._n])
+    block_no = property(lambda self: self._cols[1][: self._n])
+    hash_ = property(lambda self: self._cols[2][: self._n])
+    offset = property(lambda self: self._cols[3][: self._n])
+    size = property(lambda self: self._cols[4][: self._n])
+    crc32 = property(lambda self: self._cols[5][: self._n])
+
+    @property
+    def ends(self):
+        """Each block's end offset in the chunk file."""
+        return self.offset + self.size
+
+    @property
+    def end(self) -> int:
+        """The indexed end of the chunk file (0 for an empty index)."""
+        return int(self.offset[-1] + self.size[-1]) if self._n else 0
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __getitem__(self, i):
+        cols = [c[i] for c in self._live]
+        if isinstance(i, slice):
+            return ChunkIndex(*cols)
+        s, b, h, o, z, c = cols
+        return IndexEntry(int(s), int(b), h.tobytes(), int(o), int(z), int(c))
+
+    def __iter__(self) -> Iterator[IndexEntry]:
+        s, b, h, o, z, c = self._live
+        hb = h.tobytes()
+        rows = zip(s.tolist(), b.tolist(), o.tolist(), z.tolist(), c.tolist())
+        for i, (s, b, o, z, c) in enumerate(rows):
+            yield IndexEntry(s, b, hb[32 * i : 32 * i + 32], o, z, c)
+
+    def __eq__(self, other):
+        if isinstance(other, ChunkIndex):
+            return len(other) == self._n and all(
+                map(np.array_equal, self._live, other._live)
+            )
+        if isinstance(other, (list, tuple)):
+            return len(other) == self._n and all(
+                a == b for a, b in zip(self, other)
+            )
+        return NotImplemented
+
+    __hash__ = None
+
+    def __repr__(self) -> str:
+        return f"ChunkIndex({list(self)!r})"
+
+    def append(self, slot: int, block_no: int, hash_: bytes, offset: int,
+               size: int, crc32: int) -> None:
+        n = self._n
+        if n == len(self._cols[0]):
+            grown = []
+            for c in self._cols:
+                g = np.zeros((max(64, 2 * n),) + c.shape[1:], c.dtype)
+                g[:n] = c
+                grown.append(g)
+            self._cols = tuple(grown)
+        row = (slot, block_no, np.frombuffer(hash_, np.uint8), offset, size,
+               crc32)
+        for c, v in zip(self._cols, row):
+            c[n] = v
+        self._n = n + 1
+
+
+def _span(label: str):
+    """The span `label` of the replay in progress (protocol/batch's
+    `_enclose`). The tracer lives in that module: where nothing has
+    imported it there is no tracer, and this module never imports JAX
+    for the asking."""
+    pbatch = sys.modules.get("ouroboros_consensus_tpu.protocol.batch")
+    return contextlib.nullcontext() if pbatch is None else pbatch._enclose(label)
 
 
 def _chunk_name(n: int) -> str:
@@ -126,7 +245,7 @@ class ImmutableDB:
             path, self.fs, quarantine_dir
         )
         self.repairs: list[dict] = []  # repair rows of THIS open
-        self._entries: dict[int, list[IndexEntry]] = {}  # chunk -> entries
+        self._entries: dict[int, ChunkIndex] = {}  # chunk -> its index
         self._chunks: list[int] = []
         self._truncated: dict[int, bool] = {}
         self._validate(check_integrity, validate_all)
@@ -234,12 +353,12 @@ class ImmutableDB:
         ))
 
     def _repair_truncate(self, n: int, data: bytes,
-                         entries: list[IndexEntry], dropped: int = 0,
+                         entries: ChunkIndex, dropped: int = 0,
                          detail: str = "") -> None:
         """Cut chunk n's corrupted on-disk tail to `entries`:
         quarantine the snipped bytes, rewrite chunk + index — or,
         read-only, record the would-be action."""
-        end = entries[-1].offset + entries[-1].size if entries else 0
+        end = entries.end
         snip = max(0, len(data) - end)
         q = snip
         if self._repair:
@@ -260,7 +379,7 @@ class ImmutableDB:
             idx = self._load_index(
                 os.path.join(self.path, _index_name(n))
             )
-            dropped = len(idx) if idx else 0
+            dropped = 0 if idx is None else len(idx)
         q = 0
         if self._repair:
             for name in (_chunk_name(n), _index_name(n), _cols_name(n)):
@@ -281,7 +400,7 @@ class ImmutableDB:
         chunk bytes are already in hand (the stream reader just loaded
         them) — re-reading a production chunk is hundreds of MB of I/O
         on the exact path where the disk is already suspect."""
-        entries = self._entries.get(n, [])
+        entries = self._entries.get(n, ChunkIndex())
         if data is None:
             try:
                 data = self.fs.read_bytes(
@@ -305,7 +424,8 @@ class ImmutableDB:
     def _load_chunk(self, n: int, deep: bool, check_integrity):
         ipath = os.path.join(self.path, _index_name(n))
         cpath = os.path.join(self.path, _chunk_name(n))
-        entries = self._load_index(ipath)
+        with _span("open.index"):
+            entries = self._load_index(ipath)
         if entries is None:
             # index missing/corrupt (e.g. crash before flush): rebuild it
             # from the chunk data — blocks are self-delimiting CBOR
@@ -315,7 +435,7 @@ class ImmutableDB:
             return entries
         # deferred index writes mean the on-disk index can LAG the chunk
         # data after a crash: reparse any bytes past the indexed end
-        end = entries[-1].offset + entries[-1].size if entries else 0
+        end = entries.end
         try:
             fsize = self.fs.getsize(cpath)
         except OSError:
@@ -341,17 +461,10 @@ class ImmutableDB:
             else:
                 # no native library (or a custom per-block hook without a
                 # batched twin): the per-blob reference loop
-                good = []
-                for e in entries:
-                    blob = data[e.offset : e.offset + e.size]
-                    if len(blob) != e.size or zlib.crc32(blob) != e.crc32:
-                        self._truncated[n] = True
-                        break
-                    if check_integrity is not None and not check_integrity(blob):
-                        self._truncated[n] = True
-                        break
-                    good.append(e)
-                entries = good
+                good = self._deep_check_slow(data, entries, check_integrity)
+                if good < len(entries):
+                    self._truncated[n] = True
+                entries = entries[:good]
             if self._truncated.get(n):
                 self._repair_truncate(
                     n, data, entries, dropped=n_indexed - len(entries),
@@ -374,6 +487,13 @@ class ImmutableDB:
         )
         if fast is not None:
             return fast
+        return self._deep_check_slow(data, entries, check_integrity)
+
+    @staticmethod
+    def _deep_check_slow(data, entries, check_integrity) -> int:
+        """The per-blob reference loop (no native library, or a custom
+        per-block hook without a batched twin): count of good leading
+        entries."""
         good = 0
         for e in entries:
             blob = data[e.offset : e.offset + e.size]
@@ -402,10 +522,7 @@ class ImmutableDB:
         from .. import native_loader
 
         rc = native_loader.crc32_first_bad(
-            data,
-            [e.offset for e in entries],
-            [e.size for e in entries],
-            [e.crc32 for e in entries],
+            data, entries.offset, entries.size, entries.crc32
         )
         if rc is None:
             return None  # no native library
@@ -466,10 +583,12 @@ class ImmutableDB:
                 )
             )
             off = end
-        return self._finish_reparse(n, data, entries, why)
+        return self._finish_reparse(
+            n, data, ChunkIndex.from_entries(entries), why
+        )
 
     def _finish_reparse(self, n: int, data: bytes,
-                        entries: list[IndexEntry], why: str):
+                        entries: ChunkIndex, why: str):
         """Bank the rebuild and write it back (repair permitting): the
         index is reconstructed from chunk bytes; a torn chunk tail
         found on the way is truncated + quarantined too."""
@@ -485,7 +604,7 @@ class ImmutableDB:
             self._write_index(n, entries)
         return entries
 
-    def _reparse_chunk_native(self, n: int, data: bytes) -> list[IndexEntry] | None:
+    def _reparse_chunk_native(self, n: int, data: bytes) -> ChunkIndex | None:
         """Native-scanner reparse (no integrity predicate): columnar
         header extraction + hashlib blake2b for the header hashes.
         Returns None when the native library is unavailable or the
@@ -506,30 +625,33 @@ class ImmutableDB:
             )
         except ValueError:
             return None  # parseable CBOR but not our block layout
-        entries: list[IndexEntry] = []
-        for i in range(len(offsets)):
-            off, sz = int(offsets[i]), int(sizes[i])
-            # header bytes span: after the block's array(2) head (1 byte),
-            # through the end of the kes_sig item
-            hdr = data[off + 1 : int(cols.header_end[i])]
-            h = hashlib.blake2b(hdr, digest_size=32).digest()
-            entries.append(
-                IndexEntry(
-                    int(cols.slot[i]), int(cols.block_no[i]), h, off, sz,
-                    zlib.crc32(data[off : off + sz]),
-                )
-            )
         if end < len(data):
             self._truncated[n] = True  # _finish_reparse writes back
-        return entries
+        if cols is None:
+            return ChunkIndex()
+        offs = offsets.tolist()
+        # header bytes span: after the block's array(2) head (1 byte),
+        # through the end of the kes_sig item
+        hashes = b"".join(
+            hashlib.blake2b(data[off + 1 : he], digest_size=32).digest()
+            for off, he in zip(offs, cols.header_end.tolist())
+        )
+        crcs = [
+            zlib.crc32(data[off : off + sz])
+            for off, sz in zip(offs, sizes.tolist())
+        ]
+        return ChunkIndex(cols.slot, cols.block_no,
+                          np.frombuffer(hashes, np.uint8), offsets, sizes,
+                          crcs)
 
-    def _rewrite_chunk(self, n: int, data: bytes, entries: list[IndexEntry]):
+    def _rewrite_chunk(self, n: int, data: bytes, entries: ChunkIndex):
         # the chunk bytes change, so any sidecar's seal is now a lie:
         # quarantine it BEFORE the rewrite (never trusted past its
         # seal, never deleted) — the next writer replay backfills
         self._invalidate_sidecar(n)
-        end = entries[-1].offset + entries[-1].size if entries else 0
-        self.fs.write_bytes(os.path.join(self.path, _chunk_name(n)), data[:end])
+        self.fs.write_bytes(
+            os.path.join(self.path, _chunk_name(n)), data[: entries.end]
+        )
         self._write_index(n, entries)
 
     def _invalidate_sidecar(self, n: int) -> int:
@@ -544,7 +666,7 @@ class ImmutableDB:
         for name in (_chunk_name(n), _index_name(n), _cols_name(n)):
             self.fs.remove(os.path.join(self.path, name))
 
-    def _load_index(self, ipath: str) -> list[IndexEntry] | None:
+    def _load_index(self, ipath: str) -> ChunkIndex | None:
         """Index file = concatenated CBOR entry arrays (append-only, like
         the reference's secondary index). A torn final entry (crash
         mid-append) just ends the list — the fsize-lag check reparses."""
@@ -566,12 +688,18 @@ class ImmutableDB:
                 # plausible sizes — a corrupt entry with a huge
                 # offset/size must surface as "index corrupt -> reparse"
                 # (the reference truncates gracefully), not as an int64
-                # overflow crash in the vectorized deep check
+                # overflow crash in the vectorized deep check. The
+                # columns are int64 and (n, 32) uint8: what does not fit
+                # them is corrupt too, as the native parse reads it
                 bad = (
                     e.offset != end
                     or e.size <= 0
                     or e.size > (1 << 40)
-                    or not isinstance(e.crc32, int)
+                    or not all(
+                        isinstance(v, int) and 0 <= v < (1 << 63)
+                        for v in (e.slot, e.block_no, e.crc32)
+                    )
+                    or len(e.hash_) != 32
                 )
             except Exception:
                 break
@@ -579,9 +707,9 @@ class ImmutableDB:
                 break
             end = e.offset + e.size
             entries.append(e)
-        return entries
+        return ChunkIndex.from_entries(entries)
 
-    def _load_index_native(self, data: bytes) -> list[IndexEntry] | None:
+    def _load_index_native(self, data: bytes) -> ChunkIndex | None:
         """Columnar native index parse + vectorized sanity checks (the
         open-time bottleneck at the 1M-header scale: ~9 us/entry of
         Python CBOR decode vs ~20 ns here). None -> Python loop."""
@@ -590,29 +718,19 @@ class ImmutableDB:
         cols = native_loader.parse_index(data)
         if cols is None:
             return None
-        slots, block_nos, hashes, offsets, sizes, crcs = cols
-        n = len(slots)
-        if n == 0:
-            return []
-        import numpy as np
-
+        idx = ChunkIndex(*cols)
+        if not idx:
+            return idx
         # same contiguous-tiling sanity as the Python loop: offsets must
-        # tile from 0 with plausible sizes; keep the good prefix only
-        starts = np.concatenate(([0], (offsets + sizes)[:-1]))
-        good = (offsets == starts) & (sizes > 0) & (sizes <= (1 << 40))
+        # tile from 0 with plausible sizes (and a field past int64 reads
+        # negative here); keep the good prefix only
+        starts = np.concatenate(([0], idx.ends[:-1]))
+        good = (idx.offset == starts) & (idx.size > 0) & (idx.size <= (1 << 40))
+        good &= (idx.slot >= 0) & (idx.block_no >= 0) & (idx.crc32 >= 0)
         bad = np.flatnonzero(~good)
-        if bad.size:
-            n = int(bad[0])
-        hb = hashes.tobytes()
-        return [
-            IndexEntry(
-                int(slots[i]), int(block_nos[i]), hb[32 * i : 32 * i + 32],
-                int(offsets[i]), int(sizes[i]), int(crcs[i]),
-            )
-            for i in range(n)
-        ]
+        return idx[: int(bad[0])] if bad.size else idx
 
-    def _write_index(self, n: int, entries: list[IndexEntry]):
+    def _write_index(self, n: int, entries: ChunkIndex):
         data = b"".join(cbor.encode(e.to_cbor_obj()) for e in entries)
         self.fs.write_atomic(os.path.join(self.path, _index_name(n)), data)
 
@@ -643,7 +761,7 @@ class ImmutableDB:
             raise ImmutableDBError(f"append out of order: {slot} <= {t.slot}")
         n = slot // self.chunk_size
         if n not in self._entries:
-            self._entries[n] = []
+            self._entries[n] = ChunkIndex()
             self._chunks.append(n)
             self._chunks.sort()
         cpath = os.path.join(self.path, _chunk_name(n))
@@ -675,11 +793,11 @@ class ImmutableDB:
             # a REAL kill between the chunk append and the index
             # append: the reopened store finds the index lagging
             os.kill(os.getpid(), signal.SIGKILL)
-        e = IndexEntry(slot, block_no, hash_, offset, len(raw), zlib.crc32(raw))
-        self._entries[n].append(e)
+        row = (slot, block_no, hash_, offset, len(raw), zlib.crc32(raw))
+        self._entries[n].append(*row)
         # O(1) append-only index write (no fsync: startup validation
         # recovers from torn tails); CRC lives in the entry
-        enc = cbor.encode(e.to_cbor_obj())
+        enc = cbor.encode(list(row))
         ipath = os.path.join(self.path, _index_name(n))
         self.fs.append(ipath, enc)
         if fault == "index-truncate":
@@ -704,16 +822,21 @@ class ImmutableDB:
 
     # -- reading -------------------------------------------------------------
 
-    def _read(self, n: int, e: IndexEntry) -> bytes:
-        return self.fs.read_at(
-            os.path.join(self.path, _chunk_name(n)), e.offset, e.size
-        )
-
     def get_block_bytes(self, point: Point) -> bytes:
         n = point.slot // self.chunk_size
-        for e in self._entries.get(n, ()):
-            if e.slot == point.slot and e.hash_ == point.hash_:
-                return self._read(n, e)
+        idx = self._entries.get(n)
+        if idx is not None:
+            # slots rise within a chunk: the row by bisection, not a scan
+            i = int(np.searchsorted(idx.slot, point.slot))
+            if (
+                i < len(idx)
+                and idx.slot[i] == point.slot
+                and idx.hash_[i].tobytes() == point.hash_
+            ):
+                return self.fs.read_at(
+                    os.path.join(self.path, _chunk_name(n)),
+                    int(idx.offset[i]), int(idx.size[i]),
+                )
         raise MissingBlock(point)
 
     def iter_entries(self) -> Iterator[IndexEntry]:
@@ -730,13 +853,7 @@ class ImmutableDB:
 
     def stream_all(self) -> Iterator[tuple[IndexEntry, bytes]]:
         """Stream every block in slot order (db-analyser processAll)."""
-        for n in self._chunks:
-            entries = self._entries[n]
-            if not entries:
-                continue
-            data = self.fs.read_bytes(os.path.join(self.path, _chunk_name(n)))
-            for e in entries:
-                yield e, data[e.offset : e.offset + e.size]
+        yield from self.stream_from(-1)
 
     def stream_from(self, after_slot: int) -> Iterator[tuple[IndexEntry, bytes]]:
         """Stream blocks with slot > after_slot, seeking to the first
@@ -744,20 +861,21 @@ class ImmutableDB:
         replay, LedgerDB/Init.hs:116 — must not reread the whole DB)."""
         for n in self._chunks:
             entries = self._entries[n]
-            if not entries or entries[-1].slot <= after_slot:
+            first = int(np.searchsorted(entries.slot, after_slot, "right"))
+            if first == len(entries):
                 continue  # chunk entirely at or before the snapshot point
             data = self.fs.read_bytes(os.path.join(self.path, _chunk_name(n)))
-            for e in entries:
-                if e.slot > after_slot:
-                    yield e, data[e.offset : e.offset + e.size]
+            for e in entries[first:]:
+                yield e, data[e.offset : e.offset + e.size]
 
     def truncate_after(self, point: Point | None) -> None:
         """db-truncater (Tools/DBTruncater/Run.hs): drop everything after
         `point` (None = wipe)."""
         keep_through = -1 if point is None else point.slot
         for n in list(self._chunks):
-            entries = [e for e in self._entries[n] if e.slot <= keep_through]
-            if len(entries) != len(self._entries[n]):
+            idx = self._entries[n]
+            entries = idx[: int(np.searchsorted(idx.slot, keep_through, "right"))]
+            if len(entries) != len(idx):
                 if entries:
                     data = self.fs.read_bytes(os.path.join(self.path, _chunk_name(n)))
                     self._entries[n] = entries

@@ -88,13 +88,11 @@ def default_check_integrity_batch(data, entries):
     decode; this path is ~2 us/block."""
     import hashlib
 
-    import numpy as np
-
     from .. import native_loader
 
     if native_loader.load() is None:
         return None
-    offsets = np.asarray([e.offset for e in entries], np.int64)
+    offsets = entries.offset
     limit = len(entries)
     try:
         cols = native_loader.extract_headers(data, offsets)
@@ -106,14 +104,13 @@ def default_check_integrity_batch(data, entries):
         if limit == 0:
             return 0
         cols = native_loader.extract_headers(data, offsets[:limit])
-    for i in range(limit):
-        e = entries[i]
-        span = data[int(cols.header_end[i]) : e.offset + e.size]
+    spans = zip(cols.header_end.tolist(), entries.ends[:limit].tolist())
+    for i, (start, end) in enumerate(spans):
         if (
-            hashlib.blake2b(span, digest_size=32).digest()
+            hashlib.blake2b(data[start:end], digest_size=32).digest()
             != cols.body_hash[i].tobytes()
         ):
-            if not default_check_integrity(data[e.offset : e.offset + e.size]):
+            if not default_check_integrity(data[int(offsets[i]) : end]):
                 return i
     return limit
 

@@ -339,7 +339,7 @@ def backfill_store(imm, walked: bool = False) -> int:
         return 0
     wrote = 0
     for n in imm._chunks:
-        entries = imm._entries.get(n, ())
+        entries = imm._entries[n]
         if not entries:
             continue
         try:
@@ -349,9 +349,8 @@ def backfill_store(imm, walked: bool = False) -> int:
         sc, outcome = load_sidecar(imm.fs, imm.path, n, data, len(entries))
         if sc is not None:
             continue  # fresh seal: write-once
-        offsets = np.asarray([e.offset for e in entries], np.int64)
         try:
-            hc = native_loader.extract_headers(data, offsets)
+            hc = native_loader.extract_headers(data, entries.offset)
         except native_loader.MalformedBlock:
             continue
         if backfill(imm.fs, imm.path, n, hc, data, walked=walked):
@@ -561,10 +560,7 @@ def integrity_batch_hook(sc: SidecarColumns):
 
         m = len(entries)
         starts = np.asarray(sc.arrays["header_end"][:m], np.int64)
-        ends = np.asarray(
-            [e.offset + e.size for e in entries], np.int64
-        )
-        digests = hash_spans(data, starts, ends)
+        digests = hash_spans(data, starts, entries.ends)
         bad = (digests != sc.arrays["body_hash"][:m]).any(axis=1)
         for i in np.flatnonzero(bad):
             e = entries[int(i)]

@@ -305,8 +305,6 @@ def _stream_windows(imm: ImmutableDB, res: "ValidationResult"):
     span, so the flight recorder's phase collector banks both."""
     import os
 
-    import numpy as np
-
     from .. import native_loader
     from ..protocol.views import ViewColumns
     from ..storage import sidecar as sidecar_mod
@@ -397,10 +395,9 @@ def _stream_windows(imm: ImmutableDB, res: "ValidationResult"):
                     res.n_blocks += sc.n
             if pieces is None and native_ok and entries:
                 with pbatch._enclose("stream-parse"):
-                    offsets = np.asarray(
-                        [e.offset for e in entries], np.int64
+                    cols = native_loader.extract_headers(
+                        data, entries.offset
                     )
-                    cols = native_loader.extract_headers(data, offsets)
                 res.n_blocks += cols.n
                 if use_sidecar and sc is None and not truncated \
                         and getattr(imm, "_repair", False):

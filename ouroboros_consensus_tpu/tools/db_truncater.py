@@ -29,6 +29,8 @@ from __future__ import annotations
 
 import os
 
+import numpy as np
+
 from ..block.abstract import Point
 from ..storage.immutable import ImmutableDB
 
@@ -79,9 +81,11 @@ def truncate(db_path: str, after_slot: int | None) -> int:
             # find the last block at or before the slot
             target = None
             for n in imm._chunks:
-                for e in imm._entries[n]:
-                    if e.slot <= after_slot:
-                        target = Point(e.slot, e.hash_)
+                idx = imm._entries[n]
+                i = int(np.searchsorted(idx.slot, after_slot, "right"))
+                if i:
+                    e = idx[i - 1]
+                    target = Point(e.slot, e.hash_)
             imm.truncate_after(target)
         imm.flush()
         return imm.n_blocks()

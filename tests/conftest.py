@@ -26,5 +26,26 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 
+import pytest  # noqa: E402
+
+
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: long-running integration test")
+
+
+@pytest.fixture
+def index_entries_built(monkeypatch) -> list:
+    """Every `IndexEntry` constructed while the test runs lands in the
+    list returned: the storage tests count constructions, they do not
+    time them."""
+    from ouroboros_consensus_tpu.storage.immutable import IndexEntry
+
+    built: list = []
+    init = IndexEntry.__init__
+
+    def counting(self, *a, **kw):
+        built.append(1)
+        init(self, *a, **kw)
+
+    monkeypatch.setattr(IndexEntry, "__init__", counting)
+    return built

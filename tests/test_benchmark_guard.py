@@ -30,16 +30,27 @@ def test_the_new_cell_is_among_the_guarded(m):  # noqa: F405 - test_manifest's
         assert names == {x.name for x in m.cell(other).per_layer}
 
 
-def test_every_cell_reports_29_per_layer_metrics_by_name(m):  # noqa: F405
-    from benchmark.readers import window_span
+def test_every_cell_reports_30_per_layer_metrics_by_name(m):  # noqa: F405
+    from benchmark.readers import phase_wall, window_span
 
     for cell in ("replay-bc-2epoch", "replay-draft03-2epoch",
                  "replay-stakepools-2epoch"):
         per_layer = {x.name: x for x in m.cell(cell).per_layer}
-        assert len(per_layer) == 29
+        assert len(per_layer) == 30
+        index = per_layer["open_index_s_per_replay"]
+        assert index.spec["kind"] == "phase_wall"
+        assert index.spec["key"] == "open.index"
+        assert (index.unit, index.layer) == ("s", "stream")
         tiles = per_layer["device_tiles_per_window"]
         assert tiles.spec == {"kind": "window_span", "key": "tiles_live"}
         assert (tiles.unit, tiles.layer) == ("tiles", "dispatch and kernels")
+    # PR 38's span: per replay; a program without it (the parent commit's)
+    # banks no such phase, and the line leaves the metric out
+    phases = {"open": 0.9, "open.index": 0.06}
+    assert phase_wall.read(index.spec, {"phase_wall": phases,
+                                        "replays": 30}) == 0.002
+    assert phase_wall.read(index.spec, {"phase_wall": {"open": 0.9},
+                                        "replays": 30}) is None
     # read off the program's own spans; a program without the field (the
     # parent commit's) gives nothing to read, and the line leaves it out
     spans = [{"lanes": 10, "tiles_live": 1}, {"lanes": 8192, "tiles_live": 64}]

@@ -111,6 +111,7 @@ def test_native_reparse_matches_python(chunk, tmp_path):
     from ouroboros_consensus_tpu.storage.immutable import ImmutableDB
 
     buf, blocks = chunk
+    rebuilt = []
     for sub, native in (("n", True), ("p", False)):
         d = str(tmp_path / sub)
         os.makedirs(d)
@@ -130,3 +131,6 @@ def test_native_reparse_matches_python(chunk, tmp_path):
         entries = db._entries[0]
         assert [e.hash_ for e in entries] == [b.hash_ for b in blocks]
         assert [e.slot for e in entries] == [b.slot for b in blocks]
+        rebuilt.append(entries)
+    # the native rebuild's columns against the Python walk's entries
+    assert rebuilt[0] == list(rebuilt[1]) and len(rebuilt[0]) == len(blocks)

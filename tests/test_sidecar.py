@@ -488,3 +488,30 @@ def test_walked_seal_provenance_and_differential(pristine, tmp_path):
     assert r_unwalked.error is None
     assert r_unwalked.n_valid == r_walked.n_valid
     assert r_unwalked.final_state == r_walked.final_state
+
+
+@pytest.mark.parametrize("seal", ["walked", "unwalked", "none"])
+def test_stream_replay_builds_no_index_entry(pristine, tmp_path,
+                                             index_entries_built, seal):
+    """The guard on PR 38's mechanism: a chunk's index stays columns
+    from the native parse to its last reader, so a stream replay
+    constructs ZERO `IndexEntry` objects (counted, not timed), whichever
+    way a chunk's integrity is owed: a walked seal (the body-hash
+    columns alone), an unwalked one (the CRC sweep too), no sidecar (the
+    native parse and `default_check_integrity_batch`)."""
+    db = _copy(pristine, tmp_path)
+    if seal != "walked":
+        for n in range(2):
+            os.unlink(os.path.join(db, "immutable", "%05d.cols" % n))
+    if seal == "unwalked":
+        assert sc_mod.backfill_store(ana.open_immutable(db)) == 2
+    want = {"walked": "hit", "unwalked": "hit", "none": "miss"}[seal]
+    index_entries_built.clear()  # the backfill above is a writer's
+    sc_mod.reset_counters()
+    res = _reval(db, backend="native", collect_phases=True)
+    assert res.error is None and res.n_valid == N_BLOCKS
+    assert sc_mod.counters()[want] == 2
+    assert index_entries_built == []
+    # the mechanism's own reading: one `open.index` span a chunk,
+    # inside `open`
+    assert 0 < res.phases["open.index"] < res.phases["open"]

@@ -146,6 +146,8 @@ def test_spans_name_the_thread_that_did_the_work(traced):
             {"validate-chain"}
     for label in ("open", "validate-chain", "stream"):
         assert {e.parent for e in _ends(events, label=label)} == {"replay"}
+    # the index of each chunk is read inside `open`, on the main thread
+    assert {e.parent for e in _ends(events, label="open.index")} == {"open"}
     # the host's nonce fold: inside the window's `epilogue`, every window
     for label in ("epilogue.fold", "epilogue.counters"):
         assert {e.parent for e in _ends(events, label=label)} == {"epilogue"}

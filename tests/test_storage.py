@@ -565,25 +565,33 @@ def test_chaindb_time_based_snapshots_on_sim_clock(tmp_path):
     assert after != first
 
 
+def _index_rows(path):
+    from ouroboros_consensus_tpu.utils import cbor
+
+    idata, rows, off = path.read_bytes(), [], 0
+    while off < len(idata):
+        obj, off = cbor.decode_prefix(idata, off)
+        rows.append(list(obj))
+    return rows
+
+
+def _write_index_rows(path, rows, tail=b""):
+    from ouroboros_consensus_tpu.utils import cbor
+
+    path.write_bytes(b"".join(cbor.encode(r) for r in rows) + tail)
+
+
 def _fix_index_crc(dirpath, chunk_name, index_name, entry_ix):
     """Recompute the stored CRC of entry `entry_ix` from the (corrupted)
     chunk bytes, so the CRC walk passes and only deeper checks can
     catch the corruption."""
     import zlib
 
-    from ouroboros_consensus_tpu.utils import cbor
-
-    idata = (dirpath / index_name).read_bytes()
-    rows, off = [], 0
-    while off < len(idata):
-        obj, off = cbor.decode_prefix(idata, off)
-        rows.append(list(obj))
+    rows = _index_rows(dirpath / index_name)
     data = (dirpath / chunk_name).read_bytes()
     e_off, e_size = rows[entry_ix][3], rows[entry_ix][4]
     rows[entry_ix][5] = zlib.crc32(data[e_off : e_off + e_size])
-    (dirpath / index_name).write_bytes(
-        b"".join(cbor.encode(r) for r in rows)
-    )
+    _write_index_rows(dirpath / index_name, rows)
     return e_off, e_size
 
 
@@ -651,3 +659,243 @@ def test_body_hash_bad_before_malformed_truncates_earlier(tmp_path):
         check_integrity_batch=default_check_integrity_batch,
     )
     assert db2.n_blocks() == 1
+
+
+# -- the columnar chunk index (PR 38) ----------------------------------------
+
+
+def _damage_clean(rows, ipath):
+    pass
+
+
+def _damage_torn_final_entry(rows, ipath):
+    from ouroboros_consensus_tpu.utils import cbor
+
+    _write_index_rows(ipath, rows[:-1], cbor.encode(rows[-1])[:-7])
+
+
+def _damage_tiling_break(rows, ipath):
+    rows[4][3] += 1  # entry 4 no longer starts where entry 3 ends
+    _write_index_rows(ipath, rows)
+
+
+def _damage_zero_size(rows, ipath):
+    rows[3][4] = 0
+    _write_index_rows(ipath, rows)
+
+
+def _damage_oversized_size(rows, ipath):
+    rows[5][4] = (1 << 40) + 1
+    _write_index_rows(ipath, rows)
+
+
+def _damage_field_past_int64(rows, ipath):
+    rows[6][0] = (1 << 63) + 5  # a slot no int64 column holds
+    _write_index_rows(ipath, rows)
+
+
+def _damage_lagging_index(rows, ipath):
+    _write_index_rows(ipath, rows[:5])  # the chunk holds 8 blocks
+
+
+def _damage_empty_index(rows, ipath):
+    ipath.write_bytes(b"")
+
+
+_INDEX_DAMAGE = {
+    f.__name__[len("_damage_"):]: f
+    for f in (_damage_clean, _damage_torn_final_entry, _damage_tiling_break,
+              _damage_zero_size, _damage_oversized_size,
+              _damage_field_past_int64, _damage_lagging_index,
+              _damage_empty_index)
+}
+
+
+@pytest.mark.parametrize("damage", sorted(_INDEX_DAMAGE))
+def test_columnar_index_load_equals_cbor_loop(tmp_path, monkeypatch, damage):
+    """The columnar load of an index (native parse, vectorised tiling
+    check) against the Python CBOR loop, which stays the reference:
+    the same entries, entry for entry, and the same repair rows from
+    the open that follows, whatever is wrong with the index."""
+    from ouroboros_consensus_tpu import native_loader
+    from ouroboros_consensus_tpu.storage.immutable import ChunkIndex
+
+    if native_loader.load() is None:
+        pytest.skip("native loader unavailable: one load path only")
+    root = tmp_path / "imm"
+    db = ImmutableDB(str(root), chunk_size=100)
+    for b in forge_chain(8):
+        db.append_block(b.slot, b.block_no, b.hash_, b.bytes_)
+    ipath = root / "00000.index"
+    _INDEX_DAMAGE[damage](_index_rows(ipath), ipath)
+
+    def load_and_open():
+        idx = db._load_index(str(ipath))
+        opened = ImmutableDB(str(root), chunk_size=100, repair=False)
+        return idx, opened
+
+    cols, db_cols = load_and_open()
+    monkeypatch.setattr(ImmutableDB, "_load_index_native",
+                        lambda self, data: None)
+    loop, db_loop = load_and_open()
+    assert isinstance(cols, ChunkIndex) and isinstance(loop, ChunkIndex)
+    assert list(cols) == list(loop)
+    assert cols == loop and cols == list(loop)
+    want = {"clean": 8, "torn_final_entry": 7, "tiling_break": 4,
+            "zero_size": 3, "oversized_size": 5, "field_past_int64": 6,
+            "lagging_index": 5,
+            "empty_index": 0}[damage]
+    assert len(cols) == want
+    assert db_cols.repairs == db_loop.repairs
+    assert (damage == "clean") == (db_cols.repairs == [])
+    assert db_cols._entries == db_loop._entries
+    assert db_cols.n_blocks() == db_loop.n_blocks() == 8  # the chunk is whole
+
+
+def _model_store(tmp_path, n=11, chunk_size=4):
+    """A store written, closed and reopened, beside its model: one list
+    of `IndexEntry` a chunk, reckoned from the blocks alone."""
+    path = str(tmp_path / "imm")
+    db = ImmutableDB(path, chunk_size=chunk_size)
+    blocks = forge_chain(n)
+    model: dict[int, list] = {}
+    for b in blocks:
+        db.append_block(b.slot, b.block_no, b.hash_, b.bytes_)
+        _model_append(model, b, chunk_size)
+    db.flush()
+    return ImmutableDB(path, chunk_size=chunk_size), blocks, model, path
+
+
+def _model_append(model, b, chunk_size=4):
+    import zlib
+
+    from ouroboros_consensus_tpu.storage.immutable import IndexEntry
+
+    rows = model.setdefault(b.slot // chunk_size, [])
+    off = rows[-1].offset + rows[-1].size if rows else 0
+    rows.append(IndexEntry(b.slot, b.block_no, b.hash_, off,
+                           len(b.bytes_), zlib.crc32(b.bytes_)))
+    return rows[-1]
+
+
+def _flat(model):
+    return [e for n in sorted(model) for e in model[n]]
+
+
+def _check_slices(db, blocks, model, path):
+    for n, rows in model.items():
+        idx = db._entries[n]
+        assert idx == rows and len(idx) == len(rows)
+        assert [idx[i] for i in range(len(rows))] == rows
+        assert idx[-1] == rows[-1]
+        for cut in (slice(0, 2), slice(1, None), slice(None, -1),
+                    slice(2, 2), slice(None, 99)):
+            assert idx[cut] == rows[cut], cut
+            assert list(idx[cut]) == rows[cut]
+        assert idx[:2].end == rows[1].offset + rows[1].size
+        assert idx[:0].end == 0 and idx[:0] == []
+        assert idx.ends.tolist() == [e.offset + e.size for e in rows]
+        with pytest.raises(IndexError):
+            idx[len(rows)]
+
+
+def _check_tip(db, blocks, model, path):
+    assert db.tip() == _flat(model)[-1]
+    assert db.tip_point() == blocks[-1].point
+    assert db.n_blocks() == len(blocks) and not db.is_empty
+    assert list(db.iter_entries()) == _flat(model)
+    assert list(db.iter_points()) == [b.point for b in blocks]
+    empty = ImmutableDB(path + "-none", chunk_size=4, repair=False)
+    assert empty.tip() is None and empty.is_empty and empty.n_blocks() == 0
+
+
+def _check_get_block_bytes(db, blocks, model, path):
+    from ouroboros_consensus_tpu.storage.immutable import MissingBlock
+
+    for b in blocks:
+        assert db.get_block_bytes(b.point) == b.bytes_
+    wrong_hash = Point(blocks[5].slot, blocks[4].hash_)
+    absent = [Point(0, blocks[0].hash_),  # before the first block
+              Point(blocks[-1].slot + 1, blocks[-1].hash_),  # past the tip
+              Point(10_000, blocks[0].hash_)]  # a chunk that is not there
+    for p in [wrong_hash] + absent:
+        with pytest.raises(MissingBlock):
+            db.get_block_bytes(p)
+
+
+def _check_stream_from(db, blocks, model, path):
+    by_hash = {b.hash_: b.bytes_ for b in blocks}
+    for after in (-1, 0, 3, 4, 7, blocks[-1].slot - 1, blocks[-1].slot, 99):
+        want = [(e, by_hash[e.hash_]) for e in _flat(model)
+                if e.slot > after]
+        assert list(db.stream_from(after)) == want, after
+    assert list(db.stream_all()) == list(db.stream_from(-1))
+
+
+def _check_truncate_after(db, blocks, model, path):
+    kept_view = db._entries[1][:2]
+    db.truncate_after(blocks[5].point)  # slot 6: inside chunk 1
+    want = [e for e in _flat(model) if e.slot <= blocks[5].slot]
+    assert list(db.iter_entries()) == want and db.tip() == want[-1]
+    assert kept_view == model[1][:2]
+    assert list(ImmutableDB(path, chunk_size=4).iter_entries()) == want
+    db.truncate_after(None)
+    assert db.is_empty and db.tip() is None
+    assert ImmutableDB(path, chunk_size=4).is_empty
+
+
+def _check_append_after_reopen(db, blocks, model, path):
+    n_last = max(model)
+    before = db._entries[n_last]
+    view, old = before[:], list(model[n_last])
+    more = forge_chain(70, start_slot=blocks[-1].slot + 1,
+                       start_bno=len(blocks), prev=blocks[-1].hash_)
+    for b in more:
+        db.append_block(b.slot, b.block_no, b.hash_, b.bytes_)
+        assert db.tip() == _model_append(model, b)
+    # a view cut before the appends still says what it said
+    assert view == old and db._entries[n_last][: len(old)] == old
+    assert {n: list(v) for n, v in db._entries.items()} == model
+    with pytest.raises(Exception, match="out of order"):
+        db.append_block(more[-1].slot, 0, more[-1].hash_, b"x")
+    db.flush()
+    again = ImmutableDB(path, chunk_size=4)
+    assert list(again.iter_entries()) == _flat(model)
+    assert again.get_block_bytes(more[33].point) == more[33].bytes_
+
+
+@pytest.mark.parametrize("check", [
+    _check_slices, _check_tip, _check_get_block_bytes, _check_stream_from,
+    _check_truncate_after, _check_append_after_reopen,
+], ids=lambda f: f.__name__[len("_check_"):])
+def test_chunk_index_agrees_with_entry_list_model(tmp_path, check):
+    """The columns behind `_entries` answer every per-entry question as
+    a list of `IndexEntry` would: the model is such a list."""
+    check(*_model_store(tmp_path))
+
+
+def test_append_block_builds_no_entry_list(tmp_path, index_entries_built):
+    """The writer stays O(1) a block: 2,000 appends over several chunks
+    build at most the one `IndexEntry` a `tip()` hands back each, and
+    grow the open chunk's columns by doubling."""
+    from ouroboros_consensus_tpu.storage import immutable as imm_mod
+
+    built = index_entries_built
+    path = str(tmp_path / "imm")
+    db = ImmutableDB(path, chunk_size=1500)
+    raw = os.urandom(64)
+    per_quarter = []
+    for q in range(4):
+        built.clear()
+        for i in range(500 * q, 500 * q + 500):
+            db.append_block(3 * i, i, i.to_bytes(32, "big"), raw)
+        per_quarter.append(len(built))
+    assert per_quarter == [499, 500, 500, 500]  # its own tip() alone
+    assert db.n_blocks() == 2000 and db._chunks == [0, 1, 2, 3]
+    assert all(isinstance(v, imm_mod.ChunkIndex)
+               for v in db._entries.values())
+    assert len(db._entries[0]._cols[0]) == 512  # 64, doubled three times
+    built.clear()
+    again = ImmutableDB(path, chunk_size=1500)
+    assert built == [] and again._entries == db._entries
+    assert again.tip().block_no == 1999
