@@ -253,6 +253,104 @@ int ocx_extract_headers(
     return 0;
 }
 
+// Era-tagged blocks (hardfork/combinator.HardForkBlock: [era, inner]
+// with the inner block's CBOR as a byte string): the era and the inner
+// block's span, n blocks at offsets[]. Returns 0, or the 1-based index
+// of the first block that is not an era-tagged one.
+int ocx_scan_eras(const uint8_t* buf, size_t len,
+                  const int64_t* offsets, int n,
+                  int64_t* era, int64_t* inner_off, int64_t* inner_len) {
+    for (int i = 0; i < n; i++) {
+        Cursor c{buf, len, (size_t)offsets[i], true};
+        uint64_t na;
+        if (!expect_array(c, &na) || na != 2) return i + 1;
+        if (!read_uint(c, &era[i])) return i + 1;
+        if (!read_bytes_span(c, &inner_off[i], &inner_len[i])) return i + 1;
+    }
+    return 0;
+}
+
+// Byron blocks (hardfork/byron_mock.py) at offsets[]:
+//   block   = [kind, header, txs]          kind 1: main, 0: EBB
+//   main    = [to_sign, delegate_vk, sig, [epoch8, genesis_vk, vk, sig]]
+//   to_sign = [magic, prev|null, [cnt8, root, 4 x hash], slot8, diff8,
+//              [..], [..]]
+//   EBB     = [magic, prev|null, slot8, diff8, proof]
+// -> kind, slot, block_no, prev_hash (+has_prev), delegate_vk, sig,
+// genesis_vk, the body's tx root (an EBB: its proof), the signed
+// span (to_sign) and the txs span. Returns 0 or 1-based bad index.
+static bool read_be8(Cursor& c, int64_t* out) {
+    uint8_t b[8];
+    if (!read_bytes_fixed(c, b, 8, nullptr)) return false;
+    uint64_t v = 0;
+    for (int k = 0; k < 8; k++) v = (v << 8) | b[k];
+    *out = (int64_t)v;
+    return true;
+}
+
+int ocx_extract_byron(
+    const uint8_t* buf, size_t len, const int64_t* offsets, int n,
+    int64_t* kind, int64_t* slot, int64_t* block_no,
+    uint8_t* prev_hash /* n*32 */, uint8_t* has_prev,
+    uint8_t* delegate_vk /* n*32 */, uint8_t* sig /* n*64 */,
+    uint8_t* genesis_vk /* n*32 */, uint8_t* tx_root /* n*32 */,
+    int64_t* signed_off, int64_t* signed_len,
+    int64_t* txs_off, int64_t* txs_len) {
+    for (int i = 0; i < n; i++) {
+        Cursor c{buf, len, (size_t)offsets[i], true};
+        uint64_t na;
+        int64_t magic;
+        if (!expect_array(c, &na) || na != 3) return i + 1;
+        if (!read_uint(c, &kind[i]) || kind[i] > 1) return i + 1;
+        if (kind[i] == 0) {
+            if (!expect_array(c, &na) || na != 5) return i + 1;
+            if (!read_uint(c, &magic)) return i + 1;
+            if (!read_bytes_fixed(c, prev_hash + 32 * i, 32, &has_prev[i]))
+                return i + 1;
+            if (!read_be8(c, &slot[i]) || !read_be8(c, &block_no[i]))
+                return i + 1;
+            if (!read_bytes_fixed(c, tx_root + 32 * i, 32, nullptr))
+                return i + 1;
+            memset(delegate_vk + 32 * i, 0, 32);
+            memset(genesis_vk + 32 * i, 0, 32);
+            memset(sig + 64 * i, 0, 64);
+            signed_off[i] = signed_len[i] = 0;
+        } else {
+            if (!expect_array(c, &na) || na != 4) return i + 1;
+            size_t ts = c.off;
+            if (!expect_array(c, &na) || na != 7) return i + 1;
+            if (!read_uint(c, &magic)) return i + 1;
+            if (!read_bytes_fixed(c, prev_hash + 32 * i, 32, &has_prev[i]))
+                return i + 1;
+            if (!expect_array(c, &na) || na != 6) return i + 1;
+            int64_t cnt;
+            if (!read_be8(c, &cnt)) return i + 1;
+            if (!read_bytes_fixed(c, tx_root + 32 * i, 32, nullptr))
+                return i + 1;
+            for (int k = 0; k < 4; k++)
+                if (!skip_item(c) || !c.ok) return i + 1;
+            if (!read_be8(c, &slot[i]) || !read_be8(c, &block_no[i]))
+                return i + 1;
+            if (!skip_item(c) || !skip_item(c) || !c.ok) return i + 1;
+            signed_off[i] = (int64_t)ts;
+            signed_len[i] = (int64_t)(c.off - ts);
+            if (!read_bytes_fixed(c, delegate_vk + 32 * i, 32, nullptr))
+                return i + 1;
+            if (!read_bytes_fixed(c, sig + 64 * i, 64, nullptr)) return i + 1;
+            if (!expect_array(c, &na) || na != 4) return i + 1;
+            if (!skip_item(c) || !c.ok) return i + 1;
+            if (!read_bytes_fixed(c, genesis_vk + 32 * i, 32, nullptr))
+                return i + 1;
+            if (!skip_item(c) || !skip_item(c) || !c.ok) return i + 1;
+        }
+        size_t t0 = c.off;
+        if (!skip_item(c) || !c.ok) return i + 1;  // txs, walked whole
+        txs_off[i] = (int64_t)t0;
+        txs_len[i] = (int64_t)(c.off - t0);
+    }
+    return 0;
+}
+
 // Batched CRC-32 (ISO-HDLC, the zlib.crc32 polynomial) over n spans of
 // buf. Returns the 0-based index of the first span whose CRC differs
 // from expected[], or -1 when all match. This is the ImmutableDB deep

@@ -17,15 +17,23 @@ collapsed to the three protocol classes that matter for consensus: one
 PBFT era and the two Praos-class eras sharing the batched TPU crypto
 backend. Era boundaries are config-driven (TriggerHardForkAtEpoch).
 
-`synthesize` forges a chain crossing both transitions into an on-disk
-ImmutableDB of era-tagged blocks; `revalidate` streams it back and
-validates every segment with the chosen backend — the Praos-class
-segments as fused device batches, the PBFT segment as a batched Ed25519
-verify + host threshold fold.
+The classic three-era composite IS a Cardano chain of the normal path
+(hardfork/cardano: `CardanoMock.cardano_params` / `cardano_views`):
+`synthesize` forges it with `tools/db_synthesizer` and `revalidate` on a
+batched backend replays it with `tools/db_analyser` — ONE `validate_stream`
+over the era-tagged stream, Byron lanes on the `ed` stage, both
+translations inside the stream. `backend="host"` is the sequential
+reference rung: the hard-fork combinator's per-header walk
+(`HardForkProtocol`, `Era.translate_chain_dep`).
+
+What still walks segment by segment in memory (ROADMAP Reach 5): the
+ledger-backed composite (`with_ledgers`: the era ledgers' fold) and the
+5- and 7-era composites on their non-host backends.
 """
 
 from __future__ import annotations
 
+import itertools
 import os
 from dataclasses import dataclass, replace
 from fractions import Fraction
@@ -39,13 +47,16 @@ from ..ops import ed25519_batch
 from ..protocol import batch as pbatch
 from ..protocol import nonces, praos, tpraos
 from ..protocol.leader import check_leader_value
-from ..protocol.instances import PBftParams, PBftProtocol, PraosProtocol
+from ..protocol.instances import (PBftLedgerView, PBftParams, PBftProtocol,
+                                  PraosProtocol)
 from ..protocol.views import hash_vrf_vk
 from ..storage.immutable import ImmutableDB
 from ..testing import fixtures
 from .byron_mock import ByronMockBlock
+from .cardano import byron_to_shelley
 from .combinator import (
     Era,
+    HFState,
     HardForkBlock,
     HardForkLedger,
     HardForkProtocol,
@@ -76,8 +87,9 @@ class CardanoMockConfig:
     leios_epoch_length: int = 30
     k: int = 5
     kes_depth: int = 3
-    # with n_delegs=2 round-robin and window k, each delegate signs
-    # ~k/2 + 1 of any window — the threshold must clear that
+    # with n_delegs=2 round-robin and a window of k signed blocks, a
+    # delegate signs ceil(k/2) of any window (3 of 5), this block
+    # included; PBFT allows floor(threshold x k) (4 of 5)
     pbft_threshold: Fraction = Fraction(4, 5)
     shelley_initial_nonce: bytes = b"\x0b" * 32
     # LEDGERS IN THE LOOP: era 0 = real Byron-class UTxO+delegation
@@ -125,21 +137,37 @@ class CardanoMock:
             epoch_length=cfg.epoch_length,
             kes_depth=cfg.kes_depth,
         )
+        # each era's epochs on the hard-fork clock: an era's params carry
+        # where its first epoch starts (the Summary's bound, below);
+        # consecutive eras of one epoch length share one origin
+        b0 = cfg.byron_epochs
+        s0 = cfg.byron_epochs * cfg.byron_epoch_length
+        b1 = b0 + (cfg.shelley_epochs if not cfg.seven_era
+                   else 4 * cfg.era_epochs)
+        s1 = s0 + (b1 - b0) * cfg.epoch_length
         self.tpraos_params = tpraos.TPraosParams(
             praos=praos.PraosParams(
-                active_slot_coeff=cfg.shelley_f, **common
+                active_slot_coeff=cfg.shelley_f, era_start_slot=s0,
+                era_start_epoch=b0, **common
             ),
             decentralization=cfg.shelley_d,
         )
         self.praos_params = praos.PraosParams(
-            active_slot_coeff=cfg.babbage_f, **common
+            active_slot_coeff=cfg.babbage_f, era_start_slot=s1,
+            era_start_epoch=b1, **common
         )
+        b2 = b1 + (cfg.conway_epochs or 0)
+        s2 = s1 + (b2 - b1) * cfg.epoch_length
         self.conway_params = praos.PraosParams(
-            active_slot_coeff=cfg.conway_f,
+            active_slot_coeff=cfg.conway_f, era_start_slot=s2,
+            era_start_epoch=b2,
             **{**common, "epoch_length": cfg.conway_epoch_length},
         )
+        b3 = b2 + (cfg.leios_epochs or 0)
+        s3 = s2 + (b3 - b2) * cfg.conway_epoch_length
         self.leios_params = praos.PraosParams(
-            active_slot_coeff=cfg.leios_f,
+            active_slot_coeff=cfg.leios_f, era_start_slot=s3,
+            era_start_epoch=b3,
             **{**common, "epoch_length": cfg.leios_epoch_length},
         )
         self.pbft = PBftProtocol(
@@ -184,12 +212,8 @@ class CardanoMock:
                 "shelley",
                 self.tpraos_proto,
                 ledger=None,
-                # Byron's PBftState carries nothing Praos-shaped: Shelley
-                # starts from the genesis nonce (CanHardFork.hs
-                # translateLedgerStateByronToShelley + protocol init)
-                translate_chain_dep=lambda _s: replace(
-                    tpraos.TPraosState(), epoch_nonce=nonce
-                ),
+                # translateChainDepStateByronToShelley (hardfork/cardano)
+                translate_chain_dep=lambda s: byron_to_shelley(s, nonce),
             ),
             Era(
                 "babbage",
@@ -258,9 +282,7 @@ class CardanoMock:
             Era("byron", self.pbft, ledger=None),
             Era(
                 "shelley", self.tpraos_proto, ledger=None,
-                translate_chain_dep=lambda _s: replace(
-                    tpraos.TPraosState(), epoch_nonce=nonce
-                ),
+                translate_chain_dep=lambda s: byron_to_shelley(s, nonce),
             ),
             Era("allegra", self.tpraos_proto, ledger=None,
                 translate_chain_dep=lambda s: s),
@@ -482,6 +504,33 @@ class CardanoMock:
         if era == 0:
             return None
         return self.tpraos_view if self.is_tpraos_era(era) else self.praos_view
+
+    @property
+    def on_normal_path(self) -> bool:
+        """The classic three eras, no ledger in the loop: a chain of the
+        normal path (hardfork/cardano)."""
+        cfg = self.cfg
+        return not (cfg.with_ledgers or cfg.seven_era
+                    or cfg.conway_epochs is not None)
+
+    def cardano_params(self):
+        """The composite as the normal path's deployment."""
+        from ..protocol.pbft import PBftEraParams
+        from .cardano import CardanoParams
+
+        cfg = self.cfg
+        byron = PBftEraParams(
+            num_genesis_keys=cfg.n_delegs, threshold=cfg.pbft_threshold,
+            security_param=cfg.k, epoch_length=cfg.byron_epoch_length)
+        return CardanoParams(byron, self.tpraos_params, self.praos_params,
+                             cfg.shelley_initial_nonce)
+
+    def cardano_views(self):
+        from .cardano import CardanoViews
+
+        return CardanoViews(
+            PBftLedgerView({d.vk_cold: j for j, d in enumerate(self.delegs)}),
+            self.tpraos_view, self.praos_view)
 
 
 # ---------------------------------------------------------------------------
@@ -715,10 +764,25 @@ class _LedgerTxChain:
 
 
 def synthesize(path: str, cfg: CardanoMockConfig, n_slots: int, chunk_size: int = 500):
-    """Forge a chain crossing both era boundaries; returns block count."""
+    """Forge a chain crossing both era boundaries; returns block count.
+    The classic three eras are forged by the normal path's forge
+    (`tools/db_synthesizer`, hardfork/cardano.forge), with the chain's
+    own config ("Protocol": "Cardano"); the ledger-backed and the 5-/7-era
+    composites by the walk below."""
     from . import byron_mock
 
     cm = CardanoMock(cfg)
+    if cm.on_normal_path:
+        from ..tools import config as tconfig
+        from ..tools import db_synthesizer as synth
+
+        res = synth.synthesize(
+            path, cm.cardano_params(), [*cm.pools, *cm.delegs],
+            cm.cardano_views(), synth.ForgeLimit(slots=n_slots),
+            chunk_size=chunk_size, vrf_backend="host")
+        tconfig.write_genesis_files(os.path.join(path, "config"),
+                                    cm.cardano_params(), cm.cardano_views())
+        return res.n_blocks
     os.makedirs(path, exist_ok=True)
     imm = ImmutableDB(os.path.join(path, "immutable"), chunk_size=chunk_size)
     if not imm.is_empty:
@@ -831,6 +895,21 @@ def synthesize(path: str, cfg: CardanoMockConfig, n_slots: int, chunk_size: int 
 # ---------------------------------------------------------------------------
 
 
+def _revalidate_normal(path: str, cm: "CardanoMock", backend: str):
+    """A batched replay through db_analyser, as a MixedResult."""
+    from ..tools import db_analyser
+
+    r = db_analyser.revalidate(path, cm.cardano_params(), cm.cardano_views(),
+                               backend=backend, validate_all="stream")
+    res = MixedResult(n_blocks=r.n_blocks, n_valid=r.n_valid, error=r.error,
+                      final_state=HFState(r.era, r.final_state), per_era={})
+    imm = ImmutableDB(os.path.join(path, "immutable"), repair=False)
+    for _e, raw in itertools.islice(imm.stream_all(), r.n_valid):
+        name = cm.eras[raw[1]].name  # [era, inner]: the era's one byte
+        res.per_era[name] = res.per_era.get(name, 0) + 1
+    return res
+
+
 @dataclass
 class MixedResult:
     n_blocks: int = 0
@@ -898,10 +977,13 @@ def _validate_pbft_segment(proto: PBftProtocol, headers, st, backend: str):
 
 def revalidate(path: str, cfg: CardanoMockConfig, backend: str = "device") -> MixedResult:
     """Full mixed-era revalidation (config 5: Cardano/CanHardFork.hs:273
-    semantics): decode era-tagged blocks, walk the telescope, validate
-    each era segment with its protocol — Praos-class eras through the
-    batched backend."""
+    semantics). On a batched backend the classic three eras go through
+    the normal path (`tools/db_analyser.revalidate`: one stream, the
+    translations inside it); `backend="host"` is the hard-fork
+    combinator's sequential walk, the reference rung."""
     cm = CardanoMock(cfg)
+    if backend != "host" and cm.on_normal_path:
+        return _revalidate_normal(path, cm, backend)
     # repair=False: this analysis holds no DB lock (direct embedder —
     # COVERAGE.md §5.17 honest gap), so it must never mutate the store;
     # a lagging index is reparsed in memory only

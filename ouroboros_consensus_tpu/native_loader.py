@@ -55,6 +55,16 @@ def load():
         ctypes.c_void_p, ctypes.c_int,  # offsets, n
         *([ctypes.c_void_p] * 25),
     ]
+    lib.ocx_scan_eras.restype = ctypes.c_int
+    lib.ocx_scan_eras.argtypes = [
+        ctypes.c_char_p, ctypes.c_size_t, ctypes.c_void_p, ctypes.c_int,
+        *([ctypes.c_void_p] * 3),
+    ]
+    lib.ocx_extract_byron.restype = ctypes.c_int
+    lib.ocx_extract_byron.argtypes = [
+        ctypes.c_char_p, ctypes.c_size_t, ctypes.c_void_p, ctypes.c_int,
+        *([ctypes.c_void_p] * 13),
+    ]
     lib.ocx_crc32_first_bad.restype = ctypes.c_int64
     lib.ocx_crc32_first_bad.argtypes = [
         ctypes.c_char_p, ctypes.c_size_t,
@@ -527,6 +537,54 @@ class HeaderColumns:
     @cached_property
     def signed_bytes_mat(self):  # [n, body_len] uint8 | None
         return _span_matrix(self._buf_u8, self.sgn_off, self.sgn_len)
+
+
+def _ptr(a):
+    return a.ctypes.data_as(ctypes.c_void_p)
+
+
+def scan_eras(buf: bytes, offsets: np.ndarray):
+    """The era tag and the inner block's span of each era-tagged block
+    ([era, inner], hardfork/combinator.HardForkBlock) at `offsets`:
+    -> (era, inner_off, inner_len) int64 columns; None if the native
+    library is unavailable. Raises MalformedBlock at the first block
+    that is not era-tagged."""
+    lib = load()
+    if lib is None:
+        return None
+    n = len(offsets)
+    offs = np.ascontiguousarray(offsets, np.int64)
+    era, off, ln = (np.zeros(n, np.int64) for _ in range(3))
+    rc = lib.ocx_scan_eras(buf, len(buf), _ptr(offs), n, _ptr(era),
+                           _ptr(off), _ptr(ln))
+    if rc != 0:
+        raise MalformedBlock(rc - 1)
+    return era, off, ln
+
+
+def extract_byron(buf: bytes, offsets: np.ndarray) -> dict | None:
+    """The columns of the Byron blocks (hardfork/byron_mock.py) at
+    `offsets`: kind (1 main, 0 EBB), slot, block_no, prev_hash,
+    has_prev, delegate_vk, sig, genesis_vk, tx_root, and the (offset,
+    length) spans of the signed bytes and of the txs. None if the native
+    library is unavailable; MalformedBlock at the first bad block."""
+    lib = load()
+    if lib is None:
+        return None
+    n = len(offsets)
+    offs = np.ascontiguousarray(offsets, np.int64)
+    i64 = lambda: np.zeros(n, np.int64)  # noqa: E731
+    u8 = lambda w: np.zeros((n, w), np.uint8)  # noqa: E731
+    cols = dict(kind=i64(), slot=i64(), block_no=i64(), prev_hash=u8(32),
+                has_prev=np.zeros(n, np.uint8), delegate_vk=u8(32),
+                sig=u8(64), genesis_vk=u8(32), tx_root=u8(32),
+                signed_off=i64(), signed_len=i64(), txs_off=i64(),
+                txs_len=i64())
+    rc = lib.ocx_extract_byron(buf, len(buf), _ptr(offs), n,
+                               *(_ptr(a) for a in cols.values()))
+    if rc != 0:
+        raise MalformedBlock(rc - 1)
+    return cols
 
 
 def extract_headers(buf: bytes, offsets: np.ndarray) -> HeaderColumns | None:

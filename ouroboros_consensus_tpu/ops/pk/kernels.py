@@ -361,6 +361,33 @@ def finish_tp(ed_ok, ed_pt, ed_r, kes_ok, kes_pt, kes_r,
     )
 
 
+def _finish_pbft_kernel(edok_ref, edpt_ref, edr_ref, out_ref, eta_ref):
+    tile = edr_ref.shape[-1]
+    with fe.kernel_consts(tile):
+        ok = pv.finish_pbft_core(edok_ref[:][0] != 0,
+                                 _unstack_point(edpt_ref[:]), edr_ref[:])
+        one = jnp.ones(ok.shape, jnp.int32)
+        out_ref[:] = jnp.stack(
+            [ok.astype(jnp.int32), one, one, one, 0 * one], axis=0)
+        eta_ref[:] = jnp.zeros(eta_ref.shape, jnp.int32)
+
+
+def finish_pbft(ed_ok, ed_pt, ed_r, n_live):
+    """The `finish` of a Byron window, whose lanes hold an Ed25519 check
+    and nothing else: the `ed` point compressed and compared with R ->
+    (flags [5, B] with `finish`'s rows: the signature's verdict in row
+    0, rows 1-3 set, row 4 clear; eta [32, B] zeros), what `reduce`
+    takes unchanged."""
+    b = ed_r.shape[-1]
+    return _call(
+        _finish_pbft_kernel, "finish_pbft", b,
+        [(1,), (80,), (32,)],
+        [(5,), (32,)],
+        (ed_ok, ed_pt, ed_r),
+        with_base8=False, n_live=n_live,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Fused driver (one jit = one host dispatch)
 # ---------------------------------------------------------------------------
@@ -685,6 +712,7 @@ def split_stage_fns(kes_depth: int):
         ("vrf_bc", _jit1("vrf_bc", vrf_points_bc)),
         ("finish", _jit1("finish", finish)),
         ("finish_tp", _jit1("finish_tp", finish_tp)),
+        ("finish_pbft", _jit1("finish_pbft", finish_pbft)),
     ]
 
 
@@ -730,6 +758,23 @@ def _mk_packed_unpack(layout):
         if layout.vrf_proof_len == 128:
             return staged_to_limb_first_bc(*staged)
         return staged_to_limb_first(*staged)
+
+    return unpack_limb
+
+
+def _mk_pbft_unpack(layout):
+    """Factory for a Byron window's `unpack`: the packed wire (signed
+    bytes, R ‖ s, the delegate key) -> the `ed` stage's limb-first
+    operands (pk, r, s [32, B]; R ‖ A ‖ M in SHA-512 blocks [NB, 128, B];
+    blocks a lane [1, B]), NB = 3 at Byron's width."""
+
+    def unpack_limb(signed, sig, vk):  # `^jit_unpack_limb` in a trace
+        from ...protocol import pbft
+
+        pk, r, s, hb, hnb = pbft.unpack_pbft(layout, signed, sig, vk)
+        b = sig.shape[0]
+        return (_bf(pk), _bf(r), _bf(s), _bf_blocks(hb),
+                jnp.asarray(hnb).astype(jnp.int32).reshape(1, b))
 
     return unpack_limb
 
@@ -833,9 +878,11 @@ def verify_praos_packed_split(layout, *packed, tiles_live: int):
     behind it come back holding anything, and the caller slices them
     off."""
     kes_depth = layout.kes_depth
-    unpack = _jit1(("unpack", layout), _mk_packed_unpack(layout))
     b = np.asarray(packed[0]).shape[0]
     n_live = jax.device_put(np.full((1,), tiles_live, np.int32))
+    if layout.proofs == 0:  # a Byron window (protocol/pbft)
+        return _verify_pbft(layout, packed, b, n_live)
+    unpack = _jit1(("unpack", layout), _mk_packed_unpack(layout))
     a = _stage_call(
         packed_unpack_name(layout), unpack, b, kes_depth, *packed
     )
@@ -845,6 +892,22 @@ def verify_praos_packed_split(layout, *packed, tiles_live: int):
         "reduce", _jit1("reduce", reduce_fn), b, kes_depth, flags, eta
     )
     return (red, flags, eta, *rest)
+
+
+def _verify_pbft(layout, packed, b, n_live):
+    """A Byron window: `unpack` -> `ed` (its own width: three SHA-512
+    blocks) -> `finish_pbft` -> `reduce`, each stage's program stored
+    and dispatched as the other windows' are. No KES, no VRF."""
+    stages = dict(split_stage_fns(0))
+    unpack = _jit1(("unpack", layout), _mk_pbft_unpack(layout))
+    pk, r, s, hb, hnb = _stage_call(
+        packed_unpack_name(layout), unpack, b, 0, *packed)
+    ed = _stage_call("ed", stages["ed"], b, 0, pk, s, hb, hnb, n_live)
+    flags, eta = _stage_call("finish_pbft", stages["finish_pbft"], b, 0,
+                             ed[0], ed[1], r, n_live, span="finish")
+    red = _stage_call(
+        "reduce", _jit1("reduce", reduce_fn), b, 0, flags, eta)
+    return (red, flags, eta, eta)
 
 
 def _verify_praos_generic(relayout: str, cols, kes_depth: int):

@@ -330,6 +330,13 @@ def finish_core(
     return CoreVerdicts(ok_ed, ok_kes, ok_vrf, certain_win, ambiguous, eta, lv)
 
 
+def finish_pbft_core(ok_ed_pre, ed_point, ed_r):
+    """A Byron lane's finish: the Ed25519 check's point compressed and
+    compared with the signature's R -> ok [T] bool."""
+    enc = pc.compress_many([ed_point])[0]
+    return ok_ed_pre & jnp.all(enc == ed_r.astype(jnp.int32), axis=0)
+
+
 class TPraosCoreVerdicts(NamedTuple):
     ok_ocert_sig: jnp.ndarray  # [T] bool
     ok_kes_sig: jnp.ndarray

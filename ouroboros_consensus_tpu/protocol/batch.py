@@ -1362,7 +1362,7 @@ def pad_packed_to(packed: PraosPacked, size: int) -> PraosPacked:
     """Pad the per-lane columns up to `size` by replicating lane 0
     (window tables and the nonce are shared, not padded). Same jit-cache
     rationale as pad_batch_to."""
-    b = packed.body.shape[0]
+    b = packed[0].shape[0]  # the body (a Byron window's: signed) column
     if b == size:
         return packed
 
@@ -1573,6 +1573,15 @@ def verdict_reduce(flags, eta_bt, within, n_real, ev0, ev0_set, cand0,
 def _packed_xla_fn(layout: PraosPackedLayout):
     """The RAW (un-jitted) XLA-twin packed program of one layout:
     unpack -> fused verify -> pack."""
+
+    if layout.proofs == 0:  # a Byron window: the `ed` check alone
+        from . import pbft
+
+        def fn_pbft(*packed):
+            flags, eta = pbft.verify_xla(layout, *packed)
+            return (verdict_pack(flags, eta), flags, eta, eta)
+
+        return fn_pbft
 
     def fn(*packed):
         cols = unpack_packed(layout, *packed)
@@ -1927,6 +1936,29 @@ class BatchResult:
     n_valid: int  # length of the valid prefix
     error: praos.PraosValidationError | None  # error at position n_valid
     states: list | None = None  # per-position states (collect_states=True)
+    # a stream over eras (hardfork/cardano): the era `state` is in, and
+    # at each crossing (from era, to era, the state before the
+    # translation, the state after it)
+    era: int = 0
+    crossings: list | None = None
+
+
+class EraPiece:
+    """A run of one era's headers in chain order (ViewColumns, a list of
+    HeaderViews or pbft.ByronColumns) tagged with the era's index: what
+    the stream of a chain over eras (hardfork/cardano) hands
+    `validate_stream`."""
+
+    __slots__ = ("era", "cols")
+
+    def __init__(self, era: int, cols):
+        self.era, self.cols = era, cols
+
+    def __len__(self) -> int:
+        return len(self.cols)
+
+    def __getitem__(self, i: slice) -> "EraPiece":
+        return EraPiece(self.era, self.cols[i])
 
 
 def _counter_m(hk, counters, pool_distr):
@@ -2005,8 +2037,14 @@ def _proof_len_at(hvs, i: int) -> int:
     return len(hvs[i].vrf_proof)
 
 
+def _columnar(hvs) -> bool:
+    """A window of columns (ViewColumns, pbft.ByronColumns), not a list
+    of views."""
+    return not isinstance(hvs, (list, tuple))
+
+
 def _slot_at(hvs, i: int) -> int:
-    if isinstance(hvs, ViewColumns):
+    if _columnar(hvs):
         return int(hvs.slot[i])
     return hvs[i].slot
 
@@ -2263,6 +2301,7 @@ def _emit_window_span(meta, lanes: int, n_valid: int, failed: bool,
         stage_thread=meta.stage_thread, tiles_live=meta.tiles_live,
         epilogue_counters_s=_COUNTERS_S[0],
         vrf_proofs=lanes * meta.proofs,
+        pbft_s=_PBFT_S[0],
         **(meta.census._asdict() if meta.census is not None else {}),
     ))
 
@@ -2278,9 +2317,16 @@ class _StageCensus(NamedTuple):
     prechecks_s: float  # span `stage.prechecks`
     overlay_lanes: int = 0  # TPraos: live lanes in active overlay slots
     overlay_s: float = 0.0  # TPraos: span `stage.overlay`
+    pbft_lanes: int = 0  # PBFT: live regular (signed, non-EBB) lanes
+    ebbs: int = 0  # PBFT: epoch boundary blocks among the live lanes
 
 
 def _stage_census(hvs, pre, packed, prechecks_s: float) -> _StageCensus:
+    if hasattr(pre, "gk"):  # a Byron window (protocol/pbft.PBftChecks)
+        main = np.asarray(pre.main)
+        return _StageCensus(len(set(pre.gk[main].tolist())), 0, 0,
+                            prechecks_s, pbft_lanes=int(main.sum()),
+                            ebbs=int(main.size - main.sum()))
     if isinstance(pre, ColumnChecks):
         issuers = len(set(pre.uniq_hk))
     else:
@@ -2377,19 +2423,7 @@ def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
         packed = None
         gate = None
         if PACKED_STAGE and not os.environ.get("OCT_PK_FUSED"):
-            if isinstance(hvs, ViewColumns):
-                if isinstance(pre, ColumnChecks):
-                    packed = stage_packed_columns(
-                        params, lview, eta0, hvs, pre
-                    )
-                    if packed is None:
-                        gate = _LAST_DECLINE
-                else:
-                    gate = "no-column-prechecks"
-            else:
-                packed = stage_packed(params, lview, eta0, hvs)
-                if packed is None:
-                    gate = _LAST_DECLINE
+            packed, gate = rules.stage_packed(params, lview, eta0, hvs, pre)
         else:
             gate = "packed-off"
         census = (_stage_census(hvs, pre, packed, t_pre - t0)
@@ -2410,7 +2444,7 @@ def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
         layout, parr = packed
         parr = pad_packed_to(parr, size)
         h2d = _nbytes(parr)
-        lanes = parr.body.shape[0]
+        lanes = parr[0].shape[0]
     return _StagedWindow(pre, (layout, parr), None, b, lanes, h2d, gate,
                          t0, time.monotonic(), window, thread, census)
 
@@ -2615,6 +2649,8 @@ def _materialize_packed(out, b, impl, window=None):
 # wall of the last `epilogue.counters` span: the retire path clears it
 # before `_epilogue` and reads it into the window's WindowSpan after
 _COUNTERS_S = [0.0]
+# and of the last `epilogue.pbft` span (protocol/pbft.epilogue writes it)
+_PBFT_S = [0.0]
 
 
 @contextlib.contextmanager
@@ -2637,7 +2673,7 @@ def _fold_nonces(params: PraosParams, st: PraosState, slots, etas):
     here. The candidate follows the evolving nonce up to the window's
     last lane inside the stability window (praos.update)."""
     slots = np.asarray(slots)
-    first_next = (slots // params.epoch_length + 1) * params.epoch_length
+    first_next = params.first_slot_of(params.epoch_of(slots) + 1)
     w_idx = np.flatnonzero(slots + params.stability_window < first_next)
     k = int(w_idx[-1]) if w_idx.size else -1
     evolving = st.evolving_nonce
@@ -2929,6 +2965,7 @@ class PraosRules:
     protocol = "Praos"  # the name in a chain DB's config (tools/config)
     packed_only = False  # windows may fall back to the staged columns
     overlay = False  # no BFT overlay schedule: every slot is the lottery's
+    nonces = True  # epoch nonces: the loop looks the next one up ahead
 
     def initial_state(self) -> PraosState:
         return PraosState()
@@ -2959,6 +2996,19 @@ class PraosRules:
     def counter_known(self, lview):
         """Issuers whose OCert counter starts at 0 (Praos.hs:585-590)."""
         return lview.pool_distr
+
+    def stage_packed(self, params, lview, eta0, hvs, pre):
+        """-> ((layout, packed) or None, the gate that declined)."""
+        if isinstance(hvs, ViewColumns):
+            if not isinstance(pre, ColumnChecks):
+                return None, "no-column-prechecks"
+            packed = stage_packed_columns(params, lview, eta0, hvs, pre)
+        else:
+            packed = stage_packed(params, lview, eta0, hvs)
+        return packed, (_LAST_DECLINE if packed is None else None)
+
+    def epilogue(self, params, ticked, hvs, pre, v):
+        return _epilogue(params, ticked, hvs, pre, v)
 
     def lane_error(self, *a):
         return _lane_error(*a)
@@ -3109,8 +3159,8 @@ def _epoch_segments_idx(params, hvs) -> list[tuple[int, int, int]]:
     n = len(hvs)
     if n == 0:
         return []
-    if isinstance(hvs, ViewColumns):
-        epochs = hvs.slot // params.epoch_length
+    if _columnar(hvs):
+        epochs = params.epoch_of(hvs.slot)
         cuts = np.flatnonzero(np.diff(epochs)) + 1
         bounds = [0, *cuts.tolist(), n]
         return [
@@ -3132,6 +3182,8 @@ def _epoch_segments_idx(params, hvs) -> list[tuple[int, int, int]]:
 def _proof_break(hvs, w: int, j: int) -> int:
     """First index in (w, j) where the VRF proof format changes (a
     window must stage one uniform proof column), else j."""
+    if not isinstance(hvs, ViewColumns) and _columnar(hvs):
+        return j  # a Byron piece: one width, no proof
     if isinstance(hvs, ViewColumns):
         pl = hvs.vrf_proof_len
         diff = np.flatnonzero(pl[w + 1 : j] != pl[w])
@@ -3153,10 +3205,21 @@ def _validate_chain_loop(
 
     total_valid = 0
     win_idx = 0  # retire-order window index (RecoveryEvent / checkpoints)
+    rules = rules_of(params)
     for epoch, i, seg_end in _epoch_segments_idx(params, hvs):
         lview = ledger_view_for_epoch(epoch)
         while i < seg_end:
             j = min(i + max_batch, seg_end)
+            if not rules.nonces:
+                # a Byron window: the native verifier and the PBFT fold
+                res = rules.run_native(params, rules.tick(
+                    params, lview, _slot_at(hvs, i), state), hvs[i:j])
+                state = res.state
+                total_valid += res.n_valid
+                if res.error is not None:
+                    return BatchResult(state, total_valid, res.error)
+                i = j
+                continue
             ticked = praos.tick(params, lview, _slot_at(hvs, i), state)
             try:
                 res = validate_batch(
@@ -3212,6 +3275,20 @@ def _device_loop(
     # the protocol's prechecks, wire format and epilogue: what this loop
     # is parameterised by (`prepare_window` and `_epilogue` ask again)
     rules = rules_of(params)
+    # a chain over eras (hardfork/cardano.CardanoParams): its pieces come
+    # tagged (EraPiece) and each era has its own params and rules; the
+    # loop carries ONE state, in era `era_cur`, translated at a crossing
+    eras = getattr(params, "eras", None)
+
+    def era_of(item):
+        """-> (era, its params, its rules, the bare run of headers)."""
+        if isinstance(item, EraPiece):
+            p = eras[item.era]
+            return item.era, p, rules_of(p), item.cols
+        return 0, params, rules, item
+
+    era_cur = 0  # the era `state` is in
+    crossings: list = []
 
     # Backpressure at pipeline_depth on EACH side of the double buffer:
     # up to pipeline_depth windows staged-but-undispatched AND up to
@@ -3219,16 +3296,20 @@ def _device_loop(
     # thread the staged deque never exceeds one window; with it, at
     # most 2 x pipeline_depth windows are alive — ~8 MB packed each at
     # 8192 lanes, still far under HBM).
-    staged: deque = deque()  # (epoch, eta, window_hvs, staged-or-future, id)
-    inflight: deque = deque()  # (epoch, eta, window_hvs, pre, meta, future)
+    # (epoch, eta, window_hvs, staged-or-future, id, era)
+    staged: deque = deque()
+    # (epoch, eta, window_hvs, pre, meta, future, era)
+    inflight: deque = deque()
     total_valid = 0
 
     stream_done = False
     pending: deque = deque()  # runs of a pulled piece still to be cut
     piece = None  # the piece being cut (None: exhausted, pull the next)
+    p_era, pp, pr = 0, params, rules  # its era, the era's params, rules
     cuts: list = []  # its [(epoch, start, end)], and where the cut stands
     k = w = 0
     cut_epoch = cut_eta = None  # of the segment cut last
+    cut_era = None
     # (epoch, nonce of the epoch after it), published by the retire
     # path once `epoch`'s candidate nonce is frozen: the lookahead
     rotation = None
@@ -3247,20 +3328,35 @@ def _device_loop(
     def segment_eta(epoch: int, slot: int):
         """The epoch nonce the windows of a new segment stage with;
         `unknown` while it is not derivable (its predecessors must
-        retire further: past the freeze slot, or all of them)."""
+        retire further: past the freeze slot, or all of them). None in
+        an era without nonces (PBFT)."""
+        if not pr.nonces:
+            return None
         if not staged and not inflight:
             # everything cut so far has retired: the fold's own rotation
+            st = params.cross(state, era_cur, p_era) if eras else state
             return praos.tick(
-                params, lview_for(epoch), slot, state
+                pp, lview_for(epoch), slot, st
             ).state.epoch_nonce
         if epoch == cut_epoch:
             return cut_eta  # a row-width step: no rotation inside an epoch
         if rotation is not None and rotation[0] == cut_epoch < epoch:
             return rotation[1]
+        if cut_era is not None and cut_era != p_era \
+                and not eras[cut_era].batch_rules.nonces:
+            # the first epoch after an era without nonces: its nonce is
+            # the translation's (the Shelley genesis nonce) whatever the
+            # last era's state, so it is known now
+            st = params.cross(rules_of(eras[cut_era]).initial_state(),
+                              cut_era, p_era)
+            return praos.tick(
+                pp, lview_for(epoch), slot, st
+            ).state.epoch_nonce
         return unknown
 
     def enqueue_staging():
         nonlocal stream_done, piece, cuts, k, w, cut_epoch, cut_eta, progress
+        nonlocal p_era, pp, pr, cut_era
         while (
             # producer thread: stage ahead up to pipeline_depth
             # regardless of the in-flight side (double buffer)
@@ -3271,8 +3367,8 @@ def _device_loop(
             else not staged and len(inflight) < pipeline_depth
         ):
             if piece is None and pending:
-                nxt = pending.popleft()
-                cuts = _epoch_segments_idx(params, nxt)
+                p_era, pp, pr, nxt = era_of(pending.popleft())
+                cuts = _epoch_segments_idx(pp, nxt)
                 piece, k, w = nxt, 0, cuts[0][1]
             if piece is None:
                 if stream_done:
@@ -3298,20 +3394,23 @@ def _device_loop(
                 progress += 1
                 if not len(nxt):
                     continue
+                n_era, n_p, n_r, nxt = era_of(nxt)
                 # the runs the protocol's windows can be cut from (a
                 # piece whole, but for a TPraos list of ragged views)
-                nxt, *more = rules.runs(nxt)
-                pending.extend(more)
-                cuts = _epoch_segments_idx(params, nxt)
+                nxt, *more = n_r.runs(nxt)
+                pending.extend(EraPiece(n_era, m) if eras else m
+                               for m in more)
+                cuts = _epoch_segments_idx(n_p, nxt)
                 if not cuts:
                     continue
+                p_era, pp, pr = n_era, n_p, n_r
                 piece, k, w = nxt, 0, cuts[0][1]
             epoch, start, seg_end = cuts[k]
             if w == start:
                 eta = segment_eta(epoch, _slot_at(piece, start))
                 if eta is unknown:
                     return
-                cut_epoch, cut_eta = epoch, eta
+                cut_epoch, cut_eta, cut_era = epoch, eta, p_era
             # a window must stage a uniform proof column: break at the
             # first 80/128-byte format change (the reference fold
             # length-dispatches per header, so mixed chains stay valid;
@@ -3322,14 +3421,14 @@ def _device_loop(
             win = next_window_id()
             if stage_pool is not None:
                 item = stage_pool.submit(
-                    prepare_window, params, lview_for(epoch), cut_eta,
+                    prepare_window, pp, lview_for(epoch), cut_eta,
                     whvs, lanes, win,
                 )
             else:
                 item = prepare_window(
-                    params, lview_for(epoch), cut_eta, whvs, lanes, win,
+                    pp, lview_for(epoch), cut_eta, whvs, lanes, win,
                 )
-            staged.append((epoch, cut_eta, whvs, item, win))
+            staged.append((epoch, cut_eta, whvs, item, win, p_era))
             progress += 1
             w = j
             if w >= seg_end:
@@ -3356,7 +3455,7 @@ def _device_loop(
         # the producer keeps staging
         if not staged or len(inflight) >= pipeline_depth:
             return
-        epoch_w, eta_w, whvs_w, item, win = staged[0]
+        epoch_w, eta_w, whvs_w, item, win, era_w = staged[0]
         stage_wait_s = 0.0
         if stage_pool is not None and hasattr(item, "result"):
             late = not item.done()
@@ -3378,9 +3477,8 @@ def _device_loop(
                 staged.popleft()
                 if not _queue_failure(e):
                     raise
-                inflight.append(
-                    (epoch_w, eta_w, whvs_w, None, None, _FailedDispatch(e))
-                )
+                inflight.append((epoch_w, eta_w, whvs_w, None, None,
+                                 _FailedDispatch(e), era_w))
                 return
         staged.popleft()
         try:
@@ -3388,16 +3486,15 @@ def _device_loop(
         except Exception as e:  # noqa: BLE001 — gated below
             if not _queue_failure(e):
                 raise
-            inflight.append(
-                (epoch_w, eta_w, whvs_w, None, None, _FailedDispatch(e))
-            )
+            inflight.append((epoch_w, eta_w, whvs_w, None, None,
+                             _FailedDispatch(e), era_w))
             return
         meta = out.meta
         if meta is not None and stage_wait_s:
             meta = meta._replace(stage_wait_s=stage_wait_s)
         inflight.append(
             (epoch_w, eta_w, whvs_w, pre, meta,
-             pool.submit(materialize_verdicts, out, b))
+             pool.submit(materialize_verdicts, out, b), era_w)
         )
 
     win_retired = 0  # retire-order window index (recovery/checkpoints)
@@ -3423,7 +3520,8 @@ def _device_loop(
             # end of the stream leaves nothing in flight here
             assert (stream_done and piece is None and not staged
                     and not pending)
-            return BatchResult(state, total_valid, None)
+            return BatchResult(state, total_valid, None, era=era_cur,
+                               crossings=crossings or None)
 
         # refill the staging side BEFORE blocking on the retire below:
         # dispatching just freed buffer room, and the producer must be
@@ -3431,7 +3529,9 @@ def _device_loop(
         # thread idled during every retire block (the whole overlap)
         enqueue_staging()
 
-        epoch, eta, whvs, pre, meta, fut = inflight.popleft()
+        epoch, eta, whvs, pre, meta, fut, era_w = inflight.popleft()
+        wp = eras[era_w] if eras else params
+        wr = rules_of(wp)
         win = meta.index if meta is not None else None
         # the pipeline's fill as this window's retire wait begins
         inflight_behind, staged_ahead = len(inflight), len(staged)
@@ -3446,32 +3546,47 @@ def _device_loop(
                 raise
             fail = e
         t_m1 = time.monotonic()
+        if era_w != era_cur:
+            # a hard fork: the state into the window's era (span
+            # `era-cross`; the era's first windows were staged and
+            # dispatched behind the last era's, as at an epoch boundary)
+            with _enclose("era-cross"):
+                before = state
+                state = params.cross(state, era_cur, era_w)
+                crossings.append((era_cur, era_w, before, state))
+                era_cur = era_w
         with _enclose("tick", win):
-            ticked = praos.tick(
-                params, lview_for(epoch), _slot_at(whvs, 0), state
-            )
+            if wr.nonces:
+                ticked = praos.tick(
+                    wp, lview_for(epoch), _slot_at(whvs, 0), state
+                )
+            else:
+                ticked = wr.tick(wp, lview_for(epoch), _slot_at(whvs, 0),
+                                 state)
         # the window staged with a nonce carried over or looked AHEAD:
         # the real rotation must agree (internal invariant)
-        assert ticked.state.epoch_nonce == eta, (
+        assert not wr.nonces or ticked.state.epoch_nonce == eta, (
             "lookahead epoch nonce mismatch"
         )
         t_e0 = time.monotonic()
-        _COUNTERS_S[0] = 0.0  # a window off the fast epilogue reads 0
+        _COUNTERS_S[0] = _PBFT_S[0] = 0.0  # a window off the span reads 0
         if fail is None:
             try:
                 with _enclose("epilogue", win):
-                    res = _epilogue(params, ticked, whvs, pre, v)
+                    res = wr.epilogue(wp, ticked, whvs, pre, v)
             except Exception as e:  # noqa: BLE001 — gated below
                 if not _queue_failure(e):
                     raise
                 fail = e
+        if fail is not None and not wr.nonces:
+            raise fail  # a Byron window has no ladder to fall down
         if fail is not None:
             # the supervisor re-validates JUST this window down the
             # degradation ladder (retry -> stage-split -> xla-twin ->
             # host reference); any rung's result IS the window's
             # verdict
             res = _recovery.supervisor().recover_window(
-                params, ticked, whvs, fail, backend="device",
+                wp, ticked, whvs, fail, backend="device",
                 window=win_retired,
             )
         state = res.state
@@ -3482,7 +3597,8 @@ def _device_loop(
             staged_ahead,
         )
         if res.error is not None:
-            return BatchResult(state, total_valid, res.error)
+            return BatchResult(state, total_valid, res.error, era=era_cur,
+                               crossings=crossings or None)
         # progress record BEFORE the sigkill seam: a chaos (or real)
         # kill after this point loses nothing — the resume re-seeds
         # from exactly this retired window (obs/recovery.py)
@@ -3490,9 +3606,9 @@ def _device_loop(
         _chaos.fire("retire")
         win_retired += 1
 
-        if (rotation is None or rotation[0] != epoch) and (
+        if wr.nonces and (rotation is None or rotation[0] != epoch) and (
             state.last_slot + 1
-            >= params.first_slot_of(epoch + 1) - params.stability_window
+            >= wp.first_slot_of(epoch + 1) - wp.stability_window
         ):
             # every later header of the epoch has a larger slot, so the
             # candidate is frozen, and the LAB component was latched a

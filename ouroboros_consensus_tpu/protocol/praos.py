@@ -49,6 +49,11 @@ class PraosParams:
     active_slot_coeff: Fraction = Fraction(1, 20)  # f
     epoch_length: int = 432000  # fixed EpochInfo (slots per epoch)
     kes_depth: int = host_kes.DEFAULT_DEPTH  # CompactSum tree depth
+    # the era's origin on the hard-fork combinator's clock (EraParams /
+    # the Summary's era bound): the slot its first epoch starts at and
+    # that epoch's number. 0, 0: an era from genesis
+    era_start_slot: int = 0
+    era_start_epoch: int = 0
 
     @cached_property
     def stability_window(self) -> int:
@@ -59,11 +64,16 @@ class PraosParams:
         w = 3 * self.security_param / self.active_slot_coeff
         return int(-(-w // 1))
 
-    def epoch_of(self, slot: int) -> int:
-        return slot // self.epoch_length
+    def epoch_of(self, slot):
+        """The epoch of `slot` (an int or a numpy column of them),
+        counted from the era's origin: a slot before the era's first
+        lands in the epoch before its first."""
+        return (slot - self.era_start_slot) // self.epoch_length \
+            + self.era_start_epoch
 
     def first_slot_of(self, epoch: int) -> int:
-        return epoch * self.epoch_length
+        return (self.era_start_slot
+                + (epoch - self.era_start_epoch) * self.epoch_length)
 
     def kes_period_of(self, slot: int) -> int:
         assert self.slots_per_kes_period > 0

@@ -216,7 +216,7 @@ def overlay_columns(params: TPraosParams, n_delegs: int, slots):
         return kind, deleg
     p, q = d.numerator, d.denominator
     length = params.praos.epoch_length
-    i = slots - (slots // length) * length
+    i = slots - params.praos.first_slot_of(params.praos.epoch_of(slots))
     if (length + 1) * p + q >= 1 << 62:  # never, for a chain's d
         i = i.astype(object)
     lo = (i * p + (q - 1)) // q

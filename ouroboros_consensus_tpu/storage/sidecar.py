@@ -571,3 +571,151 @@ def integrity_batch_hook(sc: SidecarColumns):
         return m
 
     return hook
+
+
+# ---------------------------------------------------------------------------
+# era-tagged chunks (a Cardano chain: hardfork/cardano)
+# ---------------------------------------------------------------------------
+#
+# A chunk of era-tagged blocks ([era, inner]) holds runs of Byron headers
+# and of Shelley-family headers: its sidecar is the chunk's parse as it
+# streams (hardfork/cardano.parse_chunk): each piece's columns by name,
+# the piece's era and kind, and each block's txs span and committed hash
+# (the deep walk's body-hash compare). Same file name, same seals, same
+# tmp+rename protocol and chaos seams as the one-era format; its own
+# magic, so neither loader mistakes the other's file for its own.
+#
+#   header   magic ``OCTCOLSE`` + version + flags + n + chunk_len +
+#            chunk_crc32 + payload_crc32 + manifest length
+#   payload  the manifest (JSON: each array's name, dtype, shape and
+#            offset, by piece), then the arrays, 8-byte aligned
+
+ERA_MAGIC = b"OCTCOLSE"
+ERA_VERSION = 1
+_ERA_HEADER = struct.Struct("<8sIIIQIII")
+_BYRON_FIELDS = ("kind", "slot", "block_no", "prev_hash", "has_prev",
+                 "delegate_vk", "sig", "signed")
+
+
+@dataclass
+class EraSidecar:
+    """A loaded, seal-verified era sidecar: the chunk's pieces (zero-copy
+    views over the mapped file) and its integrity spans."""
+
+    pieces: list
+    spans: tuple  # (txs starts, ends, committed hashes)
+    walked: bool
+    _keepalive: tuple = field(default=(), repr=False)
+
+
+def _piece_arrays(cols) -> dict:
+    from ..protocol.pbft import ByronColumns
+
+    if isinstance(cols, ByronColumns):
+        return {f: getattr(cols, f) for f in _BYRON_FIELDS}
+    from dataclasses import fields as dc_fields
+
+    return {f.name: getattr(cols, f.name) for f in dc_fields(cols)}
+
+
+def build_era_bytes(pieces, spans, chunk_bytes,
+                    walked: bool = False) -> bytes | None:
+    """Serialize one era-tagged chunk's parse into a sealed blob."""
+    import json
+
+    from ..protocol.pbft import ByronColumns
+
+    n = int(len(spans[0]))
+    if n == 0:
+        return None
+    blobs: list = []
+    off = 0
+
+    def put(a) -> list:
+        nonlocal off
+        a = np.ascontiguousarray(a)
+        entry = [a.dtype.str, list(a.shape), off]
+        raw = a.tobytes()
+        pad = (-len(raw)) % 8
+        blobs.append(raw + bytes(pad))
+        off += len(raw) + pad
+        return entry
+
+    manifest = {
+        "spans": [put(np.asarray(x)) for x in spans],
+        "pieces": [
+            {"era": int(p.era),
+             "kind": "byron" if isinstance(p.cols, ByronColumns)
+             else "praos",
+             "cols": {k: put(v) for k, v in _piece_arrays(p.cols).items()}}
+            for p in pieces],
+    }
+    mbytes = json.dumps(manifest, separators=(",", ":")).encode()
+    mbytes += b" " * ((-len(mbytes)) % 8)  # JSON ends in blanks
+    payload = mbytes + b"".join(blobs)
+    header = _ERA_HEADER.pack(
+        ERA_MAGIC, ERA_VERSION, FLAG_WALKED if walked else 0, n,
+        len(chunk_bytes), _crc32(chunk_bytes), _crc32(payload),
+        len(mbytes))
+    return header + payload
+
+
+def backfill_era(fs, db_dir: str, chunk: int, pieces, spans, chunk_bytes,
+                 walked: bool = False) -> bool:
+    """`backfill` for an era-tagged chunk, from an in-hand parse."""
+    blob = build_era_bytes(pieces, spans, chunk_bytes, walked=walked)
+    if blob is None:
+        return False
+    try:
+        return write_sidecar(fs, db_dir, chunk, blob)
+    except OSError:
+        return False  # a missed optimization, never an error
+
+
+def load_era_sidecar(fs, db_dir: str, chunk: int, chunk_bytes,
+                     n_entries: int) -> tuple[EraSidecar | None, str]:
+    """`load_sidecar` for an era-tagged chunk: ``hit`` only when every
+    seal matches the LIVE chunk bytes."""
+    import json
+
+    from ..protocol.batch import EraPiece
+    from ..protocol.pbft import ByronColumns
+    from ..protocol.views import ViewColumns
+    from ..testing import chaos
+
+    path = sidecar_path(db_dir, chunk)
+    if chaos.sidecar_fault("sidecar-open", chunk=chunk) == "sidecar-stale":
+        return None, "stale"
+    if not fs.exists(path):
+        return None, "miss"
+    buf, keep = _map_bytes(fs, path)
+    hs = _ERA_HEADER.size
+    if len(buf) < hs:
+        return None, "torn"
+    (magic, version, flags, n, chunk_len, chunk_crc, payload_crc,
+     mlen) = _ERA_HEADER.unpack_from(buf, 0)
+    if magic != ERA_MAGIC or version != ERA_VERSION:
+        return None, "torn" if magic != MAGIC else "stale"
+    if n != n_entries or chunk_len != len(chunk_bytes) \
+            or chunk_crc != _crc32(chunk_bytes):
+        return None, "stale"
+    if payload_crc != _crc32(buf[hs:]):
+        return None, "torn"
+    manifest = json.loads(bytes(buf[hs:hs + mlen]))
+    base = hs + mlen
+
+    def get(entry):
+        dt, shape, off = entry
+        dtype = np.dtype(dt)
+        count = int(np.prod(shape)) if shape else 1
+        return np.frombuffer(buf, dtype, count=count,
+                             offset=base + off).reshape(shape)
+
+    spans = tuple(get(e) for e in manifest["spans"])
+    pieces = []
+    for p in manifest["pieces"]:
+        cols = {k: get(v) for k, v in p["cols"].items()}
+        pieces.append(EraPiece(p["era"], ByronColumns(**cols)
+                               if p["kind"] == "byron"
+                               else ViewColumns(**cols)))
+    return EraSidecar(pieces, spans, bool(flags & FLAG_WALKED), keep), "hit"

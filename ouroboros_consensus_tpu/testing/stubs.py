@@ -44,6 +44,32 @@ def stub_verify_tpraos(*cols):
     )
 
 
+def stub_verify_pbft(layout, signed, sig, vk):
+    """`pbft.verify_xla` with the curve arithmetic left to the host: the
+    window's Ed25519 verdicts come back through `jax.pure_callback` from
+    the native verifier (exact verdicts, no curve graph to compile), the
+    rest of the program — the packed wire, the flag rows, `reduce` — as
+    the device runs it."""
+    import jax
+    import numpy as np
+
+    from .. import native_loader
+
+    def host(signed, sig, vk):
+        signed, sig, vk = (np.asarray(x, np.uint8) for x in (signed, sig, vk))
+        return np.array([
+            native_loader.native_ed25519_verify(
+                vk[i].tobytes(), sig[i].tobytes(), signed[i].tobytes())
+            for i in range(sig.shape[0])], np.bool_)
+
+    b = sig.shape[0]
+    ok = jax.pure_callback(host, jax.ShapeDtypeStruct((b,), jnp.bool_),
+                           signed, sig, vk)
+    one = jnp.ones((b,), jnp.int32)
+    flags = jnp.stack([ok.astype(jnp.int32), one, one, one, 0 * one])
+    return flags, jnp.zeros((b, 32), jnp.int32)
+
+
 def stub_verify(*cols):
     """All-valid crypto stub with the real eta / leader-value range
     extensions. Arity-generic (21 draft-03 / 22 batch-compatible
@@ -330,6 +356,12 @@ def install_stub_crypto(monkeypatch=None, agg_delay_s=None):
         else:
             setattr(pbatch, name, value)
 
+    from ..protocol import pbft
+
+    if monkeypatch is not None:
+        monkeypatch.setattr(pbft, "verify_xla", stub_verify_pbft)
+    else:
+        pbft.verify_xla = stub_verify_pbft
     setattr_("verify_praos", stub_verify)
     setattr_("verify_praos_bc", stub_verify)
     setattr_("verify_praos_any", stub_verify)

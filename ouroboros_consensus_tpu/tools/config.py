@@ -8,7 +8,7 @@ from which `mkProtocolInfo` assembles the protocol configuration.
 
 This framework's single-protocol analog:
 
-  config.json            {"Protocol": "Praos" | "TPraos",
+  config.json            {"Protocol": "Praos" | "TPraos" | "Cardano",
                           "GenesisFile": "genesis.json",
                           "CredentialsFile": "credentials.json"?}
   genesis.json           protocol parameters + pool distribution
@@ -16,6 +16,11 @@ This framework's single-protocol analog:
                          a TPraos chain's also holds `decentralisation`
                          [num, den] and `genDelegs` (cold key + VRF key
                          hash of each genesis delegate, in order)
+                         a Cardano chain's holds one such object an era
+                         ("byron": the PBFT numbers and the delegation
+                         map; "shelley", "babbage": the above, each with
+                         the epoch its era starts at, TriggerHardForkAtEpoch)
+                         and the Shelley genesis nonce
   credentials.json       signing seeds per pool (synthesizer side only,
                          the analog of the bulk credentials file
                          DBSynthesizer/Run.hs loads)
@@ -73,24 +78,10 @@ def write_genesis_files(
     material is provided). Returns the config.json path."""
     os.makedirs(dir_path, exist_ok=True)
     rules = pbatch.rules_of(params)
-    genesis = {
-        "params": _params_to_json(params),
-        "poolDistr": [
-            {
-                "poolId": pid.hex(),
-                "stake": [ips.stake.numerator, ips.stake.denominator],
-                "vrfKeyHash": ips.vrf_key_hash.hex(),
-            }
-            for pid, ips in sorted(lview.pool_distr.items())
-        ],
-    }
-    if rules.overlay:
-        d = params.decentralization
-        genesis["decentralisation"] = [d.numerator, d.denominator]
-        genesis["genDelegs"] = [
-            {"vkCold": g.vk_cold.hex(), "vrfKeyHash": g.vrf_key_hash.hex()}
-            for g in lview.gen_delegs
-        ]
+    if rules.protocol == "Cardano":
+        genesis = _cardano_genesis(params, lview)
+    else:
+        genesis = _genesis(params, lview)
     with open(os.path.join(dir_path, "genesis.json"), "w") as f:
         json.dump(genesis, f, indent=1, sort_keys=True)
     config = {"Protocol": rules.protocol, "GenesisFile": "genesis.json"}
@@ -113,23 +104,80 @@ def write_genesis_files(
     return cpath
 
 
-def load_config(config_path: str):
-    """mkProtocolInfo analog: (params, ledger_view, pools|None). The
-    config's `Protocol` says what the params and the view are: a TPraos
-    chain's are `TPraosParams` / `TPraosLedgerView`, and every tool that
-    takes them (db_synthesizer.synthesize, db_analyser.revalidate)
-    forges or validates by that protocol's rules."""
-    base = os.path.dirname(os.path.abspath(config_path))
-    with open(config_path) as f:
-        config = json.load(f)
-    protocol = config.get("Protocol", "Praos")
-    if protocol not in ("Praos", "TPraos"):
-        raise ValueError(
-            f"unsupported Protocol {protocol!r}: this tool takes "
-            '"Praos" and "TPraos"'
-        )
-    with open(os.path.join(base, config["GenesisFile"])) as f:
-        genesis = json.load(f)
+def _genesis(params, lview) -> dict:
+    rules = pbatch.rules_of(params)
+    genesis = {
+        "params": _params_to_json(params),
+        "poolDistr": [
+            {
+                "poolId": pid.hex(),
+                "stake": [ips.stake.numerator, ips.stake.denominator],
+                "vrfKeyHash": ips.vrf_key_hash.hex(),
+            }
+            for pid, ips in sorted(lview.pool_distr.items())
+        ],
+    }
+    if rules.overlay:
+        d = params.decentralization
+        genesis["decentralisation"] = [d.numerator, d.denominator]
+        genesis["genDelegs"] = [
+            {"vkCold": g.vk_cold.hex(), "vrfKeyHash": g.vrf_key_hash.hex()}
+            for g in lview.gen_delegs
+        ]
+    return genesis
+
+
+def _cardano_genesis(params, views) -> dict:
+    """One object an era (hardfork/cardano.CardanoParams / CardanoViews):
+    Byron's PBFT numbers and delegation map, Shelley's and Babbage's
+    genesis as a one-era chain's, each with its first epoch."""
+    by = params.byron
+    shelley = _genesis(params.shelley, views.shelley)
+    shelley["startEpoch"] = params.shelley.era_start_epoch
+    babbage = _genesis(params.babbage, views.babbage)
+    babbage["startEpoch"] = params.babbage.era_start_epoch
+    return {
+        "byron": {
+            "numGenesisKeys": by.num_genesis_keys,
+            "threshold": [by.threshold.numerator, by.threshold.denominator],
+            "securityParam": by.security_param,
+            "epochLength": by.epoch_length,
+            "delegates": [
+                {"delegateVk": vk.hex(), "genesisKey": g}
+                for vk, g in sorted(views.byron.delegates.items(),
+                                    key=lambda kv: kv[1])
+            ],
+        },
+        "shelley": shelley,
+        "babbage": babbage,
+        "shelleyGenesisNonce": params.genesis_nonce.hex(),
+    }
+
+
+def _load_cardano(genesis: dict):
+    from ..hardfork import cardano
+    from ..protocol.instances import PBftLedgerView
+    from ..protocol.pbft import PBftEraParams
+
+    b = genesis["byron"]
+    byron = PBftEraParams(
+        num_genesis_keys=b["numGenesisKeys"],
+        threshold=Fraction(*b["threshold"]),
+        security_param=b["securityParam"], epoch_length=b["epochLength"])
+    sh, sh_view = _load_one(genesis["shelley"], "TPraos")
+    bb, bb_view = _load_one(genesis["babbage"], "Praos")
+    params = cardano.make(byron, sh.praos, sh.decentralization, bb,
+                          bytes.fromhex(genesis["shelleyGenesisNonce"]),
+                          genesis["shelley"]["startEpoch"],
+                          genesis["babbage"]["startEpoch"])
+    views = cardano.CardanoViews(
+        PBftLedgerView({bytes.fromhex(d["delegateVk"]): d["genesisKey"]
+                        for d in b["delegates"]}),
+        sh_view, bb_view)
+    return params, views
+
+
+def _load_one(genesis: dict, protocol: str):
     params = _params_from_json(genesis["params"])
     pool_distr = {
         bytes.fromhex(e["poolId"]): IndividualPoolStake(
@@ -151,6 +199,30 @@ def load_config(config_path: str):
         )
     else:
         lview = LedgerView(pool_distr=pool_distr)
+    return params, lview
+
+
+def load_config(config_path: str):
+    """mkProtocolInfo analog: (params, ledger_view, pools|None). The
+    config's `Protocol` says what the params and the view are: a TPraos
+    chain's are `TPraosParams` / `TPraosLedgerView`, and every tool that
+    takes them (db_synthesizer.synthesize, db_analyser.revalidate)
+    forges or validates by that protocol's rules."""
+    base = os.path.dirname(os.path.abspath(config_path))
+    with open(config_path) as f:
+        config = json.load(f)
+    protocol = config.get("Protocol", "Praos")
+    if protocol not in ("Praos", "TPraos", "Cardano"):
+        raise ValueError(
+            f"unsupported Protocol {protocol!r}: this tool takes "
+            '"Praos", "TPraos" and "Cardano"'
+        )
+    with open(os.path.join(base, config["GenesisFile"])) as f:
+        genesis = json.load(f)
+    if protocol == "Cardano":
+        params, lview = _load_cardano(genesis)
+    else:
+        params, lview = _load_one(genesis, protocol)
     pools = None
     if "CredentialsFile" in config:
         with open(os.path.join(base, config["CredentialsFile"])) as f:

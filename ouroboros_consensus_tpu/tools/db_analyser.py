@@ -61,6 +61,11 @@ class ValidationResult:
     d2h_bytes: int = 0  # verdict/nonce bytes shipped device->host
     n_windows: int = 0  # dispatched windows
     packed_windows: int = 0  # windows that staged packed
+    # a chain over eras (hardfork/cardano): the era of `final_state`,
+    # and at each hard fork (from era, to era, the state before the
+    # translation, the state after it)
+    era: int = 0
+    crossings: list | None = None
 
 
 class _PhaseCollector:
@@ -320,6 +325,15 @@ def _stream_windows(imm: ImmutableDB, res: "ValidationResult"):
         entries = imm._entries[n]
         if not entries:
             continue
+        if native_ok and columnar and _era_tagged(imm, n, entries):
+            # a chain over eras (hardfork/cardano): era-tagged blocks,
+            # each run of one era parsed into its era's columns
+            pieces, truncated = _era_chunk_pieces(imm, res, n, chunk_idx,
+                                                  entries, stream_deep)
+            yield from pieces
+            if truncated:
+                return  # corruption truncates the chain here
+            continue
         # the per-chunk disk read + integrity walk + native column
         # extraction is the "stream" span of the flight recorder (one
         # Enclose bracket per CHUNK — per-window granularity, no object
@@ -433,6 +447,81 @@ def _stream_windows(imm: ImmutableDB, res: "ValidationResult"):
             return  # corruption truncates the chain here
 
 
+def _era_tagged(imm, n, entries) -> bool:
+    """Does chunk `n` hold era-tagged blocks ([era, inner]: a 2-array
+    whose first item is an integer, where a Praos block's is its header
+    array)? Read from the first block's first two bytes."""
+    from ..storage.immutable import _chunk_name
+
+    with open(os.path.join(imm.path, _chunk_name(n)), "rb") as f:
+        f.seek(int(entries.offset[0]))
+        head = f.read(2)
+    return len(head) == 2 and head[0] == 0x82 and head[1] <= 0x17
+
+
+def _era_chunk_pieces(imm, res, n, chunk_idx, entries, stream_deep):
+    """One chunk of era-tagged blocks -> (EraPiece runs in chain order,
+    whether the deep walk truncated the chunk): Byron blocks as
+    pbft.ByronColumns, Shelley-family blocks as ViewColumns. From the
+    chunk's era sidecar (storage/sidecar.load_era_sidecar) where a fresh
+    seal holds it, else from ONE native parse (hardfork/cardano
+    .parse_chunk), which a writer open seals for the next replay. Either
+    hands the deep walk (when the open deferred it to this read) each
+    block's txs span and committed hash: the batched body-hash compare
+    (storage/open.check_spans), behind the per-blob CRC walk unless a
+    WALKED seal's whole-chunk CRC stands in for it."""
+    from .. import native_loader
+    from ..hardfork import cardano
+    from ..storage import sidecar as sidecar_mod
+    from ..storage.immutable import _chunk_name
+    from ..storage.open import (check_spans, default_check_integrity,
+                                default_check_integrity_batch)
+
+    truncated = False
+    with pbatch._enclose("stream", parent="replay"):
+        data = _read_chunk(os.path.join(imm.path, _chunk_name(n)), chunk_idx)
+        sc = parsed = None
+        if sidecar_mod.enabled():
+            with pbatch._enclose("stream-mmap"):
+                sc, outcome = sidecar_mod.load_era_sidecar(
+                    imm.fs, imm.path, n, data, len(entries))
+            sidecar_mod.record(outcome, n)
+        if sc is not None:
+            parsed = (sc.pieces, sc.spans)
+        else:
+            with pbatch._enclose("stream-parse"):
+                try:
+                    parsed = cardano.parse_chunk(data, entries.offset)
+                except native_loader.MalformedBlock:
+                    pass  # the deep walk names the block
+        if stream_deep:
+            if sc is not None and sc.walked:
+                good = check_spans(data, entries, *sc.spans)
+            else:
+                hook = default_check_integrity_batch
+                if parsed is not None:
+                    def hook(data, prefix, p=parsed):
+                        return check_spans(data, prefix, *p[1])
+                good = imm.deep_check_loaded(data, entries,
+                                             default_check_integrity, hook)
+            truncated = good < len(entries)
+            if truncated:
+                entries = entries[:good]
+                if getattr(imm, "stream_repair", False):
+                    imm.repair_to(n, good, data=data)
+        if truncated or parsed is None:
+            with pbatch._enclose("stream-parse"):
+                parsed = cardano.parse_chunk(data, entries.offset)
+        elif sc is None and getattr(imm, "_repair", False):
+            # a writer open seals what it just parsed (walked only when
+            # this replay's deep walk covered the whole chunk)
+            if sidecar_mod.backfill_era(imm.fs, imm.path, n, *parsed, data,
+                                        walked=stream_deep):
+                sidecar_mod.record("rebuilt", n)
+        res.n_blocks += len(entries)
+    return parsed[0], truncated
+
+
 def _stream_views(imm: ImmutableDB, res: "ValidationResult"):
     """Per-header HeaderView stream (the sequential reference fold's
     input; the batched backends consume `_stream_windows`)."""
@@ -485,12 +574,16 @@ def _epoch_window_segments(params: PraosParams, wins):
     within-epoch tick is a no-op rotation)."""
     from ..protocol.views import ViewColumns
 
+    if getattr(params, "eras", None) is not None:
+        yield from _era_epoch_segments(params, wins)
+        return
+
     def pieces():
         import numpy as np
 
         for win in wins:
             if isinstance(win, ViewColumns):
-                epochs = win.slot // params.epoch_length
+                epochs = params.epoch_of(win.slot)
                 cuts = np.flatnonzero(np.diff(epochs)) + 1
                 bounds = [0, *cuts.tolist(), len(win)]
                 for k in range(len(bounds) - 1):
@@ -540,6 +633,49 @@ def _epoch_window_segments(params: PraosParams, wins):
             acc, epoch = [piece], e
     if acc:
         yield from flush(acc)
+
+
+def _era_epoch_segments(params, wins):
+    """`_epoch_window_segments` for a chain over eras: each EraPiece is
+    cut at its era's epoch boundaries (the era params' own clock:
+    Byron's epochs, then Shelley's, on one count), and consecutive runs
+    of one era, one epoch and one row width merge into ONE segment. An
+    era boundary is an epoch boundary, so no segment spans two eras."""
+    import numpy as np
+
+    from ..protocol.batch import EraPiece
+    from ..protocol.pbft import ByronColumns
+    from ..protocol.views import ViewColumns
+
+    def width(cols):
+        if isinstance(cols, ByronColumns):
+            return ("byron", cols.signed.shape[1])
+        return (cols.signed_bytes.shape[1], cols.kes_sig.shape[1])
+
+    def concat(group):
+        if len(group) == 1:
+            return group[0]
+        if isinstance(group[0], ByronColumns):
+            return ByronColumns.concat(group)
+        return ViewColumns.concat(group)
+
+    group: list = []
+    key = None
+    for ep in wins:
+        p = params.era_params(ep.era)
+        epochs = p.epoch_of(ep.cols.slot)
+        cuts = np.flatnonzero(np.diff(epochs)) + 1
+        bounds = [0, *cuts.tolist(), len(ep.cols)]
+        for a, b in zip(bounds, bounds[1:]):
+            part = ep.cols[a:b]
+            k = (ep.era, int(epochs[a]), width(part))
+            if group and k != key:
+                yield EraPiece(key[0], concat(group))
+                group = []
+            group.append(part)
+            key = k
+    if group:
+        yield EraPiece(key[0], concat(group))
 
 
 class _Prefetched:
@@ -865,6 +1001,19 @@ def _revalidate_body(
     # state, its sequential reference and its batched path
     rules = pbatch.rules_of(params)
     st = rules.initial_state()
+    # the ledger view of each epoch: a chain over eras has one a era
+    # (hardfork/cardano.CardanoViews); the others one for every epoch
+    for_epoch = (lview.for_epoch(params) if hasattr(lview, "for_epoch")
+                 else (lambda _e: lview))
+    if rules.protocol == "Cardano" and backend == "host":
+        from ..hardfork import cardano
+
+        wins = _stream_windows(imm, res)
+        if max_headers is not None:
+            wins = _cap_windows(wins, max_headers)
+        cardano.host_replay(params, lview, wins, res)
+        res.wall_s = time.monotonic() - t0
+        return res
     if ledger is not None and getattr(ledger, "view_for_epoch", None):
         # ledger-derived epoch views: stream BLOCKS (the ledger replay
         # needs tx bodies), segment at epoch boundaries, and feed each
@@ -972,26 +1121,40 @@ def _revalidate_body(
                     segs = _prefetch_iter(segs, depth=2)
                 ts = time.monotonic()
                 result = pbatch.validate_stream(
-                    params, lambda _e: lview, st, segs, max_batch=max_batch,
+                    params, for_epoch, st, segs, max_batch=max_batch,
                 )
                 res.device_s += time.monotonic() - ts
                 st = result.state
                 res.n_valid += result.n_valid
                 res.error = result.error
+                res.era, res.crossings = result.era, result.crossings
                 if res.error is None:
                     trace(f"validated {res.n_valid} headers")
             else:
                 # native / sharded: one epoch segment buffered at a time
                 segs, end = iter(segs), object()
+                era_cur, crossings = 0, []
                 while True:
                     # the main thread's wait for the stream
                     with pbatch._enclose("segment-wait"):
                         seg = next(segs, end)
                     if seg is end:
                         break
+                    seg_params, seg_views = params, for_epoch
+                    if isinstance(seg, pbatch.EraPiece):
+                        # a chain over eras: the era's params and view,
+                        # the state translated at a hard fork
+                        if seg.era != era_cur:
+                            before = st
+                            st = params.cross(st, era_cur, seg.era)
+                            crossings.append((era_cur, seg.era, before, st))
+                            era_cur = seg.era
+                        seg_params = params.era_params(seg.era)
+                        seg, era_view = seg.cols, lview.era_view(seg.era)
+                        seg_views = lambda _e, v=era_view: v  # noqa: E731
                     ts = time.monotonic()
                     result = pbatch.validate_chain(
-                        params, lambda _e: lview, st, seg,
+                        seg_params, seg_views, st, seg,
                         max_batch=max_batch, backend=backend,
                     )
                     res.device_s += time.monotonic() - ts
@@ -1001,6 +1164,7 @@ def _revalidate_body(
                         res.error = result.error
                         break
                     trace(f"validated {res.n_valid} headers")
+                res.era, res.crossings = era_cur, crossings or None
             w = _recovery._WRITER
             if w is not None:
                 # mark the record COMPLETE (cleanly or at a validation
@@ -1416,53 +1580,41 @@ def main(argv=None) -> None:
     p.add_argument("--out-csv", default=None)
     p.add_argument("--config", default=None,
                    help="node config.json (defaults to <db>/config/config.json "
-                        "when present) instead of --pools/--kes-depth")
-    p.add_argument("--cardano", action="store_true",
-                   help="the DB holds the multi-era composite "
-                        "(DBAnalyser/Block/Cardano.hs dispatch): "
-                        "era-tagged blocks, per-era protocols, optional "
-                        "full ledger replay (--with-ledgers)")
+                        "when present) instead of --pools/--kes-depth; its "
+                        "\"Protocol\" (Praos, TPraos, Cardano) says what "
+                        "the chain is (DBAnalyser/Block/Cardano.hs)")
     p.add_argument("--with-ledgers", action="store_true",
-                   help="with --cardano: fold the real era ledgers too")
+                   help="the toy composite forged by db_synthesizer "
+                        "--cardano --with-ledgers (hardfork/composite): "
+                        "fold its era ledgers too")
     a = p.parse_args(argv)
-    if a.with_ledgers and not a.cardano:
-        p.error("--with-ledgers requires --cardano")
     from .. import compile_cache
 
     compile_cache.configure()  # before the first trace
-    if a.cardano:
-        # block-type dispatch to the composite (the reference's
-        # db-analyser picks the block type from the node config;
-        # the composite's defaults mirror CardanoMockConfig)
+    if a.with_ledgers:
+        # the era ledgers' fold is the composite's (ROADMAP Reach 5)
         import json as _json
 
         from ..hardfork import composite as cardano
 
         if a.analysis != "only-validation":
-            raise SystemExit("--cardano supports only-validation")
-        if a.repair or a.resume:
+            raise SystemExit("--with-ledgers supports only-validation")
+        if a.repair or a.resume or a.config is not None:
             # a silently ignored flag would fake a repair/resume that
-            # never ran — refuse loudly (same rule as --config below)
+            # never ran, or revalidate under WRONG parameters
             raise SystemExit(
-                "--cardano does not support --repair/--resume (the "
-                "composite replay opens its stores read-only)"
+                "--with-ledgers reads the composite's built-in config "
+                "and opens its store read-only: no --config, --repair "
+                "or --resume"
             )
-        if a.config is not None:
-            # an ignored config would revalidate under WRONG parameters
-            # and report spurious errors — refuse loudly instead
-            raise SystemExit(
-                "--cardano reads the composite's built-in config "
-                "(CardanoMockConfig defaults); --config is not supported"
-            )
-        cfg = cardano.CardanoMockConfig(with_ledgers=a.with_ledgers)
+        cfg = cardano.CardanoMockConfig(with_ledgers=True)
         res = cardano.revalidate(a.db, cfg, backend=a.backend)
         out = {
             "blocks": res.n_blocks, "valid": res.n_valid,
             "per_era": res.per_era,
             "error": None if res.error is None else repr(res.error),
         }
-        if (res.error is not None and a.with_ledgers
-                and res.n_valid == res.n_blocks):
+        if res.error is not None and res.n_valid == res.n_blocks:
             # CONSENSUS passed on every block, only the LEDGER replay
             # failed — most often a flag mismatch, not corruption
             out["hint"] = (

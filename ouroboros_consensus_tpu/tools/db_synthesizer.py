@@ -319,7 +319,7 @@ def _forge_pipeline(
         # reference loop does
         ticked0 = rules.tick(params, lv_now, slot, st)
         eta0 = ticked0.state.epoch_nonce
-        epoch_end = (params.epoch_of(slot) + 1) * params.epoch_length
+        epoch_end = params.first_slot_of(params.epoch_of(slot) + 1)
         wend = min(epoch_end, slot + forge_mod.window_slots(len(pools)))
         if limit.slots is not None:
             wend = min(wend, limit.slots)
@@ -410,6 +410,41 @@ def _forge_pipeline(
     return st, prev_hash, block_no, slot
 
 
+def _synthesize_cardano(imm, params, pools, views, limit, trace, elector,
+                        ledger, vrf_backend) -> ForgeResult:
+    """A chain over the three eras (hardfork/cardano.forge), forged on the
+    host from genesis."""
+    from ..hardfork import cardano
+
+    if elector is not None or ledger is not None or not imm.is_empty:
+        raise ValueError("a Cardano chain is forged here, on the host, "
+                         "from genesis, against constant ledger views")
+    if vrf_backend == "device":
+        raise ValueError("no Cardano device forge: pass vrf_backend="
+                         "\"host\"")
+    res = ForgeResult()
+    t0 = time.monotonic()
+    res.n_blocks, res.n_slots, res.final_state = cardano.forge(
+        imm, params, pools, views, limit, trace)
+    imm.flush()
+    # forge-time sidecars, as the one-era chains': every chunk sealed
+    # WALKED (the forge wrote these exact bytes)
+    from ..storage import sidecar as sidecar_mod
+    from ..storage.immutable import _chunk_name
+
+    if sidecar_mod.enabled():
+        for n in imm._chunks:
+            entries = imm._entries[n]
+            if not entries:
+                continue
+            data = imm.fs.read_bytes(os.path.join(imm.path, _chunk_name(n)))
+            sidecar_mod.backfill_era(
+                imm.fs, imm.path, n, *cardano.parse_chunk(
+                    data, entries.offset), data, walked=True)
+    res.wall_s = time.monotonic() - t0
+    return res
+
+
 def _synthesize_locked(
     imm, db_path, params, pools, lview, limit, txs_per_block,
     vrf_backend, trace, ledger_view_for_epoch, txs_for_block,
@@ -424,6 +459,9 @@ def _synthesize_locked(
         forge_mod.engine_from_env(vrf_backend))
     rules = pbatch.rules_of(params)
     st = rules.initial_state()
+    if rules.protocol == "Cardano":
+        return _synthesize_cardano(imm, params, pools, lview, limit, trace,
+                                   elector, ledger, vrf_backend)
     if rules.overlay:
         if elector is not None or ledger is not None:
             raise ValueError("a TPraos chain is elected here, on the "
@@ -540,7 +578,7 @@ def _synthesize_locked(
         eta0 = ticked.state.epoch_nonce
         if vrf_backend == "device" and slot >= span_end:
             # next span: up to the epoch boundary (eta0 is epoch-constant)
-            epoch_end = (params.epoch_of(slot) + 1) * params.epoch_length
+            epoch_end = params.first_slot_of(params.epoch_of(slot) + 1)
             span_end = min(epoch_end, slot + 16 * _VRF_BUCKET)
             if limit.slots is not None:
                 span_end = min(span_end, limit.slots)
@@ -654,20 +692,24 @@ def main(argv=None) -> None:
     p.add_argument("--config", default=None,
                    help="node config.json (with CredentialsFile) instead "
                         "of --pools/--kes-depth generated credentials")
-    p.add_argument("--protocol", choices=("praos", "tpraos"),
+    p.add_argument("--protocol", choices=("praos", "tpraos", "cardano"),
                    default="praos",
                    help="tpraos: the Shelley-era protocol (two VRF "
-                        "certificates a block, the BFT overlay)")
+                        "certificates a block, the BFT overlay); cardano: "
+                        "Byron (PBFT) from genesis, Shelley (TPraos) from "
+                        "epoch 1, Babbage (Praos) from epoch 2, era-tagged "
+                        "blocks (hardfork/cardano)")
     p.add_argument("--delegates", type=int, default=7,
-                   help="tpraos: genesis delegates (mainnet: 7)")
+                   help="tpraos, cardano: genesis delegates (mainnet: 7)")
     p.add_argument("--decentralisation", default="1/2",
                    help="tpraos: d, the overlay's share of the slots")
     p.add_argument("--cardano", action="store_true",
-                   help="forge the multi-era composite (era-tagged "
-                        "blocks crossing the Byron/Shelley/Babbage "
-                        "boundaries); pairs with db_analyser --cardano")
+                   help="forge the toy multi-era composite "
+                        "(hardfork/composite: 40-slot Byron epochs, k = 5; "
+                        "its own config says \"Protocol\": \"Cardano\")")
     p.add_argument("--with-ledgers", action="store_true",
-                   help="with --cardano: real era ledgers in the loop")
+                   help="with --cardano: real era ledgers in the loop; "
+                        "pairs with db_analyser --with-ledgers")
     a = p.parse_args(argv)
     if a.with_ledgers and not a.cardano:
         p.error("--with-ledgers requires --cardano")
@@ -698,6 +740,13 @@ def main(argv=None) -> None:
             params, pools, lview = make_tpraos(
                 params, pools, lview, a.delegates,
                 Fraction(a.decentralisation), first_seed=a.pools)
+        elif a.protocol == "cardano":
+            from ..hardfork import cardano
+
+            params, pools, lview = cardano.deployment(
+                0, delegates=a.delegates, pools=a.pools,
+                kes_depth=a.kes_depth, d=Fraction(a.decentralisation),
+                delegate_seed=a.pools)
     res = synthesize(
         a.out, params, pools, lview,
         ForgeLimit(slots=a.slots, blocks=a.blocks, epochs=a.epochs),

@@ -389,6 +389,12 @@ class WindowSpan:
     # `stage.prechecks`, on `stage_thread`); 0 under Praos
     overlay_lanes: int = 0
     overlay_s: float = 0.0
+    # PBFT (a Byron window): live regular lanes (EBB lanes excluded), the
+    # wall of the columnar signing-window count (span `epilogue.pbft`) and
+    # the EBB lanes; 0 in the other eras
+    pbft_lanes: int = 0
+    pbft_s: float = 0.0
+    ebbs: int = 0  # epoch boundary blocks among a Byron window's lanes
 
 
 # -- the consensus event vocabulary (Tracers' record, condensed) -------------

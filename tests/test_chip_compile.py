@@ -261,6 +261,57 @@ def test_finish_lowers_with_its_kernel_name(one_chip, chip_seams):
     assert 'kernel_name = "finish"' in text
 
 
+@functools.lru_cache(maxsize=None)
+def _packed_pbft():
+    """(layout, unpack-argument arrays padded to LANES) of a real Byron
+    window: an EBB, then main headers signed by three delegates."""
+    from ouroboros_consensus_tpu.hardfork import byron_mock
+    from ouroboros_consensus_tpu.protocol import pbft
+
+    blocks = [byron_mock.forge_ebb(slot=0, block_no=0, prev_hash=None)]
+    for i in range(1, 8):
+        blocks.append(byron_mock.forge_block(
+            bytes([i % 3 + 1]) * 32, slot=i, block_no=i - 1,
+            prev_hash=blocks[-1].hash_))
+    cols = pbft.ByronColumns.from_headers([b.header for b in blocks])
+    layout, parr = pbft.stage_packed(cols)
+    assert layout.body_len == byron_mock.BYRON_SIGNED_LEN
+    return layout, pbatch.pad_packed_to(parr, LANES)
+
+
+def test_pbft_unpack_compiles(one_chip, chip_seams):
+    """A Byron window's `unpack`: R ‖ A ‖ M in THREE SHA-512 blocks a
+    lane (an OCert's message takes one), the `ed` operands limb-first."""
+    layout, cols = _packed_pbft()
+    args = [_sds(one_chip, c.shape, c.dtype) for c in map(np.asarray, cols)]
+    _compile(K._mk_pbft_unpack(layout), args)
+    out = jax.eval_shape(K._mk_pbft_unpack(layout), *cols)
+    assert [o.shape for o in out] == [(32, LANES)] * 3 + [
+        (3, 128, LANES), (1, LANES)]
+
+
+def test_finish_pbft_compiles_with_the_kernel(one_chip, chip_seams):
+    """`finish_pbft`: one point a lane compressed, the R compare, the
+    verdict rows `reduce` takes; under a name `^jit_finish` matches."""
+    s = functools.partial(_sds, one_chip)
+    args = [s((1, LANES)), s((80, LANES)), s((32, LANES)),
+            _n_live(one_chip)]
+    compiled = _compile(K.finish_pbft, args)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "finish_pbft" in text
+
+
+@pytest.mark.slow
+def test_pbft_ed_compiles(one_chip, chip_seams):
+    """The `ed` program at Byron's width (three hash blocks a lane)."""
+    layout, cols = _packed_pbft()
+    out = jax.eval_shape(K._mk_pbft_unpack(layout), *cols)
+    limb = [_sds(one_chip, o.shape, o.dtype) for o in out]
+    compiled = _compile(
+        K.ed_points, [limb[0], limb[2], limb[3], limb[4], _n_live(one_chip)])
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.slow
 def test_ed_compiles(one_chip, chip_seams):
     limb = _limb(one_chip)

@@ -399,9 +399,11 @@ def test_five_era_ledger_backed_chain(tmp_path):
     assert int(val) == cm.LEDGER_GENESIS_COIN - n_byron_txs * cm.LEDGER_BYRON_FEE
 
 
-def test_cardano_analyser_cli(tmp_path, capsys):
-    """db_analyser --cardano: the CLI drives the composite revalidation
-    (DBAnalyser/Block/Cardano.hs block dispatch analog)."""
+@pytest.mark.parametrize("backend", ["host", "native"])
+def test_cardano_analyser_cli(tmp_path, capsys, backend):
+    """db_analyser on a composite chain: the chain's own config says
+    "Protocol": "Cardano" (DBAnalyser/Block/Cardano.hs takes the block
+    type from the node config), and the normal path replays it."""
     import json
 
     from ouroboros_consensus_tpu.tools import db_analyser
@@ -409,12 +411,12 @@ def test_cardano_analyser_cli(tmp_path, capsys):
     path = str(tmp_path / "db")
     cfg = composite.CardanoMockConfig()  # CLI defaults
     n = composite.synthesize(path, cfg, 2 * 40 + 2 * 60 + 30)
-    db_analyser.main([
-        "--db", path, "--cardano", "--backend", "host",
-    ])
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["error"] is None and out["valid"] == out["blocks"] == n
-    assert set(out["per_era"]) == {"byron", "shelley", "babbage"}
+    with open(f"{path}/config/config.json") as f:
+        assert json.load(f)["Protocol"] == "Cardano"
+    db_analyser.main(["--db", path, "--backend", backend])
+    out = capsys.readouterr().out.strip().splitlines()[-1]
+    assert out.startswith(f"validated {n}/{n} headers")
+    assert out.endswith("-> OK")
 
 
 def test_cardano_cli_pipeline(tmp_path, capsys):
@@ -432,7 +434,7 @@ def test_cardano_cli_pipeline(tmp_path, capsys):
     forged = capsys.readouterr().out
     assert "forged" in forged
     db_analyser.main([
-        "--db", path, "--cardano", "--with-ledgers", "--backend", "host",
+        "--db", path, "--with-ledgers", "--backend", "host",
     ])
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["error"] is None and out["valid"] == out["blocks"] > 0

@@ -372,5 +372,5 @@ def test_a_tpraos_chain_carries_its_own_config(tmp_path, capsys):
     doc["Protocol"] = "PBFT"
     with open(cfg, "w") as f:
         json.dump(doc, f)
-    with pytest.raises(ValueError, match='"Praos" and "TPraos"'):
+    with pytest.raises(ValueError, match='"TPraos" and "Cardano"'):
         tconfig.load_config(cfg)
