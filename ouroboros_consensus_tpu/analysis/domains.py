@@ -14,8 +14,8 @@ Two pluggable domains:
               residues, SUBC's top limb is 12287 while the others reach
               2^15.5, and the FOLD/FOLD^2 wraps multiply exactly those
               rows — so a whole-tensor bound provably cannot certify
-              them (it flags the `top * FOLD^2` fold at limbs.py:183 /
-              field.py:166 that is in fact bounded by ~21 * FOLD^2).
+              them (it flags the `top * FOLD^2` fold of `limbs.mul` /
+              `field.mul` that is in fact bounded by ~21 * FOLD^2).
               The interpreter checks every SIGNED integer eqn against
               its dtype range; UNSIGNED arithmetic wraps to the full
               dtype range silently (two's-complement wrap is defined

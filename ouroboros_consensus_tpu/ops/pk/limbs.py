@@ -6,10 +6,16 @@ normalized bound B_MAX), identical reduction identities (2^260 == 608
 mod p), but with the limb axis FIRST so that inside Pallas kernels the
 limbs occupy sublanes and the batch tile occupies lanes.
 
-The multiply uses the pad-accumulate formulation (measured fastest of
-the candidates in scripts/exp_layout3.py): 20 shifted [41, T] terms from
-2D broadcasts, no roll, no scatter — both Mosaic and XLA vectorize it
-fully.
+The multiply is pad-accumulate at vreg-aligned rows from eight
+row-offset copies: 2D broadcasts, no roll, no scatter, so both Mosaic
+and XLA vectorize it fully. A vreg holds 8 int32 rows (sublanes), so a
+term padded to row i with i % 8 != 0 costs a sublane shift and select
+over every vreg it spans; padding each of the 20 terms to its own row
+of a [41, T] accumulator paid that 17 times a multiply. Instead the
+multiplicand is placed once at each row offset r = 0..7 (seven shifts)
+and term i = 8q + r is that copy times b[i], added at row 8q: whole
+vregs at an aligned row. Every ladder step of every stage kernel is
+these multiplies.
 
 Reference equivalent: libsodium fe25519 / sc25519 (see ops/field.py,
 ops/scalar.py docstrings for the reference call sites).
@@ -170,23 +176,59 @@ def mul_small(a, k: int):
     return weak_reduce(a * k, passes=3)
 
 
+SUBLANES = 8  # int32 rows a vreg holds
+
+
+def _row_offset_copies(a):
+    """a placed at row r of a zero array a whole number of vregs high,
+    r = 0..SUBLANES-1: [24, w] for r <= 4, [32, w] above. Row r = 0 is a
+    itself; each other copy is one sublane shift of a."""
+    w = a.shape[-1]
+    copies = []
+    for r in range(SUBLANES):
+        h = -(-(NLIMBS + r) // SUBLANES) * SUBLANES
+        # Mosaic rejects zero-size concat operands: only emit non-empty pads
+        parts = [jnp.zeros((r, w), jnp.int32)] if r else []
+        parts.append(a)
+        if h - NLIMBS - r:
+            parts.append(jnp.zeros((h - NLIMBS - r, w), jnp.int32))
+        copies.append(jnp.concatenate(parts, axis=0))
+    return copies
+
+
 def mul(a, b):
     """Field multiplication, [20, T] x [20, T] -> [20, T].
 
     Same bound analysis as ops/field.mul: coefficients < 20 * B_MAX^2 <
     2^31; carries can reach limb 40, so the accumulator is 41 rows and
     row 40 folds with weight FOLD^2 (= 2^520 mod p).
+
+    Term i = 8q + r (a * b[i], due at row i) is the row-offset copy a_r
+    times b[i], added at row 8q: every add of the accumulator is whole
+    vregs at a vreg-aligned row, and the only sublane shifts are the
+    seven that build a_1..a_7. Each accumulator row sums the same
+    products a_j * b_i (i + j = row) as a term-by-term shift would, in
+    another order: int32 addition gives the same bits.
     """
     t = max(a.shape[-1], b.shape[-1])  # constants may be [20, 1]
-    ztail = jnp.zeros((21, t), jnp.int32)
-    first = jnp.broadcast_to(a * b[0:1], (NLIMBS, t))
-    acc = jnp.concatenate([first, ztail], axis=0)  # [41, T]
-    for i in range(1, NLIMBS):
-        term = a * b[i : i + 1]
-        shifted = jnp.concatenate(
-            [jnp.zeros((i, t), jnp.int32), term, ztail[: 21 - i]], axis=0
-        )
-        acc = acc + shifted
+    copies = _row_offset_copies(a)
+    # sum the terms of one row block q and one height first, then add
+    # each sum into the accumulator's vreg blocks it covers
+    sums = {}
+    for i in range(NLIMBS):
+        q, r = divmod(i, SUBLANES)
+        term = copies[r] * b[i : i + 1]
+        key = (q, term.shape[0])
+        sums[key] = term if key not in sums else sums[key] + term
+    blocks = [None] * (2 * NLIMBS // SUBLANES)  # rows 0..39
+    for (q, h), s in sums.items():
+        for k in range(h // SUBLANES):
+            blk = s[k * SUBLANES : (k + 1) * SUBLANES]
+            j = q + k
+            blocks[j] = blk if blocks[j] is None else blocks[j] + blk
+    # row 39 holds no product (i + j <= 38) and row 40 only what the
+    # carries bring: [41, T]
+    acc = jnp.concatenate(blocks + [jnp.zeros((1, t), jnp.int32)], axis=0)
     # two carry passes over 41 rows (carry cannot leave row 40)
     for _ in range(2):
         c = acc >> BITS

@@ -51,6 +51,108 @@ def test_mul_sqr_add_sub(ab):
     assert [g % fe.P_INT for g in got] == [(x - y) % fe.P_INT for x, y in zip(av, bv)]
 
 
+def _term_by_term_mul(a, b):
+    """The reference formulation of limbs.mul: each of the 20 terms
+    a * b[i] zero-padded to its own row i of a [41, T] accumulator and
+    added there, then the same carries and folds."""
+    t = max(a.shape[-1], b.shape[-1])
+    ztail = jnp.zeros((21, t), jnp.int32)
+    first = jnp.broadcast_to(a * b[0:1], (pk.NLIMBS, t))
+    acc = jnp.concatenate([first, ztail], axis=0)
+    for i in range(1, pk.NLIMBS):
+        term = a * b[i : i + 1]
+        acc = acc + jnp.concatenate(
+            [jnp.zeros((i, t), jnp.int32), term, ztail[: 21 - i]], axis=0
+        )
+    for _ in range(2):
+        c = acc >> pk.BITS
+        acc = (acc & pk.MASK) + jnp.concatenate(
+            [jnp.zeros((1, t), jnp.int32), c[:-1]], axis=0
+        )
+    lo, hi, top = acc[: pk.NLIMBS], acc[pk.NLIMBS : 40], acc[40:]
+    lo = lo + hi * pk.FOLD
+    row0 = lo[:1] + top * (pk.FOLD * pk.FOLD)
+    lo = jnp.concatenate([row0, lo[1:]], axis=0)
+    return pk.weak_reduce(lo, passes=2)
+
+
+TILE = 128
+_C = 0x5DEECE66D * fe.P_INT // 7919  # an arbitrary field constant
+
+
+def _operands(case):
+    """(a, b, which operand is the constant _C or None) for one case,
+    [20, TILE] nearly-normalized limbs."""
+    g = np.random.default_rng(7)
+    a = g.integers(0, fe.B_MAX + 1, size=(fe.NLIMBS, TILE), dtype=np.int32)
+    b = g.integers(0, fe.B_MAX + 1, size=(fe.NLIMBS, TILE), dtype=np.int32)
+    if case == "b-max":
+        a[:] = b[:] = fe.B_MAX
+    elif case == "zeros":
+        a[:] = b[:] = 0
+    elif case.startswith("one-hot-"):
+        # lane j: a one-hot at row j, b one-hot at row i: the product
+        # term lands at row i + j, for every j at this i
+        i = int(case.rsplit("-", 1)[1])
+        a[:, : fe.NLIMBS] = fe.B_MAX * np.eye(fe.NLIMBS, dtype=np.int32)
+        b[:, : fe.NLIMBS] = 0
+        b[i, : fe.NLIMBS] = fe.B_MAX
+    return a, b, case[-1] if case.startswith("const-") else None
+
+
+def _both(a, b, const):
+    """mul and sqr by limbs.mul and by the reference, stacked [80, T]."""
+    if const == "a":
+        a = pk.constant(_C)
+    elif const == "b":
+        b = pk.constant(_C)
+    outs = [pk.mul(a, b), pk.sqr(a), _term_by_term_mul(a, b),
+            _term_by_term_mul(a, a)]
+    return jnp.concatenate(
+        [jnp.broadcast_to(o, (fe.NLIMBS, TILE)) for o in outs], axis=0)
+
+
+@pytest.mark.parametrize("where", ["outside-kernel", "in-kernel"])
+@pytest.mark.parametrize(
+    "case",
+    ["random", "b-max", "zeros", "const-a", "const-b"]
+    + [f"one-hot-{i}" for i in range(fe.NLIMBS)],
+)
+def test_mul_is_the_term_by_term_product_bit_for_bit(case, where):
+    """limbs.mul adds each term at a vreg-aligned row from row-offset
+    copies of a: the same integers in every accumulator row, so the same
+    bits out, outside a kernel (constants [20, 1]) and inside one under
+    kernel_consts (constants [20, T] fills), in Pallas interpret mode."""
+    from jax.experimental import pallas as pl
+
+    a, b, const = _operands(case)
+    if where == "outside-kernel":
+        out = jax.jit(lambda x, y: _both(x, y, const))(a, b)
+    else:
+
+        def kernel(a_ref, b_ref, o_ref):
+            with pk.kernel_consts(TILE):
+                o_ref[...] = _both(a_ref[...], b_ref[...], const)
+
+        out = pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct((4 * fe.NLIMBS, TILE), jnp.int32),
+            interpret=True,
+        )(a, b)
+    out = np.asarray(out)
+    got, want = out[: 2 * fe.NLIMBS], out[2 * fe.NLIMBS :]
+    np.testing.assert_array_equal(got, want)
+    if case == "zeros":
+        assert not got.any()
+    x = _C if const == "a" else None
+    y = _C if const == "b" else None
+    for k in (0, 19, TILE - 1):
+        av = x if x is not None else fe.limbs_to_int_np(a[:, k])
+        bv = y if y is not None else fe.limbs_to_int_np(b[:, k])
+        assert fe.limbs_to_int_np(got[: fe.NLIMBS, k]) % fe.P_INT == (
+            av * bv % fe.P_INT)
+
+
 def test_canonical_parity_eq(ab):
     a, av, b, bv = ab
     got = col_ints(jax.jit(pk.canonical)(a))
