@@ -11,6 +11,8 @@ package is the TPU build's equivalent surface, all host-side:
                   registry (see `OCT_TRACE` below)
   * `spans`     — self time of the replay's span tree (a span less
                   its children on the same thread)
+  * `idle`      — the device's idle time of a replay, put down to the
+                  main thread's span at each idle instant
   * `warmup`    — compile/warmup forensics: per-stage first-execute
                   walls, pk-AOT load/reject attribution, the bench
                   cache probe; crash-safe JSON via $OCT_WARMUP_REPORT

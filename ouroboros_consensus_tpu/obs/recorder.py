@@ -26,6 +26,9 @@ event into the metrics registry:
                                            (protocol/forge ForgeSpan)
     oct_forge_elected_total                slots won across windows
     oct_forge_signed_total                 blocks forged + appended
+    oct_device_idle_seconds_total{under=}  the device's idle time of each
+                                           replay, by the main thread's
+                                           span then (obs/idle.py)
 
 Per-window granularity only — a 1M-header replay emits a few hundred
 events, so the host feed ceiling is untaxed."""
@@ -40,6 +43,7 @@ from ..utils.trace import (
     RepairEvent, ShardSpan, SidecarEvent, StallEvent, TransferEvent,
     WindowSpan, WindowStaged,
 )
+from . import idle as _idle
 from . import registry as _registry
 
 # bounded event buffer: a pathological run cannot grow without limit
@@ -142,6 +146,14 @@ class FlightRecorder:
         self._forge_signed = r.counter(
             "oct_forge_signed_total", "blocks forged and appended"
         )
+        # the device's idle time of each replay, by the main thread's
+        # span at the idle instant (obs/idle.py), counted as the replay
+        # ends from the end edges of its spans, kept until then
+        self._device_idle = r.counter(
+            "oct_device_idle_seconds_total",
+            "device idle seconds by the main thread's span", ("under",),
+        )
+        self._replays: dict[int, list] = {}
         # heartbeat source: the most recent event (kept even after the
         # bounded buffer fills) + the latest retired window index
         self._last: "tuple[float, object] | None" = None
@@ -151,13 +163,22 @@ class FlightRecorder:
 
     def __call__(self, ev) -> None:
         now = time.monotonic()
+        ended = None
         with self._lock:
             self._last = (now, ev)
             if len(self.events) < MAX_EVENTS:
                 self.events.append((now, ev))
             else:
                 self.dropped += 1
-        if isinstance(ev, WindowStaged):
+            if isinstance(ev, EncloseEvent) and ev.replay is not None:
+                ended = self._replay_span(ev)
+        if isinstance(ev, EncloseEvent):
+            # kept in the event stream (Perfetto slices); a replay's
+            # last one closes its idle account
+            if ended is not None:
+                for cause, seconds in _idle.account(ended)[0].items():
+                    self._device_idle.labels(under=cause).inc(seconds)
+        elif isinstance(ev, WindowStaged):
             self._windows.labels(outcome=ev.outcome).inc()
             if ev.outcome == "generic":
                 self._gates.labels(gate=ev.gate or "packed-off").inc()
@@ -204,7 +225,19 @@ class FlightRecorder:
             self._forge_windows.labels(engine=ev.engine).inc()
             self._forge_elected.inc(ev.elected)
             self._forge_signed.inc(ev.signed)
-        # EncloseEvent: kept in the event stream (Perfetto slices) only
+
+    def _replay_span(self, ev: EncloseEvent) -> "list | None":
+        """Keep the end edges of each replay in progress (the lock is
+        held); -> all of them once the replay's `replay` span ends."""
+        if ev.edge == "start":
+            if ev.label == "replay":
+                self._replays[ev.replay] = []
+            return None
+        spans = self._replays.get(ev.replay)
+        if spans is None:
+            return None
+        spans.append(ev)
+        return self._replays.pop(ev.replay) if ev.label == "replay" else None
 
     # -- live plane (obs/live.py heartbeat source) --------------------------
 
@@ -282,3 +315,4 @@ class FlightRecorder:
             self.dropped = 0
             self._last = None
             self._last_span_index = -1
+            self._replays.clear()

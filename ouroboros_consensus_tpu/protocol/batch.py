@@ -73,6 +73,7 @@ from jax import numpy as jnp
 
 from ..ops import blake2b, ecvrf_batch, ed25519_batch, kes_batch
 from ..ops.host import kes as host_kes
+from ..utils.trace import GcSpans
 from . import leader, nonces, praos
 from .praos import PraosParams, PraosState, TickedPraosState
 from .views import (
@@ -2153,8 +2154,18 @@ BATCH_TRACER = None  # None = off (zero overhead on the hot path)
 
 
 def set_batch_tracer(tracer) -> None:
+    """Install `tracer` (None: none). While one is installed, each
+    collection of the interpreter inside a replay is a span `gc`
+    (`_GC_SPANS`)."""
     global BATCH_TRACER
+    _GC_SPANS.flush(BATCH_TRACER)
     BATCH_TRACER = tracer
+    _GC_SPANS.hook(tracer is not None)
+
+
+# the collections of the interpreter inside a replay, handed to the
+# tracer at its next span or change
+_GC_SPANS = GcSpans(lambda: _REPLAY if BATCH_TRACER is not None else None)
 
 
 class _Null:
@@ -2195,6 +2206,8 @@ def _enclose(label, window=None, parent=None):
     causing span where none encloses it on the emitting thread."""
     if BATCH_TRACER is None:
         return _NULL
+    if _GC_SPANS.pending:
+        _GC_SPANS.flush(BATCH_TRACER)
     from ..utils.trace import Enclose
 
     return Enclose(BATCH_TRACER, label, _REPLAY, window, parent)
@@ -2235,6 +2248,8 @@ class _WinMeta(NamedTuple):
     stage_wait_s: float = 0.0
     census: "_StageCensus | None" = None
     proofs: int = 1  # VRF proofs the device verifies a lane
+    dispatch_offcpu_s: float = 0.0  # WindowSpan.dispatch_offcpu_s
+    stage_offcpu_s: float = 0.0  # WindowSpan.stage_offcpu_s
 
 
 class _Dispatched(NamedTuple):
@@ -2258,23 +2273,27 @@ def _emit_transfer(phase: str, **kw) -> None:
 
 
 def _win_meta(outcome: str, gate: str | None, sw: "_StagedWindow",
-              t_d0: float, tiles_live: int = 0,
+              t_d0: float, c_d0: float, tiles_live: int = 0,
               proofs: int = 1) -> _WinMeta | None:
     """Build the per-window telemetry meta and emit the WindowStaged
     event. Returns None (zero residual cost) when no tracer is set.
+    `c_d0`: the thread's CPU clock where `t_d0` was read.
     `tiles_live`: the count the window's stage kernels were bounded by
     (`_dispatch_packed_lanes`), 0 where its live lanes bounded none."""
     if BATCH_TRACER is None:
         return None
     from ..utils.trace import WindowStaged
 
+    c2 = time.thread_time()
     t2 = time.monotonic()
     stage_s, dispatch_s = sw.t1 - sw.t0, t2 - t_d0
     BATCH_TRACER(WindowStaged(sw.window, sw.b, sw.lanes, outcome, gate,
                               stage_s, dispatch_s))
     return _WinMeta(sw.window, outcome, gate, stage_s, dispatch_s,
                     sw.lanes, t2, sw.t0, sw.t1, t_d0, sw.thread,
-                    tiles_live, census=sw.census, proofs=proofs)
+                    tiles_live, census=sw.census, proofs=proofs,
+                    dispatch_offcpu_s=dispatch_s - (c2 - c_d0),
+                    stage_offcpu_s=stage_s - sw.cpu_s)
 
 
 def _emit_window_span(meta, lanes: int, n_valid: int, failed: bool,
@@ -2302,6 +2321,8 @@ def _emit_window_span(meta, lanes: int, n_valid: int, failed: bool,
         epilogue_counters_s=_COUNTERS_S[0],
         vrf_proofs=lanes * meta.proofs,
         pbft_s=_PBFT_S[0],
+        dispatch_offcpu_s=meta.dispatch_offcpu_s,
+        stage_offcpu_s=meta.stage_offcpu_s,
         **(meta.census._asdict() if meta.census is not None else {}),
     ))
 
@@ -2313,7 +2334,6 @@ class _StageCensus(NamedTuple):
 
     issuers: int  # distinct cold keys
     kes_tails: int  # rows of the KES tail table before padding
-    thr_rows: int  # rows of the threshold table before padding
     prechecks_s: float  # span `stage.prechecks`
     overlay_lanes: int = 0  # TPraos: live lanes in active overlay slots
     overlay_s: float = 0.0  # TPraos: span `stage.overlay`
@@ -2324,21 +2344,19 @@ class _StageCensus(NamedTuple):
 def _stage_census(hvs, pre, packed, prechecks_s: float) -> _StageCensus:
     if hasattr(pre, "gk"):  # a Byron window (protocol/pbft.PBftChecks)
         main = np.asarray(pre.main)
-        return _StageCensus(len(set(pre.gk[main].tolist())), 0, 0,
+        return _StageCensus(len(set(pre.gk[main].tolist())), 0,
                             prechecks_s, pbft_lanes=int(main.sum()),
                             ebbs=int(main.size - main.sum()))
     if isinstance(pre, ColumnChecks):
         issuers = len(set(pre.uniq_hk))
     else:
         issuers = len({hv.vk_cold for hv in hvs})
-    kes_tails = thr_rows = 0
+    kes_tails = 0
     if packed is not None:
-        # every row of a dedup table is some lane's, so the largest
+        # every row of the dedup table is some lane's, so the largest
         # index names the last row (padding replicates lane 0's)
-        parr = packed[1]
-        kes_tails = int(parr.kes_tail_idx.max()) + 1
-        thr_rows = int(parr.thr_idx.max()) + 1
-    census = _StageCensus(issuers, kes_tails, thr_rows, prechecks_s)
+        kes_tails = int(packed[1].kes_tail_idx.max()) + 1
+    census = _StageCensus(issuers, kes_tails, prechecks_s)
     if isinstance(pre, ColumnChecks) and pre.overlay is not None:
         census = census._replace(
             overlay_lanes=int(np.count_nonzero(pre.overlay)),
@@ -2365,6 +2383,7 @@ class _StagedWindow(NamedTuple):
     window: int  # the window's id (`next_window_id`)
     thread: str  # the thread that staged it
     census: _StageCensus | None = None
+    cpu_s: float = 0.0  # the thread's CPU time in t0..t1 (with a tracer)
 
 
 def window_lanes(max_batch: int) -> int | None:
@@ -2415,7 +2434,9 @@ def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
     if window is None:
         window = next_window_id()
     thread = threading.current_thread().name
+    traced = BATCH_TRACER is not None
     t0 = time.monotonic()
+    c0 = time.thread_time() if traced else 0.0
     with _enclose("stage", window):
         with _enclose("stage.prechecks"):
             pre = rules.prechecks(params, lview, hvs)
@@ -2427,7 +2448,7 @@ def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
         else:
             gate = "packed-off"
         census = (_stage_census(hvs, pre, packed, t_pre - t0)
-                  if BATCH_TRACER is not None else None)
+                  if traced else None)
         if packed is None:
             if rules.packed_only:
                 raise RuntimeError(
@@ -2438,15 +2459,15 @@ def prepare_window(params, lview, eta0, hvs, lanes: int | None = None,
             padded = pad_batch_to(batch, size)
             h2d = _nbytes(flatten_batch(padded))
             lanes = padded.beta.shape[0]
-            return _StagedWindow(pre, None, padded, b, lanes, h2d, gate,
-                                 t0, time.monotonic(), window, thread,
-                                 census)
-        layout, parr = packed
-        parr = pad_packed_to(parr, size)
-        h2d = _nbytes(parr)
-        lanes = parr[0].shape[0]
-    return _StagedWindow(pre, (layout, parr), None, b, lanes, h2d, gate,
-                         t0, time.monotonic(), window, thread, census)
+        else:
+            layout, parr = packed
+            packed = (layout, pad_packed_to(parr, size))
+            padded = None
+            h2d = _nbytes(packed[1])
+            lanes = packed[1][0].shape[0]
+    cpu_s = time.thread_time() - c0 if traced else 0.0
+    return _StagedWindow(pre, packed, padded, b, lanes, h2d, gate, t0,
+                         time.monotonic(), window, thread, census, cpu_s)
 
 
 def dispatch_prepared(sw: _StagedWindow):
@@ -2466,6 +2487,7 @@ def dispatch_prepared(sw: _StagedWindow):
     chaos.fire("dispatch")
     pre, b, lanes, gate = sw.pre, sw.b, sw.lanes, sw.gate
     t_d0 = time.monotonic()
+    c_d0 = time.thread_time() if BATCH_TRACER is not None else 0.0
     with _enclose("dispatch", sw.window):
         _emit_transfer(
             "dispatch", lanes=lanes, h2d_bytes=sw.h2d,
@@ -2481,7 +2503,7 @@ def dispatch_prepared(sw: _StagedWindow):
                     *(jnp.asarray(x) for x in flatten_batch(padded))
                 )
                 impl = "xla"
-            meta = _win_meta("generic", gate, sw, t_d0)
+            meta = _win_meta("generic", gate, sw, t_d0, c_d0)
             return pre, _Dispatched(impl, False, out, meta), b
         layout, parr = sw.packed
         if layout.vrf_proof_len == 128 and _agg_enabled():
@@ -2490,11 +2512,11 @@ def dispatch_prepared(sw: _StagedWindow):
             # re-dispatches per-lane on any anomaly)
             agg_mode = "all" if _rlc_all_enabled() else "vrf"
             out = _jitted_packed_agg(layout, agg_mode)(*parr)
-            meta = _win_meta("packed-agg", None, sw, t_d0)
+            meta = _win_meta("packed-agg", None, sw, t_d0, c_d0)
             return pre, _Dispatched("agg", True, (layout, parr, out),
                                     meta), b
         impl, out, tiles_live = _dispatch_packed_lanes(layout, parr, b)
-        meta = _win_meta("packed", None, sw, t_d0, tiles_live,
+        meta = _win_meta("packed", None, sw, t_d0, c_d0, tiles_live,
                          layout.proofs)
         return pre, _Dispatched(impl, True, out, meta), b
 
@@ -3355,6 +3377,10 @@ def _device_loop(
         return unknown
 
     def enqueue_staging():
+        """Cut windows and submit them for staging while the staging
+        side has room, one span `enqueue` a window. The stream's next
+        piece is taken before that span: polled, or waited for in
+        `segment-wait` where the pipeline is empty."""
         nonlocal stream_done, piece, cuts, k, w, cut_epoch, cut_eta, progress
         nonlocal p_era, pp, pr, cut_era
         while (
@@ -3366,11 +3392,8 @@ def _device_loop(
             # dispatch immediately — the round-9 loop exactly
             else not staged and len(inflight) < pipeline_depth
         ):
-            if piece is None and pending:
-                p_era, pp, pr, nxt = era_of(pending.popleft())
-                cuts = _epoch_segments_idx(pp, nxt)
-                piece, k, w = nxt, 0, cuts[0][1]
-            if piece is None:
+            nxt = None
+            if piece is None and not pending:
                 if stream_done:
                     return
                 # never wait for the stream behind a busy pipeline: a
@@ -3391,52 +3414,58 @@ def _device_loop(
                 except StopIteration:
                     stream_done = True
                     return
-                progress += 1
-                if not len(nxt):
-                    continue
-                n_era, n_p, n_r, nxt = era_of(nxt)
-                # the runs the protocol's windows can be cut from (a
-                # piece whole, but for a TPraos list of ragged views)
-                nxt, *more = n_r.runs(nxt)
-                pending.extend(EraPiece(n_era, m) if eras else m
-                               for m in more)
-                cuts = _epoch_segments_idx(n_p, nxt)
-                if not cuts:
-                    continue
-                p_era, pp, pr = n_era, n_p, n_r
-                piece, k, w = nxt, 0, cuts[0][1]
-            epoch, start, seg_end = cuts[k]
-            if w == start:
-                eta = segment_eta(epoch, _slot_at(piece, start))
-                if eta is unknown:
-                    return
-                cut_epoch, cut_eta, cut_era = epoch, eta, p_era
-            # a window must stage a uniform proof column: break at the
-            # first 80/128-byte format change (the reference fold
-            # length-dispatches per header, so mixed chains stay valid;
-            # segmentation never changes verdicts or the first error)
-            j = _proof_break(piece, w, min(w + max_batch, seg_end))
-            whvs = piece[w:j]
-            # the window's id: staging order is dispatch order
-            win = next_window_id()
-            if stage_pool is not None:
-                item = stage_pool.submit(
-                    prepare_window, pp, lview_for(epoch), cut_eta,
-                    whvs, lanes, win,
-                )
-            else:
-                item = prepare_window(
-                    pp, lview_for(epoch), cut_eta, whvs, lanes, win,
-                )
-            staged.append((epoch, cut_eta, whvs, item, win, p_era))
-            progress += 1
-            w = j
-            if w >= seg_end:
-                k += 1
-                if k < len(cuts):
-                    w = cuts[k][1]
+            with _enclose("enqueue"):
+                if piece is None and pending:
+                    p_era, pp, pr, nxt = era_of(pending.popleft())
+                    cuts = _epoch_segments_idx(pp, nxt)
+                    piece, k, w = nxt, 0, cuts[0][1]
+                if piece is None:
+                    progress += 1
+                    if not len(nxt):
+                        continue
+                    n_era, n_p, n_r, nxt = era_of(nxt)
+                    # the runs the protocol's windows can be cut from (a
+                    # piece whole, but for a TPraos list of ragged views)
+                    nxt, *more = n_r.runs(nxt)
+                    pending.extend(EraPiece(n_era, m) if eras else m
+                                   for m in more)
+                    cuts = _epoch_segments_idx(n_p, nxt)
+                    if not cuts:
+                        continue
+                    p_era, pp, pr = n_era, n_p, n_r
+                    piece, k, w = nxt, 0, cuts[0][1]
+                epoch, start, seg_end = cuts[k]
+                if w == start:
+                    eta = segment_eta(epoch, _slot_at(piece, start))
+                    if eta is unknown:
+                        return
+                    cut_epoch, cut_eta, cut_era = epoch, eta, p_era
+                # a window must stage a uniform proof column: break at the
+                # first 80/128-byte format change (the reference fold
+                # length-dispatches per header, so mixed chains stay valid;
+                # segmentation never changes verdicts or the first error)
+                j = _proof_break(piece, w, min(w + max_batch, seg_end))
+                whvs = piece[w:j]
+                # the window's id: staging order is dispatch order
+                win = next_window_id()
+                if stage_pool is not None:
+                    item = stage_pool.submit(
+                        prepare_window, pp, lview_for(epoch), cut_eta,
+                        whvs, lanes, win,
+                    )
                 else:
-                    piece = None
+                    item = prepare_window(
+                        pp, lview_for(epoch), cut_eta, whvs, lanes, win,
+                    )
+                staged.append((epoch, cut_eta, whvs, item, win, p_era))
+                progress += 1
+                w = j
+                if w >= seg_end:
+                    k += 1
+                    if k < len(cuts):
+                        w = cuts[k][1]
+                    else:
+                        piece = None
 
     def _queue_failure(exc: BaseException) -> bool:
         """True when the supervisor may absorb `exc`: the window rides

@@ -66,6 +66,10 @@ class ValidationResult:
     # translation, the state after it)
     era: int = 0
     crossings: list | None = None
+    # filled by collect_phases=True: the device's idle intervals of the
+    # replay, each put down to the main thread's span then (obs/idle.py):
+    # [(start, end, cause)] on time.monotonic(), in time order
+    idle_gaps: list | None = None
 
 
 class _PhaseCollector:
@@ -102,13 +106,20 @@ class _PhaseCollector:
                 self.d2h += ev.d2h_bytes
 
     def fill(self, res: "ValidationResult") -> None:
+        from ..obs import idle as obs_idle
         from ..obs import spans as obs_spans
 
         res.phases = dict(self.wall)
+        res.phases.setdefault("gc", 0.0)  # no collection is a reading
         # "<label>.self": the label's wall less what its child spans on
         # the same thread cover
         for label, self_s in obs_spans.self_times(self.spans).items():
             res.phases[label + ".self"] = self_s
+        # the device's idle time, by the main thread's span at the time
+        idle, res.idle_gaps = obs_idle.account(self.spans)
+        res.phases["device-idle"] = obs_idle.total(idle)
+        for cause, seconds in idle.items():
+            res.phases["device-idle." + cause] = seconds
         res.h2d_bytes = self.h2d
         res.d2h_bytes = self.d2h
         res.n_windows = self.windows
@@ -810,7 +821,10 @@ def revalidate(
     collect_phases=True threads a batch tracer through the replay and
     fills `res.phases` / `res.h2d_bytes` / `res.d2h_bytes` /
     `res.n_windows` / `res.packed_windows` — the per-phase wall and
-    device-boundary byte attribution the bench json reports.
+    device-boundary byte attribution the bench json reports — and the
+    device's idle time by cause (`res.phases["device-idle.<cause>"]`,
+    `res.idle_gaps`: obs/idle.py; the flight recorder counts the same
+    account with or without this flag).
 
     With OCT_TRACE=1 the obs flight recorder additionally rides the
     replay (per-window spans, gate-decline attribution, Perfetto-

@@ -13,8 +13,10 @@ embedding application's job, exactly as in the reference (§5.5)."""
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable
 
@@ -170,6 +172,73 @@ class Enclose:
         return False
 
 
+class GcSpans:
+    """A `gc.callbacks` hook: each collection of the interpreter while
+    `replay()` names a replay, as the span `gc` on the thread that ran
+    it, whose parent is the `Enclose` span open there; the profiler's
+    annotation `oct:gc` carries its generation. The two edges are queued
+    and handed to a tracer by `flush`, since a collection can begin
+    while its thread holds a tracer's lock."""
+
+    def __init__(self, replay: Callable[[], "int | None"]):
+        self._replay = replay
+        self._done: deque = deque()
+        self._at: list = []  # the collection in progress: t0, replay, ann
+
+    def hook(self, on: bool) -> None:
+        """Put the hook in `gc.callbacks` (on) or take it out."""
+        hooked = any(cb is self for cb in gc.callbacks)
+        if on and not hooked:
+            gc.callbacks.append(self)
+        elif hooked and not on:
+            gc.callbacks.remove(self)
+
+    @property
+    def pending(self) -> bool:
+        return bool(self._done)
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._at.clear()
+            replay = self._replay()
+            if replay is None:
+                return
+            annotation = _annotation()
+            ann = None
+            if annotation is not None:
+                ann = annotation(
+                    "oct:gc", thread=threading.current_thread().name,
+                    replay=replay, generation=info["generation"])
+                ann.__enter__()
+            self._at.extend((time.monotonic(), replay, ann))
+            return
+        if not self._at:
+            return
+        t1 = time.monotonic()
+        t0, replay, ann = self._at
+        self._at.clear()
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        stack = getattr(_OPEN, "stack", None)
+        parent = stack[-1].label if stack else None
+        thread = threading.current_thread().name
+        self._done.append(EncloseEvent("gc", "start", t0, None, replay, None,
+                                       parent, thread))
+        self._done.append(EncloseEvent("gc", "end", t1, t1 - t0, replay, None,
+                                       parent, thread))
+
+    def flush(self, tracer: "Tracer | None") -> None:
+        """Hand the queued edges to `tracer` (None: drop them); any
+        thread may call it at any time."""
+        while True:
+            try:
+                ev = self._done.popleft()
+            except IndexError:
+                return
+            if tracer is not None:
+                tracer(ev)
+
+
 @dataclass(frozen=True)
 class TransferEvent:
     """Device-boundary byte accounting for one batch-path phase: H2D
@@ -188,8 +257,8 @@ class TransferEvent:
 
 # -- per-window pipeline spans (the obs/ flight-recorder vocabulary) ---------
 # Per-WINDOW granularity by design: a 100k-header replay emits ~21 of
-# these, so the 118.7k headers/s host ceiling is untaxed (the round-8
-# object-tax lesson applied to telemetry).
+# these, never one a header (the round-8 object-tax lesson applied to
+# telemetry).
 
 
 @dataclass(frozen=True)
@@ -371,7 +440,6 @@ class WindowSpan:
     # one-pool chain reads 1, 2-3, 1)
     issuers: int = 0  # distinct cold keys
     kes_tails: int = 0  # rows of the KES tail table before padding
-    thr_rows: int = 0  # rows of the threshold table before padding
     prechecks_s: float = 0.0  # span `stage.prechecks`, on `stage_thread`
     epilogue_counters_s: float = 0.0  # span `epilogue.counters`
     # lane tiles that hold the window's `lanes` (ops/pk/kernels.live_tiles),
@@ -395,6 +463,14 @@ class WindowSpan:
     pbft_lanes: int = 0
     pbft_s: float = 0.0
     ebbs: int = 0  # epoch boundary blocks among a Byron window's lanes
+    # wall less the thread's CPU time (`time.thread_time`) of `dispatch_s`
+    # (main) and of `stage_s` (on `stage_thread`): the time the thread was
+    # off the CPU — waiting for the interpreter lock, blocked in the
+    # runtime, or descheduled. Where that clock advances in scheduler
+    # ticks (10 ms on some hosts) one window's reading is that coarse, and
+    # can be below 0: read means over many windows
+    dispatch_offcpu_s: float = 0.0
+    stage_offcpu_s: float = 0.0
 
 
 # -- the consensus event vocabulary (Tracers' record, condensed) -------------

@@ -36,7 +36,9 @@ def test_every_cell_reports_30_per_layer_metrics_by_name(m):  # noqa: F405
     for cell in ("replay-bc-2epoch", "replay-draft03-2epoch",
                  "replay-stakepools-2epoch"):
         per_layer = {x.name: x for x in m.cell(cell).per_layer}
-        assert len(per_layer) == 30
+        # 30, and the 8 of the device's idle account, the off-CPU time
+        # of dispatch and stage, and the collections
+        assert len(per_layer) == 38
         index = per_layer["open_index_s_per_replay"]
         assert index.spec["kind"] == "phase_wall"
         assert index.spec["key"] == "open.index"
@@ -56,3 +58,63 @@ def test_every_cell_reports_30_per_layer_metrics_by_name(m):  # noqa: F405
     spans = [{"lanes": 10, "tiles_live": 1}, {"lanes": 8192, "tiles_live": 64}]
     assert window_span.read(tiles.spec, {"window_spans": spans}) == 32.5
     assert window_span.read(tiles.spec, {"window_spans": [{"lanes": 10}]}) is None
+
+
+IDLE_ACCOUNT = {
+    "device_idle_s_per_replay": ("phase_wall", "device-idle", "device"),
+    "device_idle_s_per_replay.dispatch": ("phase_wall", "device-idle.dispatch",
+                                          "dispatch and kernels"),
+    "device_idle_s_per_replay.epilogue": ("phase_wall", "device-idle.epilogue",
+                                          "epilogue"),
+    "device_idle_s_per_replay.materialize": (
+        "phase_wall", "device-idle.materialize", "window loop"),
+    "device_idle_s_per_replay.unspanned": (
+        "phase_wall", "device-idle.unspanned", "window loop"),
+    "gc_s_per_replay": ("phase_wall", "gc", "window loop"),
+    "dispatch_offcpu_ms_per_window": ("window_span", "dispatch_offcpu_s",
+                                      "dispatch and kernels"),
+    "stage_offcpu_ms_per_window": ("window_span", "stage_offcpu_s",
+                                   "staging"),
+}
+
+
+def test_every_cell_reports_the_idle_account_and_reads_none_without_it(m):  # noqa: F405
+    """The eight metrics of the device's idle account, the off-CPU time
+    and the collections: in every cell (no `workloads` key), read by the
+    readers that were there; a program without the phase or the field
+    (the parent commit's) gives None, and the line leaves it out."""
+    import pytest
+
+    from benchmark import readers
+
+    for cell in m.cells():
+        per_layer = {x.name: x for x in cell.per_layer}
+        for name, (kind, key, layer) in IDLE_ACCOUNT.items():
+            x = per_layer[name]
+            assert (x.spec["kind"], x.spec["key"], x.layer) == \
+                (kind, key, layer)
+            assert (x.source, x.better, x.moves) == \
+                ("program_span", "lower", "replay_headers_per_s")
+    now = {"replays": 4,
+           "phase_wall": {"device-idle": 0.4, "device-idle.dispatch": 0.1,
+                          "device-idle.epilogue": 0.04,
+                          "device-idle.materialize": 0.0,
+                          "device-idle.unspanned": 0.2, "gc": 0.002},
+           "window_spans": [{"dispatch_offcpu_s": 0.001,
+                             "stage_offcpu_s": 0.004},
+                            {"dispatch_offcpu_s": 0.003,
+                             "stage_offcpu_s": 0.0}]}
+    before = {"replays": 4, "phase_wall": {"open": 0.1},
+              "window_spans": [{"dispatch_s": 0.006}]}
+    mine = [x for x in m.cell("replay-bc-2epoch").per_layer
+            if x.name in IDLE_ACCOUNT]
+    got = {x.name: readers.read(x.spec, now) for x in mine}
+    assert got == pytest.approx({"device_idle_s_per_replay": 0.1,
+                   "device_idle_s_per_replay.dispatch": 0.025,
+                   "device_idle_s_per_replay.epilogue": 0.01,
+                   "device_idle_s_per_replay.materialize": 0.0,
+                   "device_idle_s_per_replay.unspanned": 0.05,
+                   "gc_s_per_replay": 0.0005,
+                   "dispatch_offcpu_ms_per_window": 2.0,
+                   "stage_offcpu_ms_per_window": 2.0})
+    assert all(readers.read(x.spec, before) is None for x in mine)

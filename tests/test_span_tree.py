@@ -136,12 +136,16 @@ def test_spans_name_the_thread_that_did_the_work(traced):
             assert e.thread.startswith("oct-read"), e
         elif e.label.startswith("stream"):
             assert e.thread == "oct-prefetch", e
+        elif e.label == "gc":
+            # a collection, on whichever thread ran it: no window's
+            assert e.window is None and e.replay is not None, e
         else:
             assert e.thread == "MainThread", e
     # ONE pipeline a replay: the wait for the stream is the pipeline's
-    # own (an empty one's), beside its windows' spans
-    for label in ("segment-wait", "stage-wait", "dispatch", "materialize",
-                  "tick", "epilogue"):
+    # own (an empty one's), beside its windows' spans and the cutting
+    # of its windows (`enqueue`)
+    for label in ("segment-wait", "enqueue", "stage-wait", "dispatch",
+                  "materialize", "tick", "epilogue"):
         assert {e.parent for e in _ends(events, label=label)} == \
             {"validate-chain"}
     for label in ("open", "validate-chain", "stream"):

@@ -269,7 +269,7 @@ def test_revalidate_agrees_with_the_reference_on_a_chain_of_many_issuers(
 
 @limit(300)
 def test_window_span_counts_who_the_window_holds(stake_db, monkeypatch):
-    """`issuers`, `kes_tails`, `thr_rows` of every retired window against
+    """`issuers` and `kes_tails` of every retired window against
     counts taken from the chain's own headers; the two new spans' walls
     inside their parents'."""
     path, _ = stake_db
@@ -301,7 +301,6 @@ def test_window_span_counts_who_the_window_holds(stake_db, monkeypatch):
         at += s.lanes
         assert s.outcome.startswith("packed")  # the twin aggregates
         assert s.issuers == len({h.vk_cold for h in win})
-        assert s.thr_rows == len({(h.vk_cold, h.vrf_vk) for h in win})
         assert s.kes_tails == len({h.kes_sig[64:] for h in win})
         assert 0 < s.prechecks_s <= s.stage_s
         assert 0 < s.epilogue_counters_s <= s.epilogue_s
