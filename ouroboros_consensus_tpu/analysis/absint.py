@@ -1474,6 +1474,7 @@ BOUND_CLASSES = {
     "limb": (0, 9500),
     "limb13": (0, 8191),
     "nblocks": (0, 64),
+    "u16": (0, 2 ** 16 - 1),
     "i32": (-(2 ** 31), 2 ** 31 - 1),
     "nonneg": (0, 2 ** 31 - 1),
     "u32": (0, 2 ** 32 - 1),

@@ -375,9 +375,9 @@ def _graph_packed_unpack(t=None):
     body-sourced u8 columns -> the 21 staged columns, including the
     on-device SHA-512 padding, VRF alpha hash and table gathers —
     CHAINED into staged_to_limb_first, exactly the graph the per-stage
-    jit/AOT executable compiles and dispatches. Traced at a synthetic
-    (non-overlapping-offset) layout — offsets only slide slices, never
-    change graph structure."""
+    jit/AOT executable compiles and dispatches. The body layouts are an
+    operand (a table and each lane's row of it), so no offset changes
+    the graph."""
     import jax
     from jax import numpy as jnp
 
@@ -386,9 +386,7 @@ def _graph_packed_unpack(t=None):
 
     b = t or 4
     layout = pbatch.PraosPackedLayout(
-        body_len=304, o_issuer=0, o_vrf_vk=32, o_vrf_out=64,
-        o_vrf_proof=128, o_vk_hot=208, o_sigma=240,
-        kes_depth=_DEPTH, slots_per_kes=100, has_nonce=True,
+        body_len=304, kes_depth=_DEPTH, slots_per_kes=100, has_nonce=True,
     )
 
     def u8(*shape):
@@ -396,7 +394,8 @@ def _graph_packed_unpack(t=None):
 
     args = (
         u8(b, 304), u8(b, 64), _s(b), u8(8, 32 + 32 * _DEPTH),
-        _s(b), _s(b), _s(b), _s(b), u8(8, 64), u8(32),
+        _s(b), _s(b), _s(b), _s(b), u8(8, 64), u8(32), _s(b),
+        _s(pbatch._MAX_BODY_LAYOUTS, len(pbatch.BODY_TAB_COLS)),
     )
     return pk_kernels._mk_packed_unpack(layout), args
 

@@ -434,29 +434,37 @@ class MalformedBlock(ValueError):
 
 def _span_matrix(buf_u8: np.ndarray, off: np.ndarray, ln: np.ndarray):
     """[n, w] uint8 matrix over the (offset, length) spans of the chunk
-    buffer, or None when the spans are not uniform width (the columnar
-    pipeline requires row-major rectangular columns; callers fall back
-    to the per-row bytes list).
+    buffer (`_padded_span_matrix`), or None when the spans are not
+    uniform width (the columnar pipeline requires row-major rectangular
+    columns; callers fall back to the per-row bytes list)."""
+    if len(ln) and not (ln == ln[0]).all():
+        return None
+    return _padded_span_matrix(buf_u8, off, ln)
 
-    Uniform-STRIDE spans (the common case: a chunk of equal-size
-    blocks) come back as a ZERO-COPY strided view into the buffer;
-    anything else is one vectorized fancy-index gather (int32 indices —
-    chunk files are far under 2 GiB)."""
+
+def _padded_span_matrix(buf_u8: np.ndarray, off: np.ndarray,
+                        ln: np.ndarray) -> np.ndarray:
+    """[n, max(ln)] uint8 matrix over the spans, each row zero-padded
+    past its own length. Spans of one length and one stride (the common
+    case: a chunk's run of equal-size blocks, which is how the stream
+    cuts its pieces) come back as a ZERO-COPY strided view into the
+    buffer; anything else is one vectorized fancy-index gather (int32
+    indices — chunk files are far under 2 GiB)."""
     n = len(off)
     if n == 0:
         return np.zeros((0, 0), np.uint8)
-    w = int(ln[0])
-    if not (ln == w).all():
-        return None
-    if n > 1:
+    w = int(ln.max())
+    if (ln == w).all():
         d = np.diff(off)
-        d0 = int(d[0])
-        if d0 > 0 and (d == d0).all():
+        if n == 1 or (d[0] > 0 and (d == d[0]).all()):
             return np.lib.stride_tricks.as_strided(
-                buf_u8[int(off[0]) :], shape=(n, w), strides=(d0, 1),
+                buf_u8[int(off[0]) :], shape=(n, w),
+                strides=(int(d[0]) if n > 1 else 1, 1),
             )
     idx = off.astype(np.int32)[:, None] + np.arange(w, dtype=np.int32)
-    return buf_u8[idx]
+    out = buf_u8[np.minimum(idx, len(buf_u8) - 1)]
+    out[np.arange(w) >= ln[:, None]] = 0
+    return out
 
 
 @dataclass
@@ -469,8 +477,10 @@ class HeaderColumns:
     buffer: the per-row `bytes`-list views are built LAZILY on first
     access (the per-row slicing loop is exactly the object tax the
     columnar pipeline avoids), and the `*_mat` properties expose them as
-    row-major uint8 matrices via one vectorized gather when the spans
-    are uniform width (always, on real chains)."""
+    row-major uint8 matrices via one vectorized gather: the sigma and
+    KES signature when their spans are uniform width, the signed body
+    zero-padded to the widest row (its length steps with the CBOR
+    widths of the integers it holds)."""
 
     n: int
     block_no: np.ndarray  # [n] int64
@@ -535,8 +545,8 @@ class HeaderColumns:
         return _span_matrix(self._buf_u8, self.kes_off, self.kes_len)
 
     @cached_property
-    def signed_bytes_mat(self):  # [n, body_len] uint8 | None
-        return _span_matrix(self._buf_u8, self.sgn_off, self.sgn_len)
+    def signed_bytes_mat(self):  # [n, widest body] uint8, zero-padded
+        return _padded_span_matrix(self._buf_u8, self.sgn_off, self.sgn_len)
 
 
 def _ptr(a):

@@ -65,19 +65,64 @@ def stage_np(
     return KesBatch(vk, period, r, s, vk_leaf, siblings, hblocks, hnblocks)
 
 
-def build_hblocks(r, vk_leaf, body):
+def _pad_rows(xp, data, msg_len):
+    """SHA-512 padding of [B, M] message rows that are each `msg_len`
+    [B] bytes long and zero past it, at each row's own length: the 0x80
+    byte, zeros, the 128-bit big-endian bit length at the end of the
+    row's own last block -> ([B, NB * 128] bytes, [B] int32 block
+    counts), NB the longest row's count. `xp` is numpy (host staging)
+    or jax.numpy (inside the jit): one arithmetic for both."""
+    nb = sha512.nblocks_for_len(data.shape[-1])
+    width = nb * sha512.BLOCK
+    data = xp.concatenate(
+        [data, xp.zeros((data.shape[0], width - data.shape[-1]), xp.uint8)],
+        axis=-1,
+    ).astype(xp.int32)
+    ln = msg_len.astype(xp.int32)[:, None]
+    pos = xp.arange(width, dtype=xp.int32)[None, :]
+    k = (ln + 16 + sha512.BLOCK) // sha512.BLOCK  # nblocks_for_len
+    # the bit length is under 2^32: the tail's last four bytes hold it
+    j = xp.clip(pos - (k * sha512.BLOCK - 4), 0, 3)
+    tail = ((ln * 8) >> (8 * (3 - j))) & 0xFF
+    out = xp.where(
+        pos < ln, data,
+        xp.where(pos == ln, 0x80,
+                 xp.where(pos >= k * sha512.BLOCK - 4,
+                          xp.where(pos < k * sha512.BLOCK, tail, 0), 0)),
+    )
+    return out.astype(xp.uint8), k[:, 0].astype(xp.int32)
+
+
+def pad_rows_np(mat: np.ndarray, msg_len: np.ndarray):
+    """Host staging of [B, M] uint8 message rows of their own lengths
+    (zero past them) -> (blocks [B, NB, 16, 2] uint32, nblocks [B]
+    int32), byte-identical to `sha512.pad_messages_np` on the rows."""
+    out, k = _pad_rows(np, mat, np.asarray(msg_len))
+    n = mat.shape[0]
+    return sha512.bytes_to_blocks_np(out.reshape(n, -1, sha512.BLOCK)), k
+
+
+def build_hblocks(r, vk_leaf, body, body_len):
     """Device staging of the KES leaf-signature hash input
-    R ‖ vk_leaf ‖ body for a batch of FIXED-length bodies — the packed
-    H2D contract: the host ships the raw signed header-body column once
-    (no padded block columns, no duplicated R ‖ leaf prefix) and the SHA
-    padding runs inside the jit. Byte-identical to `stage_np`'s blocks
-    on uniform-length bodies."""
+    R ‖ vk_leaf ‖ body — the packed H2D contract: the host ships the raw
+    signed header-body column once (no padded block columns, no
+    duplicated R ‖ leaf prefix) and the SHA padding runs inside the jit.
+    `body` is zero-padded to the window's widest and each lane is padded
+    at its own length `body_len` [B], so it hashes the blocks its own
+    body needs and no more (the ed/kes kernels take per-lane block
+    counts). Byte-identical to `stage_np`'s blocks."""
     data = jnp.concatenate(
         [r.astype(jnp.uint8), vk_leaf.astype(jnp.uint8),
          body.astype(jnp.uint8)],
         axis=-1,
     )
-    return sha512.pad_blocks_fixed(data, 64 + body.shape[-1])
+    out, k = _pad_rows(jnp, data, 64 + jnp.asarray(body_len))
+    b = data.shape[0]
+    return (
+        sha512.bytes_to_blocks(
+            out.reshape(b, -1, sha512.BLOCK).astype(jnp.int32)),
+        k,
+    )
 
 
 def verify(vk, period, r, s, vk_leaf, siblings, hblocks, hnblocks, *, depth: int | None = None):

@@ -57,7 +57,7 @@ class WindowShape:
     the staged layout (and therefore the compiled program family)."""
 
     proof_len: int  # 80 draft-03 | 128 batch-compatible
-    body_len: int  # KES-signed body bytes (packed layout body column)
+    body_len: int  # the widest KES-signed body (packed body column width)
 
 
 @dataclass(frozen=True)
@@ -78,7 +78,6 @@ def shape_of(tenant_id: str, hvs) -> WindowShape:
     if not len(hvs):
         raise AdmissionRefused(tenant_id, "empty candidate suffix")
     plen = len(hvs[0].vrf_proof)
-    blen = len(hvs[0].signed_bytes)
     prev_slot = None
     for hv in hvs:
         if len(hv.vrf_proof) != plen:
@@ -88,12 +87,6 @@ def shape_of(tenant_id: str, hvs) -> WindowShape:
                 f"{len(hv.vrf_proof)} bytes) — one window stages one "
                 "uniform proof column",
             )
-        if len(hv.signed_bytes) != blen:
-            raise AdmissionRefused(
-                tenant_id,
-                "suffix mixes body lengths — packed staging needs "
-                "rectangular columns",
-            )
         if prev_slot is not None and hv.slot <= prev_slot:
             raise AdmissionRefused(
                 tenant_id,
@@ -101,7 +94,10 @@ def shape_of(tenant_id: str, hvs) -> WindowShape:
                 "candidate suffix is a chain",
             )
         prev_slot = hv.slot
-    return WindowShape(proof_len=plen, body_len=blen)
+    # bodies of several lengths stage as one packed window (a row each
+    # of its layout table, `batch.BODY_TAB_COLS`)
+    return WindowShape(proof_len=plen,
+                       body_len=max(len(hv.signed_bytes) for hv in hvs))
 
 
 class AdmissionPolicy:

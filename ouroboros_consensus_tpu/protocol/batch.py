@@ -489,7 +489,7 @@ def stage_columns(
     depth = params.kes_depth
     siblings = np.ascontiguousarray(ks[:, 96:].reshape(len(vc), depth, 32))
     kes_msg = np.concatenate([kes_r, vk_leaf, vc.signed_bytes], axis=1)
-    kes_hb, kes_hnb = sha512.pad_matrix_np(kes_msg)
+    kes_hb, kes_hnb = kes_batch.pad_rows_np(kes_msg, 64 + vc.signed_len)
     kes = kes_batch.KesBatch(
         np.ascontiguousarray(vc.ocert_vk_hot),
         np.asarray(evolution, np.int32),
@@ -1017,36 +1017,60 @@ def pk_arrays(batch: PraosBatch) -> list[np.ndarray]:
 # ---------------------------------------------------------------------------
 
 
+# a packed window's body layout table (`PraosPacked.body_tab`): a row a
+# signed-body layout, the body's length and the byte offset of each
+# field the device extracts. A chain's bodies differ in both wherever a
+# CBOR integer before a field steps width (block number and slot from
+# genesis, the body size on a chain with real block bodies), so a
+# window holds a few. The leader columns are a TPraos body's LEADER
+# certificate (64-byte output, 80-byte draft-03 proof; `vrf_out` /
+# `vrf_proof` are then its NONCE certificate), 0 in a Praos body's row.
+# Every entry is under 2^16 (staging checks): `unpack`'s arithmetic on
+# the table is certified for that (analysis/shapes.json)
+BODY_TAB_COLS = (
+    "length",
+    "issuer",  # vk_cold (32)
+    "vrf_vk",  # vrf_vk (32)
+    "vrf_out",  # declared beta (64)
+    "vrf_proof",  # gamma ‖ c ‖ s (80) or gamma ‖ u ‖ v ‖ s (128)
+    "vk_hot",  # OCert KES root vk (32)
+    "sigma",  # OCert cold-key signature R ‖ s (64)
+    "vrf_leader_out",
+    "vrf_leader_proof",
+)
+# a window holds a few body layouts; past this many it stages generic
+_MAX_BODY_LAYOUTS = 16
+# a field's offset differs between a window's layouts by less than this
+# (a power of two): `unpack` shifts each lane's field into place one
+# select a bit. The widest CBOR steps before a field (block number,
+# slot, the previous hash's null, body size, counter, period) add up to
+# 73 bytes
+_MAX_BODY_SHIFT = 128
+
+
 class PraosPackedLayout(NamedTuple):
     """Static per-window descriptor of the packed staging format
-    (hashable — part of the jit cache key). The offsets point INTO the
-    KES-signed header body at the byte positions of each field the
-    device extracts; `stage_packed` VERIFIES them lane-for-lane before
-    committing to this format."""
+    (hashable — part of the jit cache key). Where a lane's fields lie in
+    its body rides the wire, not the descriptor: the window's body
+    layouts as a table (`PraosPacked.body_tab`) and each lane's row of
+    it (`.body_layout`), so ONE `unpack` program serves every window of
+    a body width and proof format, whatever layouts it holds.
+    `stage_packed_columns` VERIFIES every lane's fields at its own
+    layout's offsets before committing to this format."""
 
-    body_len: int
-    o_issuer: int  # vk_cold (32)
-    o_vrf_vk: int  # vrf_vk (32)
-    o_vrf_out: int  # declared beta (64)
-    o_vrf_proof: int  # gamma ‖ c ‖ s (80) or gamma ‖ u ‖ v ‖ s (128)
-    o_vk_hot: int  # OCert KES root vk (32)
-    o_sigma: int  # OCert cold-key signature R ‖ s (64)
+    body_len: int  # the body column's width: the window's widest body
     kes_depth: int
     slots_per_kes: int
     has_nonce: bool  # False = neutral epoch nonce (genesis)
     vrf_proof_len: int = 80  # 80 = draft-03, 128 = batch-compatible
-    # a TPraos body's LEADER certificate (64-byte output, 80-byte
-    # draft-03 proof); `o_vrf_out` / `o_vrf_proof` are then its NONCE
-    # certificate. -1: a one-certificate (Praos) body
-    o_vrf_leader_out: int = -1
-    o_vrf_leader_proof: int = -1
+    two_certs: bool = False  # a TPraos body: two VRF certificates
 
     @property
     def proofs(self) -> int:
         """VRF proofs the device verifies a lane: the count the layout
         fixes, and with it the window's programs (`unpack`, `finish` or
         `finish_tp`; the `vrf` stage once or twice)."""
-        return 2 if self.o_vrf_leader_proof >= 0 else 1
+        return 2 if self.two_certs else 1
 
 
 class PraosPacked(NamedTuple):
@@ -1057,11 +1081,12 @@ class PraosPacked(NamedTuple):
     (issuer/VRF keys, proof, declared beta, OCert), the KES Merkle tail
     (leaf vk ‖ siblings — period-constant per pool) is deduplicated into
     a window table, SHA-512 block padding and the 32-byte VRF alpha are
-    built on device (ops/sha512.pad_blocks_fixed,
+    built on device (ops/kes_batch.build_hblocks,
     ops/ecvrf_batch.alpha_from_slots), and the leader thresholds ride as
     a per-pool table + per-lane index."""
 
-    body: np.ndarray  # [B, body_len] uint8 — KES-signed header body
+    body: np.ndarray  # [B, body_len] uint8 — KES-signed header body,
+    # zero-padded past each lane's own length
     kes_rs: np.ndarray  # [B, 64] uint8 — KES leaf signature R ‖ s
     kes_tail_idx: np.ndarray  # [B] int32 into kes_tail_tab
     kes_tail_tab: np.ndarray  # [Kt, 32 + depth*32] uint8 — leaf vk ‖ siblings
@@ -1071,6 +1096,10 @@ class PraosPacked(NamedTuple):
     thr_idx: np.ndarray  # [B] int32 into thr_tab
     thr_tab: np.ndarray  # [Kr, 64] uint8 — thr_lo ‖ thr_hi per pool
     nonce: np.ndarray  # [32] uint8 — epoch nonce bytes (zeros if neutral)
+    body_layout: np.ndarray  # [B] int32 — each lane's row of body_tab
+    # [_MAX_BODY_LAYOUTS, len(BODY_TAB_COLS)] int32 — the window's body
+    # layouts (rows past them replicate the first)
+    body_tab: np.ndarray
 
 
 class TPraosPacked(NamedTuple):
@@ -1090,6 +1119,8 @@ class TPraosPacked(NamedTuple):
     thr_idx: np.ndarray
     thr_tab: np.ndarray  # [Kr, 128] uint8 — thr_lo ‖ thr_hi, 64 bytes each
     nonce: np.ndarray
+    body_layout: np.ndarray
+    body_tab: np.ndarray
     overlay: np.ndarray  # [B] int32 — 1 = an active overlay slot's lane
 
 
@@ -1138,11 +1169,6 @@ def _table_rows(params: PraosParams, ledger_view: LedgerView, slots,
             _table_bucket(max(thr_rows, min(b, pools))))
 
 
-def _col(parts: Sequence[bytes], n: int) -> np.ndarray:
-    b = len(parts)
-    return np.frombuffer(b"".join(parts), np.uint8).reshape(b, n)
-
-
 def stage_packed(
     params: PraosParams,
     ledger_view: LedgerView,
@@ -1150,120 +1176,64 @@ def stage_packed(
     hvs: Sequence[HeaderView],
 ) -> tuple[PraosPackedLayout, PraosPacked] | None:
     """Columnarize a window into the packed H2D format, or None when the
-    window does not qualify (the caller falls back to `stage`).
-
-    Qualification is VERIFIED, not assumed: all bodies must share one
-    length, every device-extracted field must equal the parsed
-    HeaderView field byte-for-byte in EVERY lane at the lane-0 offsets,
-    and the staged integers must fit int32. Whenever this returns a
-    layout, the device extraction is byte-identical to the generic
-    staged path by construction — real CBOR header codecs (block/
-    praos_block.py, the synthesizer chains) always qualify; synthetic
-    test views whose signed bytes do not embed the fields fall back."""
+    window does not qualify (the caller falls back to `stage`): the
+    views as `ViewColumns`, then `stage_packed_columns`' verified
+    qualification. Real CBOR header codecs (block/praos_block.py, the
+    synthesizer chains) always qualify; synthetic test views whose
+    signed bytes do not embed the fields fall back."""
     if not hvs:
         return _decline("empty-window")
     if hvs[0].vrf_leader_proof is not None:
         # TPraos windows stage columnar (`stage_packed_columns`)
         return _decline("two-certificates")
-    b = len(hvs)
-    h0 = hvs[0]
-    body0 = h0.signed_bytes
-    lb = len(body0)
-    if any(len(hv.signed_bytes) != lb for hv in hvs):
-        return _decline("body-width-mixed")
-    if epoch_nonce is not None and len(epoch_nonce) != 32:
-        return _decline("nonce-len")
-    depth = params.kes_depth
-    sig_len = 64 + 32 + 32 * depth
-    if any(len(hv.kes_sig) != sig_len for hv in hvs):
-        return _decline("kes-sig-len")
+    vc = ViewColumns.from_views(hvs)
+    if vc is None:
+        return _decline("columns")
+    return stage_packed_columns(params, ledger_view, epoch_nonce, vc,
+                                host_prechecks_columns(params, ledger_view,
+                                                       vc))
 
-    plen = len(h0.vrf_proof)
-    if plen not in (80, 128) or any(
-        len(hv.vrf_proof) != plen for hv in hvs
-    ):
-        return _decline("proof-format")
 
-    # lane-0 offset discovery (how the offset is FOUND does not matter —
-    # the per-lane verification below is what makes extraction correct)
-    fields0 = (
-        h0.vk_cold, h0.vrf_vk, h0.vrf_output, h0.vrf_proof,
-        h0.ocert.vk_hot, h0.ocert.sigma,
-    )
-    offs = tuple(body0.find(f) for f in fields0)
-    if min(offs) < 0:
-        return _decline("field-offsets")
-
-    body = np.frombuffer(
-        b"".join(hv.signed_bytes for hv in hvs), np.uint8
-    ).reshape(b, lb)
-    refs = (
-        (offs[0], _col([hv.vk_cold for hv in hvs], 32)),
-        (offs[1], _col([hv.vrf_vk for hv in hvs], 32)),
-        (offs[2], _col([hv.vrf_output for hv in hvs], 64)),
-        (offs[3], _col([hv.vrf_proof for hv in hvs], plen)),
-        (offs[4], _col([hv.ocert.vk_hot for hv in hvs], 32)),
-        (offs[5], _col([hv.ocert.sigma for hv in hvs], 64)),
-    )
-    for o, ref in refs:
-        if not np.array_equal(body[:, o : o + ref.shape[1]], ref):
-            return _decline("field-mismatch")
-
-    slot = np.fromiter((hv.slot for hv in hvs), np.int64, b)
-    counter = np.fromiter((hv.ocert.counter for hv in hvs), np.int64, b)
-    c0 = np.fromiter((hv.ocert.kes_period for hv in hvs), np.int64, b)
-    for a in (slot, counter, c0):
-        if a.min() < 0 or a.max() >= 2**31:
-            return _decline("int32-range")
-
-    sigs = np.frombuffer(
-        b"".join(hv.kes_sig for hv in hvs), np.uint8
-    ).reshape(b, sig_len)
-    kes_rs = np.ascontiguousarray(sigs[:, :64])
-    tails: dict[bytes, int] = {}
-    kt_idx = np.empty(b, np.int32)
-    for i, hv in enumerate(hvs):
-        kt_idx[i] = tails.setdefault(hv.kes_sig[64:], len(tails))
-    f = Fraction(params.active_slot_coeff)
-    thr_rows: dict = {}
-    rows: list[np.ndarray] = []
-    thr_idx = np.empty(b, np.int32)
-    for i, hv in enumerate(hvs):
-        entry = ledger_view.pool_distr.get(hash_key(hv.vk_cold))
-        sigma = entry.stake if entry is not None else Fraction(0)
-        j = thr_rows.get(sigma)
-        if j is None:
-            j = thr_rows[sigma] = len(rows)
-            lo, hi = _threshold_rows(sigma, f)
-            rows.append(np.concatenate([lo, hi]))
-        thr_idx[i] = j
-    kt_n, thr_n = _table_rows(params, ledger_view, slot, len(tails),
-                              len(rows))
-    kt_tab = np.zeros((kt_n, sig_len - 64), np.uint8)
-    for t, j in tails.items():
-        kt_tab[j] = np.frombuffer(t, np.uint8)
-    kt_tab[len(tails) :] = kt_tab[0]
-    thr_tab = np.zeros((thr_n, 64), np.uint8)
-    thr_tab[: len(rows)] = np.stack(rows)
-    thr_tab[len(rows) :] = thr_tab[0]
-
-    layout = PraosPackedLayout(
-        lb, *offs, depth, params.slots_per_kes_period,
-        epoch_nonce is not None, plen,
-    )
-    packed = PraosPacked(
-        body=body.copy(),
-        kes_rs=kes_rs,
-        kes_tail_idx=kt_idx,
-        kes_tail_tab=kt_tab,
-        slot=slot.astype(np.int32),
-        counter=counter.astype(np.int32),
-        c0=c0.astype(np.int32),
-        thr_idx=thr_idx,
-        thr_tab=thr_tab,
-        nonce=np.frombuffer(epoch_nonce or bytes(32), np.uint8),
-    )
-    return layout, packed
+def _body_layouts(body: np.ndarray, lens: np.ndarray, refs
+                  ) -> tuple[np.ndarray, np.ndarray] | None:
+    """-> (the window's body layout table, [L, len(BODY_TAB_COLS)]
+    int32, and [B] int32 each lane's row of it), or None (declined). A
+    layout's offsets are found in one lane (where each field of `refs`
+    first occurs in its body) and VERIFIED byte for byte in every lane
+    given it, which has that body length: how an offset is found does
+    not matter, the equality makes extraction at a lane's own offsets
+    exact. The lanes no layout has taken yet give the next one, found in
+    the first lane of their most common body length: the first pass,
+    over the whole window, takes most of it, and the later ones gather
+    few rows."""
+    n = body.shape[0]
+    lane = np.zeros(n, np.int32)
+    rows: list = []
+    rest = np.arange(n)
+    while rest.size:
+        if len(rows) == _MAX_BODY_LAYOUTS:
+            return _decline("body-layouts")
+        widths, counts = np.unique(lens[rest], return_counts=True)
+        u = int(rest[np.argmax(lens[rest] == widths[counts.argmax()])])
+        row = body[u, : int(lens[u])].tobytes()
+        offs = tuple(row.find(r[u].tobytes()) for r in refs)
+        if min(offs) < 0:
+            return _decline("field-mismatch" if rows else "field-offsets")
+        whole = rest.size == n  # the first pass: no gather
+        sub = body if whole else body[rest]
+        ok = lens[rest] == lens[u]
+        for o, r in zip(offs, refs):
+            ok &= (sub[:, o : o + r.shape[1]]
+                   == (r if whole else r[rest])).all(axis=1)
+        lane[rest[ok]] = len(rows)
+        rows.append((int(lens[u]), *offs))
+        rest = rest[~ok]
+    tab = np.zeros((len(rows), len(BODY_TAB_COLS)), np.int32)
+    tab[:, : len(rows[0])] = rows
+    spread = tab[:, 1 : len(rows[0])].max(0) - tab[:, 1 : len(rows[0])].min(0)
+    if spread.max() >= _MAX_BODY_SHIFT or tab.max() > 0xFFFF:
+        return _decline("body-layouts")
+    return tab, lane
 
 
 def stage_packed_columns(
@@ -1273,20 +1243,19 @@ def stage_packed_columns(
     vc: ViewColumns,
     pre: ColumnChecks,
 ) -> tuple[PraosPackedLayout, PraosPacked] | None:
-    """Columnar `stage_packed`: the packed wire built straight from the
-    window columns. The columns are already row-major uint8, so the
-    body column IS `vc.signed_bytes`, the per-field verification is six
-    whole-matrix compares, the KES-tail dedup is one np.unique, and the
+    """The packed wire built straight from the window columns. The
+    columns are already row-major uint8, so the body column IS
+    `vc.signed_bytes` (cut to the window's widest body), the per-field
+    verification is a few whole-matrix compares a body layout
+    (`_body_layouts`), the KES-tail dedup is one np.unique, and the
     threshold table rides the precheck pool dedup — nothing slices
-    per-header bytes. Qualification rules are IDENTICAL to
-    `stage_packed` (same verified offsets, same int32 gates), so the
-    two stagings are interchangeable lane-for-lane; only the dedup
-    table ORDERING may differ (gather indices compensate)."""
+    per-header bytes. Whenever this returns a layout, the device
+    extraction is byte-identical to the generic staged path."""
     b = len(vc)
     if not b:
         return _decline("empty-window")
-    body = vc.signed_bytes
-    lb = int(body.shape[1])
+    lens = vc.signed_len
+    body = vc.signed_bytes[:, : int(lens.max())]
     if epoch_nonce is not None and len(epoch_nonce) != 32:
         return _decline("nonce-len")
     depth = params.kes_depth
@@ -1297,10 +1266,6 @@ def stage_packed_columns(
     if plen not in (80, 128) or not (vc.vrf_proof_len == plen).all():
         return _decline("proof-format")
 
-    # lane-0 offset discovery, then whole-matrix per-lane verification
-    # (the same contract as stage_packed: HOW the offsets are found does
-    # not matter, the byte-equality below makes extraction correct)
-    body0 = body[0].tobytes()
     proof_ref = np.ascontiguousarray(vc.vrf_proof[:, :plen])
     two = vc.two_certs
     if two and (plen != 80 or pre.overlay is None):
@@ -1310,12 +1275,12 @@ def stage_packed_columns(
         vc.ocert_vk_hot, vc.ocert_sigma,
         *((vc.vrf_leader_output, vc.vrf_leader_proof) if two else ()),
     )
-    offs = tuple(body0.find(r[0].tobytes()) for r in refs)
-    if min(offs) < 0:
-        return _decline("field-offsets")
-    for o, ref in zip(offs, refs):
-        if not np.array_equal(body[:, o : o + ref.shape[1]], ref):
-            return _decline("field-mismatch")
+    found = _body_layouts(body, lens, refs)
+    if found is None:
+        return None
+    rows, lane_layout = found
+    body_tab = np.repeat(rows[:1], _MAX_BODY_LAYOUTS, axis=0)
+    body_tab[: len(rows)] = rows
 
     slot, counter, c0 = vc.slot, vc.ocert_counter, vc.ocert_kes_period
     for a in (slot, counter, c0):
@@ -1338,8 +1303,8 @@ def stage_packed_columns(
     thr_tab[len(rows) :] = thr_tab[0]
 
     layout = PraosPackedLayout(
-        lb, *offs[:6], depth, params.slots_per_kes_period,
-        epoch_nonce is not None, plen, *offs[6:],
+        int(body.shape[1]), depth, params.slots_per_kes_period,
+        epoch_nonce is not None, plen, two,
     )
     packed = PraosPacked(
         body=np.ascontiguousarray(body),
@@ -1352,6 +1317,8 @@ def stage_packed_columns(
         thr_idx=pre.uniq_inv.astype(np.int32),
         thr_tab=thr_tab,
         nonce=np.frombuffer(epoch_nonce or bytes(32), np.uint8),
+        body_layout=lane_layout,
+        body_tab=body_tab,
     )
     if two:
         packed = TPraosPacked(
@@ -1372,7 +1339,7 @@ def pad_packed_to(packed: PraosPacked, size: int) -> PraosPacked:
 
     return packed._replace(**{
         f: _pad(getattr(packed, f)) for f in packed._fields
-        if f not in ("kes_tail_tab", "thr_tab", "nonce")
+        if f not in ("kes_tail_tab", "thr_tab", "nonce", "body_tab")
     })
 
 
@@ -1394,7 +1361,7 @@ SEED_L = nonces.mk_input_vrf(1, None)
 def unpack_packed(
     layout: PraosPackedLayout,
     body, kes_rs, kes_tail_idx, kes_tail_tab, slot, counter, c0,
-    thr_idx, thr_tab, nonce, overlay=None,
+    thr_idx, thr_tab, nonce, body_layout, body_tab, overlay=None,
 ):
     """The device-side unpack: packed columns -> the 21 staged columns
     in flatten_batch order, byte-identical to what `stage` builds on the
@@ -1402,29 +1369,48 @@ def unpack_packed(
     Runs inside the jit — limb decomposition for the pk path continues
     through ops/pk/kernels.staged_to_limb_first on these outputs.
 
+    Each lane's fields are cut at its own body layout's offsets (its row
+    of `body_tab`): a slice at the window's least offset of the field,
+    then shifted left lane by lane, one select for each bit of the
+    lane's excess over it; the KES message is padded at each lane's own
+    body length. The program depends on no offset.
+
     A two-certificate (TPraos) layout yields 27: the second proof's
     (gamma, c, s, alpha) behind the first's, both declared outputs, the
     64-byte threshold rows and the overlay column
     (`kernels.staged_to_limb_first_tp`)."""
     body = jnp.asarray(body).astype(jnp.uint8)
     bsz = body.shape[0]
+    # [B, len(BODY_TAB_COLS)]: each lane's layout
+    offs = jnp.take(jnp.asarray(body_tab).astype(jnp.int32),
+                    jnp.asarray(body_layout), axis=0)
+    spare = _MAX_BODY_SHIFT - 1
+    padded = jnp.pad(body, ((0, 0), (0, spare)))
 
-    def _slice(o, n):
-        return body[:, o : o + n]
+    def _slice(field, n):
+        o = offs[:, BODY_TAB_COLS.index(field)]
+        base = jnp.min(o)
+        x = jax.lax.dynamic_slice_in_dim(padded, base, n + spare, axis=1)
+        d = (o - base)[:, None]
+        for bit in range(spare.bit_length()):
+            w = x.shape[1] - (1 << bit)
+            x = jnp.where((d >> bit) & 1 == 1, x[:, x.shape[1] - w :],
+                          x[:, :w])
+        return x
 
-    issuer = _slice(layout.o_issuer, 32)
-    vrf_vk = _slice(layout.o_vrf_vk, 32)
-    beta = _slice(layout.o_vrf_out, 64)
+    issuer = _slice("issuer", 32)
+    vrf_vk = _slice("vrf_vk", 32)
+    beta = _slice("vrf_out", 64)
     bc = layout.vrf_proof_len == 128
-    proof = _slice(layout.o_vrf_proof, layout.vrf_proof_len)
+    proof = _slice("vrf_proof", layout.vrf_proof_len)
     if bc:  # gamma ‖ u ‖ v ‖ s announced-points format
         gamma, vrf_u, vrf_v, vrf_s = (
             proof[:, :32], proof[:, 32:64], proof[:, 64:96], proof[:, 96:]
         )
     else:
         gamma, vrf_c, vrf_s = proof[:, :32], proof[:, 32:48], proof[:, 48:]
-    vk_hot = _slice(layout.o_vk_hot, 32)
-    sigma = _slice(layout.o_sigma, 64)
+    vk_hot = _slice("vk_hot", 32)
+    sigma = _slice("sigma", 64)
     ed_r, ed_s = sigma[:, :32], sigma[:, 32:]
 
     kes_rs = jnp.asarray(kes_rs).astype(jnp.uint8)
@@ -1453,7 +1439,8 @@ def unpack_packed(
     ed_hb, ed_hnb = ed25519_batch.build_hblocks(
         ed_msg[:, :32], ed_msg[:, 32:64], ed_msg[:, 64:]
     )
-    kes_hb, kes_hnb = kes_batch.build_hblocks(kes_r, vk_leaf, body)
+    kes_hb, kes_hnb = kes_batch.build_hblocks(kes_r, vk_leaf, body,
+                                              offs[:, 0])
 
     alpha = ecvrf_batch.alpha_from_slots(
         slot, nonce if layout.has_nonce else None
@@ -1470,8 +1457,8 @@ def unpack_packed(
         # lane serves both inputs
         seed_e = jnp.asarray(np.frombuffer(SEED_ETA, np.uint8))
         seed_l = jnp.asarray(np.frombuffer(SEED_L, np.uint8))
-        beta_l = _slice(layout.o_vrf_leader_out, 64)
-        proof_l = _slice(layout.o_vrf_leader_proof, 80)
+        beta_l = _slice("vrf_leader_out", 64)
+        proof_l = _slice("vrf_leader_proof", 80)
         return (
             issuer, ed_r, ed_s, ed_hb, ed_hnb,
             vk_hot, period, kes_r, kes_s, vk_leaf, siblings, kes_hb, kes_hnb,
@@ -1661,10 +1648,10 @@ def _packed_agg_fn(layout: PraosPackedLayout, mode: str = "all"):
               else pk_aggregate.aggregate_window_vrf)
 
     def fn(body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-           thr_idx, thr_tab, nonce):
+           thr_idx, thr_tab, nonce, body_layout, body_tab):
         cols = unpack_packed(
             layout, body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-            thr_idx, thr_tab, nonce,
+            thr_idx, thr_tab, nonce, body_layout, body_tab,
         )
         limb = pk_kernels.staged_to_limb_first_bc(*cols)
         av = agg_fn(*limb, kes_depth=layout.kes_depth)
@@ -1832,9 +1819,7 @@ def run_batch_native(
         )
         kes_vk = vc.ocert_vk_hot
         kes_sig = vc.kes_sig
-        lb = vc.signed_bytes.shape[1]
-        body = vc.signed_bytes.tobytes()
-        body_off = np.arange(n + 1, dtype=np.int64) * lb
+        body, body_off = vc.body_spans()
         vrf_vk = vc.vrf_vk
         plen = int(vc.vrf_proof_len[0])
         vrf_proof = np.ascontiguousarray(vc.vrf_proof[:, :plen])
@@ -2339,14 +2324,20 @@ class _StageCensus(NamedTuple):
     overlay_s: float = 0.0  # TPraos: span `stage.overlay`
     pbft_lanes: int = 0  # PBFT: live regular (signed, non-EBB) lanes
     ebbs: int = 0  # PBFT: epoch boundary blocks among the live lanes
+    layouts: int = 0  # distinct signed-body layouts (0: staged generic)
 
 
 def _stage_census(hvs, pre, packed, prechecks_s: float) -> _StageCensus:
+    layouts = 0 if packed is None else (
+        # a Byron window signs one width; a packed Praos window's rows
+        # of its layout table are each some lane's
+        1 if hasattr(pre, "gk") else int(packed[1].body_layout.max()) + 1)
     if hasattr(pre, "gk"):  # a Byron window (protocol/pbft.PBftChecks)
         main = np.asarray(pre.main)
         return _StageCensus(len(set(pre.gk[main].tolist())), 0,
                             prechecks_s, pbft_lanes=int(main.sum()),
-                            ebbs=int(main.size - main.sum()))
+                            ebbs=int(main.size - main.sum()),
+                            layouts=layouts)
     if isinstance(pre, ColumnChecks):
         issuers = len(set(pre.uniq_hk))
     else:
@@ -2356,7 +2347,7 @@ def _stage_census(hvs, pre, packed, prechecks_s: float) -> _StageCensus:
         # every row of the dedup table is some lane's, so the largest
         # index names the last row (padding replicates lane 0's)
         kes_tails = int(packed[1].kes_tail_idx.max()) + 1
-    census = _StageCensus(issuers, kes_tails, prechecks_s)
+    census = _StageCensus(issuers, kes_tails, prechecks_s, layouts=layouts)
     if isinstance(pre, ColumnChecks) and pre.overlay is not None:
         census = census._replace(
             overlay_lanes=int(np.count_nonzero(pre.overlay)),
@@ -2390,17 +2381,15 @@ def window_lanes(max_batch: int) -> int | None:
     """The ONE padded lane count of a replay on the `pk` implementation
     (the chip): every window pads to the caller's `max_batch` bucket,
     not to its own, so each stage is traced, lowered and compiled once
-    per replay whatever the chain's short genesis windows (the columnar
-    stream cuts at every CBOR integer-width step), epoch tails and
-    width steps look like — each distinct lane count costs a full set
+    per replay whatever the chain's short windows (a tip, an epoch
+    tail) look like — each distinct lane count costs a full set
     of stage programs (minutes of set-up on a v5e) against seconds of
     device work. Padded lanes replicate lane 0 behind the live ones and
     are sliced off at materialize, so verdicts do not change; and the
     stage kernels are told how many lane tiles hold live lanes
     (`_dispatch_packed_lanes` -> `kernels.live_tiles`), so the device's
-    time follows what a window holds: a 13-header genesis window or a
-    one-header tip window is one tile of 64, an epoch tail of 5,216
-    headers 41. What a short window still pays at full width is the
+    time follows what a window holds: a one-header tip window is one
+    tile of 64, an epoch tail of 5,216 headers 41. What a short window still pays at full width is the
     `unpack` and `reduce` programs (XLA over all lanes, 0.42 ms) and
     the padded columns' transfer.
     None on the XLA twin: windows keep their own `bucket_size`, so no

@@ -551,7 +551,7 @@ def _columns(hvs) -> ViewColumns:
     if vc is None or not vc.two_certs:
         raise ValueError(
             "a TPraos window must columnarise: two-certificate headers "
-            "of one body and signature width"
+            "of one signature width"
         )
     return vc
 
@@ -618,7 +618,7 @@ def run_batch_native(params, lview, eta0, hvs, pre) -> pbatch.TPraosVerdicts:
 
     vc = _columns(hvs)
     n = len(vc)
-    lb = vc.signed_bytes.shape[1]
+    body, body_off = vc.body_spans()
     a_eta, a_l = _seed_columns(vc, eta0)
     rc, kind, eta = nl.native_validate_tpraos(
         vc.vk_cold, vc.ocert_sigma,
@@ -626,8 +626,7 @@ def run_batch_native(params, lview, eta0, hvs, pre) -> pbatch.TPraosVerdicts:
             [vc.ocert_vk_hot, pbatch._be8_np(vc.ocert_counter),
              pbatch._be8_np(vc.ocert_kes_period)], axis=1),
         vc.ocert_vk_hot, pre.kes_evolution.astype(np.int64), vc.kes_sig,
-        params.praos.kes_depth, vc.signed_bytes.tobytes(),
-        np.arange(n + 1, dtype=np.int64) * lb, vc.vrf_vk,
+        params.praos.kes_depth, body, body_off, vc.vrf_vk,
         np.ascontiguousarray(vc.vrf_proof[:, :80]), a_eta, vc.vrf_output,
         vc.vrf_leader_proof, a_l, vc.vrf_leader_output,
     )
@@ -709,15 +708,14 @@ class TPraosRules(pbatch.PraosRules):
         return _columns(hvs)
 
     def runs(self, hvs) -> list:
-        """Lists of views are cut where a body or signature width
-        steps (CBOR integer widths; the columnar stream cuts its chunks
-        there too): each run columnarises."""
+        """Lists of views are cut where the KES signature width steps
+        (the columnar stream cuts its chunks there too; bodies of any
+        lengths share a run): each run columnarises."""
         if isinstance(hvs, ViewColumns):
             return [hvs]
         cuts = [0] + [
             i for i in range(1, len(hvs))
-            if (len(hvs[i].signed_bytes), len(hvs[i].kes_sig))
-            != (len(hvs[i - 1].signed_bytes), len(hvs[i - 1].kes_sig))
+            if len(hvs[i].kes_sig) != len(hvs[i - 1].kes_sig)
         ] + [len(hvs)]
         return [_columns(hvs[a:b]) for a, b in zip(cuts, cuts[1:])]
 

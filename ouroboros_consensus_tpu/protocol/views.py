@@ -99,11 +99,16 @@ class ViewColumns:
     lanes (exact reference-error reconstruction), the generic-fallback
     staging path, and the sequential reference fold.
 
-    Construction REQUIRES rectangular columns: `from_header_columns` /
-    `from_views` return None when the KES-signed bodies (or signature
-    spans) are not uniform width, and the caller streams plain
-    HeaderView lists for that window instead — the columnar type never
-    carries ragged data.
+    The KES-signed bodies are the one ragged column: `signed_bytes` is
+    zero-padded to the widest row and `signed_len` holds each row's own
+    length. A body's length steps wherever a CBOR integer it holds
+    crosses a width (block number and slot from genesis, and on a chain
+    with real block bodies the body size, every time a big block and
+    an ordinary one alternate), so one window holds several. Every other
+    column is rectangular: `from_header_columns` / `from_views` return
+    None when the OCert sigma is not 64 bytes or the KES signatures of
+    the range differ in width, and the caller streams plain HeaderView
+    lists for that window instead.
     """
 
     slot: np.ndarray  # [n] int64
@@ -119,17 +124,21 @@ class ViewColumns:
     ocert_kes_period: np.ndarray  # [n] int64
     ocert_sigma: np.ndarray  # [n, 64] uint8
     kes_sig: np.ndarray  # [n, 96 + 32*depth] uint8
-    signed_bytes: np.ndarray  # [n, body_len] uint8
+    signed_bytes: np.ndarray  # [n, widest body] uint8, zero-padded
     # the TPraos leader certificate (HeaderView.vrf_leader_*): [n, 64] and
     # [n, 80], or zero-width on a window of one-certificate headers
     vrf_leader_output: np.ndarray = None  # type: ignore[assignment]
     vrf_leader_proof: np.ndarray = None  # type: ignore[assignment]
+    # [n] int64 — each row's body length (None: every row is as wide as
+    # the column)
+    signed_len: np.ndarray = None  # type: ignore[assignment]
 
     def __post_init__(self):
+        n = int(self.slot.shape[0])
         if self.vrf_leader_output is None:
-            self.vrf_leader_output, self.vrf_leader_proof = _no_leader_cert(
-                int(self.slot.shape[0])
-            )
+            self.vrf_leader_output, self.vrf_leader_proof = _no_leader_cert(n)
+        if self.signed_len is None:
+            self.signed_len = np.full(n, self.signed_bytes.shape[1], np.int64)
 
     @property
     def two_certs(self) -> bool:
@@ -164,7 +173,8 @@ class ViewColumns:
                 self.ocert_sigma[i].tobytes(),
             ),
             slot=int(self.slot[i]),
-            signed_bytes=self.signed_bytes[i].tobytes(),
+            signed_bytes=self.signed_bytes[i, : int(self.signed_len[i])
+                                            ].tobytes(),
             kes_sig=self.kes_sig[i].tobytes(),
             vrf_leader_output=(
                 self.vrf_leader_output[i].tobytes() if self.two_certs
@@ -194,6 +204,7 @@ class ViewColumns:
         kw = self.kes_sig.shape[1]
         sgn_b = np.ascontiguousarray(self.signed_bytes).tobytes()
         sw = self.signed_bytes.shape[1]
+        slens = self.signed_len.tolist()
         has_prev = self.has_prev.tolist()
         slots = self.slot.tolist()
         counters = self.ocert_counter.tolist()
@@ -218,39 +229,63 @@ class ViewColumns:
                     sigma_b[64 * i:64 * i + 64],
                 ),
                 slot=slots[i],
-                signed_bytes=sgn_b[sw * i:sw * (i + 1)],
+                signed_bytes=sgn_b[sw * i:sw * i + slens[i]],
                 kes_sig=kes_b[kw * i:kw * (i + 1)],
                 vrf_leader_output=lout_b[64 * i:64 * i + 64] if two else None,
                 vrf_leader_proof=lprf_b[80 * i:80 * i + 80] if two else None,
             ))
         return out
 
+    def body_spans(self) -> tuple[bytes, np.ndarray]:
+        """The bodies back to back and their [n + 1] int64 offsets (the
+        native verifiers' message spans)."""
+        lens = self.signed_len
+        n, w = self.signed_bytes.shape
+        off = np.zeros(n + 1, np.int64)
+        np.cumsum(lens, out=off[1:])
+        if int(off[-1]) == n * w:  # every row as wide as the column
+            return np.ascontiguousarray(self.signed_bytes).tobytes(), off
+        keep = np.arange(w) < lens[:, None]
+        return self.signed_bytes[keep].tobytes(), off
+
     @classmethod
     def concat(cls, parts: Sequence["ViewColumns"]) -> "ViewColumns | None":
-        """Concatenate same-shape windows (epoch segmentation across
-        chunk files), or None when the parts' row widths differ (the
-        caller falls back to a HeaderView list for that segment)."""
+        """Concatenate windows (epoch segmentation across chunk files),
+        the body column zero-padded to the widest part's, or None when
+        the parts' KES signatures differ in width or their headers in
+        certificate count (the caller keeps them apart)."""
         if len(parts) == 1:
             return parts[0]
-        if len({p.signed_bytes.shape[1] for p in parts}) > 1 or len(
-            {p.kes_sig.shape[1] for p in parts}
-        ) > 1 or len({p.two_certs for p in parts}) > 1:
+        if len({p.kes_sig.shape[1] for p in parts}) > 1 or len(
+            {p.two_certs for p in parts}
+        ) > 1:
             return None
-        return cls(*(
-            np.concatenate([getattr(p, f.name) for p in parts], axis=0)
-            for f in fields(cls)
-        ))
+        w = max(p.signed_bytes.shape[1] for p in parts)
+
+        def col(name):
+            if name != "signed_bytes":
+                return np.concatenate([getattr(p, name) for p in parts])
+            out = np.zeros((sum(len(p) for p in parts), w), np.uint8)
+            at = 0
+            for p in parts:
+                out[at:at + len(p), : p.signed_bytes.shape[1]] = (
+                    p.signed_bytes)
+                at += len(p)
+            return out
+
+        return cls(*(col(f.name) for f in fields(cls)))
 
     @classmethod
     def from_header_columns(cls, hc, lo: int = 0, hi: int | None = None
                             ) -> "ViewColumns | None":
         """Build from (a range of) a native_loader.HeaderColumns chunk
         scan — pure array plumbing (the span matrices gather
-        vectorized). None when the OCert sigma / KES signature /
-        signed-body spans of the range are not uniform width (callers
-        split at width changes via `pieces_from_header_columns`, or use
-        the per-view path)."""
-        from ..native_loader import _span_matrix
+        vectorized; the bodies zero-padded to the widest). None when the
+        OCert sigma or KES signature spans of the range are not uniform
+        width, or the sigma is not 64 bytes (callers split at those
+        changes via `pieces_from_header_columns`, or use the per-view
+        path)."""
+        from ..native_loader import _padded_span_matrix, _span_matrix
 
         hi = hc.n if hi is None else hi
         if lo == 0 and hi == hc.n:
@@ -261,8 +296,9 @@ class ViewColumns:
             buf = hc._buf_u8
             sigma = _span_matrix(buf, hc.sig_off[lo:hi], hc.sig_len[lo:hi])
             kes = _span_matrix(buf, hc.kes_off[lo:hi], hc.kes_len[lo:hi])
-            body = _span_matrix(buf, hc.sgn_off[lo:hi], hc.sgn_len[lo:hi])
-        if sigma is None or kes is None or body is None or sigma.shape[1] != 64:
+            body = _padded_span_matrix(buf, hc.sgn_off[lo:hi],
+                                       hc.sgn_len[lo:hi])
+        if sigma is None or kes is None or sigma.shape[1] != 64:
             return None
         s = slice(lo, hi)
         return cls(
@@ -280,6 +316,7 @@ class ViewColumns:
             ocert_sigma=sigma,
             kes_sig=kes,
             signed_bytes=body,
+            signed_len=np.asarray(hc.sgn_len[s], np.int64),
             **cls._leader_cert_of(hc, s),
         )
 
@@ -299,12 +336,15 @@ class ViewColumns:
     @classmethod
     def pieces_from_header_columns(cls, hc) -> "list[ViewColumns] | None":
         """The chunk as a minimal list of rectangular ViewColumns
-        pieces, split where any span width changes (CBOR integer-width
-        steps move the signed-body length a few times per chain). None
-        when even a uniform-width run cannot columnarize (malformed
-        sigma width) — the caller streams per-view lists instead."""
+        pieces, split where any span width, the VRF proof format or the
+        certificate count changes: a piece of one width is a zero-copy
+        view of the chunk, and the epoch segmentation's concat (a copy
+        in any case) merges bodies of several lengths into one segment
+        (`db_analyser._epoch_window_segments`). None when even a
+        uniform-width run cannot columnarize (malformed sigma width) —
+        the caller streams per-view lists instead."""
         widths = np.stack(
-            [hc.sig_len, hc.kes_len, hc.sgn_len,
+            [hc.sig_len, hc.kes_len, hc.sgn_len, hc.vrf_proof_len,
              hc.vrf_two.astype(np.int64)], axis=1,
         )
         chg = np.flatnonzero((widths[1:] != widths[:-1]).any(axis=1)) + 1
@@ -319,9 +359,10 @@ class ViewColumns:
 
     @classmethod
     def from_views(cls, hvs: Sequence[HeaderView]) -> "ViewColumns | None":
-        """Columnarize a HeaderView list (tests, synthetic chains).
-        None when the views cannot form rectangular columns (mixed
-        KES-signature widths)."""
+        """Columnarize a HeaderView list (tests, synthetic chains), the
+        bodies zero-padded to the widest. None when the views cannot
+        form the other columns (mixed KES-signature widths, a sigma not
+        64 bytes, mixed certificate counts)."""
         n = len(hvs)
         if n == 0:
             return None
@@ -334,9 +375,10 @@ class ViewColumns:
         proof = np.zeros((n, 128), np.uint8)
         for i, hv in enumerate(hvs):
             proof[i, : plen[i]] = np.frombuffer(hv.vrf_proof, np.uint8)
-        sw = len(hvs[0].signed_bytes)
-        if any(len(hv.signed_bytes) != sw for hv in hvs):
-            return None
+        slen = np.asarray([len(hv.signed_bytes) for hv in hvs], np.int64)
+        body = np.zeros((n, int(slen.max())), np.uint8)
+        body[np.arange(body.shape[1]) < slen[:, None]] = np.frombuffer(
+            b"".join(hv.signed_bytes for hv in hvs), np.uint8)
         two = hvs[0].vrf_leader_proof is not None
         if any((hv.vrf_leader_proof is not None) != two for hv in hvs):
             return None
@@ -372,7 +414,8 @@ class ViewColumns:
             ),
             ocert_sigma=col(lambda hv: hv.ocert.sigma, 64),
             kes_sig=col(lambda hv: hv.kes_sig, kw),
-            signed_bytes=col(lambda hv: hv.signed_bytes, sw),
+            signed_bytes=body,
+            signed_len=slen,
             **(dict(
                 vrf_leader_output=col(lambda hv: hv.vrf_leader_output, 64),
                 vrf_leader_proof=col(lambda hv: hv.vrf_leader_proof, 80),

@@ -414,11 +414,12 @@ class SidecarColumns:
     def pieces(self, data) -> list | None:
         """The chunk as rectangular `ViewColumns` pieces — the same
         split-at-width-steps contract as
-        ``ViewColumns.pieces_from_header_columns``, but from the
-        sidecar's columns instead of a parse. UNIFORM chunks are one
-        piece straight off the mapped matrices; non-uniform chunks
-        gather the ragged kes/sgn spans from the in-hand chunk bytes
-        (the span-gather fallback — still zero parse)."""
+        ``ViewColumns.pieces_from_header_columns`` (a width step of the
+        KES signature or the body, or a VRF proof format change), but
+        from the sidecar's columns instead of a parse. UNIFORM chunks
+        are one piece straight off the mapped matrices; non-uniform
+        chunks take the kes/sgn spans from the in-hand chunk bytes (the
+        span-gather fallback — still zero parse)."""
         from ..protocol.views import ViewColumns
 
         a = self.arrays
@@ -454,7 +455,7 @@ class SidecarColumns:
         sgn_len = a["sgn_len"].astype(np.int64)
         kes_off = a["kes_off"].astype(np.int64)
         sgn_off = a["sgn_off"].astype(np.int64)
-        widths = np.stack([kes_len, sgn_len], axis=1)
+        widths = np.stack([kes_len, sgn_len, a["vrf_proof_len"]], axis=1)
         chg = np.flatnonzero((widths[1:] != widths[:-1]).any(axis=1)) + 1
         bounds = [0, *chg.tolist(), self.n]
         out = []
@@ -591,7 +592,7 @@ def integrity_batch_hook(sc: SidecarColumns):
 #            offset, by piece), then the arrays, 8-byte aligned
 
 ERA_MAGIC = b"OCTCOLSE"
-ERA_VERSION = 1
+ERA_VERSION = 2  # 2: a Praos piece's columns hold `signed_len`
 _ERA_HEADER = struct.Struct("<8sIIIQIII")
 _BYRON_FIELDS = ("kind", "slot", "block_no", "prev_hash", "has_prev",
                  "delegate_vk", "sig", "signed")

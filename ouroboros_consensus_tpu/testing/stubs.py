@@ -125,10 +125,10 @@ def stub_agg_program_builder(delay_s=None):
         if key not in pbatch._JIT:
 
             def fn(body, kes_rs, kt_idx, kt_tab, slot, counter, c0,
-                   thr_idx, thr_tab, nonce):
+                   thr_idx, thr_tab, nonce, body_layout, body_tab):
                 cols = pbatch.unpack_packed(
                     layout, body, kes_rs, kt_idx, kt_tab, slot, counter,
-                    c0, thr_idx, thr_tab, nonce,
+                    c0, thr_idx, thr_tab, nonce, body_layout, body_tab,
                 )
                 v = stub_verify(*cols)
                 flags = jnp.stack(

@@ -574,19 +574,28 @@ def _skip_headers(wins, n: int):
             left = 0
 
 
-def _epoch_window_segments(params: PraosParams, wins):
+def _epoch_window_segments(params: PraosParams, wins,
+                           cut: int | None = None):
     """Cut a stream of chunk windows at epoch boundaries (SURVEY.md
     §5.7), merging same-epoch pieces: the columnar analog of
-    `_epoch_segments`. Consecutive same-width ViewColumns pieces merge
-    into ONE columnar segment per epoch (one array concat); a row-width
-    change inside an epoch (CBOR integer-width step) yields separate
-    columnar segments rather than falling back to objects —
-    validate_chain threads state across them identically (the
-    within-epoch tick is a no-op rotation)."""
+    `_epoch_segments`. Consecutive ViewColumns pieces of one KES
+    signature width and certificate count merge into ONE columnar
+    segment per epoch (one array concat, the bodies zero-padded to the
+    widest: a body's length steps with the CBOR widths of the integers
+    it holds, and a window takes bodies of any lengths); a KES width
+    change inside an epoch yields separate columnar segments rather
+    than falling back to objects — validate_chain threads state across
+    them identically (the within-epoch tick is a no-op rotation).
+
+    With `cut` (the replay's window size), an epoch's whole windows are
+    handed over as soon as the stream has read them: a segment of `cut`
+    rows a time from the epoch's start, its rest at the epoch's end. The
+    windows cut from the segments are the same, but the replay's first
+    one is staged while the rest of the epoch is still being read."""
     from ..protocol.views import ViewColumns
 
     if getattr(params, "eras", None) is not None:
-        yield from _era_epoch_segments(params, wins)
+        yield from _era_epoch_segments(params, wins, cut)
         return
 
     def pieces():
@@ -618,7 +627,7 @@ def _epoch_window_segments(params: PraosParams, wins):
         gw = None
         for p in parts:
             if isinstance(p, ViewColumns):
-                wkey = (p.signed_bytes.shape[1], p.kes_sig.shape[1])
+                wkey = (p.kes_sig.shape[1], p.two_certs)
                 if group and gw == wkey:
                     group.append(p)
                     continue
@@ -636,22 +645,37 @@ def _epoch_window_segments(params: PraosParams, wins):
     acc: list = []
     epoch = None
     for e, piece in pieces():
-        if epoch is None or e == epoch:
-            acc.append(piece)
-            epoch = e
-        else:
+        if acc and e != epoch:
             yield from flush(acc)
-            acc, epoch = [piece], e
+            acc = []
+        acc.append(piece)
+        epoch = e
+        if cut and sum(len(p) for p in acc) >= cut:
+            *done, last = flush(acc)
+            yield from done
+            whole, acc = _whole_windows(last, cut)
+            yield from whole
     if acc:
         yield from flush(acc)
 
 
-def _era_epoch_segments(params, wins):
+def _whole_windows(seg, cut: int):
+    """-> ([the first `cut` x k rows of `seg`, when k > 0], [the rest,
+    when there is one]): a columnar run's whole windows, and what waits
+    for more of its epoch. A HeaderView list is handed over whole."""
+    if isinstance(seg, list):
+        return [seg], []
+    k = len(seg) // cut * cut
+    return [seg[:k]] if k else [], [seg[k:]] if k < len(seg) else []
+
+
+def _era_epoch_segments(params, wins, cut: int | None = None):
     """`_epoch_window_segments` for a chain over eras: each EraPiece is
     cut at its era's epoch boundaries (the era params' own clock:
     Byron's epochs, then Shelley's, on one count), and consecutive runs
-    of one era, one epoch and one row width merge into ONE segment. An
-    era boundary is an epoch boundary, so no segment spans two eras."""
+    of one era, one epoch and one KES signature width (Byron: one
+    signed width) merge into ONE segment. An era boundary is an epoch
+    boundary, so no segment spans two eras. `cut` as there."""
     import numpy as np
 
     from ..protocol.batch import EraPiece
@@ -661,7 +685,7 @@ def _era_epoch_segments(params, wins):
     def width(cols):
         if isinstance(cols, ByronColumns):
             return ("byron", cols.signed.shape[1])
-        return (cols.signed_bytes.shape[1], cols.kes_sig.shape[1])
+        return (cols.kes_sig.shape[1], cols.two_certs)
 
     def concat(group):
         if len(group) == 1:
@@ -685,6 +709,9 @@ def _era_epoch_segments(params, wins):
                 group = []
             group.append(part)
             key = k
+            if cut and sum(len(g) for g in group) >= cut:
+                whole, group = _whole_windows(concat(group), cut)
+                yield from (EraPiece(key[0], w) for w in whole)
     if group:
         yield EraPiece(key[0], concat(group))
 
@@ -1123,7 +1150,7 @@ def _revalidate_body(
                 wins = _cap_windows(wins, max_headers)
             if res.resumed_headers:
                 wins = _skip_headers(wins, res.resumed_headers)
-            segs = _epoch_window_segments(params, wins)
+            segs = _epoch_window_segments(params, wins, cut=max_batch)
             if backend == "device":
                 # ONE window pipeline a replay (validate_stream): the
                 # next segment's first windows are staged and

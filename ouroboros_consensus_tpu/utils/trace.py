@@ -440,6 +440,9 @@ class WindowSpan:
     # one-pool chain reads 1, 2-3, 1)
     issuers: int = 0  # distinct cold keys
     kes_tails: int = 0  # rows of the KES tail table before padding
+    # distinct signed-body layouts (length and field offsets: CBOR width
+    # classes) the packed window holds; 0 where it staged generic
+    layouts: int = 0
     prechecks_s: float = 0.0  # span `stage.prechecks`, on `stage_thread`
     epilogue_counters_s: float = 0.0  # span `epilogue.counters`
     # lane tiles that hold the window's `lanes` (ops/pk/kernels.live_tiles),

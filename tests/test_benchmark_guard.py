@@ -37,8 +37,12 @@ def test_every_cell_reports_30_per_layer_metrics_by_name(m):  # noqa: F405
                  "replay-stakepools-2epoch"):
         per_layer = {x.name: x for x in m.cell(cell).per_layer}
         # 30, and the 8 of the device's idle account, the off-CPU time
-        # of dispatch and stage, and the collections
-        assert len(per_layer) == 38
+        # of dispatch and stage, and the collections; and the body
+        # layouts a window holds
+        assert len(per_layer) == 39
+        layouts = per_layer["layouts_per_window"]
+        assert layouts.spec == {"kind": "window_span", "key": "layouts"}
+        assert (layouts.unit, layouts.layer) == ("layouts", "staging")
         index = per_layer["open_index_s_per_replay"]
         assert index.spec["kind"] == "phase_wall"
         assert index.spec["key"] == "open.index"

@@ -145,6 +145,38 @@ def test_unpack_compiles(one_chip, chip_seams, has_nonce):
     _compile(K._mk_packed_unpack(layout), args)
 
 
+@functools.lru_cache(maxsize=None)
+def _packed_genesis():
+    """A window from genesis, as a replay's first: no previous hash, then
+    block numbers and slots crossing 24 and 256 (six body layouts)."""
+    params = _params()
+    pool = fixtures.make_pool(0, kes_depth=KES_DEPTH)
+    lview = fixtures.make_ledger_view([pool])
+    hvs, prev = [], None
+    for block_no, slot in [(0, 1), (1, 3), (22, 22), (23, 23), (24, 24),
+                           (25, 254), (26, 255), (255, 256), (256, 300)]:
+        blk = forge_block(params, pool, slot=slot, block_no=block_no,
+                          prev_hash=prev, epoch_nonce=None)
+        hvs.append(blk.header.to_view())
+        prev = blk.header.hash_
+    layout, parr = pbatch.stage_packed(params, lview, None, hvs)
+    assert parr.body_layout.max() + 1 > 4
+    return layout, pbatch.pad_packed_to(parr, LANES)
+
+
+def test_unpack_compiles_with_several_body_layouts(one_chip, chip_seams):
+    """The genesis window's `unpack`: each lane's fields shifted into
+    place from its row of the layout table, its KES message padded at
+    its own length."""
+    layout, cols = _packed_genesis()
+    args = [_sds(one_chip, c.shape, c.dtype) for c in map(np.asarray, cols)]
+    _compile(K._mk_packed_unpack(layout), args)
+    assert [s.shape for s in jax.eval_shape(
+        K._mk_packed_unpack(layout), *cols)] == [
+        s.shape for s in jax.eval_shape(
+            K._mk_packed_unpack(_packed(False)[0]), *_packed(False)[1])]
+
+
 def test_reduce_compiles(one_chip, chip_seams):
     """Bit packing and a cast: the compiled program holds no loop (the
     round-6 nonce scan, a `while` of one trip a lane, ran 3.3 s a

@@ -302,35 +302,41 @@ def test_clean_chain_span_sequence(pools, lview, stubbed):
 
 
 def test_gate_decline_names_the_gate(pools, lview, stubbed):
-    """A window mixing CBOR body widths cannot stage packed: the
-    WindowStaged event says generic AND names the qualification gate
-    (the PR 5 gates were silent about why)."""
+    """A window whose signed bodies do not embed its fields cannot stage
+    packed: the WindowStaged event says generic AND names the
+    qualification gate (the first gates were silent about why). A window
+    mixing CBOR body widths stages packed, one body layout each."""
     params = make_params()
     # block_no 18..: crosses the CBOR 1->2-byte boundary at 24, so one
     # window mixes body widths (the test_columnar boundary idiom)
     _, hvs = _forge_chain(params, pools, lview, 16, first_blkno=18)
     widths = {len(hv.signed_bytes) for hv in hvs}
     assert len(widths) == 2, "fixture must cross a CBOR width boundary"
-    st0 = praos.PraosState(epoch_nonce=b"\x07" * 32)
-    lt = T.ListTracer()
-    pbatch.set_batch_tracer(lt)
-    try:
-        res = pbatch.validate_chain(
-            params, lambda _e: lview, st0, hvs, max_batch=16
-        )
-    finally:
-        pbatch.set_batch_tracer(None)
-    assert res.error is None and res.n_valid == 16
-    staged = _of(lt.events, T.WindowStaged)
-    declined = [s for s in staged if s.outcome == "generic"]
-    assert declined, "the mixed-width window must fall back"
-    assert declined[0].gate == "body-width-mixed"
-    # and the retired span carries the same attribution
-    sp = next(
-        s for s in _of(lt.events, T.WindowSpan)
-        if s.index == declined[0].index
-    )
-    assert sp.outcome == "generic" and sp.gate == "body-width-mixed"
+    nonce = b"\x07" * 32
+    synthetic = [
+        fixtures.forge_header_view(params, pools[i % 2], slot=100 + i,
+                                   epoch_nonce=nonce, prev_hash=b"x" * 32,
+                                   body_bytes=b"body-%d" % i)
+        for i in range(16)
+    ]
+    st0 = praos.PraosState(epoch_nonce=nonce)
+    for chain, outcome, gate, layouts in ((hvs, "packed", None, 2),
+                                          (synthetic, "generic",
+                                           "field-offsets", 0)):
+        lt = T.ListTracer()
+        pbatch.set_batch_tracer(lt)
+        try:
+            res = pbatch.validate_chain(
+                params, lambda _e: lview, st0, chain, max_batch=16
+            )
+        finally:
+            pbatch.set_batch_tracer(None)
+        assert res.error is None and res.n_valid == 16
+        (staged,) = _of(lt.events, T.WindowStaged)
+        assert (staged.outcome, staged.gate) == (outcome, gate)
+        # and the retired span carries the same attribution
+        (sp,) = _of(lt.events, T.WindowSpan)
+        assert (sp.outcome, sp.gate, sp.layouts) == (outcome, gate, layouts)
 
 
 def test_stage_packed_decline_reasons_unit(pools, lview):
@@ -342,9 +348,10 @@ def test_stage_packed_decline_reasons_unit(pools, lview):
     assert pbatch.stage_packed(params, lview, nonce, []) is None
     assert pbatch._LAST_DECLINE == "empty-window"
 
+    # bodies of two lengths are no gate: one layout each
     bad = [replace(hvs[0], signed_bytes=hvs[0].signed_bytes + b"x"), *hvs[1:]]
-    assert pbatch.stage_packed(params, lview, nonce, bad) is None
-    assert pbatch._LAST_DECLINE == "body-width-mixed"
+    assert pbatch.stage_packed(params, lview, nonce,
+                               bad)[1].body_layout.max() == 1
 
     bad = [replace(hv, kes_sig=hv.kes_sig + b"x") for hv in hvs]
     assert pbatch.stage_packed(params, lview, nonce, bad) is None

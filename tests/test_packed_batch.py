@@ -122,7 +122,7 @@ def test_packed_unpack_roundtrips_all_families(nonce, depth, first_slot):
     layout, parr = res
     staged = pbatch.stage(params, lv, nonce, hvs, pre.kes_evolution)
     ref = pbatch.flatten_batch(staged)
-    got = jax.jit(lambda *a: pbatch.unpack_packed(layout, *a))(*parr[:10])
+    got = jax.jit(lambda *a: pbatch.unpack_packed(layout, *a))(*parr)
     # batch-compatible proofs (the forge default) stage 22 columns
     assert len(ref) == len(got) == (22 if layout.vrf_proof_len == 128 else 21)
     for name, a, b in zip(cols_of(staged), ref, got):
@@ -144,7 +144,7 @@ def test_packed_limb_first_matches_pk_arrays(pools, lview):
     layout, parr = pbatch.stage_packed(params, lview, nonce, hvs)
     staged = pbatch.stage(params, lview, nonce, hvs, pre.kes_evolution)
     ref = pbatch.pk_arrays(staged)
-    got = jax.jit(K._mk_packed_unpack(layout))(*parr[:10])
+    got = jax.jit(K._mk_packed_unpack(layout))(*parr)
     assert len(ref) == len(got) == 22  # bc-staged: u, v replace c
     for i, (a, b) in enumerate(zip(ref, got)):
         a, b = np.asarray(a), np.asarray(b)
@@ -174,7 +174,7 @@ def test_packed_unpack_pads_kes_hash_column_with_headroom(pools, lview,
     k = ref[11].shape[0]
     monkeypatch.setattr(K, "kes_hash_blocks", lambda body_len: k + 1)
     got = [np.asarray(x) for x in
-           jax.jit(K._mk_packed_unpack(layout))(*parr[:10])]
+           jax.jit(K._mk_packed_unpack(layout))(*parr)]
     assert got[11].shape == (k + 1, *ref[11].shape[1:])
     assert (got[11][:k] == ref[11]).all() and not got[11][k:].any()
     assert (got[12] == ref[12]).all() and (got[12] == k).all()
@@ -200,13 +200,18 @@ def test_packed_h2d_bytes_shrink(pools, lview):
 def test_stage_packed_fallback_gates(pools, lview):
     params = make_params()
     nonce = b"\x07" * 32
-    # mixed body lengths (genesis prev=None header) -> generic fallback
+    # mixed body lengths (genesis prev=None header) stage packed: one
+    # body layout each, every lane at its own
     hvs = real_chain(params, pools, 4)
     blk0 = forge_block(params, pools[0], slot=99, block_no=29,
                        prev_hash=None, epoch_nonce=nonce)
-    assert pbatch.stage_packed(
-        params, lview, nonce, [blk0.header.to_view()] + hvs
-    ) is None
+    layout, parr = pbatch.stage_packed(
+        params, lview, nonce, [blk0.header.to_view()] + hvs)
+    # the most common length first: its pass takes most lanes whole
+    assert parr.body_layout.tolist() == [1, 0, 0, 0, 0]
+    assert layout.body_len == parr.body_tab[:2, 0].max()
+    # the rows past the window's layouts replicate its first
+    assert (parr.body_tab[2:] == parr.body_tab[0]).all()
     # synthetic views whose signed bytes do not embed the fields
     fv = [
         fixtures.forge_header_view(params, pools[0], slot=s,
@@ -235,6 +240,295 @@ def test_kes_tail_table_dedupes(pools, lview):
     for i, hv in enumerate(hvs):
         row = parr.kes_tail_tab[parr.kes_tail_idx[i]]
         assert row.tobytes() == hv.kes_sig[64:]
+
+
+# -- windows of several body layouts ---------------------------------------
+
+# every CBOR width step a window meets, from genesis: (slot, block_no,
+# body bytes). The first header has no previous hash; block numbers cross
+# 24 and 256; slots cross 24, 256 and 65,536; bodies straddle 256 and
+# 65,536 bytes, so the body size's own width alternates
+_RAGGED = [
+    (0, 0, 0), (7, 1, 300), (14, 2, 10), (21, 3, 70_000),
+    (22, 22, 0), (23, 23, 300), (24, 24, 70_000), (25, 25, 10),
+    (254, 254, 300), (255, 255, 0), (256, 256, 70_000), (257, 257, 10),
+    (65_534, 258, 70_000), (65_535, 259, 300), (65_536, 260, 0),
+    (65_537, 261, 300),
+]
+
+
+def ragged_params(kes_depth=3):
+    """f = 1 (every pool leads every slot it forges) and KES periods of
+    10,000 slots, so that slot 65,537 is evolution 6 of a depth-3 key."""
+    return replace(make_params(kes_depth=kes_depth),
+                   active_slot_coeff=Fraction(1),
+                   slots_per_kes_period=10_000)
+
+
+def ragged_chain(params, pools, epoch_nonce=b"\x07" * 32):
+    hvs, prev = [], None
+    for i, (slot, block_no, size) in enumerate(_RAGGED):
+        blk = forge_block(
+            params, pools[i % len(pools)], slot=slot, block_no=block_no,
+            prev_hash=prev, epoch_nonce=epoch_nonce,
+            txs=(bytes([i]) * size,) if size else (),
+        )
+        hvs.append(blk.header.to_view())
+        prev = blk.header.hash_
+    return hvs
+
+
+@pytest.fixture(scope="module")
+def ragged():
+    params = ragged_params()
+    pls = [fixtures.make_pool(40 + i, kes_depth=3) for i in range(2)]
+    lv = fixtures.make_ledger_view(pls)
+    return params, pls, lv, ragged_chain(params, pls)
+
+
+@pytest.mark.parametrize("lo,hi", [(0, 16), (4, 12), (11, 16)])
+def test_packed_unpack_roundtrips_ragged_windows(ragged, lo, hi):
+    """The packed round trip over windows of several body layouts: each
+    lane's fields come out of its own layout's offsets, its KES message
+    is padded at its own length, and the whole equals the host staging
+    (per view, and columnar) byte for byte, limb-first too."""
+    from ouroboros_consensus_tpu.ops.pk import kernels as K
+
+    params, _, lv, hvs = ragged
+    hvs = hvs[lo:hi]
+    nonce = b"\x07" * 32
+    assert len({len(hv.signed_bytes) for hv in hvs}) > 1
+    layout, parr = pbatch.stage_packed(params, lv, nonce, hvs)
+    assert parr.body_layout.max() > 0
+    assert layout.body_len == max(len(hv.signed_bytes) for hv in hvs)
+    for i, hv in enumerate(hvs):
+        assert parr.body_tab[parr.body_layout[i], 0] == len(hv.signed_bytes)
+    pre = pbatch.host_prechecks(params, lv, hvs)
+    staged = pbatch.stage(params, lv, nonce, hvs, pre.kes_evolution)
+    vc = ViewColumns.from_views(hvs)
+    cstaged = pbatch.stage_columns(
+        params, lv, nonce, vc, pre.kes_evolution,
+        pbatch.host_prechecks_columns(params, lv, vc))
+    got = jax.jit(lambda *a: pbatch.unpack_packed(layout, *a))(*parr)
+    ref = pbatch.flatten_batch(staged)
+    for name, a, c, b in zip(cols_of(staged), ref,
+                             pbatch.flatten_batch(cstaged), got):
+        a, b, c = np.asarray(a), np.asarray(b), np.asarray(c)
+        assert a.shape == b.shape == c.shape and a.dtype == b.dtype, name
+        assert (a == b).all() and (a == c).all(), name
+    limb = jax.jit(K._mk_packed_unpack(layout))(*parr)
+    for i, (a, b) in enumerate(zip(pbatch.pk_arrays(staged), limb)):
+        a, b = np.asarray(a), np.asarray(b)
+        if i == 11:  # the kes hash column carries its headroom block
+            assert (b[: a.shape[0]] == a).all() and not b[a.shape[0]:].any()
+        else:
+            assert (a == b).all(), i
+
+
+
+def test_one_unpack_program_whatever_the_layouts(ragged):
+    """The body layouts ride the wire: two windows of one widest body
+    and proof format get ONE layout descriptor (the jit key and the
+    stored program's name) whatever layouts each holds."""
+    from ouroboros_consensus_tpu.ops.pk import kernels as K
+
+    params, _, lv, hvs = ragged
+    nonce = b"\x07" * 32
+    la, pa = pbatch.stage_packed(params, lv, nonce, hvs)
+    lb, pb = pbatch.stage_packed(params, lv, nonce, hvs[1:])
+    assert pa.body_layout.max() > pb.body_layout.max()
+    assert la == lb and K.packed_unpack_name(la) == K.packed_unpack_name(lb)
+
+
+@pytest.mark.parametrize("at,ok", [(127, True), (128, False)])
+def test_body_layouts_bound_a_fields_spread(at, ok):
+    """`unpack` shifts a lane's field by under `_MAX_BODY_SHIFT` bytes
+    from the window's least offset: a window whose layouts spread a
+    field further stages generic."""
+    field = np.arange(1, 33, dtype=np.uint8)
+    body = np.zeros((2, 200), np.uint8)
+    body[0, :32] = field
+    body[1, at:at + 32] = field
+    lens = np.array([200, 200])
+    got = pbatch._body_layouts(body, lens, (np.stack([field, field]),))
+    if ok:
+        tab, lane = got
+        assert tab[:, :2].tolist() == [[200, 0], [200, at]]
+        assert lane.tolist() == [0, 1]
+    else:
+        assert got is None and pbatch._LAST_DECLINE == "body-layouts"
+
+def _words_to_bytes(words: np.ndarray) -> bytes:
+    """[NB, 16, 2] uint32 SHA-512 words -> the padded message bytes."""
+    return np.asarray(words, ">u4").tobytes()
+
+
+def _padded_message(blocks, nblocks: int) -> bytes:
+    """The message of a lane's padded SHA-512 blocks (its bit length
+    sits in the last 16 bytes of its last block)."""
+    raw = _words_to_bytes(blocks[:nblocks])
+    return raw[: int.from_bytes(raw[-16:], "big") // 8]
+
+
+def exact_host_verify(*cols):
+    """`verify_praos_any` with the curve arithmetic left to the native
+    verifier (`jax.pure_callback`): EXACT verdicts from the staged
+    columns the packed `unpack` built — the OCert and KES messages read
+    back out of their padded hash blocks — and the real eta / leader
+    value extensions of `stubs.stub_verify`."""
+    from ouroboros_consensus_tpu import native_loader as nl
+    from ouroboros_consensus_tpu.testing import stubs
+
+    bc = len(cols) == 22
+    proof_cols = cols[14:18] if bc else cols[14:17]
+    alpha, beta = cols[-4], cols[-3]
+
+    def host(issuer, ed_r, ed_s, ed_hb, ed_hnb, vk_hot, period, kes_r,
+             kes_s, vk_leaf, sib, kes_hb, kes_hnb, vrf_vk, alpha, beta,
+             *proof):
+        a = [np.asarray(x) for x in (issuer, ed_r, ed_s, ed_hb, ed_hnb,
+                                     vk_hot, period, kes_r, kes_s, vk_leaf,
+                                     sib, kes_hb, kes_hnb, vrf_vk, alpha,
+                                     beta)]
+        proof = [np.asarray(p) for p in proof]
+        u8 = [x.astype(np.uint8) if x.dtype != np.uint32 else x for x in a]
+        (issuer, ed_r, ed_s, ed_hb, ed_hnb, vk_hot, period, kes_r, kes_s,
+         vk_leaf, sib, kes_hb, kes_hnb, vrf_vk, alpha, beta) = u8
+        b = issuer.shape[0]
+        ok = np.zeros((3, b), np.bool_)
+        depth = sib.shape[1]
+        for i in range(b):
+            ok[0, i] = nl.native_ed25519_verify(
+                issuer[i].tobytes(), ed_r[i].tobytes() + ed_s[i].tobytes(),
+                _padded_message(ed_hb[i], int(ed_hnb[i]))[64:])
+            ok[1, i] = nl.native_kes_verify(
+                vk_hot[i].tobytes(), depth, int(period[i]),
+                _padded_message(kes_hb[i], int(kes_hnb[i]))[64:],
+                b"".join(x[i].tobytes() for x in (kes_r, kes_s, vk_leaf))
+                + sib[i].astype(np.uint8).tobytes())
+            out = nl.native_ecvrf_verify(
+                vrf_vk[i].tobytes(),
+                b"".join(p[i].astype(np.uint8).tobytes() for p in proof),
+                alpha[i].tobytes())
+            ok[2, i] = out is not None and out == beta[i].tobytes()
+        return ok
+
+    b = jnp.asarray(cols[0]).shape[0]
+    ok = jax.pure_callback(
+        host, jax.ShapeDtypeStruct((3, b), jnp.bool_),
+        *cols[:14], alpha, beta, *proof_cols)
+    v = stubs.stub_verify(*cols)
+    lv = v.leader_value
+    thr_lo = jnp.asarray(cols[-2]).astype(jnp.int32)
+    thr_hi = jnp.asarray(cols[-1]).astype(jnp.int32)
+    win = pbatch._lt_be(lv, thr_lo)
+    return pbatch.Verdicts(ok[0], ok[1], ok[2], win,
+                           ~win & pbatch._lt_be(lv, thr_hi), v.eta, lv)
+
+
+@pytest.fixture
+def exact_packed(monkeypatch):
+    """The per-lane XLA programs, packed and staged, with exact host
+    verdicts (their programs fenced off the process-wide jit table)."""
+    before = set(pbatch._JIT)
+    monkeypatch.setenv("OCT_VRF_AGG", "0")
+    for name in ("verify_praos", "verify_praos_bc", "verify_praos_any"):
+        monkeypatch.setattr(pbatch, name, exact_host_verify)
+    fused = jax.jit(exact_host_verify)
+    monkeypatch.setattr(pbatch, "_jitted_verify", lambda bc=False: fused)
+    yield
+    for k in set(pbatch._JIT) - before:
+        del pbatch._JIT[k]
+
+
+def _flip(b: bytes, i: int) -> bytes:
+    return b[:i] + bytes([b[i] ^ 1]) + b[i + 1:]
+
+
+def _corrupt(params, pools, hvs, i, what):
+    """Header i wrong as an attacker would send it: the OCert signature
+    (in the view and the body it is signed in), the KES signature, or
+    the VRF proof (the body signed again, so the proof is what is
+    wrong)."""
+    from ouroboros_consensus_tpu.ops.host import kes as host_kes
+    from ouroboros_consensus_tpu.protocol.views import OCert
+
+    hv = hvs[i]
+    if what == "kes-signature":
+        bad = replace(hv, kes_sig=_flip(hv.kes_sig, 32))
+    elif what == "ocert-signature":
+        sigma = _flip(hv.ocert.sigma, 32)
+        o = hv.signed_bytes.index(hv.ocert.sigma)
+        bad = replace(hv, ocert=OCert(hv.ocert.vk_hot, hv.ocert.counter,
+                                      hv.ocert.kes_period, sigma),
+                      signed_bytes=hv.signed_bytes[:o] + sigma
+                      + hv.signed_bytes[o + 64:])
+    else:
+        proof = _flip(hv.vrf_proof, len(hv.vrf_proof) - 32)
+        o = hv.signed_bytes.index(hv.vrf_proof)
+        body = (hv.signed_bytes[:o] + proof
+                + hv.signed_bytes[o + len(proof):])
+        pool = next(p for p in pools if p.kes_vk == hv.ocert.vk_hot)
+        t = params.kes_period_of(hv.slot) - hv.ocert.kes_period
+        bad = replace(hv, vrf_proof=proof, signed_bytes=body,
+                      kes_sig=host_kes.sign(pool.kes_seed, pool.kes_depth,
+                                            t, body))
+    return [*hvs[:i], bad, *hvs[i + 1:]]
+
+
+@pytest.mark.parametrize("what,at", [
+    (None, None), ("ocert-signature", 3), ("kes-signature", 12),
+    ("vrf-proof", 9),
+])
+def test_ragged_window_validates_as_the_fold(ragged, exact_packed, what,
+                                             at):
+    """A window from genesis holding every body layout of `_RAGGED`
+    goes through the packed path (the XLA twin's program, exact
+    verdicts) to the sequential reference's valid count, first error and
+    final state; one wrong lane of each kind is refused at its own
+    index."""
+    from ouroboros_consensus_tpu.obs import recovery
+
+    params, pls, lv, hvs = ragged
+    if what is not None:
+        hvs = _corrupt(params, pls, hvs, at, what)
+    st0 = praos.PraosState(epoch_nonce=b"\x07" * 32)
+    lt = []
+    pbatch.set_batch_tracer(lt.append)
+    try:
+        got = pbatch.validate_chain(params, lambda _e: lv, st0,
+                                    ViewColumns.from_views(hvs),
+                                    max_batch=16)
+    finally:
+        pbatch.set_batch_tracer(None)
+    want = recovery.host_reference_fold(
+        params, praos.tick(params, lv, hvs[0].slot, st0), hvs)
+    assert got.n_valid == want.n_valid == (len(hvs) if at is None else at)
+    assert repr(got.error) == repr(want.error)
+    assert got.state == want.state
+    from ouroboros_consensus_tpu.utils.trace import WindowSpan
+
+    (span,) = [e for e in lt if isinstance(e, WindowSpan)]
+    assert span.outcome == "packed" and span.layouts > 4
+
+
+@pytest.mark.parametrize(
+    "rung", ("retry", "stage-split", "xla-twin", "host-reference"))
+def test_recovery_rung_revalidates_a_ragged_window(ragged, exact_packed,
+                                                   rung):
+    """Each rung of the device ladder re-validates a window of several
+    body layouts from its views to the same verdict."""
+    from ouroboros_consensus_tpu.obs import recovery
+
+    params, pls, lv, hvs = ragged
+    hvs = _corrupt(params, pls, hvs, 10, "kes-signature")
+    st0 = praos.PraosState(epoch_nonce=b"\x07" * 32)
+    ticked = praos.tick(params, lv, hvs[0].slot, st0)
+    want = recovery.host_reference_fold(params, ticked, hvs)
+    got = recovery.supervisor()._run_rung(
+        rung, params, ticked, ViewColumns.from_views(hvs), "device", None)
+    assert (got.n_valid, repr(got.error), got.state) == (
+        want.n_valid, repr(want.error), want.state) and got.n_valid == 10
 
 
 # ---------------------------------------------------------------------------

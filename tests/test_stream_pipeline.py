@@ -30,7 +30,8 @@ from ouroboros_consensus_tpu.testing import chaos, stubs
 from ouroboros_consensus_tpu.tools import db_analyser as ana
 from ouroboros_consensus_tpu.utils import trace as T
 # the span tree suite's chain: three epochs and more from genesis, a
-# row-width step inside an epoch, 16-lane windows
+# row-width step inside an epoch (one segment holds both widths), 16-lane
+# windows
 from tests.test_span_tree import MAX_BATCH, PARAMS, db  # noqa: F401
 
 DEPTH = 3  # validate_stream's default pipeline_depth
@@ -44,9 +45,9 @@ def _views(seg):
 
 @pytest.fixture(scope="module")
 def segments(db):
-    """The chain as the replay's own stream cuts it, with the first
-    segment of more than two headers cut once more into one-header
-    segments and its rest."""
+    """The chain as the replay's own stream cuts it (one segment an
+    epoch), with the first segment of more than two headers cut once
+    more into one-header segments and its rest."""
     path, _ = db
     imm = ana.open_immutable(path, validate_all=False)
     segs, cut = [], False
@@ -59,14 +60,55 @@ def segments(db):
             segs.append(seg)
     epochs = [PARAMS.epoch_of(_views(s)[0].slot) for s in segs]
     assert len(set(epochs)) >= 3 and epochs == sorted(epochs)
-    # a row-width step inside an epoch: two ViewColumns segments of one
-    # epoch whose CBOR rows differ in width
-    widths = [s.signed_bytes.shape[1] for s in segs]
-    assert any(e0 == e1 and w0 != w1 for e0, e1, w0, w1
-               in zip(epochs, epochs[1:], widths, widths[1:]))
+    # a row-width step inside an epoch: ONE ViewColumns segment whose
+    # bodies differ in length; the only two segments of one epoch are
+    # this fixture's own cut
+    assert any(isinstance(s, ViewColumns)
+               and len(set(s.signed_len.tolist())) > 1 for s in segs)
+    assert sum(e0 == e1 for e0, e1 in zip(epochs, epochs[1:])) == 2
     assert sum(len(s) == 1 for s in segs) >= 2
     return segs
 
+
+def test_stream_cuts_one_segment_an_epoch(db):
+    """From genesis the bodies step width (no previous hash, then block
+    numbers and slots crossing 24 and 256): the stream cuts at epoch
+    boundaries only, each segment holding every body layout of its
+    epoch."""
+    path, _ = db
+    imm = ana.open_immutable(path, validate_all=False)
+    segs = list(ana._epoch_window_segments(
+        PARAMS, ana._stream_windows(imm, ana.ValidationResult())))
+    epochs = [PARAMS.epoch_of(int(s.slot[0])) for s in segs]
+    assert all(isinstance(s, ViewColumns) for s in segs)
+    assert epochs == sorted(set(epochs)) and len(epochs) >= 3
+    assert all(len(set(PARAMS.epoch_of(s.slot).tolist())) == 1 for s in segs)
+    assert len(set(segs[0].signed_len.tolist())) > 1  # genesis, widths
+    assert sum(len(s) for s in segs) == imm.n_blocks()
+
+
+
+def test_stream_hands_whole_windows_over_as_it_reads(db):
+    """With `cut` (the replay's window size) an epoch's segments are its
+    whole windows from its start, then its rest: the rows and their
+    order are the uncut stream's, so the windows cut from them are too,
+    and the first is there before the epoch has been read."""
+    path, _ = db
+    imm = ana.open_immutable(path, validate_all=False)
+
+    def stream(**kw):
+        return list(ana._epoch_window_segments(
+            PARAMS, ana._stream_windows(imm, ana.ValidationResult()), **kw))
+
+    whole, cut = stream(), stream(cut=MAX_BATCH)
+    assert len(cut) > len(whole)
+    for seg in whole:
+        e = PARAMS.epoch_of(int(seg.slot[0]))
+        mine = [s for s in cut if PARAMS.epoch_of(int(s.slot[0])) == e]
+        assert [len(s) for s in mine[:-1]] == [MAX_BATCH] * (len(mine) - 1)
+        assert 0 < len(mine[-1]) <= MAX_BATCH
+        assert [hv.signed_bytes for s in mine for hv in s.views()] == [
+            hv.signed_bytes for hv in seg.views()]
 
 @pytest.fixture(scope="module")
 def reference(db, segments):
@@ -186,9 +228,11 @@ def test_invalid_header_discards_the_segments_behind_it(
         db, segments, reference, stubbed, monkeypatch, thread):
     monkeypatch.setenv("OCT_STAGE_THREAD", thread)
     # the LAST header of a segment that is not the stream's last: every
-    # window behind it belongs to a later segment
-    k = next(i for i, s in enumerate(segments)
-             if i >= 3 and i < len(segments) - 1 and len(s) >= 2)
+    # window behind it belongs to a later segment, of the same epoch (one
+    # staged without waiting for a nonce: the fixture's own cut)
+    epochs = [PARAMS.epoch_of(_views(s)[0].slot) for s in segments]
+    k = next(i for i in range(1, len(segments) - 1)
+             if epochs[i + 1] == epochs[i])
     bad_i = len(segments[k]) - 1
     segs = _bad_counter(segments, k, bad_i)
     n_before = sum(len(s) for s in segs[:k]) + bad_i
@@ -357,7 +401,8 @@ def _revalidate(db, **kw):
                           validate_all=False, max_batch=MAX_BATCH, **kw)
 
 
-@pytest.mark.parametrize("fault_at", [4, 7])
+# the chain's replay is seven windows (two an epoch, one for its last)
+@pytest.mark.parametrize("fault_at", [4, 5])
 def test_checkpoint_resume_mid_stream_is_verdict_identical(
         db, reference, stubbed, fresh_recovery, monkeypatch, tmp_path,
         fault_at):
@@ -457,10 +502,10 @@ def test_window_behind_a_segments_last_is_in_flight(db, segments,
         if seg_of[i + 1] != seg_of[i] and \
                 epochs[seg_of[i + 1]] == epochs[seg_of[i]]:
             # a segment's last window, its successor another segment's
-            # first (a row-width step, a one-header segment): in flight
+            # first (the fixture's one-header segments): in flight
             boundaries += 1
             assert s.inflight_behind >= 1, (i, seg_of[i])
-    assert boundaries >= 3
+    assert boundaries == 2
     # across an epoch boundary the successor waits for the freeze slot
     # only: somewhere a new epoch's first window was in flight behind
     # the old epoch's last
